@@ -25,12 +25,13 @@ entry header (a filename hash collision reads as a miss, never a wrong
 load) and the payload checksum (a torn/corrupt body quarantines the file
 and reads as a miss, never a crash).
 
-Entry format: `aot-<sha256[:40]>.bin` = 8-byte magic "NDSAOT1\\n",
+Entry format: `aot-<sha256[:40]>.bin` = 8-byte magic "NDSAOT2\\n",
 8-byte big-endian header length, canonical-JSON header (full key +
-payload sha256 + sizes), then the pickled (payload, in_tree, out_tree)
-from serialize_executable. Pickle is acceptable here: entries live in a
-user-owned cache directory and carry the same trust as the jax
-persistent compilation cache (the payload itself is pickle-based).
+payload sha256 + sizes + the ids of the devices the executable runs on),
+then the pickled (payload, in_tree, out_tree) from serialize_executable.
+Pickle is acceptable here: entries live in a user-owned cache directory
+and carry the same trust as the jax persistent compilation cache (the
+payload itself is pickle-based).
 
 Production treatment (the spill pool / lakehouse patterns):
   * atomic writes — pid-tempfile sibling + os.replace, so a concurrent
@@ -70,7 +71,7 @@ from .. import faults
 from .. import __version__ as _NDS_VERSION
 from .lockdebug import make_lock
 
-_MAGIC = b"NDSAOT1\n"
+_MAGIC = b"NDSAOT2\n"
 _ENTRY_PREFIX = "aot-"
 _ENTRY_SUFFIX = ".bin"
 _QUARANTINE_PREFIX = "quarantine-"
@@ -83,22 +84,31 @@ _BUDGET_LO = 256 << 20
 _BUDGET_HI = 32 << 30
 
 
+def compile_cache_root() -> str:
+    """The one directory both compile caches live under: where
+    `JAX_COMPILATION_CACHE_DIR` says when it is set, else the fixed
+    `.nds_cache/` at the root of this checkout (git-ignored). Never under
+    `$HOME` or a temporary name: the path is part of the XLA cache's key,
+    and a machine that is thrown away keeps only what its caller placed."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)
+        ))),
+        ".nds_cache",
+    )
+
+
 def resolve_aot_cache_dir(conf: dict | None = None) -> str | None:
     """Cache directory: conf `engine.aot_cache_dir`, env NDS_AOT_CACHE_DIR,
-    else a user-owned XDG default (same /tmp-squatting reasoning as the
-    XLA persistent cache in session._enable_persistent_compile_cache).
-    Explicit "" / "0" disables the AOT cache."""
+    else `aot_exec/` under `compile_cache_root()`, beside the XLA
+    persistent cache. Explicit "" / "0" disables the AOT cache."""
     v = None
     if conf:
         v = conf.get("engine.aot_cache_dir")
     if v is None:
         v = os.environ.get("NDS_AOT_CACHE_DIR")
     if v is None:
-        return os.path.join(
-            os.environ.get("XDG_CACHE_HOME")
-            or os.path.join(os.path.expanduser("~"), ".cache"),
-            "nds_aot_exec",
-        )
+        return os.path.join(compile_cache_root(), "aot_exec")
     v = str(v)
     return v if v not in ("", "0") else None
 
@@ -161,6 +171,39 @@ def environment_key() -> dict:
         "processes": jax.process_count(),
         "x64": bool(jax.config.jax_enable_x64),
     }
+
+
+class _ThreadTally(threading.local):
+    xla_cache_hits = 0
+
+
+_tally = _ThreadTally()
+# process-lifetime once-latch for the jax.monitoring listener below; worst
+# case under a race is a second listener and hits counted twice, which
+# still reads as "a hit happened"
+# nds-lint: disable=mutable-module-global
+_LISTENING = []
+
+
+def _on_jax_event(event: str, **_):
+    if event == "/jax/compilation_cache/cache_hits":
+        _tally.xla_cache_hits += 1
+
+
+def xla_cache_hits() -> int:
+    """How many compiles on THIS thread were served by jax's persistent
+    compilation cache so far (jax.monitoring reports each hit on the
+    compiling thread). A caller brackets a compile with two reads to learn
+    whether its executable was compiled here or loaded: a loaded XLA:CPU
+    executable re-serializes into an entry that deserializes cleanly and
+    then fails when it RUNS ("Function … not found", jax 0.9.0), out of
+    reach of any check `store` can make, so it must never be stored."""
+    if not _LISTENING:
+        import jax.monitoring
+
+        _LISTENING.append(True)
+        jax.monitoring.register_event_listener(_on_jax_event)
+    return _tally.xla_cache_hits
 
 
 def dictionary_hash(dictionary) -> str:
@@ -234,6 +277,7 @@ class AotCache:
         self.stats = {  # nds-guarded-by: _lock
             "lookups": 0, "disk_hits": 0, "misses": 0, "stores": 0,
             "store_failures": 0, "quarantined": 0, "evictions": 0,
+            "call_failures": 0,
         }
         self._store_disabled = False  # nds-guarded-by: _lock
 
@@ -325,11 +369,20 @@ class AotCache:
             with self._lock:
                 self.stats["misses"] += 1
             return None
+        body, device_ids = entry
         try:
+            import jax
             from jax.experimental import serialize_executable as se
 
-            payload, in_tree, out_tree = pickle.loads(entry)
-            compiled = se.deserialize_and_load(payload, in_tree, out_tree)
+            # load for the executable's OWN devices: left to its default,
+            # jax loads for every local device and the call then expects
+            # one shard of each argument per device
+            by_id = {d.id: d for d in jax.local_devices()}
+            payload, in_tree, out_tree = pickle.loads(body)
+            compiled = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids],
+            )
         except Exception as exc:
             self._quarantine(path, f"deserialize failed: {exc}")
             with self._lock:
@@ -348,9 +401,10 @@ class AotCache:
         return compiled
 
     def _parse_entry(self, raw: bytes, key: dict, path: str):
-        """Validated pickled blob from one raw entry, or None (quarantined).
-        Full-key equality — not just the filename hash — and a payload
-        checksum stand between a bad file and a wrong load."""
+        """(validated pickled blob, execution device ids) from one raw
+        entry, or None (quarantined). Full-key equality — not just the
+        filename hash — and a payload checksum stand between a bad file
+        and a wrong load."""
         try:
             if not raw.startswith(_MAGIC):
                 raise ValueError("bad magic")
@@ -372,7 +426,7 @@ class AotCache:
                 hashlib.sha256(body).hexdigest() != header.get("body_sha256")
             ):
                 raise ValueError("payload checksum mismatch")
-            return body
+            return body, [int(i) for i in header["devices"]]
         except Exception as exc:
             self._quarantine(path, str(exc))
             return None
@@ -409,14 +463,18 @@ class AotCache:
             from jax.experimental import serialize_executable as se
 
             payload, in_tree, out_tree = se.serialize(compiled)
-            # validate BEFORE publishing: an executable that was itself
-            # loaded from the XLA persistent compilation cache serializes
-            # into a payload whose symbol table cannot reload (observed
-            # on jax 0.4.37 CPU: "Symbols not found" at deserialize) —
-            # publishing it would make every future process quarantine it
-            # on first touch. One extra deserialize per STORE (compile-
-            # level rarity) buys "an entry on disk always loads".
-            se.deserialize_and_load(payload, in_tree, out_tree)
+            devices = compiled.runtime_executable().local_devices()
+            # validate BEFORE publishing: an unreloadable payload would
+            # make every future process quarantine it on first touch. One
+            # extra deserialize per STORE (compile-level rarity) buys "an
+            # entry on disk always loads". It does not buy "always runs":
+            # an executable that was itself loaded from the XLA persistent
+            # compilation cache failed right here on jax 0.4.37 ("Symbols
+            # not found"), but on 0.9.0 it reloads and fails only when
+            # called — callers keep those away (xla_cache_hits).
+            se.deserialize_and_load(
+                payload, in_tree, out_tree, execution_devices=devices
+            )
             body = pickle.dumps((payload, in_tree, out_tree))
         except Exception:
             # unserializable executable (backend without AOT support, or
@@ -430,6 +488,7 @@ class AotCache:
             "key": json.loads(canonical_key_bytes(key).decode("utf-8")),
             "body_bytes": len(body),
             "body_sha256": hashlib.sha256(body).hexdigest(),
+            "devices": [d.id for d in devices],
             "created": int(time.time()),
             "pid": os.getpid(),
         })
@@ -471,7 +530,13 @@ class AotCache:
 
     def quarantine_key(self, key: dict):
         """Quarantine the entry for `key` (a loaded executable that failed
-        at call time: keyed correctly but unusable on this runtime)."""
+        at call time: keyed correctly but unusable on this runtime). The
+        caller recompiles, so the failure is counted and emitted as its
+        own `call`/`failed` event — a recompile nobody hears of is how a
+        broken cache stays broken."""
+        with self._lock:
+            self.stats["call_failures"] += 1
+        self._emit("call", "failed", key=_entry_name(key))
         self._quarantine(
             os.path.join(self.dir, _entry_name(key)), "failed at call time"
         )

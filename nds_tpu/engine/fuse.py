@@ -64,6 +64,7 @@ from ..dtypes import FLOAT64, INT64
 from ..ops import kernels as K
 from . import expr as E
 from . import plan as P
+from .aotcache import xla_cache_hits
 from .columnar import Column, Table, bucket_cap, sort_dictionary
 from .expr import Evaluator
 
@@ -557,8 +558,14 @@ class _FusedBase:
         compiled = self._aot.load(key)
         if compiled is not None:
             return compiled, True
+        loaded_before = xla_cache_hits()
         compiled = self._aot_compile(flat, slots)
-        self._aot.store(key, compiled)
+        # only what was compiled HERE is persisted: an executable jax's
+        # own persistent cache served runs fine but re-serializes into an
+        # entry that fails at call time (aotcache.xla_cache_hits), and
+        # the next process gets it from that cache again anyway
+        if xla_cache_hits() == loaded_before:
+            self._aot.store(key, compiled)
         return compiled, False
 
     def _donate_slots(self, table: Table, flat) -> tuple:

@@ -37,10 +37,14 @@ _PERSISTENT_CACHE_SET = False
 
 
 def _enable_persistent_compile_cache():
-    """Point XLA's persistent compilation cache at a shared directory so the
-    99-query compile footprint is paid once per machine, not once per process
-    (cold query compiles dominate wall clock ~50x over steady-state
-    execution). Opt out with NDS_XLA_CACHE_DIR=0."""
+    """Turn on XLA's persistent compilation cache so the 99-query compile
+    footprint is paid once per machine, not once per process (cold query
+    compiles dominate wall clock ~50x over steady-state execution).
+
+    Where `JAX_COMPILATION_CACHE_DIR` is set, jax has already read it and
+    no directory is set in code; where it is not, the cache goes to the
+    fixed in-checkout directory (aotcache.compile_cache_root), which the
+    AOT executable cache shares."""
     # process-wide once-latch, not per-stream state: worst case under a
     # race is a second, idempotent jax.config.update with the same values
     # nds-lint: disable=mutable-module-global
@@ -48,34 +52,23 @@ def _enable_persistent_compile_cache():
     if _PERSISTENT_CACHE_SET:
         return
     _PERSISTENT_CACHE_SET = True
-    # user-owned default (XDG): a /tmp default could be pre-created by any
-    # other local user (/tmp squatting), putting cache entries in an
-    # attacker-owned directory
-    default_dir = os.path.join(
-        os.environ.get("XDG_CACHE_HOME")
-        or os.path.join(os.path.expanduser("~"), ".cache"),
-        "nds_xla",
-    )
-    cache_dir = os.environ.get("NDS_XLA_CACHE_DIR", default_dir)
-    if not cache_dir or cache_dir == "0":
-        return
-    try:
-        import jax
+    import jax
 
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # NDS_XLA_CACHE_MIN_COMPILE_S=0 persists even sub-100ms kernel
-        # compiles — the cold-start gate (tools/fuse_microbench.py) and
-        # fleets whose cold cost is MANY small kernels want everything on
-        # disk; the 0.1 s default keeps steady-state dev runs from
-        # churning the cache with trivial entries
-        min_s = os.environ.get("NDS_XLA_CACHE_MIN_COMPILE_S")
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(min_s) if min_s else 0.1,
-        )
-    except Exception:
-        pass  # older jax without the knobs: in-memory cache only
+    from .aotcache import compile_cache_root
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", compile_cache_root())
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # NDS_XLA_CACHE_MIN_COMPILE_S=0 persists even sub-100ms kernel
+    # compiles — the cold-start gate (tools/fuse_microbench.py) and
+    # fleets whose cold cost is MANY small kernels want everything on
+    # disk; the 0.1 s default keeps steady-state dev runs from
+    # churning the cache with trivial entries
+    min_s = os.environ.get("NDS_XLA_CACHE_MIN_COMPILE_S")
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(min_s) if min_s else 0.1,
+    )
 
 
 class _PlanResultCache:

@@ -336,6 +336,7 @@ def profile_events(events) -> dict:
         "aot_misses": 0,
         "aot_stores": 0,
         "aot_quarantined": 0,
+        "aot_call_failures": 0,
         "aot_evictions": 0,
         "pipelines_fused": 0,
         "pipelines_eager": 0,
@@ -437,6 +438,8 @@ def profile_events(events) -> dict:
                     tallies["aot_misses"] += 1
             elif op == "store" and result == "stored":
                 tallies["aot_stores"] += 1
+            elif op == "call":
+                tallies["aot_call_failures"] += 1
             elif op == "evict":
                 tallies["aot_evictions"] += int(ev.get("entries") or 0)
         elif k == "pipeline_span":

@@ -92,7 +92,9 @@ EVENT_SCHEMA = {
     "exec_cache": ("pipeline", "bucket", "hit"),
     # persistent AOT executable cache activity (engine/aotcache.py):
     # op "load" (result hit | miss | key_mismatch | quarantined), "store"
-    # (stored | io_error | unserializable), "evict", "vacuum". Optional:
+    # (stored | io_error | unserializable), "call" (failed: a loaded
+    # executable raised when called and was quarantined), "evict",
+    # "vacuum". Optional:
     # bytes, dur_ms, key, entries, removed, error. A `load`/`hit` event in
     # a fresh process is the trace-level evidence an executable came from
     # disk instead of a recompile (the two-process microbench gate reads

@@ -233,10 +233,10 @@ def _compact_full_sorted(mask: jnp.ndarray) -> jnp.ndarray:
 
     Re-tested 2026-08-07 on jax 0.4.37: an 8-way forced-host-device mesh
     (xla_force_host_platform_device_count) lowers the scatter path
-    correctly on CPU, so the mislowering is specific to the XLA:TPU SPMD
-    pipeline and CANNOT be re-verified from this host. Keep the sorted
-    route for sharded masks until the mesh-vs-oracle gate passes with it
-    removed on real TPU devices."""
+    correctly on CPU, so the mislowering was specific to the XLA:TPU SPMD
+    pipeline. Not re-tested on jax 0.9.0, which is what is installed now.
+    Keep the sorted route for sharded masks until the mesh-vs-oracle gate
+    passes with it removed on real TPU devices."""
     n = mask.shape[0]
     perm = sort_by_words([(~mask).astype(jnp.int64)])
     count = jnp.sum(mask, dtype=jnp.int32)

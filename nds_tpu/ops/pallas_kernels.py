@@ -29,38 +29,87 @@ import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
 
-ROW_TILE = 2048     # fact rows per grid step
-GROUP_TILE = 512    # group columns per grid step (VMEM: one-hot 4 MB f32)
+ROW_TILE = 2048     # fact rows per one-hot tile (the lane dimension)
+GROUP_TILE = 512    # group rows per grid step (VMEM: one-hot 4 MB f32)
+_SUB = 8            # one-hot tiles per grid step: the sublanes of a block
+
+
+# Layout, shared by every kernel here. The row dimension is reshaped to
+# (n / t, t) and a grid step takes an (8, t) block: Mosaic wants blocks of
+# at least 8 sublanes by 128 lanes, and 8 * t rows make a DMA worth
+# issuing. Inside a step the eight (1, t) rows are folded one at a time,
+# each against a one-hot tile held TRANSPOSED, groups on sublanes and rows
+# on lanes: `groups (g, t) == key (1, t)` is a sublane broadcast of the row
+# as it lies in memory, where the (t, 1) column the untransposed tile needs
+# would be a relayout of every block.
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _row_tiling(n: int, row_tile: int):
+    """(t, padded n): lanes per one-hot tile and n rounded up to whole
+    (8, t) blocks."""
+    t = min(row_tile, _round_up(max(-(-n // _SUB), 1), 128))
+    return t, _round_up(n, _SUB * t)
+
+
+def _group_tiling(n_groups: int):
+    gt = min(GROUP_TILE, _round_up(n_groups, 128))
+    return gt, _round_up(n_groups, gt)
+
+
+def _tile_ids(tile: int, t: int, j):
+    """(tile, t) int32: the ids of group tile `j` down the sublanes, the
+    same in every lane — what a (1, t) row of keys is compared with."""
+    return jax.lax.broadcasted_iota(jnp.int32, (tile, t), 0) + j * tile
+
+
+def _zero():
+    """Block index 0 as int32: under x64 a literal 0 in an index map is an
+    int64, which Mosaic refuses beside the int32 program ids."""
+    return jnp.int32(0)
+
+
+def _fold_rows(body, init):
+    """Fold the eight rows of the current block: body(r, acc) -> acc.
+    int32 bounds: under x64 a Python-int loop index is 64 bits wide, which
+    Mosaic has no vector layout for."""
+    return jax.lax.fori_loop(jnp.int32(0), jnp.int32(_SUB), body, init)
 
 
 def _seg_kernel(group_tile: int, vals_ref, gid_ref, out_ref):
     j = pl.program_id(0)  # group tile (outer)
-    i = pl.program_id(1)  # row tile (inner)
+    i = pl.program_id(1)  # row block (inner)
 
     @pl.when(i == 0)
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
     t = vals_ref.shape[1]
-    vals = vals_ref[0, :]
-    gid = gid_ref[0, :]
-    base = j * group_tile
-    cols = jax.lax.broadcasted_iota(jnp.int32, (t, group_tile), 1) + base
-    onehot = (gid.reshape(t, 1) == cols).astype(jnp.float32)
-    left = jnp.concatenate(
-        [
-            vals.reshape(1, t),
-            jnp.ones((1, t), jnp.float32),
-            jnp.zeros((6, t), jnp.float32),
-        ]
-    )
-    # HIGHEST precision: the TPU MXU default multiplies f32 via bf16 passes
-    # (~8 mantissa bits), which would break the "exact for measures with
-    # <= 24 significant bits" contract; full-precision f32 passes keep it
-    out_ref[:] += jnp.dot(
-        left, onehot, preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )
+    groups = _tile_ids(group_tile, t, j)
+    lrow = jax.lax.broadcasted_iota(jnp.int32, (8, t), 0)
+
+    def body(r, acc):
+        vals = vals_ref[pl.ds(r, 1), :]
+        onehot_t = (groups == gid_ref[pl.ds(r, 1), :]).astype(jnp.float32)
+        # row 0 carries the measure, row 1 ones: one dot, sums and counts
+        left = jnp.where(
+            lrow == 0, vals,
+            jnp.where(lrow == 1, jnp.float32(1.0), jnp.float32(0.0)),
+        )
+        # HIGHEST precision: the TPU MXU default multiplies f32 via bf16
+        # passes (~8 mantissa bits), which would break the "exact for
+        # measures with <= 24 significant bits" contract; full-precision
+        # f32 passes keep it
+        return acc + jax.lax.dot_general(
+            left, onehot_t, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+
+    out_ref[:] += _fold_rows(body, jnp.zeros(out_ref.shape, jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("n_groups", "interpret"))
@@ -72,21 +121,18 @@ def segment_sums_pallas(vals, gid, n_groups: int, interpret: bool = False):
     if n == 0:  # grid of zero steps would return the output uninitialized
         z = jnp.zeros(n_groups, jnp.float32)
         return z, z
-    # lane-dim blocks must be 128-multiples for Mosaic
-    t = -(-max(128, min(ROW_TILE, n)) // 128) * 128
-    n_pad = -(-n // t) * t
-    gt = min(GROUP_TILE, -(-n_groups // 128) * 128)
-    g_pad = -(-n_groups // gt) * gt
+    t, n_pad = _row_tiling(n, ROW_TILE)
+    gt, g_pad = _group_tiling(n_groups)
     vals = jnp.pad(vals.astype(jnp.float32), (0, n_pad - n))
     gid = jnp.pad(gid.astype(jnp.int32), (0, n_pad - n), constant_values=-1)
     out = pl.pallas_call(
         functools.partial(_seg_kernel, gt),
-        grid=(g_pad // gt, n_pad // t),
+        grid=(g_pad // gt, n_pad // (_SUB * t)),
         in_specs=[
-            pl.BlockSpec((1, t), lambda j, i: (i, 0)),
-            pl.BlockSpec((1, t), lambda j, i: (i, 0)),
+            pl.BlockSpec((_SUB, t), lambda j, i: (i, _zero())),
+            pl.BlockSpec((_SUB, t), lambda j, i: (i, _zero())),
         ],
-        out_specs=pl.BlockSpec((8, gt), lambda j, i: (0, j)),
+        out_specs=pl.BlockSpec((8, gt), lambda j, i: (_zero(), j)),
         out_shape=jax.ShapeDtypeStruct((8, g_pad), jnp.float32),
         interpret=interpret,
     )(vals.reshape(-1, t), gid.reshape(-1, t))
@@ -94,37 +140,35 @@ def segment_sums_pallas(vals, gid, n_groups: int, interpret: bool = False):
 
 
 def _seg_extreme_kernel(group_tile: int, is_max: bool, vals_ref, gid_ref,
-                        out_ref):
+                        ext_ref, cnt_ref):
     j = pl.program_id(0)  # group tile (outer)
-    i = pl.program_id(1)  # row tile (inner)
+    i = pl.program_id(1)  # row block (inner)
     fill = jnp.float32(-jnp.inf if is_max else jnp.inf)
 
     @pl.when(i == 0)
     def _():
-        ridx = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
-        out_ref[:] = jnp.where(ridx == 0, fill, jnp.float32(0.0))
+        ext_ref[:] = jnp.full(ext_ref.shape, fill, jnp.float32)
+        cnt_ref[:] = jnp.zeros_like(cnt_ref)
 
     t = vals_ref.shape[1]
-    vals = vals_ref[0, :]
-    gid = gid_ref[0, :]
-    base = j * group_tile
-    cols = jax.lax.broadcasted_iota(jnp.int32, (t, group_tile), 1) + base
-    onehot = gid.reshape(t, 1) == cols
-    masked = jnp.where(onehot, vals.reshape(t, 1), fill)
-    tile_ext = (
-        jnp.max(masked, axis=0) if is_max else jnp.min(masked, axis=0)
-    )
-    tile_cnt = jnp.sum(onehot.astype(jnp.float32), axis=0)
-    cur = out_ref[:]
-    ext = (
-        jnp.maximum(cur[0, :], tile_ext)
-        if is_max
-        else jnp.minimum(cur[0, :], tile_ext)
-    )
-    cnt = cur[1, :] + tile_cnt
-    out_ref[:] = jnp.concatenate(
-        [ext.reshape(1, -1), cnt.reshape(1, -1), cur[2:, :]]
-    )
+    groups = _tile_ids(group_tile, t, j)
+    reduce = jnp.max if is_max else jnp.min
+    pick = jnp.maximum if is_max else jnp.minimum
+
+    def body(r, acc):
+        ext, cnt = acc
+        onehot_t = groups == gid_ref[pl.ds(r, 1), :]
+        masked = jnp.where(onehot_t, vals_ref[pl.ds(r, 1), :], fill)
+        return (
+            pick(ext, reduce(masked, axis=1, keepdims=True)),
+            cnt + jnp.sum(
+                onehot_t.astype(jnp.float32), axis=1, keepdims=True
+            ),
+        )
+
+    ext, cnt = _fold_rows(body, (ext_ref[:], cnt_ref[:]))
+    ext_ref[:] = ext
+    cnt_ref[:] = cnt
 
 
 @functools.partial(
@@ -146,52 +190,49 @@ def segment_extreme_pallas(vals, gid, n_groups: int, is_max: bool,
             jnp.full(n_groups, fill, jnp.float32),
             jnp.zeros(n_groups, jnp.float32),
         )
-    t = -(-max(128, min(ROW_TILE, n)) // 128) * 128
-    n_pad = -(-n // t) * t
-    gt = min(GROUP_TILE, -(-n_groups // 128) * 128)
-    g_pad = -(-n_groups // gt) * gt
+    t, n_pad = _row_tiling(n, ROW_TILE)
+    gt, g_pad = _group_tiling(n_groups)
     vals = jnp.pad(vals.astype(jnp.float32), (0, n_pad - n))
     gid = jnp.pad(gid.astype(jnp.int32), (0, n_pad - n), constant_values=-1)
-    out = pl.pallas_call(
+    # per-group results come out of a lane reduction, so they lie along
+    # sublanes: (g, 1) columns, one per output
+    col = pl.BlockSpec((gt, 1), lambda j, i: (j, _zero()))
+    ext, cnt = pl.pallas_call(
         functools.partial(_seg_extreme_kernel, gt, is_max),
-        grid=(g_pad // gt, n_pad // t),
+        grid=(g_pad // gt, n_pad // (_SUB * t)),
         in_specs=[
-            pl.BlockSpec((1, t), lambda j, i: (i, 0)),
-            pl.BlockSpec((1, t), lambda j, i: (i, 0)),
+            pl.BlockSpec((_SUB, t), lambda j, i: (i, _zero())),
+            pl.BlockSpec((_SUB, t), lambda j, i: (i, _zero())),
         ],
-        out_specs=pl.BlockSpec((8, gt), lambda j, i: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((8, g_pad), jnp.float32),
+        out_specs=[col, col],
+        out_shape=[jax.ShapeDtypeStruct((g_pad, 1), jnp.float32)] * 2,
         interpret=interpret,
     )(vals.reshape(-1, t), gid.reshape(-1, t))
-    return out[0, :n_groups], out[1, :n_groups]
+    return ext[:n_groups, 0], cnt[:n_groups, 0]
 
 
-def _dense_build_kernel(domain_tile: int, slot_ref, rowid_ref, out_ref):
+def _dense_build_kernel(domain_tile: int, slot_ref, out_ref):
     j = pl.program_id(0)  # domain tile (outer)
-    i = pl.program_id(1)  # row tile (inner)
+    i = pl.program_id(1)  # row block (inner)
 
     @pl.when(i == 0)
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
     t = slot_ref.shape[1]
-    slot = slot_ref[0, :]      # -1 = dead / out-of-range (never matches)
-    rowid = rowid_ref[0, :]
-    base = j * domain_tile
-    cols = jax.lax.broadcasted_iota(jnp.int32, (t, domain_tile), 1) + base
-    onehot = slot.reshape(t, 1) == cols
-    pres_tile = jnp.max(onehot.astype(jnp.int32), axis=0)
-    rows_tile = jnp.max(
-        jnp.where(onehot, rowid.reshape(t, 1), jnp.int32(0)), axis=0
-    )
-    cur = out_ref[:]
-    out_ref[:] = jnp.concatenate(
-        [
-            jnp.maximum(cur[0, :], pres_tile).reshape(1, -1),
-            jnp.maximum(cur[1, :], rows_tile).reshape(1, -1),
-            cur[2:, :],
-        ]
-    )
+    slots = _tile_ids(domain_tile, t, j)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
+
+    def body(r, acc):
+        # row index + 1, so that 0 is "no row": one maximum gives both
+        # presence and the row. -1 = dead / out-of-range (never matches)
+        rowid1 = lane + ((i * _SUB + r) * t + 1)
+        hit = jnp.where(
+            slots == slot_ref[pl.ds(r, 1), :], rowid1, jnp.int32(0)
+        )
+        return jnp.maximum(acc, jnp.max(hit, axis=1, keepdims=True))
+
+    out_ref[:] = _fold_rows(body, out_ref[:])
 
 
 @functools.partial(jax.jit, static_argnames=("table_cap", "interpret"))
@@ -215,26 +256,19 @@ def dense_build_pallas(rkey, rlive, rmin, table_cap: int,
             jnp.zeros(table_cap, bool),
             jnp.zeros(table_cap, jnp.int32),
         )
-    t = -(-max(128, min(ROW_TILE, n)) // 128) * 128
-    n_pad = -(-n // t) * t
-    gt = min(GROUP_TILE, -(-table_cap // 128) * 128)
-    g_pad = -(-table_cap // gt) * gt
+    t, n_pad = _row_tiling(n, ROW_TILE)
+    gt, g_pad = _group_tiling(table_cap)
     slot = jnp.pad(slot, (0, n_pad - n), constant_values=-1)
-    rowid = jnp.pad(
-        jnp.arange(n, dtype=jnp.int32), (0, n_pad - n), constant_values=0
-    )
     out = pl.pallas_call(
         functools.partial(_dense_build_kernel, gt),
-        grid=(g_pad // gt, n_pad // t),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda j, i: (i, 0)),
-            pl.BlockSpec((1, t), lambda j, i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((8, gt), lambda j, i: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((8, g_pad), jnp.int32),
+        grid=(g_pad // gt, n_pad // (_SUB * t)),
+        in_specs=[pl.BlockSpec((_SUB, t), lambda j, i: (i, _zero()))],
+        out_specs=pl.BlockSpec((gt, 1), lambda j, i: (j, _zero())),
+        out_shape=jax.ShapeDtypeStruct((g_pad, 1), jnp.int32),
         interpret=interpret,
-    )(slot.reshape(-1, t), rowid.reshape(-1, t))
-    return out[0, :table_cap] > 0, out[1, :table_cap]
+    )(slot.reshape(-1, t))
+    rowid1 = out[:table_cap, 0]
+    return rowid1 > 0, jnp.maximum(rowid1 - 1, 0)
 
 
 #: counting-sort routing caps (exec._sort_perm_route gates on them): the
@@ -246,12 +280,13 @@ SORT_MAX_ROWS = 1 << 24
 
 
 def _sort_rank_kernel(vals_ref, rank_ref, hist_ref):
-    """One row tile of the stable counting-rank: rank[r] = (# rows with
+    """One row block of the stable counting-rank: rank[r] = (# rows with
     the same key in PREVIOUS tiles) + (# earlier rows with the same key in
     THIS tile). The running per-key histogram rides the hist output block
     (revisited across the sequential grid, the same accumulation pattern
-    as the segment kernels); its final state is the key histogram the
-    caller turns into counting-sort offsets."""
+    as the segment kernels), a (g, 1) column like every per-key result
+    here; its final state is the key histogram the caller turns into
+    counting-sort offsets."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -259,34 +294,30 @@ def _sort_rank_kernel(vals_ref, rank_ref, hist_ref):
         hist_ref[:] = jnp.zeros_like(hist_ref)
 
     t = vals_ref.shape[1]
-    g = hist_ref.shape[1]
-    vals = vals_ref[0, :]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (t, g), 1)
-    onehot = (vals.reshape(t, 1) == cols).astype(jnp.float32)
-    carry = hist_ref[0, :]
-    # rank contribution from previous tiles: each row gathers its key's
-    # running count via its one-hot row (a (t,g)x(g,1) matmul-gather)
-    prev = jnp.dot(
-        onehot, carry.reshape(g, 1),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )[:, 0]
-    # within-tile stable rank: strictly-lower-triangular ones L gives
-    # (L @ onehot)[r, key] = earlier same-key rows; gather own column
-    rows_i = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
-    cols_i = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
-    tril = (cols_i < rows_i).astype(jnp.float32)
-    la = jnp.dot(
-        tril, onehot,
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )
-    within = jnp.sum(la * onehot, axis=1)
-    rank_ref[0, :] = prev + within
-    new_hist = carry + jnp.sum(onehot, axis=0)
-    hist_ref[:] = jnp.concatenate(
-        [new_hist.reshape(1, -1), jnp.zeros((7, g), jnp.float32)]
-    )
+    g = hist_ref.shape[0]
+    keys = jax.lax.broadcasted_iota(jnp.int32, (g, t), 0)
+    # strictly upper triangular ones: (onehot_t @ upper)[k, r] counts the
+    # rows c < r of this tile with key k. 0/1 operands are exact in bf16
+    # and the counts (<= t) in the f32 accumulator.
+    upper = (
+        jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+        < jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+    ).astype(jnp.bfloat16)
+
+    def body(r, carry):
+        onehot_t = keys == vals_ref[pl.ds(r, 1), :]
+        onehot_f = onehot_t.astype(jnp.float32)
+        before = jnp.dot(
+            onehot_t.astype(jnp.bfloat16), upper,
+            preferred_element_type=jnp.float32,
+        )
+        # each row picks its own key's entry out of a (g, t) column stack
+        rank_ref[pl.ds(r, 1), :] = jnp.sum(
+            onehot_f * (carry + before), axis=0, keepdims=True
+        )
+        return carry + jnp.sum(onehot_f, axis=1, keepdims=True)
+
+    hist_ref[:] = _fold_rows(body, hist_ref[:])
 
 
 @functools.partial(jax.jit, static_argnames=("domain", "interpret"))
@@ -297,29 +328,28 @@ def sort_rank_pallas(vals, domain: int, interpret: bool = False):
     holds the whole padded domain), n <= SORT_MAX_ROWS (f32-exact
     counts)."""
     n = vals.shape[0]
-    g = -(-max(domain, 128) // 128) * 128
+    g = _round_up(max(domain, 1), 128)
     if n == 0:
         return jnp.zeros(0, jnp.float32), jnp.zeros(domain, jnp.float32)
-    t = -(-max(128, min(SORT_ROW_TILE, n)) // 128) * 128
-    n_pad = -(-n // t) * t
+    t, n_pad = _row_tiling(n, SORT_ROW_TILE)
     vals = jnp.pad(
         vals.astype(jnp.int32), (0, n_pad - n), constant_values=-1
     )
     rank, hist = pl.pallas_call(
         _sort_rank_kernel,
-        grid=(n_pad // t,),
-        in_specs=[pl.BlockSpec((1, t), lambda i: (i, 0))],
+        grid=(n_pad // (_SUB * t),),
+        in_specs=[pl.BlockSpec((_SUB, t), lambda i: (i, _zero()))],
         out_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((8, g), lambda i: (0, 0)),
+            pl.BlockSpec((_SUB, t), lambda i: (i, _zero())),
+            pl.BlockSpec((g, 1), lambda i: (_zero(), _zero())),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_pad // t, t), jnp.float32),
-            jax.ShapeDtypeStruct((8, g), jnp.float32),
+            jax.ShapeDtypeStruct((g, 1), jnp.float32),
         ],
         interpret=interpret,
     )(vals.reshape(-1, t))
-    return rank.reshape(-1)[:n], hist[0, :domain]
+    return rank.reshape(-1)[:n], hist[:domain, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("domain", "interpret"))

@@ -22,16 +22,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _sm
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.8 top-level API; older releases: experimental module
-    from jax import shard_map as _sm
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+def shard_map(f, mesh, in_specs, out_specs):
+    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 jax.config.update("jax_enable_x64", True)
 
@@ -39,6 +36,11 @@ jax.config.update("jax_enable_x64", True)
 def make_mesh(n_devices=None, axis="data"):
     devs = jax.devices()
     if n_devices is not None:
+        if n_devices > len(devs):
+            raise ValueError(
+                f"a {n_devices}-device mesh was asked for and jax reports "
+                f"{len(devs)} {devs[0].platform} device(s)"
+            )
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (axis,))
 

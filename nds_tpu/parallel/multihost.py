@@ -32,18 +32,13 @@ def _enable_cpu_collectives(jax) -> None:
     must land BEFORE the backend initializes; only touched when the
     process is pinned to the CPU platform — TPU pods keep native ICI/DCN
     collectives."""
-    try:
-        platforms = str(
-            getattr(jax.config, "jax_platforms", None)
-            or os.environ.get("JAX_PLATFORMS")
-            or ""
-        )
-        if "cpu" in platforms.lower():
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        # older/newer jax without the knob: initialize() then surfaces the
-        # real capability error instead of this helper masking it
-        pass
+    platforms = str(
+        getattr(jax.config, "jax_platforms", None)
+        or os.environ.get("JAX_PLATFORMS")
+        or ""
+    )
+    if "cpu" in platforms.lower():
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def initialize(coordinator_address=None, num_processes=None, process_id=None):

@@ -9,6 +9,7 @@ import os
 import subprocess
 import sys
 import textwrap
+import time
 
 import numpy as np
 import pyarrow as pa
@@ -206,6 +207,84 @@ def test_corrupt_entry_is_quarantined_miss(tmp_path, damage):
     assert cache.load(key) is not None
 
 
+def test_loaded_executable_runs_on_its_own_devices(tmp_path):
+    """An entry is loaded for the devices it was compiled for, not for
+    every local device: on this 8-device CPU mesh the latter makes the
+    call expect eight shards of each argument (jax 0.9.0)."""
+    cache = _cache(tmp_path)
+    key = _key(cache)
+    assert cache.store(key, _tiny_compiled())
+    loaded = cache.load(key)
+    out = loaded(jnp.arange(16, dtype=jnp.float32))
+    assert out.tolist() == [2.0 * i + 1.0 for i in range(16)]
+    assert cache.stats["call_failures"] == 0
+
+
+def test_call_time_failure_is_counted_and_quarantined(tmp_path):
+    """fuse._dispatch recompiles when a loaded executable raises; the
+    cache must not let that pass in silence."""
+    from nds_tpu.obs.trace import Tracer
+
+    tracer = Tracer()
+    cache = AC.AotCache(str(tmp_path / "aot"), 1 << 30, tracer=tracer)
+    key = _key(cache)
+    assert cache.store(key, _tiny_compiled())
+    cache.quarantine_key(key)
+    assert cache.stats["call_failures"] == 1
+    assert cache.stats["quarantined"] == 1
+    assert cache.load(key) is None  # gone from the committed entries
+    assert [
+        (e["op"], e["result"]) for e in tracer.events
+        if e["kind"] == "aot_cache" and e["op"] == "call"
+    ] == [("call", "failed")]
+
+
+@pytest.mark.parametrize("placed", [True, False])
+def test_both_compile_caches_land_under_one_root(tmp_path, monkeypatch,
+                                                 placed):
+    """`JAX_COMPILATION_CACHE_DIR` set: no directory is set in code and
+    the AOT cache is a subdirectory of it. Unset: one fixed directory
+    inside the checkout, never one under $HOME."""
+    from nds_tpu.engine import session as S
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("NDS_AOT_CACHE_DIR", raising=False)
+    root = str(tmp_path) if placed else os.path.join(repo, ".nds_cache")
+    assert AC.compile_cache_root() == root
+    assert AC.resolve_aot_cache_dir({}) == os.path.join(root, "aot_exec")
+    monkeypatch.setattr(S, "_PERSISTENT_CACHE_SET", False)
+    jax.config.update("jax_compilation_cache_dir", "untouched")
+    S._enable_persistent_compile_cache()
+    assert jax.config.jax_compilation_cache_dir == (
+        "untouched" if placed else root
+    )
+
+
+def test_executable_served_by_the_xla_cache_is_not_stored(tmp_path):
+    """Only what was compiled in this process is persisted: an executable
+    jax's own persistent cache served re-serializes into an entry that
+    loads and then fails when it runs (XLA:CPU, jax 0.9.0)."""
+    prev = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    try:
+        s1 = _session(tmp_path / "a")
+        ref = s1.sql(QUERY).collect().to_pylist()
+        assert s1.aot_cache.stats["stores"] >= 1
+        # an empty AOT dir beside a warm XLA cache
+        s2 = _session(tmp_path / "b")
+        assert s2.sql(QUERY).collect().to_pylist() == ref
+        assert s2.aot_cache.stats["misses"] >= 1
+        assert s2.aot_cache.stats["stores"] == 0
+    finally:
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", prev
+        )
+
+
 def test_poisoned_entry_end_to_end_recompiles_correctly(tmp_path):
     """The acceptance contract at the session level: corrupt every stored
     entry behind a warmed cache dir — a fresh session must still return
@@ -336,6 +415,9 @@ def test_eviction_accounting_lru_to_budget(tmp_path):
     # room for ~two entries: the third store must evict the LRU one
     cache.budget = int(size * 2.5)
     assert cache.store(k2, _tiny_compiled(2.0))
+    # file times tick with the kernel's coarse clock (milliseconds): give
+    # the refresh a later tick than k2's write, or the two tie
+    time.sleep(0.05)
     assert cache.load(k1) is not None  # refresh k1: k2 becomes LRU
     assert cache.store(k3, _tiny_compiled(3.0))
     n, total = cache.usage()

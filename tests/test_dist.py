@@ -190,3 +190,10 @@ def test_multihost_single_process_degenerates(mesh):
     np.testing.assert_array_equal(np.asarray(arr), rows)
     # actually sharded: each device holds 1/N of the rows
     assert len(arr.sharding.device_set) == N_DEV
+
+
+def test_make_mesh_refuses_more_devices_than_there_are():
+    """A mesh of fewer devices than were asked for is a different
+    deployment, not a smaller one of the same."""
+    with pytest.raises(ValueError, match="99-device mesh"):
+        make_mesh(99)

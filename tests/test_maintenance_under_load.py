@@ -274,7 +274,7 @@ def warehouse(data_dir, tmp_path_factory):
         [sys.executable, "-m", "nds_tpu.cli.transcode", data_dir, str(wh),
          str(wh / "load.report"), "--output_format", "lakehouse"],
         check=True, capture_output=True, cwd=REPO,
-        env={**os.environ, "NDS_PLATFORM": "cpu"},
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     return wh
 

@@ -1,6 +1,7 @@
 """Pallas kernel tests (interpret mode — runs the real kernel logic on the
-CPU mesh; the compiled TPU lowering needs real hardware and is exercised by
-enabling engine.pallas_agg=on in a power run on-chip)."""
+CPU mesh). What the chip's compiler makes of the same kernels is
+tests/test_chip_compile.py; whether they run right and fast on the chip
+is an on-chip A/B with engine.pallas_*=on (ROADMAP C2)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -31,6 +32,7 @@ def _oracle(vals, gid, n_groups):
         (4096, 300),      # multiple row tiles, group padding
         (2048, 700),      # multiple group tiles
         (100, 1),         # single group
+        (40000, 600),     # several (8, 2048) row blocks x two group tiles
     ],
 )
 def test_segment_sums_pallas_matches_oracle(n, n_groups):
@@ -81,6 +83,7 @@ def _extreme_oracle(vals, gid, n_groups, is_max):
         (4096, 300),      # multiple row tiles, group padding
         (2048, 700),      # multiple group tiles
         (100, 1),         # single group
+        (40000, 600),     # several (8, 2048) row blocks x two group tiles
     ],
 )
 def test_segment_extreme_pallas_matches_oracle(n, n_groups, is_max):
@@ -117,7 +120,7 @@ def test_segment_extreme_all_dead_rows():
 
 @pytest.mark.parametrize(
     "n,table_cap",
-    [(500, 128), (4096, 1024), (100, 2048), (0, 256)],
+    [(500, 128), (4096, 1024), (100, 2048), (0, 256), (40000, 8192)],
 )
 def test_dense_build_pallas_matches_jnp(n, table_cap):
     from nds_tpu.ops import kernels as K
@@ -221,6 +224,7 @@ def test_pallas_agg_wired_through_sql():
         (700, 1),       # constant key (all-equal: stability visible)
         (1000, 129),    # domain padding
         (4096, 2000),   # multiple row tiles, near the domain cap
+        (9000, 300),    # several (8, 256) row blocks: the carried histogram
     ],
 )
 def test_sort_perm_pallas_matches_canonical_kernel(n, dom):

@@ -246,7 +246,7 @@ def _run_aot_child(cache_dir, trace_dir, xla_cache_dir):
     # entry points) the AOT layer deliberately does not own. A fresh
     # temp dir per gate run keeps both halves honest: nothing is warm
     # until process A warms it.
-    env["NDS_XLA_CACHE_DIR"] = xla_cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = xla_cache_dir
     # persist even sub-100ms kernel compiles: on CPU the canonical
     # kernels each compile in ~10ms, and 100+ of them ARE the cold start
     env["NDS_XLA_CACHE_MIN_COMPILE_S"] = "0"

@@ -1,8 +1,8 @@
 """Dev tool: profile per-jit compile time for one query on the real chip.
 
 Usage: python profile_compile.py query34 [query22 ...]
-Runs each query cold (fresh in-process cache; NDS_XLA_CACHE_DIR should point
-somewhere empty to measure true cold) and logs every XLA compile with its
+Runs each query cold (fresh in-process cache; JAX_COMPILATION_CACHE_DIR should
+point somewhere empty to measure true cold) and logs every XLA compile with its
 duration, sorted descending.
 """
 import logging
@@ -10,7 +10,7 @@ import os
 import sys
 import time
 
-os.environ.setdefault("NDS_XLA_CACHE_DIR", "/tmp/nds_profile_cache")
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/nds_profile_cache")
 
 import jax
 

@@ -1,4 +1,4 @@
-"""q3 regression closer (docs/q3_regression.md): assert the join-order
+"""q3 regression closer (ROADMAP A6): assert the join-order
 memo holds the q3 shape's steady-state throughput.
 
 Round 5 measured q3 at 2.92M fact-rows/s vs round 4's 3.31M — one extra

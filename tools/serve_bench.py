@@ -139,7 +139,7 @@ def _ensure_assets():
              os.path.join(wh, "load.report"), "--output_format", "lakehouse",
              "--output_mode", "overwrite"],
             check=True, capture_output=True, cwd=REPO,
-            env={**os.environ, "NDS_PLATFORM": "cpu"},
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
         open(os.path.join(wh, ".complete"), "w").close()
     dm_path = os.path.join(wh, "serve_dm")

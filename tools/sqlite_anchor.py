@@ -26,6 +26,8 @@ sys.path.insert(
     0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tests")
 )
 
+import pyarrow as pa  # noqa: E402
+
 from nds_tpu.datagen.query_streams import generate_streams  # noqa: E402
 from nds_tpu.io.csv import read_dat_dir  # noqa: E402
 from nds_tpu.power import gen_sql_from_stream  # noqa: E402
@@ -36,30 +38,34 @@ DATA = os.environ.get("NDS_BENCH_DATA", "/tmp/nds_bench_sf1.0")
 BUDGET_S = int(os.environ.get("NDS_SQLITE_BUDGET", "60"))
 
 
-def load(conn):
+def load(conn, data_dir=DATA, tables=None):
+    """Create, fill and index `tables` (default: every source table with a
+    directory under `data_dir`) from the generator's .dat files."""
     import datetime
 
     schemas = get_schemas(use_decimal=False)
     for t, schema in schemas.items():
-        path = os.path.join(DATA, t)
-        if not os.path.isdir(path):
+        path = os.path.join(data_dir, t)
+        if (tables is not None and t not in tables) or not os.path.isdir(path):
             continue
         arrow = read_dat_dir(path, schema, use_decimal=False)
         conn.execute(
             f"create table {t} ({', '.join(f.name for f in schema)})"
         )
         ph = ",".join("?" * len(schema))
+        dates = [
+            i for i, f in enumerate(arrow.schema) if pa.types.is_date(f.type)
+        ]
         # stream per record batch: to_pylist() of a whole SF1 fact table
         # would box tens of millions of Python values at once
         for batch in arrow.to_batches(max_chunksize=1 << 17):
-            rows = (
-                tuple(
-                    v.isoformat() if isinstance(v, (datetime.date,)) else v
-                    for v in r.values()
-                )
-                for r in batch.to_pylist()
-            )
-            conn.executemany(f"insert into {t} values ({ph})", rows)
+            cols = [c.to_pylist() for c in batch.columns]
+            for i in dates:
+                cols[i] = [
+                    v.isoformat() if isinstance(v, datetime.date) else v
+                    for v in cols[i]
+                ]
+            conn.executemany(f"insert into {t} values ({ph})", zip(*cols))
         print(f"loaded {t}: {arrow.num_rows} rows", flush=True)
         # index every surrogate-key column: sqlite's nested-loop joins need
         # them; this is the fair (favorable-to-sqlite) configuration
