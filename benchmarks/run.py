@@ -1,0 +1,468 @@
+#!/usr/bin/env python3
+"""Run one cell of the benchmark once.
+
+    python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+The last line of standard output is the contract's JSON object. There is
+none, and the exit code is not 0, when jax finds no TPU or fewer chips than
+the cell asks for, or when a phase fails to run at all.
+
+This parent never imports jax (it checks): a parent that has touched jax
+holds the chip. It reads `BENCHMARK.json`, the cell's configuration and its
+traffic mix by name, and runs every phase as a child:
+
+    gen_data    the seeded raw data          } kept under benchmarks/.cache/
+    Load        the warehouse, in the          data/<scale>-<seed>-<hash of
+                configuration's format       } what makes them>/, so a seed's
+    reference   sqlite's answers (beside       second run finds them; written
+                Load and the first pass)     } to a temporary name, renamed
+    chip child  first pass + window (child.py), through ./nds-tpu-submit
+
+and then judges the run: fail, never fall back.
+
+    --scale 0.01     a CPU rehearsal: runs to the end, then fails on the
+                     platform check and prints no result line
+    --control float32   runs no chip child: the comparison's control, the
+                     reference with float32 aggregation put in the
+                     program's place, against the sound reference
+"""
+
+from __future__ import annotations
+
+import argparse
+import hashlib
+import json
+import os
+import shutil
+import signal
+import subprocess
+import sys
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(HERE)
+sys.path.insert(0, REPO)
+
+from benchmarks import compare, lib  # noqa: E402
+from benchmarks.lib import BenchmarkError  # noqa: E402
+
+#: seeds whose data is kept; a full check's two sets of six share seeds
+KEEP_SEEDS = 8
+#: a run must end inside the driver's 1200 s for a first run
+DEADLINE_S = 1150
+#: what makes the raw data and a warehouse: a change to any of it must not
+#: find an old warehouse
+DATA_SOURCES = ("nds_tpu/datagen", "nds_tpu/io", "nds_tpu/lakehouse",
+                "nds_tpu/schema.py", "nds_tpu/transcode.py",
+                "nds_tpu/cli/gen_data.py", "nds_tpu/cli/transcode.py")
+
+
+def load_child(run_dir):
+    """What the chip child handed over."""
+    return lib.load_json(os.path.join(run_dir, "child.json"))
+
+
+def check_device(device, cell):
+    """The look for a chip: no TPU, fewer chips than the cell asks for, or a
+    device whose peaks nobody wrote down, and there is no result."""
+    if device["platform"] != "tpu" or device["count"] < cell["chips"]:
+        raise BenchmarkError(
+            f"the cell needs {cell['chips']} TPU chip(s); jax found "
+            f"{device['count']} x {device['platform']}: no result")
+    return lib.device_peaks(device["kind"])
+
+
+class Run:
+    def __init__(self, args):
+        self.args = args
+        self.t0 = time.time()
+        self.t0_mono = time.monotonic()
+        self.children = []
+
+    # -- children ------------------------------------------------------------
+    def spawn(self, name, cmd, env=None):
+        log = open(os.path.join(self.logs, f"{name}.log"), "w")
+        child = subprocess.Popen(
+            [str(c) for c in cmd], cwd=REPO, stdout=log,
+            stderr=subprocess.STDOUT, start_new_session=True,
+            env={**os.environ, "JAX_COMPILATION_CACHE_DIR": self.compiled,
+                 **(env or {})},
+        )
+        child.log, child.name, child.t0 = log, name, time.monotonic()
+        self.children.append(child)
+        return child
+
+    def wait(self, child):
+        left = DEADLINE_S - (time.monotonic() - self.t0_mono)
+        try:
+            rc = child.wait(timeout=max(left, 1))
+        except subprocess.TimeoutExpired:
+            rc = "deadline"
+        self.reap(child)
+        secs = time.monotonic() - child.t0
+        print(f"phase {child.name}: {secs:.1f} s rc={rc}", flush=True)
+        if rc != 0:
+            with open(child.log.name, errors="replace") as f:
+                sys.stdout.write("".join(f.readlines()[-40:]))
+            raise BenchmarkError(f"phase {child.name} exited {rc} "
+                                 f"(log: {child.log.name})")
+        return secs
+
+    def reap(self, child):
+        """The child and whatever it started are gone when this returns."""
+        try:
+            os.killpg(child.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+        child.wait()
+        child.log.close()
+
+    def reap_all(self):
+        for child in self.children:
+            self.reap(child)
+
+    # -- set-up --------------------------------------------------------------
+    def prepare(self):
+        a = self.args
+        spec = lib.Spec(REPO)
+        self.cell = spec.cell(a.workload)
+        self.config = spec.config(self.cell)
+        self.traffic = spec.traffic(self.cell)
+        self.spec = spec
+        self.scale = a.scale if a.scale is not None else self.config["scale_factor"]
+        if not os.path.isfile(os.path.join(REPO, "nds-tpu-submit")):
+            raise BenchmarkError("no nds-tpu-submit beside benchmarks/: the "
+                                 "benchmark drives the repository it sits in")
+        cache = os.path.abspath(a.cache_dir)
+        self.run_dir = os.path.join(
+            cache, "runs", f"{a.workload}-{a.seed}-t{a.trace}")
+        shutil.rmtree(self.run_dir, ignore_errors=True)
+        self.logs = os.path.join(self.run_dir, "logs")
+        os.makedirs(self.logs)
+        key = lib.tree_hash(DATA_SOURCES)
+        self.data = os.path.join(cache, "data",
+                                 f"sf{self.scale}-{a.seed}-{key}")
+        os.makedirs(self.data, exist_ok=True)
+        os.utime(self.data)
+        self.evict()
+        # the compile caches (jax's, and under the same root the engine's
+        # AOT executables and cardinality feedback) at a fixed path inside
+        # the checkout, whatever the machine's environment names: the
+        # program takes the directory it is given
+        self.compiled = os.path.join(cache, "compiled")
+        os.makedirs(self.compiled, exist_ok=True)
+        n = sum(len(files) for _, _, files in os.walk(self.compiled))
+        print(f"cell {a.workload} seed {a.seed} scale {self.scale} "
+              f"trace {a.trace}; compile cache {self.compiled} ({n} files); "
+              f"data {self.data}", flush=True)
+
+    def evict(self):
+        root = os.path.dirname(self.data)
+        dirs = sorted((os.path.join(root, d) for d in os.listdir(root)),
+                      key=os.path.getmtime, reverse=True)
+        for d in dirs[KEEP_SEEDS:]:
+            shutil.rmtree(d, ignore_errors=True)
+
+    def ensure_raw(self):
+        raw = os.path.join(self.data, "raw")
+        if not os.path.isdir(raw):
+            tmp = raw + ".tmp"
+            shutil.rmtree(tmp, ignore_errors=True)
+            self.wait(self.spawn("gen_data", [
+                sys.executable, "-m", "nds_tpu.cli.gen_data", "local",
+                "--scale", self.scale, "--parallel", 4,
+                "--seed", self.args.seed, "--data_dir", tmp,
+                "--overwrite_output",
+            ]))
+            os.rename(tmp, raw)
+        return raw
+
+    def statements(self, control=None):
+        """What the reference answers, {key: stream entry}: stream 0 (the
+        first pass) and the stream the window replays first. The control
+        answers stream 0's statements that the mix lists for it: its float32
+        SUM and AVG are Python callbacks, which query1's correlated subquery
+        calls for minutes."""
+        streams = lib.make_streams(self.traffic, self.scale, 0,
+                                   1 + self.traffic["window_passes"])
+        if control:
+            stream0 = dict(streams[0])
+            return {f"s0/{t}": stream0[t]
+                    for t in self.traffic["control_templates"]}
+        compared = lib.window_order(self.traffic, self.args.seed, 0)[0]
+        return {f"s{si}/{name}": sql
+                for si in (0, compared) for name, sql in streams[si]}
+
+    def start_reference(self, raw, statements, control=None):
+        """sqlite's answers to `statements`: found, or a child started
+        beside Load and the first pass. Returns (dir, child or None)."""
+        with open(os.path.join(HERE, "reference.py"), "rb") as f:
+            h = hashlib.sha256(f.read())
+        h.update(json.dumps(statements, sort_keys=True).encode())
+        ref = os.path.join(
+            self.data, f"ref-{control or 'sound'}-{h.hexdigest()[:16]}")
+        if os.path.isdir(ref):
+            return ref, None
+        tmp = ref + ".tmp"
+        shutil.rmtree(tmp, ignore_errors=True)
+        os.makedirs(tmp)
+        with open(os.path.join(tmp, "statements.json"), "w") as f:
+            json.dump(statements, f)
+        child = self.spawn(f"reference_{control or 'sound'}", [
+            sys.executable, os.path.join(HERE, "reference.py"), raw,
+            os.path.join(tmp, "statements.json"), tmp,
+            *(["--control", control] if control else []),
+        ], env={"JAX_PLATFORMS": "cpu"})
+        child.final = ref
+        return ref, child
+
+    def finish_reference(self, child):
+        if child is not None:
+            self.wait(child)
+            os.rename(child.final + ".tmp", child.final)
+
+    def ensure_warehouse(self, raw):
+        load = self.config["load"]
+        tables = self.config.get("tables")
+        tag = hashlib.sha256(json.dumps(
+            [load, tables], sort_keys=True).encode()).hexdigest()[:8]
+        wh = os.path.join(
+            self.data, f"wh-{self.config['storage_format']}-{tag}")
+        if not os.path.isdir(wh):
+            tmp = wh + ".tmp"
+            shutil.rmtree(tmp, ignore_errors=True)
+            self.wait(self.spawn("load", [
+                os.path.join(REPO, "nds-tpu-submit"), load["template"],
+                "nds_tpu.cli.transcode", raw, tmp,
+                os.path.join(self.run_dir, "load_report.txt"),
+                *load["flags"],
+                *(["--tables", ",".join(tables)] if tables else []),
+            ]))
+            os.rename(tmp, wh)
+        return wh
+
+    # -- the run -------------------------------------------------------------
+    def chip_child(self, wh, warm=False):
+        """The measured child or, `warm`, the same child with a window of
+        no seconds and no reference to wait for."""
+        a = self.args
+        name = "warm" if warm else "chip"
+        run_dir = os.path.join(self.run_dir, name) if warm else self.run_dir
+        os.makedirs(run_dir, exist_ok=True)
+        env = {}
+        if a.trace and not warm:
+            env["NDS_TRACE_DIR"] = os.path.join(run_dir, "trace")
+        self.gate = os.path.join(self.run_dir, "reference.done")
+        gate = self.run_dir if warm else self.gate
+        if "jax" in sys.modules:
+            raise BenchmarkError("the parent imported jax: it would hold "
+                                 "the chip its child needs")
+        return self.spawn(name, [
+            os.path.join(REPO, "nds-tpu-submit"),
+            self.config["power"]["template"], "benchmarks.child",
+            "--warehouse", wh, "--run_dir", run_dir,
+            "--traffic", self.spec.traffic_path(self.cell),
+            "--seed", a.seed, "--scale", self.scale,
+            "--seconds", 0 if warm else a.seconds,
+            "--trace", 0 if warm else a.trace, "--gate", gate,
+        ], env=env)
+
+    def warm_checkout(self, wh):
+        """`first_pass_s` is a first execution with warm disk caches. The
+        first run of a cell in a checkout finds them empty, so it runs the
+        first pass and the rehearsal once in a child that is thrown away
+        (set-up: the compile of some minutes) and leaves a marker;
+        the child that is measured then finds every program on disk, as in
+        every later run."""
+        with open(self.spec.traffic_path(self.cell), "rb") as f:
+            h = hashlib.sha256(f.read())
+        h.update(json.dumps(self.config, sort_keys=True).encode())
+        h.update(str(self.scale).encode() + self.compiled.encode())
+        marker = os.path.join(os.path.abspath(self.args.cache_dir),
+                              f"warmed-{h.hexdigest()[:16]}")
+        if not os.path.exists(marker):
+            self.wait(self.chip_child(wh, warm=True))
+            with open(marker, "w"):
+                pass
+
+    def run(self):
+        a = self.args
+        self.prepare()
+        raw = self.ensure_raw()
+        statements = self.statements()
+        ref, ref_child = self.start_reference(raw, statements)
+        if a.control:
+            return self.control(raw, ref, ref_child)
+        wh = self.ensure_warehouse(raw)
+        self.warm_checkout(wh)
+        chip = self.chip_child(wh)
+        self.finish_reference(ref_child)
+        with open(self.gate, "w"):
+            pass
+        self.wait(chip)
+        with open(chip.log.name, errors="replace") as f:
+            sys.stdout.write("".join(
+                line for line in f if line.startswith("child:")))
+        return self.judge(load_child(self.run_dir), ref, list(statements))
+
+    def control(self, raw, ref, ref_child):
+        """The control's answers in the program's place: must not pass."""
+        asked = self.statements(self.args.control)
+        bad, bad_child = self.start_reference(raw, asked, self.args.control)
+        self.finish_reference(ref_child)
+        self.finish_reference(bad_child)
+        per = compare.compare_answers(ref, bad, list(asked))
+        ok, numbers = compare.verdict(per, self.config["correct_limits"])
+        for key, p in per.items():
+            print(f"control {key}: {json.dumps(p)}")
+        print(f"control {self.args.control} seed {self.args.seed}: "
+              f"correct={ok} {json.dumps(numbers)}")
+        return 0 if not ok else 4
+
+    # -- judgement -----------------------------------------------------------
+    def faults_of(self, child):
+        """Fail, never fall back: everything but the answers that makes a
+        run that reached its end not `correct`."""
+        faults = []
+        for name, s in child["first_pass"]["statements"].items():
+            print(f"first pass {name}: {s.get('ms')} ms status={s['status']} "
+                  f"backend={s.get('backend')} mem={s.get('mem_bytes')} "
+                  f"({s.get('mem_source')})")
+            if s["status"] != ["Completed"]:
+                faults.append(f"first pass {name}: {s['status']} "
+                              f"{s.get('exceptions')}")
+            if s.get("backend") != "tpu":
+                faults.append(f"first pass {name}: ran on {s.get('backend')}")
+            if s.get("mem_source") != "device":
+                faults.append(f"first pass {name}: memory read from "
+                              f"{s.get('mem_source')}, not the device")
+            if s.get("ladder"):
+                faults.append(f"first pass {name}: ladder {s['ladder']}")
+        for phase in ("rehearsal", "statements"):
+            for s in child[phase]:
+                where = f"{phase} {s['name']} (stream {s['stream']})"
+                if s.get("ladder"):
+                    faults.append(f"{where}: ladder {s['ladder']}")
+                if phase == "rehearsal" and s["status"] != "Completed":
+                    faults.append(f"{where}: {s['status']} "
+                                  f"{s.get('exceptions')}")
+        aot = child["counters"]["window_close"]["aot"] or {}
+        if aot.get("quarantined") or aot.get("call_failures"):
+            faults.append(f"AOT executables: {aot['quarantined']} quarantined,"
+                          f" {aot['call_failures']} failed at call")
+        return faults
+
+    def judge(self, child, ref, keys):
+        device = child["device"]
+        peaks = check_device(device, self.cell)
+        faults = self.faults_of(child)
+        first = child["first_pass"]["statements"]
+        stmts = child["statements"]
+        done = [s for s in stmts if s["status"] == "Completed"]
+        if not done:
+            raise BenchmarkError("no statement completed in the window")
+        failed = (len(stmts) - len(done)) + sum(
+            1 for s in first.values() if s["status"] != ["Completed"])
+        attempted = len(stmts) + len(first)
+
+        # the answers the timed path produced, the first pass's and those of
+        # the window's first pass, each cell against sqlite's
+        per = compare.compare_answers(
+            ref, os.path.join(self.run_dir, "answers"), keys)
+        for key, p in per.items():
+            print(f"answer {key}: rows {p['rows']} cells_differ "
+                  f"{p['cells_differ']} rel_gap_max {p['rel_gap_max']:.3e}"
+                  + (f" first: {p['first']}" if p["first"] else ""))
+        ok, numbers = compare.verdict(per, self.config["correct_limits"])
+        for name, n in numbers.items():
+            print(f"compared {name}: {n['value']!r} limit {n['limit']!r}")
+        if not ok:
+            faults.append("answers differ from the reference's")
+        for f in faults:
+            print(f"FAULT: {f}")
+
+        # earlier lines: what the result line has no room for
+        by_class = {}
+        for s in done:
+            by_class.setdefault(s["name"], []).append(s["ms"])
+        reh = child["rehearsal"]
+        print(f"rehearsal: {len(reh)} statements, mean "
+              f"{sum(s['ms'] for s in reh) / len(reh):.1f} ms, "
+              f"{sum(s.get('aot_compiled', 0) for s in reh)} compiled, "
+              f"{sum(s.get('aot_loaded', 0) for s in reh)} loaded from disk")
+        print(f"window: {child['window_s']:.3f} s, {len(done)} completed of "
+              f"{len(stmts)}, {stmts[-1]['cycle']} whole cycles + part; "
+              f"new shapes met: {sum(s['new_shapes'] for s in stmts)}; "
+              f"gate wait {child['gate_wait_s']:.3f} s")
+        print("window per class, samples and median ms: " + json.dumps({
+            k: [len(v), round(lib.percentile(v, 50), 1)]
+            for k, v in by_class.items()}))
+        for mark in ("first_pass_end", "rehearsal_end", "window_close"):
+            print(f"counters at {mark}: "
+                  + json.dumps(child["counters"][mark]))
+
+        device_out = {**device,
+                      "memory_peak_bytes": child["memory"]["peak_bytes_in_use"]}
+        if device_out["memory_peak_bytes"] is None:
+            raise BenchmarkError("the device reports no peak_bytes_in_use")
+        print(f"device: {json.dumps(device_out)} of "
+              f"{child['memory']['bytes_limit']} B; peaks {json.dumps(peaks)}")
+        if self.args.trace:
+            return self.traced_line(child, attempted, failed, device_out,
+                                    not faults)
+        child["marks"]["parent_start"] = self.t0 * 1e3
+        metrics = self.spec.read_metrics(self.cell, "end_to_end", child)
+        wanted = self.spec.metrics_of(self.cell, "end_to_end")
+        if len(metrics) != len(wanted):
+            raise BenchmarkError("no value for " + ", ".join(
+                m["name"] for m in wanted if m["name"] not in metrics))
+        print(lib.result_line(not faults, attempted, failed, metrics,
+                              device_out))
+        return 0
+
+    def traced_line(self, child, attempted, failed, device_out, correct):
+        if "device_trace" not in child:
+            raise BenchmarkError(f"no device trace: {child.get('trace_error')}")
+        trace = child["device_trace"]
+        child["events"] = lib.read_events(os.path.join(self.run_dir, "trace"))
+        metrics = self.spec.read_metrics(self.cell, "per_layer", child)
+        device_out["busy_s"] = trace["busy_s"]
+        device_out["window_s"] = trace["window_s"]
+        print(f"traced slice: {trace['window_s']:.3f} s, busy "
+              f"{trace['busy_s']:.3f} s, idle share "
+              f"{1 - trace['busy_s'] / trace['window_s']:.4f}, "
+              f"{trace['statements']} statements, unannotated "
+              f"{trace['unannotated_s']:.3f} s")
+        print(lib.result_line(
+            correct, attempted, failed, metrics, device_out,
+            {"device_ops": trace["device_ops"],
+             "idle_gaps": trace["idle_gaps"]}))
+        return 0
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=[0, 1], default=0)
+    ap.add_argument("--scale", type=float,
+                    help="override the configuration's scale: CPU rehearsal")
+    ap.add_argument("--control", choices=["float32"])
+    ap.add_argument("--cache_dir", default=os.path.join(HERE, ".cache"),
+                    help="where data, answers and run directories are kept "
+                    "(tests point it at a temporary directory)")
+    args = ap.parse_args(argv)
+    run = Run(args)
+    try:
+        rc = run.run()
+    except BenchmarkError as e:
+        print(f"benchmark: FAILED: {e}", flush=True)
+        rc = 1
+    finally:
+        run.reap_all()
+    return rc
+
+
+if __name__ == "__main__":
+    sys.exit(main())
