@@ -57,6 +57,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import os
+import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -1192,14 +1193,17 @@ def analyze_plan(
     )
 
 
-def emit_budget_event(tracer, pb: PlanBudget) -> None:
+def emit_budget_event(tracer, pb: PlanBudget, dur_ms=None) -> None:
     """The one `plan_budget` event payload (EVENT_SCHEMA contract) —
     shared by the plan-time hook and the explain CLI so the two emission
-    sites can never drift. No-op without a tracer."""
+    sites can never drift. No-op without a tracer. `dur_ms`: the analysis'
+    wall, which on a table's first use includes reading its row count from
+    storage metadata (`table_rows`: seconds for a partitioned fact table)."""
     if tracer is None:
         return
     tracer.emit(
         "plan_budget",
+        **({} if dur_ms is None else {"dur_ms": round(dur_ms, 3)}),
         verdict=pb.verdict,
         peak_bytes=pb.peak_bytes,
         budget_bytes=pb.budget_bytes,
@@ -1294,6 +1298,7 @@ def budget_plan(plan: P.PlanNode, session) -> Optional[PlanBudget]:
                     rows = (rec or {}).get("rows") or {}
                     if rows.get("max") is not None:
                         fb_overrides[nid] = int(rows["max"])
+    t0 = time.perf_counter()
     try:
         pb = analyze_plan(
             plan,
@@ -1310,7 +1315,10 @@ def budget_plan(plan: P.PlanNode, session) -> Optional[PlanBudget]:
         session.last_plan_budget = {"verdict": "error", "error": str(exc)}
         session.notify_failure(f"plan budgeter failed: {str(exc)[:200]}")
         return None
-    emit_budget_event(getattr(session, "tracer", None), pb)
+    emit_budget_event(
+        getattr(session, "tracer", None), pb,
+        dur_ms=(time.perf_counter() - t0) * 1000.0,
+    )
     if fb_fps:
         # annotate estimate accounting onto the plan (the same dynamic-
         # annotation family as budget_window_rows: deliberately NOT

@@ -108,6 +108,13 @@ Every rule below encodes a bug this codebase actually shipped (and fixed):
                           engine/exec.py (the modules that resolve a
                           Scan node to files).
 
+  host-read-seam          every blocking device-to-host read of engine/
+                          and ops/ goes through obs/tally.py `host_read`,
+                          so the `host_read` counter stays whole:
+                          `jax.device_get(`, `.block_until_ready(` and
+                          `int(` / `float(` / `bool(` of a `jnp.` call
+                          are flagged anywhere else in those packages.
+
 Pragma: append `# nds-lint: disable=<rule>[,<rule>...]` (with a
 justification!) on the offending line or the line directly above to
 acknowledge a known-sound exception. `disable=all` silences every rule for
@@ -359,6 +366,35 @@ def _r_host_sync_in_fuse(tree, relpath):
                     f"({fn.name}); host work belongs at build/call "
                     f"boundaries"
                 )))
+    return out
+
+
+@_rule("host-read-seam", _scope_engine_ops)
+def _r_host_read_seam(tree, relpath):
+    out = []
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Call):
+            continue
+        f = node.func
+        what = None
+        if isinstance(f, ast.Attribute):
+            if f.attr == "block_until_ready":
+                what = "block_until_ready()"
+            elif (f.attr == "device_get" and isinstance(f.value, ast.Name)
+                    and f.value.id == "jax"):
+                what = "jax.device_get()"
+        elif (isinstance(f, ast.Name) and f.id in ("int", "float", "bool")
+                and node.args and isinstance(node.args[0], ast.Call)
+                and isinstance(node.args[0].func, ast.Attribute)
+                and isinstance(node.args[0].func.value, ast.Name)
+                and node.args[0].func.value.id == "jnp"):
+            what = f"{f.id}(jnp.{node.args[0].func.attr}(...))"
+        if what is not None:
+            out.append((node.lineno, (
+                f"{what} is a blocking device-to-host read outside the "
+                f"seam; route it through obs/tally.py host_read(why, x) so "
+                f"it is counted and timed"
+            )))
     return out
 
 

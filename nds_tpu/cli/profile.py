@@ -19,10 +19,14 @@ across the run, and cache-hit/retry tallies; a (partially) compacted
 trace dir profiles transparently — raw segments and `compact-*.json`
 summary artifacts merge with identical summary semantics.
 `--critical-path` attributes each query's wall time to named causes
-(execute / exchange-wait / spill-io / catalog-load / ladder-retry /
-backoff-wait / hung-wait / plan-host — obs/critpath.py) and, on mesh
-traces, names the straggler device and the skew share of the exchange
-gap; `--min_attributed R` exits 1 when any query's attributed share
+(device-wait / launch / jit-trace / xla-compile / cache-load /
+exec-lookup / host-python inside the statement's `result_span`, one
+`execute` lump for logs without it; exchange-wait / spill-io /
+catalog-load split into read / encode / h2d / ladder-retry /
+backoff-wait / hung-wait / plan-host — obs/critpath.py), lists per query
+the launches by kernel, the blocking reads by `why` and the compiles by
+`fun`, and, on mesh traces, names the straggler device and the skew share
+of the exchange gap; `--min_attributed R` exits 1 when any query's attributed share
 falls below R (the CI diagnosis gate). Paths that look like flight-
 recorder failure bundles (`failure-bundle-*.json`) are validated
 structurally (bundle keys + ring event schema) instead of being parsed
@@ -198,9 +202,17 @@ def _render_profile(prof, top: int, per_query: bool):
         prof.get("kernel_totals", {}).items(),
         key=lambda kv: -kv[1]["dur_ms"],
     )[:top]
+    launches = sorted(
+        prof.get("launch_totals", {}).items(), key=lambda kv: -kv[1]
+    )[:top]
+    if launches:
+        print(f"\n== top {len(launches)} kernels by launches "
+              f"(op_span.launches: seamed entries, no device time)")
+        for name, n in launches:
+            print(f"   {name:<28}{n:>8,}")
     if kernels:
-        print(f"\n== top {len(kernels)} kernels by dispatch time "
-              f"(kernel_span; NDS_TRACE_KERNELS runs)")
+        print(f"\n== top {len(kernels)} Pallas A/B measurements "
+              f"(kernel_span; engine.pallas_agg / pallas_sort = auto)")
         print(f"   {'kernel':<28}{'count':>6}{'total_ms':>12}"
               f"{'avg_ms':>10}{'rows':>14}")
         for name, k in kernels:

@@ -350,6 +350,7 @@ class AotCache:
         with self._lock:
             self.stats["lookups"] += 1
         t0 = time.perf_counter()
+        t0_ns = time.time_ns()
         faults.maybe_fire("aot:read", kinds=("io", "crash"))
         try:
             with open(path, "rb") as f:
@@ -396,7 +397,8 @@ class AotCache:
         with self._lock:
             self.stats["disk_hits"] += 1
         self._emit(
-            "load", "hit", bytes=len(raw), dur_ms=dur, key=_entry_name(key),
+            "load", "hit", bytes=len(raw), dur_ms=dur, t0_ns=t0_ns,
+            key=_entry_name(key),
         )
         return compiled
 

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, replace
 from functools import partial
+from time import perf_counter as _perf
 from typing import Optional
 
 import jax
@@ -35,6 +36,7 @@ import pyarrow as pa
 import pyarrow.compute as pc
 
 from ..dtypes import DType, parse_dtype, INT64, FLOAT64
+from ..obs.tally import host_read
 
 jax.config.update("jax_enable_x64", True)
 
@@ -160,7 +162,8 @@ class Table:
     @property
     def nrows(self) -> int:
         if not isinstance(self._nrows, int):
-            self._nrows = int(self._nrows)  # device sync on first need
+            # device sync on first need
+            self._nrows = int(host_read("nrows", self._nrows))
         return self._nrows
 
     @property
@@ -231,9 +234,9 @@ class Table:
         cols = {}
         for name, c in self.columns.items():
             cols[name] = Column(
-                c.data[idx],
+                K.take_rows(c.data, idx),
                 c.dtype,
-                None if c.valid is None else c.valid[idx],
+                None if c.valid is None else K.take_rows(c.valid, idx),
                 c.dictionary,
                 c.subset_stats(),
             )
@@ -303,7 +306,19 @@ def _np_valid(arr: pa.Array) -> Optional[np.ndarray]:
     return pc.is_valid(arr).to_numpy(zero_copy_only=False)
 
 
-def column_from_arrow(arr: pa.ChunkedArray | pa.Array, dtype: DType, cap: int) -> Column:
+def _put(host: np.ndarray, h2d=None) -> jnp.ndarray:
+    """Host buffer -> device array. `h2d`, a one-element list, accumulates
+    the seconds spent in the copy calls (catalog_load's `h2d_ms`)."""
+    if h2d is None:
+        return jnp.asarray(host)
+    t0 = _perf()
+    out = jnp.asarray(host)
+    h2d[0] += _perf() - t0
+    return out
+
+
+def column_from_arrow(arr: pa.ChunkedArray | pa.Array, dtype: DType, cap: int,
+                      h2d=None) -> Column:
     """Decode one Arrow column into the device representation."""
     if isinstance(arr, pa.ChunkedArray):
         arr = arr.combine_chunks()
@@ -316,7 +331,7 @@ def column_from_arrow(arr: pa.ChunkedArray | pa.Array, dtype: DType, cap: int) -
             arr.indices.fill_null(0).to_numpy(zero_copy_only=False), dtype=np.int32
         )
         dictionary = arr.dictionary
-        data = jnp.asarray(np.ascontiguousarray(codes))
+        data = _put(np.ascontiguousarray(codes), h2d)
     else:
         dictionary = None
         if dtype.is_decimal:
@@ -344,11 +359,11 @@ def column_from_arrow(arr: pa.ChunkedArray | pa.Array, dtype: DType, cap: int) -
             np_vals = np.asarray(
                 filled.to_numpy(zero_copy_only=False), dtype=npdt
             )
-        data = jnp.asarray(np.ascontiguousarray(np_vals))
+        data = _put(np.ascontiguousarray(np_vals), h2d)
     data = _pad_to(data, cap)
     valid = None
     if valid_np is not None:
-        valid = _pad_to(jnp.asarray(valid_np), cap, fill=False)
+        valid = _pad_to(_put(valid_np, h2d), cap, fill=False)
     return Column(data, dtype, valid, dictionary)
 
 
@@ -386,13 +401,15 @@ def arrow_column_stats(arr, dtype: DType, nrows: int) -> Optional[ColStats]:
 
 
 def table_from_arrow(
-    batch: pa.Table | pa.RecordBatch, schema=None, with_stats: bool = False
+    batch: pa.Table | pa.RecordBatch, schema=None, with_stats: bool = False,
+    h2d=None,
 ) -> Table:
     """Build a device Table from an Arrow table.
 
     `schema` (nds_tpu.schema.Schema) supplies logical types; if omitted they
     are inferred from the Arrow types. `with_stats` captures per-column
     ColStats (catalog loads set it; ad-hoc intermediates skip the pass).
+    `h2d`: see `_put`.
     """
     nrows = batch.num_rows
     cap = bucket_cap(nrows)
@@ -404,7 +421,7 @@ def table_from_arrow(
             dtype = schema.field(name).dtype
         else:
             dtype = _infer_dtype(batch.schema.field(i).type)
-        col = column_from_arrow(batch.column(i), dtype, cap)
+        col = column_from_arrow(batch.column(i), dtype, cap, h2d)
         if with_stats and col.stats is None:
             stats = arrow_column_stats(batch.column(i), dtype, nrows)
             if stats is not None:
@@ -442,8 +459,11 @@ def column_to_arrow(col: Column, nrows: int, host=None) -> pa.Array:
         data = data[:nrows]
         valid = None if valid is None else valid[:nrows]
     else:
-        data = np.asarray(col.data[:nrows])
-        valid = None if col.valid is None else np.asarray(col.valid[:nrows])
+        data = host_read("collect", col.data[:nrows])
+        valid = (
+            None if col.valid is None
+            else host_read("collect", col.valid[:nrows])
+        )
     mask = None if valid is None else ~valid
     dt = col.dtype
     if dt.is_string:
@@ -496,7 +516,7 @@ def table_to_arrow(table: Table) -> pa.Table:
             else x
             for x in flat
         ]
-    fetched = iter(jax.device_get(flat))
+    fetched = iter(host_read("collect", flat))
     arrays = []
     for c in table.columns.values():
         data = next(fetched)

@@ -23,9 +23,12 @@ import pyarrow.compute as pc
 
 from dataclasses import replace as _dc_replace
 from time import perf_counter as _perf
+from time import time_ns as _time_ns
 
 from .. import faults
 from ..dtypes import BOOL, DType, FLOAT64, INT64
+from ..obs import tally as _tally
+from ..obs.tally import host_read
 from ..ops import kernels as K
 from . import aotcache as AOTC
 from . import expr as E
@@ -77,12 +80,13 @@ def _resolve_bounds(datas, valids, stats_list, wanted, live):
             bounds.append(None)
             need.append(i)
     if need:
-        fetched = jax.device_get(
+        fetched = host_read(
+            "bounds",
             K.batched_min_max(
                 [datas[i].astype(jnp.int64) for i in need],
                 [valids[i] for i in need],
                 live,
-            )
+            ),
         )
         for i, mm in zip(need, fetched):
             bounds[i] = (int(mm[0]), int(mm[1]))
@@ -226,6 +230,13 @@ class Executor:
         self._span_depth = 0
         self._span_seq = 0
         self._exec_id = next(_EXEC_IDS) if tracer is not None else 0
+        # launches and blocking reads of this statement, counted by the
+        # seams of obs/tally.py while this is bound to the thread and
+        # flushed as fields of the op_spans (None: untraced, seams are bare)
+        self.tally = (
+            _tally.Tally(tracer, self._exec_id) if tracer is not None
+            else None
+        )
 
     # plan-node types worth caching across statements: the expensive
     # pipeline breakers (a CTE body virtually always ends in one)
@@ -326,21 +337,34 @@ class Executor:
             # repeated visits are cte-cache dict hits, so each node records
             # once per executor. Spans emit in completion (post-) order
             # with (exec_id, seq, depth) so the profiler can rebuild the
-            # tree and derive exclusive times.
+            # tree and derive exclusive times. The tally's counters are
+            # this node's own (exclusive of children): push/pop swap them.
             depth = self._span_depth
             self._span_depth = depth + 1
+            tally = self.tally
+            bound = None
+            if depth == 0 and _tally.current() is not tally:
+                # an executor driven directly, not through Result
+                bound = _tally.bind(tally)
+                bound.__enter__()
+            saved = tally.push(depth)
+            t0_ns = _time_ns()
             t0 = _perf()
             try:
                 out = m(node)
+                # estimate-vs-actual accounting inside the span: a
+                # pipeline-breaker record may force the queued count onto
+                # the host (a host_read this node waited for), and the
+                # span's actual_rows should see it
+                fp = getattr(node, "node_fp", None)
+                if fp is not None:
+                    self._record_feedback(node, out)
             finally:
                 self._span_depth = depth
+                own = tally.pop(saved)
+                if bound is not None:
+                    bound.__exit__(None, None, None)
             dur_ms = (_perf() - t0) * 1000.0
-            # estimate-vs-actual accounting BEFORE the span emit: a
-            # pipeline-breaker record may force the queued count onto the
-            # host, and the span's actual_rows should see it
-            fp = getattr(node, "node_fp", None)
-            if fp is not None:
-                self._record_feedback(node, out)
             self._span_seq += 1
             span = dict(
                 exec_id=self._exec_id,
@@ -348,11 +372,13 @@ class Executor:
                 depth=depth,
                 node=type(node).__name__,
                 explain=P.node_desc(node)[:90],
+                t0_ns=t0_ns,
                 dur_ms=round(dur_ms, 3),
                 # nrows_known only: forcing a queued count would add a
                 # device sync to every traced node
                 rows=out.nrows_known,
                 est_bytes=table_device_bytes(out),
+                **own,
             )
             if fp is not None:
                 # budgeter accounting (analysis/feedback.py annotations):
@@ -471,6 +497,7 @@ class Executor:
         session = getattr(self.catalog, "session", None)
         tracer = self.tracer
         t0 = _perf() if tracer is not None else 0.0
+        t0_ns = _time_ns() if tracer is not None else 0
         out = None
         fused = False
         has_agg = node.agg is not None
@@ -507,20 +534,32 @@ class Executor:
                         node.stages, child, aot=aot, fp=fp,
                         conf_sig=conf_sig,
                     )
+            lk_ns = _time_ns() if tracer is not None else 0
+            lk0 = _perf()
             with session.cache_lock:
                 entry, hit = session.exec_cache.lookup(
                     fp, sig, child.cap, build
                 )
             if tracer is not None:
+                # dur_ms: the lookup and, on a miss, the build (trace,
+                # lower, compile or AOT load: those have events of their own)
                 tracer.emit(
                     "exec_cache", pipeline=fp[:12], bucket=child.cap,
-                    hit=hit, fused=entry is not None,
+                    hit=hit, fused=entry is not None, t0_ns=lk_ns,
+                    dur_ms=round((_perf() - lk0) * 1000.0, 3),
                 )
             if entry is not None:
                 donate = (
                     node.donate_ok
                     and session.conf.get("engine.fuse_donate", "off")
                     == "on"
+                )
+                # one launch each, whatever the chain's length
+                token = (
+                    self.tally.enter(
+                        "fused_agg_pipeline" if has_agg else "fused_pipeline"
+                    )
+                    if self.tally is not None else None
                 )
                 try:
                     out = entry.call(child, donate)
@@ -539,6 +578,9 @@ class Executor:
                     self.on_task_failure(
                         f"pipeline fuse fallback: {str(exc)[:120]}"
                     )
+                finally:
+                    if token is not None:
+                        self.tally.leave(token)
         if out is None:
             # eager per-stage path (_apply_wrappers wants top-down order)
             out = self._apply_wrappers(child, list(reversed(node.stages)))
@@ -553,6 +595,7 @@ class Executor:
                 stages=len(node.stages),
                 fused=fused,
                 agg=has_agg,
+                t0_ns=t0_ns,
                 dur_ms=round((_perf() - t0) * 1000.0, 3),
                 rows=out.nrows_known,
             )
@@ -786,7 +829,7 @@ class Executor:
         even when the session is untraced."""
         if self.tracer is None and node_fp is None:
             return
-        c = np.asarray(counts, dtype=np.float64)
+        c = np.asarray(host_read("exchange", counts), dtype=np.float64)
         total = float(c.sum())
         skew = 1.0
         if total > 0 and c.size:
@@ -892,7 +935,7 @@ class Executor:
         while True:
             fn = get_sample_sort(mesh, len(tkeys), len(payload), cap_route)
             out = fn(route, live, *tkeys, *payload)
-            overflow = int(out[-1])
+            overflow = int(host_read("exchange", out[-1]))
             if overflow == 0:
                 break
             if cap_route >= local_rows:  # can't overflow at this cap; bug guard
@@ -1063,7 +1106,8 @@ class Executor:
         tables = [self.execute(r) for r in relations]
         lazy = [t for t in tables if t.nrows_known is None]
         if lazy:
-            for t, v in zip(lazy, jax.device_get([t.nrows_lazy for t in lazy])):
+            counts = host_read("nrows", [t.nrows_lazy for t in lazy])
+            for t, v in zip(lazy, counts):
                 t._nrows = int(v)
         return tables
 
@@ -1370,9 +1414,9 @@ class Executor:
             out_cols = {n: c.disowned() for n, c in left.columns.items()}
             ri_safe = jnp.where(matched, ri, 0)
             for name, c in right.columns.items():
-                valid = None if c.valid is None else c.valid[ri_safe]
+                valid = None if c.valid is None else K.take_rows(c.valid, ri_safe)
                 out_cols[name] = Column(
-                    c.data[ri_safe], c.dtype, valid, c.dictionary,
+                    K.take_rows(c.data, ri_safe), c.dtype, valid, c.dictionary,
                     c.gather_stats(), owned=True,
                 )
             pair = Table(
@@ -1391,9 +1435,9 @@ class Executor:
         out_cols = {n: c.disowned() for n, c in left.columns.items()}
         ri_safe = jnp.where(matched, ri, 0)
         for name, c in right.columns.items():
-            valid = c.valid[ri_safe] if c.valid is not None else jnp.ones(left.cap, bool)
+            valid = K.take_rows(c.valid, ri_safe) if c.valid is not None else jnp.ones(left.cap, bool)
             out_cols[name] = Column(
-                c.data[ri_safe], c.dtype, valid & matched, c.dictionary,
+                K.take_rows(c.data, ri_safe), c.dtype, valid & matched, c.dictionary,
                 c.gather_stats(),
             )
         return Table(
@@ -1577,7 +1621,7 @@ class Executor:
             )
             ok, rest = out[0], out[1:]
             used_l, used_r = cap_l, cap_r
-            overflow = int(rest[-1])
+            overflow = int(host_read("exchange", rest[-1]))
             if overflow == 0:
                 break
             retries += 1
@@ -1795,16 +1839,16 @@ class Executor:
         # donate it (engine/fuse.py:_donate_slots)
         cols = {}
         for name, c in left.columns.items():
-            data = c.data[li]
-            valid = None if c.valid is None else c.valid[li]
+            data = K.take_rows(c.data, li)
+            valid = None if c.valid is None else K.take_rows(c.valid, li)
             if lnull is not None:
                 v = valid if valid is not None else jnp.ones(li.shape[0], bool)
                 valid = v & ~lnull
             cols[name] = Column(data, c.dtype, valid, c.dictionary,
                                 c.gather_stats(), owned=True)
         for name, c in right.columns.items():
-            data = c.data[ri]
-            valid = None if c.valid is None else c.valid[ri]
+            data = K.take_rows(c.data, ri)
+            valid = None if c.valid is None else K.take_rows(c.valid, ri)
             if rnull is not None:
                 v = valid if valid is not None else jnp.ones(ri.shape[0], bool)
                 valid = v & ~rnull
@@ -2461,8 +2505,8 @@ class Executor:
                     base.dictionary,
                 )
             else:
-                data = c.data[first_rows]
-                valid = None if c.valid is None else c.valid[first_rows]
+                data = K.take_rows(c.data, first_rows)
+                valid = None if c.valid is None else K.take_rows(c.valid, first_rows)
                 cols[name] = Column(
                     data, c.dtype, valid, c.dictionary,
                     _group_key_stats(
@@ -2506,9 +2550,9 @@ class Executor:
         c = ev.eval(agg.arg)
         weight = live_sorted
         # order=None: direct (unsorted) aggregation — gid/live are row-aligned
-        sdata = c.data if order is None else c.data[order]
+        sdata = c.data if order is None else K.take_rows(c.data, order)
         if c.valid is not None:
-            weight = weight & (c.valid if order is None else c.valid[order])
+            weight = weight & (c.valid if order is None else K.take_rows(c.valid, order))
         if c.dtype.is_string:
             rank, sorted_dict = sort_dictionary(c)
             sdata = rank if order is None else rank[order]
@@ -2633,7 +2677,7 @@ class Executor:
         if int(words[0].shape[0]) > PK.SORT_MAX_ROWS:
             return K.sort_by_words(words)
         w = words[0]
-        lo, hi = (int(x) for x in jax.device_get(K.word_span(w)))
+        lo, hi = (int(x) for x in host_read("sort_span", K.word_span(w)))
         if lo < 0 or hi >= PK.SORT_MAX_DOMAIN:
             return K.sort_by_words(words)
         # 128-aligned domain so near-identical spans share one compiled
@@ -2724,9 +2768,11 @@ class Executor:
         session = self.catalog.session
 
         def timed(run):
+            # a measurement that has to synchronize, once per shape, ever
+            # nds-lint: disable=host-read-seam
             jax.block_until_ready(run())  # warmup: exclude compile
             t0 = _perf()
-            jax.block_until_ready(run())
+            jax.block_until_ready(run())  # nds-lint: disable=host-read-seam
             return (_perf() - t0) * 1000.0
 
         jnp_ms = timed(run_jnp)
@@ -2826,7 +2872,7 @@ class Executor:
         first2 = K.segment_starts(gid2, g2cap)
         rows2 = order2[jnp.clip(first2, 0, child.cap - 1)]
         live2 = jnp.arange(g2cap) < ng2
-        cvalid2 = None if c.valid is None else c.valid[rows2]
+        cvalid2 = None if c.valid is None else K.take_rows(c.valid, rows2)
         # re-group the distinct rows by the outer keys only. A fresh live2
         # word leads: the gathered words' embedded live bit reflects the
         # ORIGINAL rows' liveness, not the distinct slots' (dead slots gather
@@ -2846,7 +2892,7 @@ class Executor:
         w3 = live2[order3]
         if cvalid2 is not None:
             w3 = w3 & cvalid2[order3]
-        vals = c.data[rows2][order3]
+        vals = K.take_rows(c.data, rows2)[order3]
         if agg.fn == "count":
             out = K.segment_reduce(vals, gid3, w3, g3cap, "count")
             col = Column(out.astype(jnp.int64), INT64)
@@ -2907,7 +2953,7 @@ class Executor:
             flags = K._word_flags(sorted_p)
             gid = K.fast_cumsum(flags.astype(jnp.int32)) - 1
             nlive = child.nrows
-            ng = int(gid[nlive - 1]) + 1 if nlive else 0
+            ng = int(host_read("ngroups", gid[nlive - 1])) + 1 if nlive else 0
         else:
             gid = jnp.zeros(child.cap, jnp.int32)
             ng = 1 if child.nrows else 0
@@ -2933,7 +2979,7 @@ class Executor:
                     vals = cums - base[gid] + 1
                 else:
                     # rank: 1 + rows before the first row of this order-group
-                    n_og = int(ogid[child.nrows - 1]) + 1 if child.nrows else 1
+                    n_og = int(host_read("ngroups", ogid[child.nrows - 1])) + 1 if child.nrows else 1
                     og_first_pos = K.segment_starts(ogid, bucket_cap(max(n_og, 1)))
                     vals = og_first_pos[ogid] - part_first[gid] + 1
             out_sorted = vals.astype(jnp.int64)
@@ -2955,10 +3001,10 @@ class Executor:
                 # (raw dictionary codes are in encounter order)
                 ranks, sorted_dict = sort_dictionary(c)
                 c = Column(ranks, c.dtype, c.valid, sorted_dict)
-            sdata = c.data[order]
+            sdata = K.take_rows(c.data, order)
             w = live[order]
             if c.valid is not None:
-                w = w & c.valid[order]
+                w = w & K.take_rows(c.valid, order)
             dtype = c.dtype
 
         # Classify the frame. SQL default: whole partition without ORDER BY,
@@ -2998,7 +3044,7 @@ class Executor:
                 # in-frame, so read the running value at the peer-group end
                 oflags = K._word_flags([gid] + sorted_ow)
                 ogid = K.fast_cumsum(oflags.astype(jnp.int32)) - 1
-                n_og = int(ogid[child.nrows - 1]) + 1 if child.nrows else 1
+                n_og = int(host_read("ngroups", ogid[child.nrows - 1])) + 1 if child.nrows else 1
                 ogcap = bucket_cap(max(n_og, 1))
                 og_first = K.segment_starts(ogid, ogcap)
                 og_count = K.segment_reduce(
@@ -3025,7 +3071,7 @@ class Executor:
                 # so take the cumulative value at the END of the peer group
                 oflags = K._word_flags([gid] + sorted_ow)
                 ogid = K.fast_cumsum(oflags.astype(jnp.int32)) - 1
-                n_og = int(ogid[child.nrows - 1]) + 1 if child.nrows else 1
+                n_og = int(host_read("ngroups", ogid[child.nrows - 1])) + 1 if child.nrows else 1
                 ogcap = bucket_cap(max(n_og, 1))
                 og_first = K.segment_starts(ogid, ogcap)
                 og_count = K.segment_reduce(
@@ -3160,7 +3206,7 @@ class Executor:
                 fetch = [col.data[:1]]
                 if col.valid is not None:
                     fetch.append(col.valid[:1])
-                got = jax.device_get(fetch)
+                got = host_read("scalar", fetch)
                 v = got[0][0]
                 valid = True if col.valid is None else bool(got[1][0])
                 self._scalar_cache[key] = (
@@ -3208,9 +3254,9 @@ class Executor:
         cols = {}
         for name, c in table.columns.items():
             cols[name] = Column(
-                c.data[idx],
+                K.take_rows(c.data, idx),
                 c.dtype,
-                None if c.valid is None else c.valid[idx],
+                None if c.valid is None else K.take_rows(c.valid, idx),
                 c.dictionary,
                 c.subset_stats(),
                 owned=True,
@@ -3376,8 +3422,8 @@ class Executor:
                 idx = _dyn_slice(order, start, wcap)
                 cols = {
                     name: Column(
-                        c.data[idx], c.dtype,
-                        None if c.valid is None else c.valid[idx],
+                        K.take_rows(c.data, idx), c.dtype,
+                        None if c.valid is None else K.take_rows(c.valid, idx),
                         c.dictionary,
                     )
                     for name, c in child.columns.items()

@@ -24,6 +24,7 @@ import pyarrow as pa
 import pyarrow.compute as pc
 
 from ..dtypes import BOOL, DATE, DType, FLOAT64, INT32, INT64, STRING
+from ..obs.tally import host_read
 from .columnar import Column, Table, sort_dictionary, unify_dictionaries
 
 _EPOCH = datetime.date(1970, 1, 1)
@@ -687,8 +688,9 @@ class Evaluator:
             ]
             return Column(codes, STRING, valid, enc.dictionary)
         # large: materialize row-wise on host (rare path)
-        av = np.asarray(da)[np.clip(np.asarray(a.data), 0, len(da) - 1)]
-        bv = np.asarray(db)[np.clip(np.asarray(b.data), 0, len(db) - 1)]
+        a_codes, b_codes = host_read("host_eval", [a.data, b.data])
+        av = np.asarray(da)[np.clip(a_codes, 0, len(da) - 1)]
+        bv = np.asarray(db)[np.clip(b_codes, 0, len(db) - 1)]
         joined = pc.binary_join_element_wise(
             pa.array(av.astype(object)), pa.array(bv.astype(object)), ""
         )
@@ -739,7 +741,7 @@ def _cast_column(c: Column, target: DType, cap: int) -> Column:
         return c
     if target.is_string:
         # non-string -> string: format on host via dictionary of distinct vals
-        arr = np.asarray(c.data)
+        arr = host_read("host_eval", c.data)
         if src.is_decimal:
             vals = arr / 10**src.scale
             strs = np.array([f"{v:.{src.scale}f}" for v in vals], dtype=object)

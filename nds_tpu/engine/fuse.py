@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 
 from ..dtypes import FLOAT64, INT64
+from ..obs.tally import host_read
 from ..ops import kernels as K
 from . import expr as E
 from . import plan as P
@@ -999,7 +1000,7 @@ class FusedAggPipeline(_FusedBase):
         occ = out[0]
         # the ONE host sync of the fused path — the same occupied-group
         # count the eager direct aggregation fetches (K.mask_count)
-        ngroups = int(jnp.sum(occ, dtype=jnp.int32))
+        ngroups = int(host_read("ngroups", jnp.sum(occ, dtype=jnp.int32)))
         if ngroups == 0:
             return self._empty_output()
         gcap = bucket_cap(ngroups)

@@ -12,11 +12,14 @@ from __future__ import annotations
 import os
 import threading
 from time import monotonic as _monotonic, perf_counter as _perf
+from time import time_ns as _time_ns
 from typing import Optional
 
 import pyarrow as pa
 import pyarrow.dataset as pads
 
+from ..obs import tally as _tally
+from ..obs.tally import host_read
 from ..schema import get_schemas, get_maintenance_schemas
 from . import expr as E
 from . import plan as P
@@ -389,6 +392,13 @@ class Catalog:
 
         tracer = _obs_trace.current() or getattr(self.session, "tracer", None)
         t0 = _perf() if tracer is not None else 0.0
+        t0_ns = _time_ns() if tracer is not None else 0
+        # what the load spent, for the span: storage read + Arrow decode,
+        # host encode (dictionary codes, padding, stats), host-to-device copy
+        spent = (
+            {"read_ms": 0.0, "encode_ms": 0.0, "h2d_ms": 0.0}
+            if tracer is not None else None
+        )
         # capture THIS load's snapshot handle: a concurrent stream
         # re-pinning the shared entry must not swap the manifest (or the
         # column cache) out from under an in-flight read. When the
@@ -434,6 +444,7 @@ class Catalog:
 
             def _load(cols_to_load):
                 arrow = e.arrow
+                t_read = _perf()
                 if arrow is None:
                     arrow = self._dataset(
                         e, snapshot=snap,
@@ -441,7 +452,9 @@ class Catalog:
                     ).to_table(columns=cols_to_load)
                 else:
                     arrow = arrow.select(cols_to_load)
-                return self._to_device(name, arrow, e)
+                if spent is not None:
+                    spent["read_ms"] += (_perf() - t_read) * 1000.0
+                return self._to_device(name, arrow, e, spent)
 
             try:
                 t = _load(missing)
@@ -472,9 +485,10 @@ class Catalog:
                 if tracer is not None:
                     tracer.emit(
                         "catalog_load", table=name, columns=len(columns),
-                        loaded=len(columns), rows=t.nrows,
+                        loaded=len(columns), rows=t.nrows, t0_ns=t0_ns,
                         dur_ms=round((_perf() - t0) * 1000.0, 3),
                         cache="miss",
+                        **{k: round(v, 3) for k, v in spent.items()},
                     )
                 return Table(
                     {c: t.columns[c] for c in columns}, t.nrows
@@ -493,12 +507,14 @@ class Catalog:
                 columns=len(columns),
                 loaded=len(missing),
                 rows=e.nrows,
+                t0_ns=t0_ns,
                 dur_ms=round((_perf() - t0) * 1000.0, 3),
                 cache=(
                     "hit" if not missing
                     else "miss" if len(missing) == len(columns)
                     else "partial"
                 ),
+                **{k: round(v, 3) for k, v in spent.items()},
             )
         from ..schema import TABLE_PRIMARY_KEYS
 
@@ -524,8 +540,22 @@ class Catalog:
                 out.unique_key = frozenset(pk)
         return out
 
-    def _to_device(self, name, arrow, e: _Entry):
-        t = table_from_arrow(arrow, e.schema, with_stats=True)
+    def _to_device(self, name, arrow, e: _Entry, spent=None):
+        """`spent` (a traced load's read_ms/encode_ms/h2d_ms) gets the host
+        encode and the host-to-device copy, the copy timed to completion
+        once per table: a first load is not pipelined with anything (the
+        statement's first kernel needs these very buffers), so the wait
+        costs nothing there."""
+        h2d = None if spent is None else [0.0]
+        t0 = _perf()
+        t = table_from_arrow(arrow, e.schema, with_stats=True, h2d=h2d)
+        if spent is not None:
+            spent["encode_ms"] += (_perf() - t0 - h2d[0]) * 1000.0
+            t0 = _perf()
+            for c in t.columns.values():
+                # nds-lint: disable=host-read-seam
+                c.data.block_until_ready()
+            spent["h2d_ms"] += (h2d[0] + _perf() - t0) * 1000.0
         mesh = self.session.mesh
         if mesh is None:
             return t
@@ -549,7 +579,7 @@ class Catalog:
             import numpy as np
 
             def _put(x, spec):
-                host = np.asarray(x)
+                host = host_read("reshard", x)
                 return jax.make_array_from_callback(
                     host.shape, spec, lambda idx: host[idx]
                 )
@@ -663,20 +693,50 @@ class Result:
         request id + tenant instead of aliasing across concurrent
         requests on the shared session."""
         if self._table is None:
-            self.executor = self.session._executor(tracer=tracer)
-            self._table = self.executor.execute(self.plan)
-            # commit this statement's buffered cardinality records (one
-            # merge+write per touched key, not per executed node) so a
-            # second run — or another process sharing the store dir —
-            # plans from what this one measured
-            store = getattr(self.session, "feedback_store", None)
-            if store is not None:
-                with self.session.cache_lock:
-                    store.flush()
+            self._run(tracer, to_arrow=False)
         return self._table
 
     def collect(self, tracer=None) -> pa.Table:
-        return table_to_arrow(self.table(tracer=tracer))
+        return self._run(tracer, to_arrow=True)
+
+    def _run(self, tracer, to_arrow: bool):
+        """Where a statement's execution really happens (`run_script` only
+        plans): the executor's root, then the collect. Traced, the two are
+        one `result_span`, what a caller's clock around `collect()` times
+        from outside; the executor's tally stays bound throughout, so the
+        collect's compaction and read are counted too (at depth -1)."""
+        executed = self._table is None
+        if executed:
+            self.executor = self.session._executor(tracer=tracer)
+        tally = self.executor.tally if self.executor is not None else None
+        t0_ns = _time_ns()
+        t0 = _perf()
+        arrow = None
+        with _tally.bind(tally):
+            if executed:
+                self._table = self.executor.execute(self.plan)
+                # commit this statement's buffered cardinality records (one
+                # merge+write per touched key, not per executed node) so a
+                # second run — or another process sharing the store dir —
+                # plans from what this one measured
+                store = getattr(self.session, "feedback_store", None)
+                if store is not None:
+                    with self.session.cache_lock:
+                        store.flush()
+            t1 = _perf()
+            if to_arrow:
+                arrow = table_to_arrow(self._table)
+        if tally is not None and (executed or to_arrow):
+            t2 = _perf()
+            outside = tally.take()  # counted outside every op_span
+            tally.tracer.emit(
+                "result_span", exec_id=tally.exec_id, t0_ns=t0_ns,
+                dur_ms=round((t2 - t0) * 1000.0, 3),
+                exec_ms=round((t1 - t0) * 1000.0, 3) if executed else 0.0,
+                to_arrow_ms=round((t2 - t1) * 1000.0, 3) if to_arrow else 0.0,
+                **outside,
+            )
+        return arrow
 
     def to_pylist(self):
         return self.collect().to_pylist()
@@ -743,6 +803,14 @@ class Session:
         from ..obs.trace import tracer_from_conf
 
         self.tracer = tracer_from_conf(self.conf)
+        if self.tracer is not None and (
+            self.tracer.path is not None or self.tracer.sink is not None
+        ):
+            # which program jax traced, lowered, compiled or loaded, as
+            # `xla_compile` events; a ring-only tracer does not pay for it
+            from ..obs.trace import watch_compiles
+
+            watch_compiles(self.tracer)
         # live telemetry (obs/metrics.py + obs/httpserv.py): with
         # engine.metrics_port / NDS_METRICS_PORT set, tracer_from_conf
         # started the process-wide /metrics + /statusz endpoint and
@@ -1384,7 +1452,9 @@ def _pk_holds(t, pk) -> bool:
     big = jnp.iinfo(jnp.int64).max
     w = jnp.where(t.row_mask(), words[0], big)
     ws = w[K.kv_sort_perm(w)]
-    return not bool(jnp.any((ws[1:] == ws[:-1]) & (ws[1:] != big)))
+    return not bool(host_read(
+        "pk_verify", jnp.any((ws[1:] == ws[:-1]) & (ws[1:] != big))
+    ))
 
 
 def prune_columns(node: P.PlanNode, catalog=None) -> P.PlanNode:

@@ -178,7 +178,7 @@ class SpillPool:
         """Spill a device Table's live rows to the host tier. One batched
         device->host transfer for every buffer; arrays are trimmed to the
         live row count so the pool never holds capacity padding."""
-        import jax
+        from ..obs.tally import host_read  # imports jax, as this path does
 
         table = table.compacted()
         nrows = table.nrows
@@ -189,7 +189,7 @@ class SpillPool:
             flat.append(c.data)
             if c.valid is not None:
                 flat.append(c.valid)
-        fetched = iter(jax.device_get(flat)) if flat else iter(())
+        fetched = iter(host_read("spill", flat)) if flat else iter(())
         datas, valids = [], []
         for c in cols:
             datas.append(np.asarray(next(fetched))[:nrows].copy())

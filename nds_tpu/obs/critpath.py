@@ -21,10 +21,33 @@ construction):
                     what a perfectly balanced exchange would give back
     spill-io        measured `spill` dur_ms (partition + segment IO +
                     per-partition execution of the out-of-core op)
-    catalog-load    measured `catalog_load` dur_ms
-    execute         remaining root op_span inclusive time: plan-node
-                    device compute + dispatch + any jit compile paid
-                    inside the node (first-touch pipelines)
+    catalog-load    measured `catalog_load` dur_ms; split into `read`
+                    (storage read + Arrow decode), `encode` (dictionary
+                    codes, padding, stats) and `h2d` (host-to-device copy)
+                    in proportion to the span's read_ms / encode_ms /
+                    h2d_ms where it carries them
+    execute         logs WITHOUT `result_span` only (written before it
+                    existed): remaining root op_span inclusive time, one
+                    lump of device wait, launches, jax tracing, cache
+                    loads and Python. With a `result_span` the statement's
+                    execution is split by cause instead, each instant of
+                    the timeline going to the first of these that covers
+                    it (`t0_ns` + `dur_ms` intervals), so the buckets stay
+                    disjoint:
+    device-wait     `host_read`: the host blocked on a device-to-host read
+                    (device work still queued, plus the copy)
+    xla-compile     `xla_compile` stage compile that jax's persistent
+                    cache did not serve
+    cache-load      `xla_compile` stage compile served by that cache, and
+                    `aot_cache` loads (executable deserialization)
+    jit-trace       `xla_compile` stages trace and lower
+    exec-lookup     `exec_cache` dur_ms (the lookup and, on a miss, the
+                    pipeline build) not already under a compile stage
+    launch          `launch_ms` of the op_spans and the result_span: host
+                    time inside seamed kernel calls and fused-pipeline
+                    calls, less the compile stages that fell inside them
+    host-python     the rest of the `result_span`: Python between
+                    launches, dictionary work, Arrow assembly
     ladder-retry    failed attempts' wall (`ladder_rung.attempt_ms`)
     backoff-wait    deliberate sleeps between rungs (delay_s)
     hung-wait       a watchdog-abandoned attempt's budget
@@ -36,6 +59,10 @@ construction):
     prune-planning  measured `scan_prune` dur_ms (zone-map evaluation
                     at plan time — carved out of what used to be the
                     plan-host residual)
+    plan-budget     measured `plan_budget` dur_ms (the static budgeter;
+                    on a table's first use it reads the row count from
+                    storage metadata, seconds for a partitioned fact
+                    table) — carved out of plan-host the same way
     router-queue    `route_request` queue_ms: router-edge admission
                     (verdict cache lookup / /plan probe + replica pick)
                     before the first forward left the router
@@ -65,9 +92,12 @@ MAX_RESIDUAL_FRAC = 0.5
 
 #: cause names in render order
 CAUSE_ORDER = (
-    "execute", "exchange-wait", "spill-io", "catalog-load", "ladder-retry",
+    "execute", "device-wait", "launch", "jit-trace", "xla-compile",
+    "cache-load", "exec-lookup", "host-python", "exchange-wait", "spill-io",
+    "catalog-load", "read", "encode", "h2d", "ladder-retry",
     "backoff-wait", "hung-wait", "ingest-decode", "ingest-commit-wait",
-    "prune-planning", "router-queue", "router-forward", "plan-host",
+    "prune-planning", "plan-budget", "router-queue", "router-forward",
+    "plan-host",
 )
 
 
@@ -79,7 +109,9 @@ def _group_query_events(events) -> dict:
         if kind in ("op_span", "query_span", "exchange", "spill",
                     "catalog_load", "ladder_rung", "watchdog_fire",
                     "kernel_span", "ingest_chunk", "scan_prune",
-                    "route_request"):
+                    "route_request", "host_read", "result_span",
+                    "xla_compile", "aot_cache", "exec_cache",
+                    "plan_budget"):
             q = ev.get("query") or "<unscoped>"
             out.setdefault(q, []).append(ev)
     return out
@@ -126,6 +158,119 @@ def _op_tree_chain(spans) -> list:
     return chain
 
 
+# painting order of a traced execution's timeline: where several intervals
+# cover an instant, the first of these gets it
+_PAINT = ("device-wait", "xla-compile", "cache-load", "jit-trace",
+          "exchange-wait", "spill-io", "catalog-load", "exec-lookup",
+          "result")
+
+
+def _interval(ev):
+    """(start_ns, end_ns) of a span event: `t0_ns` where the log has it,
+    else back from the emission time (`ts`, epoch ms: 1 ms resolution)."""
+    dur_ns = float(ev.get("dur_ms") or 0.0) * 1e6
+    t0 = ev.get("t0_ns")
+    if t0 is None:
+        t0 = float(ev.get("ts") or 0) * 1e6 - dur_ns
+    return float(t0), float(t0) + dur_ns
+
+
+def _paint_ms(intervals) -> dict:
+    """Exclusive milliseconds per category of `[(start_ns, end_ns, cat)]`,
+    each instant counted once, for the first category of `_PAINT` among
+    those covering it."""
+    rank = {c: i for i, c in enumerate(_PAINT)}
+    points = []
+    for a, b, cat in intervals:
+        if b > a:
+            points.append((a, 1, rank[cat]))
+            points.append((b, -1, rank[cat]))
+    points.sort()
+    active = [0] * len(_PAINT)
+    out = [0.0] * len(_PAINT)
+    prev = None
+    for t, step, k in points:
+        if prev is not None and t > prev:
+            for i, n in enumerate(active):
+                if n:
+                    out[i] += t - prev
+                    break
+        active[k] += step
+        prev = t
+    return {c: out[i] / 1e6 for c, i in rank.items()}
+
+
+def _compile_cause(ev):
+    if ev.get("stage") != "compile":
+        return "jit-trace"
+    return "cache-load" if ev.get("cached") else "xla-compile"
+
+
+def _split_execution(results, spans, reads, compiles, aot_loads, lookups,
+                     cats, exchanges, spills) -> dict:
+    """The causes of a traced execution (see the module docstring), from
+    the `result_span`s of a query and what happened under them."""
+    iv = [(*_interval(e), "result") for e in results]
+    iv += [(*_interval(e), "device-wait") for e in reads]
+    iv += [(*_interval(e), _compile_cause(e)) for e in compiles]
+    iv += [(*_interval(e), "cache-load") for e in aot_loads]
+    iv += [(*_interval(e), "exec-lookup") for e in lookups]
+    iv += [(*_interval(e), "catalog-load") for e in cats]
+    iv += [(*_interval(e), "exchange-wait") for e in exchanges]
+    iv += [(*_interval(e), "spill-io") for e in spills]
+    ms = _paint_ms(iv)
+    # launches have no interval of their own (no event per launch): their
+    # host time is a sum, and the compile stages that fell inside seamed
+    # calls are already counted above
+    in_seam = _paint_ms([
+        (*_interval(e), "jit-trace") for e in compiles if e.get("in_seam")
+    ])["jit-trace"]
+    launch = sum(
+        float(e.get("launch_ms") or 0.0) for e in list(spans) + list(results)
+    )
+    launch = min(max(launch - in_seam, 0.0), ms["result"])
+    out = {c: ms[c] for c in _PAINT if c != "result"}
+    out["launch"] = launch
+    out["host-python"] = ms["result"] - launch
+    # a catalog load says what it spent: split its share accordingly
+    parts = {
+        k: sum(float(e.get(f"{k}_ms") or 0.0) for e in cats)
+        for k in ("read", "encode", "h2d")
+    }
+    total = sum(parts.values())
+    if total > 0:
+        cat_ms = out["catalog-load"]
+        for k, v in parts.items():
+            out[k] = cat_ms * v / total
+        out["catalog-load"] = 0.0
+    return out
+
+
+def _execution_detail(spans, results, reads, compiles) -> dict:
+    """Launches by kernel, reads by `why`, compiles by `fun`."""
+    launches = {}
+    for e in list(spans) + list(results):
+        for kernel, n in (e.get("launches") or {}).items():
+            launches[kernel] = launches.get(kernel, 0) + int(n)
+    by_why = {}
+    for e in reads:
+        rec = by_why.setdefault(e.get("why") or "?", {"count": 0, "ms": 0.0})
+        rec["count"] += 1
+        rec["ms"] = round(rec["ms"] + float(e.get("dur_ms") or 0.0), 3)
+    by_fun = {}
+    for e in compiles:
+        rec = by_fun.setdefault(
+            e.get("fun") or "?",
+            {"count": 0, "ms": 0.0, "fresh": 0},
+        )
+        rec["ms"] = round(rec["ms"] + float(e.get("dur_ms") or 0.0), 3)
+        if e.get("stage") == "compile":
+            rec["count"] += 1
+            if not e.get("cached"):
+                rec["fresh"] += 1
+    return {"launches": launches, "reads": by_why, "compiles": by_fun}
+
+
 def _skew_ms(ev) -> float:
     """The imbalance share of one exchange's wait: the time a perfectly
     balanced partition map would have given back, dur * (1 - 1/skew)."""
@@ -160,9 +305,11 @@ def critical_path(events) -> dict:
         runs = 0
         status = None
         spans = []
+        results, reads, compiles, aot_loads, lookups = [], [], [], [], []
+        cats, exchanges, spills = [], [], []
         exch_ms = skew_ms = spill_ms = cat_ms = 0.0
         ladder_ms = backoff_ms = hung_ms = kernel_ms = 0.0
-        decode_ms = commit_wait_ms = prune_ms = 0.0
+        decode_ms = commit_wait_ms = prune_ms = budget_ms = 0.0
         route_n = 0
         route_dur_ms = route_queue_ms = route_forward_ms = 0.0
         route_status = None
@@ -177,7 +324,20 @@ def critical_path(events) -> dict:
                     status = ev.get("status")
             elif kind == "op_span":
                 spans.append(ev)
+            elif kind == "result_span":
+                results.append(ev)
+            elif kind == "host_read":
+                reads.append(ev)
+            elif kind == "xla_compile":
+                compiles.append(ev)
+            elif kind == "aot_cache":
+                if ev.get("op") == "load" and ev.get("dur_ms") is not None:
+                    aot_loads.append(ev)
+            elif kind == "exec_cache":
+                if ev.get("dur_ms") is not None:
+                    lookups.append(ev)
             elif kind == "exchange":
+                exchanges.append(ev)
                 mesh_ops += 1
                 d = float(ev.get("dur_ms") or 0.0)
                 exch_ms += d
@@ -204,8 +364,10 @@ def critical_path(events) -> dict:
                 if exch_worst is None or sk > exch_worst[0]:
                     exch_worst = (sk, ev)
             elif kind == "spill":
+                spills.append(ev)
                 spill_ms += float(ev.get("dur_ms") or 0.0)
             elif kind == "catalog_load":
+                cats.append(ev)
                 cat_ms += float(ev.get("dur_ms") or 0.0)
             elif kind == "ladder_rung":
                 ladder_ms += float(ev.get("attempt_ms") or 0.0)
@@ -219,6 +381,8 @@ def critical_path(events) -> dict:
                 commit_wait_ms += float(ev.get("commit_ms") or 0.0)
             elif kind == "scan_prune":
                 prune_ms += float(ev.get("dur_ms") or 0.0)
+            elif kind == "plan_budget":
+                budget_ms += float(ev.get("dur_ms") or 0.0)
             elif kind == "route_request":
                 route_n += 1
                 route_dur_ms += float(ev.get("dur_ms") or 0.0)
@@ -251,16 +415,29 @@ def critical_path(events) -> dict:
         # out (floored: an exchange that outlived its op span under
         # clock jitter must not go negative)
         execute = max(root_incl - exch_ms - spill_ms - cat_ms, 0.0)
+        split = None
+        if results:
+            # a log with the statement's own boundary: the lump opens
+            split = _split_execution(
+                results, spans, reads, compiles, aot_loads, lookups, cats,
+                exchanges, spills,
+            )
+            execute = 0.0
+            exch_ms = split.pop("exchange-wait")
+            spill_ms = split.pop("spill-io")
+            cat_ms = split.pop("catalog-load")
         # hung-wait is capped at what the OTHER measured causes leave of
         # the wall (the abandoned attempt's partial spans may overlap the
         # budget; counting both would over-attribute)
         others = (
-            execute + exch_ms + spill_ms + cat_ms + ladder_ms + backoff_ms
-            + decode_ms + commit_wait_ms + prune_ms
+            execute + sum((split or {}).values())
+            + exch_ms + spill_ms + cat_ms + ladder_ms + backoff_ms
+            + decode_ms + commit_wait_ms + prune_ms + budget_ms
             + route_queue_ms + route_forward_ms
         )
         causes = {
             "execute": round(execute, 3),
+            **{k: round(v, 3) for k, v in (split or {}).items()},
             "exchange-wait": round(exch_ms, 3),
             "spill-io": round(spill_ms, 3),
             "catalog-load": round(cat_ms, 3),
@@ -271,6 +448,7 @@ def critical_path(events) -> dict:
             "ingest-decode": round(decode_ms, 3),
             "ingest-commit-wait": round(commit_wait_ms, 3),
             "prune-planning": round(prune_ms, 3),
+            "plan-budget": round(budget_ms, 3),
             "router-queue": round(route_queue_ms, 3),
             "router-forward": round(route_forward_ms, 3),
         }
@@ -296,6 +474,7 @@ def critical_path(events) -> dict:
             "unattributed_ms": round(unattributed, 3),
             "kernel_ms": round(kernel_ms, 3),  # overlaps execute: info only
             "chain": _op_tree_chain(spans),
+            **_execution_detail(spans, results, reads, compiles),
         }
         if exch_worst is not None:
             sk, ev = exch_worst
@@ -373,6 +552,21 @@ def render(cp: dict, out=None) -> None:
             p(f"   {cause:<14}{ms:>12,.1f} ms  {share:>6.1%}")
         if rec.get("unattributed_ms"):
             p(f"   {'unattributed':<14}{rec['unattributed_ms']:>12,.1f} ms")
+        if rec.get("launches"):
+            p("   launches: " + ", ".join(
+                f"{k} {n}" for k, n in sorted(
+                    rec["launches"].items(), key=lambda kv: -kv[1])))
+        if rec.get("reads"):
+            p("   reads: " + ", ".join(
+                f"{why} {r['count']} ({r['ms']:,.1f} ms)"
+                for why, r in sorted(
+                    rec["reads"].items(), key=lambda kv: -kv[1]["ms"])))
+        if rec.get("compiles"):
+            top = sorted(rec["compiles"].items(),
+                         key=lambda kv: -kv[1]["ms"])[:8]
+            p("   compiles: " + ", ".join(
+                f"{fun} {c['count']} ({c['fresh']} fresh, {c['ms']:,.1f} ms)"
+                for fun, c in top))
         if rec["chain"]:
             hops = " -> ".join(
                 f"{c['node']} {c['dur_ms']:,.0f}ms" for c in rec["chain"][:6]

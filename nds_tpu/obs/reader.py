@@ -284,7 +284,16 @@ def profile_events(events) -> dict:
     # per-kernel dispatch totals (kernel_span events, kernel tracing mode):
     # the "which KERNEL under the hot operator" answer op_spans cannot give
     kernel_totals = {}
+    # seamed kernel entries by name (op_span/result_span `launches`): how
+    # many programs the operators launched, which op times cannot say
+    launch_totals = {}
+
+    def add_launches(ev):
+        for kernel, n in (ev.get("launches") or {}).items():
+            launch_totals[kernel] = launch_totals.get(kernel, 0) + int(n)
+
     for ev in spans:
+        add_launches(ev)
         q = ev.get("query") or "<unscoped>"
         node = ev.get("node", "?")
         qrec = queries.setdefault(q, dict(_EMPTY_QUERY, ops={}))
@@ -446,6 +455,8 @@ def profile_events(events) -> dict:
             tallies[
                 "pipelines_fused" if ev.get("fused") else "pipelines_eager"
             ] += 1
+        elif k == "result_span":
+            add_launches(ev)
         elif k == "kernel_span":
             kt = kernel_totals.setdefault(
                 ev.get("kernel") or "<unknown>",
@@ -492,6 +503,7 @@ def profile_events(events) -> dict:
         "queries": queries,
         "op_totals": op_totals,
         "kernel_totals": kernel_totals,
+        "launch_totals": launch_totals,
         "tallies": tallies,
         "plan_budget": budget,
         "feedback": feedback,
@@ -642,6 +654,9 @@ def merge_profiles(base: dict, extra: dict) -> dict:
         dst["count"] = dst.get("count", 0) + int(src.get("count") or 0)
         dst["dur_ms"] = dst.get("dur_ms", 0.0) + float(src.get("dur_ms") or 0.0)
         dst["n_rows"] = dst.get("n_rows", 0) + int(src.get("n_rows") or 0)
+    for name, n in (extra.get("launch_totals") or {}).items():
+        dst = base.setdefault("launch_totals", {})
+        dst[name] = dst.get(name, 0) + int(n)
     for name, v in (extra.get("tallies") or {}).items():
         base.setdefault("tallies", {})
         if name == "exchange_max_skew":

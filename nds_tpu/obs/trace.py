@@ -39,10 +39,18 @@ readers tolerate; any earlier malformed line is a hard error —
 `obs.reader.iter_events`).
 
 Event taxonomy (golden schema — tests/test_obs.py asserts it):
-every event carries `ts` (epoch ms), `kind`, `app`, the stamped
-CONTEXT_FIELDS (`trace_id`; see TraceContext), and (when a query scope
-is active, `faults.scope`) `query`; per-kind required fields are listed in
-EVENT_SCHEMA below. `trace_id` is stamped centrally by `Tracer.emit` —
+every event carries `ts` (epoch ms, taken when the event is emitted: a
+span's END), `kind`, `app`, the stamped CONTEXT_FIELDS (`trace_id`; see
+TraceContext), and (when a query scope is active, `faults.scope`) `query`;
+per-kind required fields are listed in EVENT_SCHEMA below.
+
+One clock: every event that carries `dur_ms` also carries `t0_ns`, the
+span's START as `time.time_ns()`: the realtime clock the profiler's host
+plane is stamped from, so a program span can be laid against the device
+operations of an `.xplane.pb` (PERF.md has the offset measured on the
+chip). The sites whose start matters take it themselves; for the rest
+`emit` derives it from the emission time and `dur_ms`. Logs written before
+this field still read: readers fall back to `ts` and `dur_ms`. `trace_id` is stamped centrally by `Tracer.emit` —
 emission sites must NOT pass it ad hoc unless the kind declares it in
 EVENT_SCHEMA (the `trace-event-schema` lint rule enforces this).
 """
@@ -80,13 +88,31 @@ EVENT_SCHEMA = {
     # one fused-pipeline execution (fused=False: eager per-stage fallback;
     # also carries `agg` when the pipeline has a fused aggregate tail)
     "pipeline_span": ("stages", "fused", "dur_ms"),
-    # one synchronized device-kernel dispatch (ops/kernels.py hot kernels;
-    # only with kernel tracing on — engine.trace_kernels/NDS_TRACE_KERNELS —
-    # because the measurement blocks on the result, trading pipelining for
-    # per-kernel attribution below plan-node op_spans). `n` is the leading
-    # input length. Also records the Pallas-vs-jnp promotion measurements
-    # (kernel "segment_<fn>:jnp" / ":pallas", exec._pallas_promoted).
+    # one synchronized measurement of the Pallas-vs-jnp promotion A/B
+    # (kernel "segment_<fn>:jnp" / ":pallas", exec._measure_promotion);
+    # `n` is the leading input length. Kernel entry points no longer emit
+    # it: they count into the statement's tally (obs/tally.py), flushed as
+    # `op_span.launches`.
     "kernel_span": ("kernel", "dur_ms", "n"),
+    # one blocking device-to-host read (obs/tally.py host_read, the one
+    # seam for them): `why` from a short fixed vocabulary (nrows,
+    # mask_count, bounds, join_size, collect, scalar, ngroups, ...),
+    # `bytes` copied, `dur_ms` the wait (device work still queued plus the
+    # copy), `exec_id` + `depth` of the enclosing op_span (-1: outside
+    # every plan node, i.e. under the statement's result_span)
+    "host_read": ("why", "bytes", "dur_ms", "t0_ns", "exec_id", "depth"),
+    # one statement's execution as Result.collect / Result.table runs it
+    # (run_script only plans): `exec_ms` the executor's root, `to_arrow_ms`
+    # the collect. Optional: launches / launch_ms / reads / read_wait_ms
+    # counted outside every op_span (the collect's compaction and read)
+    "result_span": ("exec_id", "t0_ns", "dur_ms", "exec_ms", "to_arrow_ms"),
+    # one jax compile stage of one program (jax.monitoring time spans,
+    # `watch_compiles`): stage trace | lower | compile, `fun` the jitted
+    # function's name, `cached` True when jax's persistent compilation
+    # cache served the compile stage. Optional: exec_id, depth (the
+    # enclosing op_span, when an executor's tally is bound), in_seam (it
+    # fell inside a counted kernel call, so inside `launch_ms`)
+    "xla_compile": ("stage", "fun", "cached", "dur_ms", "t0_ns"),
     # executable-cache probe for a pipeline (hit=True: an executable for
     # this (structure, dtypes, bucket) already existed this session)
     "exec_cache": ("pipeline", "bucket", "hit"),
@@ -119,7 +145,9 @@ EVENT_SCHEMA = {
     "plan_verify": ("stage", "ok"),
     # the static plan budgeter's per-statement verdict (engine.plan_budget;
     # analysis/budget.py): modeled peak vs the working-set budget, plus
-    # peak_blocked_bytes/window_rows/nodes detail
+    # peak_blocked_bytes/window_rows/nodes detail. Optional: dur_ms (the
+    # analysis' wall: a table's first use reads its row count from
+    # storage metadata — the critical-path plan-budget cause)
     "plan_budget": ("verdict", "peak_bytes", "budget_bytes"),
     # the host-RSS watermark sampler pre-empted memory pressure mid-query
     # (report.py; shrinks the blocked-union window before the allocator
@@ -252,18 +280,6 @@ def resolve_trace_dir(conf: dict | None = None) -> str | None:
     return str(v) if v else None
 
 
-def resolve_kernel_trace(conf: dict | None = None) -> bool:
-    """Per-kernel dispatch timing (conf `engine.trace_kernels`, env
-    NDS_TRACE_KERNELS). Off by default: each traced kernel call blocks on
-    its result, so this is a profiling mode, not a steady-state default."""
-    v = None
-    if conf:
-        v = conf.get("engine.trace_kernels")
-    if v is None:
-        v = os.environ.get("NDS_TRACE_KERNELS")
-    return str(v).lower() in ("1", "on", "true") if v is not None else False
-
-
 def resolve_rotate_bytes(conf: dict | None = None) -> int:
     """Trace-segment rotation threshold in bytes (conf
     `engine.trace_rotate_bytes`, env NDS_TRACE_ROTATE_BYTES); 0 — the
@@ -386,13 +402,10 @@ class Tracer:
     (historically it silently reopened the file and leaked the handle)."""
 
     def __init__(self, trace_dir: str | None = None, app_id: str | None = None,
-                 kernel_spans: bool = False, sink=None, rotate_bytes: int = 0,
+                 sink=None, rotate_bytes: int = 0,
                  collect: bool | None = None, context=None, ring=None):
         self.app_id = app_id or default_app_id()
         self.trace_dir = trace_dir
-        # opt-in per-kernel dispatch timing: the ops.kernels instrumentation
-        # only fires when the thread-bound tracer carries this flag
-        self.kernel_spans = kernel_spans
         # live-telemetry bridge (obs/metrics.py): every emitted event also
         # updates the sink's counters/status; None = no live metrics
         self.sink = sink
@@ -463,8 +476,9 @@ class Tracer:
                         f"(close tracers only after their last emitter)"
                     )
             return
+        now_ns = time.time_ns()
         ev = {
-            "ts": int(time.time() * 1000), "kind": kind, "app": self.app_id,
+            "ts": now_ns // 1_000_000, "kind": kind, "app": self.app_id,
             "trace_id": self.context.trace_id,
         }
         if "query" not in fields:
@@ -473,6 +487,12 @@ class Tracer:
                 ev["query"] = scope
         ev.update(fields)  # an explicit trace_id (serve's per-request
         # forwarding tracer) overrides the stamped context here
+        if "dur_ms" in fields and "t0_ns" not in fields:
+            # a span whose site did not take its own start: emitted at its
+            # end, so the start is the emission time less the duration
+            dur = fields["dur_ms"]
+            if dur is not None:
+                ev["t0_ns"] = now_ns - int(float(dur) * 1e6)
         if self.sink is not None:
             try:
                 self.sink.record(ev)
@@ -579,12 +599,11 @@ def tracer_from_conf(conf: dict | None = None, app_id: str | None = None,
         if sink is None and ring is None:
             return None
         return Tracer(
-            None, app_id=app_id, kernel_spans=resolve_kernel_trace(conf),
-            sink=sink, collect=False, context=context, ring=ring or False,
+            None, app_id=app_id, sink=sink, collect=False, context=context,
+            ring=ring or False,
         )
     return Tracer(
-        d, app_id=app_id, kernel_spans=resolve_kernel_trace(conf),
-        sink=sink, rotate_bytes=resolve_rotate_bytes(conf),
+        d, app_id=app_id, sink=sink, rotate_bytes=resolve_rotate_bytes(conf),
         context=context, ring=ring or False,
     )
 
@@ -620,3 +639,73 @@ class bind:
 def current() -> Tracer | None:
     """The tracer bound to this thread, or None (events dropped)."""
     return getattr(_tls, "tracer", None)
+
+
+# ---------------------------------------------------------------------------
+# jax's own compile stages as `xla_compile` events
+# ---------------------------------------------------------------------------
+
+#: jax.monitoring time-span events (jax 0.9.0: `fun_name`, start and end in
+#: epoch seconds) -> the `stage` of the `xla_compile` event
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+# process-lifetime state of the one listener pair: the tracer that events
+# of a thread with no bound tracer go to. Worst case under a race is a
+# second pair of listeners, i.e. doubled events in a log
+# nds-lint: disable=mutable-module-global
+_COMPILE_WATCH = {}
+
+
+def _on_compile_event(event, **_):
+    if event == _CACHE_HIT_EVENT:
+        # reported on the compiling thread, inside its backend-compile span
+        _tls.cache_hit = True
+
+
+def _on_compile_span(event, start, end, fun_name="", **_):
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    cached = False
+    if stage == "compile":
+        cached = getattr(_tls, "cache_hit", False)
+        _tls.cache_hit = False
+    tracer = current() or _COMPILE_WATCH.get("tracer")
+    if tracer is None or tracer._closed:
+        return
+    from . import tally as _tally  # lazy: imports jax, as the caller has
+
+    extra = {}
+    tl = _tally.current()
+    if tl is not None:
+        extra = {"exec_id": tl.exec_id, "depth": tl.depth}
+        if tl.in_seam:
+            extra["in_seam"] = True
+    fun = str(fun_name)
+    if fun.startswith("jit(") and fun.endswith(")"):
+        fun = fun[4:-1]  # lower and compile say `jit(f)`, trace says `f`
+    tracer.emit(
+        "xla_compile", stage=stage, fun=fun, cached=cached,
+        dur_ms=round((end - start) * 1000.0, 3), t0_ns=int(start * 1e9),
+        **extra,
+    )
+
+
+def watch_compiles(tracer) -> None:
+    """Emit an `xla_compile` event for every program jax traces, lowers,
+    compiles or loads from its persistent cache, into the compiling
+    thread's bound tracer, else into `tracer`. A `Session` calls this when
+    its tracer writes a file or feeds a metrics sink (a ring-only tracer
+    does not pay for it); the listeners register once per process."""
+    first = not _COMPILE_WATCH
+    _COMPILE_WATCH["tracer"] = tracer
+    if first:
+        import jax.monitoring
+
+        jax.monitoring.register_event_listener(_on_compile_event)
+        jax.monitoring.register_event_time_span_listener(_on_compile_span)

@@ -29,13 +29,13 @@ Design rules (TPU/XLA-first):
 from __future__ import annotations
 
 from functools import partial, wraps
-from time import perf_counter as _perf
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs import trace as _obs_trace
+from ..obs import tally as _tally
+from ..obs.tally import host_read
 
 jax.config.update("jax_enable_x64", True)
 
@@ -44,28 +44,20 @@ U64 = jnp.uint64
 
 
 # ---------------------------------------------------------------------------
-# Per-kernel dispatch timing (`kernel_span` events)
+# The launch seam
 #
-# Plan-node op_spans (PR 3) say WHICH operator is slow; they cannot say
-# which KERNEL under it, nor whether an XLA-via-jnp formulation would lose
-# to a Pallas one — the data the promotion policy needs. With kernel
-# tracing on (engine.trace_kernels / NDS_TRACE_KERNELS, surfaced through
-# the thread-bound Tracer's `kernel_spans` flag), every decorated kernel
-# entry point below times its dispatch TO COMPLETION (block_until_ready —
-# async pipelining is deliberately traded for attribution; this is a
-# profiling mode) and emits one `kernel_span` event. Zero-cost when off:
-# one thread-local read + None check per call. Calls made while jax is
-# TRACING (a fused pipeline body re-entering segment_reduce) are skipped —
-# timing abstract values is meaningless and the side effect must not bake
-# into an executable.
+# Plan-node op_spans say WHICH operator is slow; they cannot say how many
+# programs it launched. Every decorated kernel entry point below counts
+# itself into the statement's tally (obs/tally.py: a dict add, and for the
+# outermost call of a nest one clock pair around its HOST side) and the
+# executor flushes the counts as `op_span.launches` / `launch_ms`. Nothing
+# here waits for the device: a seam that synchronizes changes what it
+# measures, and device time is the profiler's to report. An entry is at
+# least one program launch (sort_by_words runs one sort per word). Zero
+# cost with no tally bound: one thread-local read + None check per call.
+# Calls made while jax is TRACING (a fused pipeline body re-entering
+# segment_reduce) are skipped — they launch nothing.
 # ---------------------------------------------------------------------------
-
-
-def _ktracer():
-    t = _obs_trace.current()
-    if t is not None and getattr(t, "kernel_spans", False):
-        return t
-    return None
 
 
 def _has_jax_tracer(args) -> bool:
@@ -79,36 +71,29 @@ def _has_jax_tracer(args) -> bool:
     return False
 
 
-def _lead_n(args) -> int:
-    """Leading input length for the event's `n` field (best effort)."""
-    for a in args:
-        if isinstance(a, (list, tuple)) and a:
-            a = a[0]
-        shape = getattr(a, "shape", None)
-        if shape:
-            return int(shape[0])
-    return 0
-
-
 def _ktraced(name):
     def deco(fn):
         @wraps(fn)
         def wrapped(*args, **kwargs):
-            t = _ktracer()
+            t = _tally.current()
             if t is None or _has_jax_tracer(args):
                 return fn(*args, **kwargs)
-            t0 = _perf()
-            out = fn(*args, **kwargs)
-            jax.block_until_ready(out)
-            t.emit(
-                "kernel_span",
-                kernel=name,
-                dur_ms=round((_perf() - t0) * 1000.0, 3),
-                n=_lead_n(args),
-            )
-            return out
+            token = t.enter(name)
+            if token is None:
+                return fn(*args, **kwargs)
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                t.leave(token)
         return wrapped
     return deco
+
+
+@_ktraced("take_rows")
+def take_rows(data: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """`data[idx]`: the eager row gather of joins, compaction and sorts,
+    one program launch per column, behind the seam so it is counted."""
+    return data[idx]
 
 
 # ---------------------------------------------------------------------------
@@ -259,7 +244,7 @@ def compact_indices(mask: jnp.ndarray, out_cap: int) -> jnp.ndarray:
 
 
 def mask_count(mask: jnp.ndarray) -> int:
-    return int(jnp.sum(mask))
+    return int(host_read("mask_count", jnp.sum(mask)))
 
 
 # ---------------------------------------------------------------------------
@@ -346,7 +331,7 @@ def group_by_words(words, live_mask, nlive=None):
         nlive = mask_count(live_mask)
     if nlive == 0:
         return order, gid, 0
-    ngroups = int(gid[nlive - 1]) + 1
+    ngroups = int(host_read("ngroups", gid[nlive - 1])) + 1
     return order, gid, ngroups
 
 
@@ -673,7 +658,7 @@ def join_candidates(lkeys, lvalids, llive, rkeys, rvalids, rlive):
     # a candidate total past 2^31 would silently wrap into garbage pair
     # indices. Fail loudly instead (such an out_cap wouldn't allocate
     # anyway; the realistic trigger is a pathological cross-join-like key).
-    total = int(jnp.sum(counts, dtype=jnp.int64))
+    total = int(host_read("join_size", jnp.sum(counts, dtype=jnp.int64)))
     _check_pair_count(total)
     # genuine import cycle: engine.columnar jits through ops.kernels, so a
     # module-level import here would deadlock package init; cold path

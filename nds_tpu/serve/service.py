@@ -172,7 +172,7 @@ class _RequestTracer:
         }
 
     def __getattr__(self, name):
-        # delegate app_id / sink / kernel_spans / close ... to the real
+        # delegate app_id / sink / close ... to the real
         # tracer (a None inner means an untraced session: emit() below
         # still tallies, then drops)
         return getattr(self._inner, name)

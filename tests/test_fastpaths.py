@@ -112,11 +112,11 @@ def test_oom_retry_reloads_all_requested_columns(monkeypatch):
     real = Catalog._to_device
     calls = {"n": 0}
 
-    def flaky(self, name, arrow, e):
+    def flaky(self, name, arrow, e, spent=None):
         calls["n"] += 1
         if calls["n"] == 1:
             raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
-        return real(self, name, arrow, e)
+        return real(self, name, arrow, e, spent)
 
     monkeypatch.setattr(Catalog, "_to_device", flaky)
     out = s.catalog.load("t", ["a", "b"])

@@ -420,7 +420,9 @@ def test_profile_cli_critical_path_and_bundle_check(tmp_path, capsys):
                       "--min_attributed", "0.9"])
     out = capsys.readouterr().out
     assert "critical path" in out and "q_cp" in out
-    assert "execute" in out
+    # a log with result_spans: the execute lump comes split by cause
+    assert "host-python" in out and "device-wait" in out
+    assert "\n   execute " not in out
     # bundle validation through the same CLI
     rec = FL.recorder()
     path = rec.flush("on_demand", trace_id="cli-test",

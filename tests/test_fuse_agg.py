@@ -9,8 +9,8 @@ boundaries; ineligible aggregates (ROLLUP, DISTINCT, blocked unions) pin
 to the eager path UNMARKED; blocked union-aggregation windows ride one
 fused wrapper executable instead of eager per-wrapper dispatches; full-
 column donation (`Column.owned` + `donate_ok`) stays safe under OOM wipes
-and multi-consumer plans; and `kernel_span` events land on schema and
-aggregate in the profiler.
+and multi-consumer plans; and the launch seam at the kernel entry points
+counts without synchronizing and skips calls made while jax traces.
 """
 
 import json
@@ -285,52 +285,80 @@ def test_owned_flag_semantics():
     assert all(not c.owned for c in base.columns.values())
 
 
-def test_kernel_span_schema_and_profiler_aggregation(tmp_path):
-    """NDS_TRACE_KERNELS mode: kernel entry points emit schema-valid
-    kernel_span events, and the profiler aggregates them into
-    kernel_totals (count/dur/rows per kernel)."""
+def test_launch_seam_counts_without_synchronizing(tmp_path, monkeypatch):
+    """The seam at every kernel entry point counts into the statement's
+    tally, flushed as `op_span.launches`: it emits no event per launch and
+    never calls `block_until_ready`. The profiler sums the counts."""
+    import jax
+
     from nds_tpu.obs import reader as R
     from nds_tpu.obs import trace as obs_trace
 
     s = Session(conf={
         "engine.trace_dir": str(tmp_path),
-        "engine.trace_kernels": "on",
         "engine.fuse": "off",  # eager path: kernels dispatch outside jit
     })
-    assert s.tracer.kernel_spans is True
+    assert not hasattr(s.tracer, "kernel_spans")
     s.register_arrow("t", _table(2000))
+
+    def no_sync(*a, **k):
+        raise AssertionError("the launch seam synchronized")
+
+    monkeypatch.setattr(jax, "block_until_ready", no_sync)
     with obs_trace.bind(s.tracer):
         s.sql("select k, sum(v) sv, min(v) mn from t where v > 0 "
               "group by k order by k").collect()
     s.tracer.close()
     events = R.read_events([str(tmp_path)], strict=True)
     assert R.validate_events(events) == []
-    spans = [e for e in events if e["kind"] == "kernel_span"]
-    assert spans, "no kernel_span events recorded"
-    for ev in spans:
-        assert isinstance(ev["kernel"], str)
-        assert isinstance(ev["dur_ms"], (int, float))
-        assert isinstance(ev["n"], int)
-    prof = R.profile_events(events)
-    kt = prof["kernel_totals"]
-    assert "segment_reduce_with_count" in kt
-    for rec in kt.values():
-        assert rec["count"] >= 1 and rec["dur_ms"] >= 0.0
-
-
-def test_kernel_span_off_by_default(tmp_path):
-    from nds_tpu.obs import reader as R
-    from nds_tpu.obs import trace as obs_trace
-
-    s = Session(conf={"engine.trace_dir": str(tmp_path),
-                      "engine.fuse": "off"})
-    assert s.tracer.kernel_spans is False
-    s.register_arrow("t", _table(500))
-    with obs_trace.bind(s.tracer):
-        s.sql("select k, sum(v) sv from t group by k").collect()
-    s.tracer.close()
-    events = R.read_events([str(tmp_path)], strict=True)
     assert not [e for e in events if e["kind"] == "kernel_span"]
+    spans = [e for e in events if e["kind"] == "op_span"]
+    launches = {}
+    for ev in spans:
+        assert isinstance(ev["launch_ms"], (int, float))
+        for kernel, n in ev["launches"].items():
+            assert isinstance(kernel, str) and isinstance(n, int) and n >= 1
+            launches[kernel] = launches.get(kernel, 0) + n
+    assert "segment_reduce_with_count" in launches
+    assert launches["take_rows"] >= 1
+    lt = R.profile_events(events)["launch_totals"]
+    assert lt == launches
+
+
+def test_launch_seam_skips_jax_tracing():
+    """Calls made while jax traces launch nothing and are not counted: a
+    fused aggregate pipeline whose body re-enters segment_reduce is one
+    launch, and a seamed gather inside a jit is none."""
+    import jax
+    import jax.numpy as jnp
+
+    from nds_tpu.obs import tally as obs_tally
+    from nds_tpu.obs.trace import Tracer
+    from nds_tpu.ops import kernels as K
+
+    s = Session()
+    s.tracer = tracer = Tracer()
+    s.register_arrow("t", _table(500))
+    s.sql("select k, sum(v) sv from t group by k").collect()
+    launches = {}
+    for ev in tracer.events:
+        if ev["kind"] in ("op_span", "result_span"):
+            for kernel, n in ev["launches"].items():
+                launches[kernel] = launches.get(kernel, 0) + n
+    assert launches.get("fused_agg_pipeline") == 1
+    assert "segment_reduce" not in launches
+    assert "segment_reduce_with_count" not in launches
+
+    data = jnp.arange(10, dtype=jnp.int64) * 3
+    idx = jnp.asarray([7, 0, 7, 2], dtype=jnp.int32)
+    t = obs_tally.Tally(tracer, 99)
+    with obs_tally.bind(t):
+        traced = jax.jit(lambda d: K.take_rows(d, idx))(data)
+        assert t.launches == {}
+        eager = K.take_rows(data, idx)
+        assert t.launches == {"take_rows": 1}
+    assert eager.tolist() == data[idx].tolist() == traced.tolist()
+    assert obs_tally.current() is None
 
 
 def test_pallas_auto_promotion_memo():

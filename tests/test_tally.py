@@ -1,0 +1,580 @@
+"""The split of the `execute` lump: `host_read` at every blocking
+device-to-host read, the launch tally behind the kernel seam, `result_span`
+at the statement's own boundary, `xla_compile` from jax's monitoring spans,
+`catalog_load` saying what it spent, one clock (`t0_ns`), and the readers
+inside the program (obs/critpath.py, `profile --critical-path`).
+
+On the CPU and at SF0.01: counts and attachment are exact here; every time
+belongs to a chip run (PERF.md)."""
+
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pyarrow as pa
+import pytest
+
+from nds_tpu import faults
+from nds_tpu.analysis import lint as L
+from nds_tpu.engine.session import Session
+from nds_tpu.obs import critpath as CP
+from nds_tpu.obs import reader as R
+from nds_tpu.obs import tally as T
+from nds_tpu.obs import trace as obs_trace
+from nds_tpu.obs.trace import EVENT_SCHEMA, Tracer, bind
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+NEW_KINDS = ("host_read", "result_span", "xla_compile")
+WHY = {"nrows", "mask_count", "bounds", "join_size", "ngroups", "scalar",
+       "collect", "sort_span", "exchange", "spill", "host_eval", "pk_verify",
+       "reshard"}
+
+
+@pytest.fixture(autouse=True)
+def _clean_env(monkeypatch):
+    monkeypatch.delenv("NDS_TRACE_DIR", raising=False)
+    monkeypatch.delenv("NDS_METRICS_PORT", raising=False)
+    faults.reset()
+    yield
+    faults.reset()
+
+
+@pytest.fixture(scope="module")
+def raw(tmp_path_factory):
+    """SF0.01 raw data of this module's own (the shared /tmp copy other
+    modules use is raced for by xdist workers)."""
+    out = tmp_path_factory.mktemp("sf001")
+    subprocess.run(
+        [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
+         "--parallel", "2", "--data_dir", str(out), "--overwrite_output"],
+        check=True, capture_output=True, cwd=REPO,
+    )
+    return str(out)
+
+
+def _tpcds_session(raw, tracer):
+    from nds_tpu.schema import get_schemas
+
+    s = Session()
+    s.tracer = tracer
+    schemas = get_schemas(True)
+    for t in ("store_sales", "date_dim", "item", "time_dim",
+              "household_demographics", "store"):
+        s.register_csv_dir(t, os.path.join(raw, t), schemas[t])
+    return s
+
+
+def _statements():
+    from nds_tpu.datagen.query_streams import instantiate
+
+    rng = np.random.default_rng(7)
+    return {f"query{q}": instantiate(q, rng, 0.01) for q in (3, 96)}
+
+
+def _small_session(tracer=None):
+    s = Session()
+    if tracer is not None:
+        s.tracer = tracer
+    s.register_arrow("t", pa.table({
+        "a": [1, 2, 3, 4, 2, 1], "b": [10, 20, 30, 40, 50, 60],
+        "c": ["x", "y", "x", "z", "y", "x"]}))
+    s.register_arrow("u", pa.table({"a": [1, 2, 3], "d": [7, 8, 9]}))
+    return s
+
+
+JOIN_SQL = ("select c, sum(b) sb, count(*) n from t join u on t.a = u.a "
+            "where b > 10 group by c order by c")
+
+
+def _run(session, sql, name):
+    with bind(session.tracer), faults.scope(name):
+        return session.sql(sql).collect()
+
+
+def _counts(events, query):
+    """(launches by kernel, reads by why) of one statement's events."""
+    launches, reads = {}, {}
+    for e in events:
+        if e.get("query") != query:
+            continue
+        if e["kind"] in ("op_span", "result_span"):
+            for k, n in e["launches"].items():
+                launches[k] = launches.get(k, 0) + n
+        elif e["kind"] == "host_read":
+            reads[e["why"]] = reads.get(e["why"], 0) + 1
+    return launches, reads
+
+
+# ---------------------------------------------------------------------------
+# schema, one clock
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("kind", NEW_KINDS)
+def test_new_kinds_are_in_the_golden_schema(kind):
+    assert "t0_ns" in EVENT_SCHEMA[kind] and "dur_ms" in EVENT_SCHEMA[kind]
+    ev = {"ts": 1, "kind": kind, "app": "a"}
+    problems = R.validate_events([ev])
+    assert problems and "missing fields" in problems[0]
+    ev.update({f: 0 for f in EVENT_SCHEMA[kind]})
+    assert R.validate_events([ev]) == []
+
+
+def test_emitted_events_validate_and_every_span_carries_t0_ns(tmp_path):
+    s = Session(conf={"engine.trace_dir": str(tmp_path)})
+    s.register_arrow("t", pa.table({"a": [1, 2, 3], "b": [1, 2, 3]}))
+    before_ns = time.time_ns()
+    _run(s, "select a, sum(b) sb from t group by a order by a", "q")
+    after_ns = time.time_ns()
+    s.tracer.close()
+    events = R.read_events([str(tmp_path)], strict=True)
+    assert R.validate_events(events) == []
+    kinds = {e["kind"] for e in events}
+    assert set(NEW_KINDS) <= kinds  # a file tracer watches compiles too
+    spans = [e for e in events if "dur_ms" in e]
+    assert {"op_span", "catalog_load", "pipeline_span", "exec_cache",
+            "plan_budget"} <= {e["kind"] for e in spans}
+    for e in spans:
+        assert isinstance(e["t0_ns"], int), e["kind"]
+        assert before_ns <= e["t0_ns"] <= after_ns, e["kind"]
+        # `ts` (epoch ms) stays the emission time: the span's end
+        assert e["ts"] >= e["t0_ns"] // 1_000_000 - 1, e["kind"]
+
+
+def test_emit_derives_t0_ns_for_sites_that_take_none():
+    t = Tracer()
+    t.emit("scan_prune", table="x", files_total=1, files_pruned=0,
+           rows_bound=None, dur_ms=250.0)
+    t.emit("plan_cache", node="Aggregate", hit=False)
+    ev, no_span = t.events
+    assert abs(ev["t0_ns"] - (ev["ts"] * 1_000_000 - 250_000_000)) < 2_000_000
+    assert "t0_ns" not in no_span
+
+
+def test_logs_without_t0_ns_still_read():
+    old = [
+        {"ts": 5000, "kind": "query_span", "app": "a", "query": "q",
+         "dur_ms": 1000.0, "status": "Completed", "retries": 0},
+        {"ts": 4900, "kind": "op_span", "app": "a", "query": "q",
+         "exec_id": 1, "seq": 1, "depth": 0, "node": "Scan", "explain": "",
+         "dur_ms": 800.0, "rows": 1, "est_bytes": 0},
+        {"ts": 4500, "kind": "catalog_load", "app": "a", "query": "q",
+         "table": "t", "columns": 1, "loaded": 1, "rows": 1, "dur_ms": 300.0,
+         "cache": "miss"},
+    ]
+    assert R.validate_events(old) == []
+    causes = CP.critical_path(old)["queries"]["q"]["causes"]
+    # no result_span: the one lump, as before
+    assert causes["execute"] == 500.0 and causes["catalog-load"] == 300.0
+    assert CP._interval(old[2]) == (4200e6, 4500e6)
+
+
+# ---------------------------------------------------------------------------
+# host_read: the one seam
+# ---------------------------------------------------------------------------
+
+
+def test_host_read_is_the_bare_call_with_no_tally_bound():
+    import jax.numpy as jnp
+
+    assert T.current() is None
+    out = T.host_read("nrows", jnp.arange(4))
+    assert isinstance(out, np.ndarray) and out.tolist() == [0, 1, 2, 3]
+
+
+def test_host_read_emits_counts_and_hangs_under_its_span():
+    import jax.numpy as jnp
+
+    tracer = Tracer()
+    tl = T.Tally(tracer, 42)
+    with T.bind(tl):
+        saved = tl.push(3)
+        got = T.host_read("bounds", [jnp.arange(4, dtype=jnp.int32),
+                                     jnp.ones(2, bool)])
+        own = tl.pop(saved)
+    assert [g.tolist() for g in got] == [[0, 1, 2, 3], [True, True]]
+    (ev,) = tracer.events
+    assert (ev["kind"], ev["why"], ev["bytes"], ev["exec_id"],
+            ev["depth"]) == ("host_read", "bounds", 18, 42, 3)
+    assert own["reads"] == 1 and own["read_wait_ms"] == pytest.approx(
+        ev["dur_ms"], abs=1e-3)
+    assert tl.depth == -1 and tl.reads == 0  # the frame closed
+
+
+@pytest.mark.parametrize("name", ["query3", "query96"])
+def test_counts_repeat_and_every_event_attaches(raw, name):
+    """Two executions of one SF0.01 statement: `host_read`s by `why` and
+    launches by kernel are equal, and every `host_read` / `xla_compile`
+    that names an executor hangs under an `op_span` of it (depth >= 0) or
+    under its `result_span` (depth -1)."""
+    tracer = Tracer()
+    obs_trace.watch_compiles(tracer)
+    s = _tpcds_session(raw, tracer)
+    sql = _statements()[name]
+    _run(s, sql, "warm")  # loads tables, compiles
+    s.register_arrow("tick", pa.table({"n": [0]}))  # drops the result cache
+    first = _run(s, sql, "first")
+    s.register_arrow("tick", pa.table({"n": [1]}))
+    second = _run(s, sql, "second")
+    assert first.equals(second)
+    events = tracer.events
+    assert R.validate_events(events) == []
+    l1, r1 = _counts(events, "first")
+    l2, r2 = _counts(events, "second")
+    assert l1 == l2 and r1 == r2
+    assert sum(l1.values()) > 0 and sum(r1.values()) > 0
+    assert set(r1) <= WHY and r1["collect"] == 1
+    by_exec = {}
+    for e in events:
+        if e["kind"] == "op_span":
+            by_exec.setdefault(e["exec_id"], set()).add(e["depth"])
+    results = {e["exec_id"] for e in events if e["kind"] == "result_span"}
+    assert len(results) == 3
+    hung = 0
+    for e in events:
+        if e["kind"] not in ("host_read", "xla_compile"):
+            continue
+        if e.get("exec_id") is None:
+            continue  # compiled while planning: no executor yet
+        hung += 1
+        assert e["exec_id"] in results
+        assert e["depth"] == -1 or e["depth"] in by_exec[e["exec_id"]]
+    assert hung >= sum(r1.values()) * 2
+    # the op_spans' own counters are those events, no more and no fewer
+    for q in ("first", "second"):
+        spans = [e for e in events if e.get("query") == q
+                 and e["kind"] in ("op_span", "result_span")]
+        reads = [e for e in events if e.get("query") == q
+                 and e["kind"] == "host_read"]
+        assert sum(e["reads"] for e in spans) == len(reads)
+        assert sum(e["read_wait_ms"] for e in spans) == pytest.approx(
+            sum(e["dur_ms"] for e in reads), abs=0.01 * len(reads) + 0.01)
+
+
+@pytest.mark.parametrize("dtype", ["int32", "int64", "float64", "bool"])
+def test_seamed_gather_gives_what_indexing_gives(dtype):
+    import jax.numpy as jnp
+
+    from nds_tpu.ops import kernels as K
+
+    data = jnp.asarray(np.arange(64) % 5, dtype=dtype)
+    idx = jnp.asarray([63, 0, 0, 17, 5], dtype=jnp.int32)
+    out = K.take_rows(data, idx)
+    assert out.dtype == data.dtype
+    assert out.tolist() == data[idx].tolist()
+
+
+def test_launch_ms_leaves_out_the_reads_inside_a_seamed_call():
+    tracer = Tracer()
+    tl = T.Tally(tracer, 1)
+    token = tl.enter("join_candidates")
+    assert tl.enter("sort_by_words") is None  # nested: counted, not timed
+    tl.read_wait_ms += 1e6  # a read inside the call waited "1000 s"
+    time.sleep(0.002)
+    tl.leave(token)
+    assert tl.launches == {"join_candidates": 1, "sort_by_words": 1}
+    assert tl.launch_ms < 0  # the wait was taken off, not added
+    assert tl.in_seam is False
+
+
+# ---------------------------------------------------------------------------
+# result_span, catalog_load, xla_compile
+# ---------------------------------------------------------------------------
+
+
+def test_result_span_is_the_statements_boundary():
+    tracer = Tracer()
+    s = _small_session(tracer)
+    t0 = time.perf_counter()
+    _run(s, JOIN_SQL, "q")
+    outside_ms = (time.perf_counter() - t0) * 1e3
+    (res,) = [e for e in tracer.events if e["kind"] == "result_span"]
+    roots = [e for e in tracer.events
+             if e["kind"] == "op_span" and e["depth"] == 0]
+    assert res["exec_id"] == roots[-1]["exec_id"]
+    assert res["exec_ms"] >= roots[-1]["dur_ms"]
+    assert res["dur_ms"] == pytest.approx(
+        res["exec_ms"] + res["to_arrow_ms"], abs=0.01)
+    assert res["dur_ms"] <= outside_ms
+    # the collect's read is counted on the result_span, outside every node
+    assert res["reads"] == 1
+    collect = [e for e in tracer.events
+               if e["kind"] == "host_read" and e["why"] == "collect"]
+    assert [e["depth"] for e in collect] == [-1]
+    # table() after collect() executes nothing again: no second span
+    r = s.sql("select a from u")
+    with bind(tracer), faults.scope("q2"):
+        r.table()
+        r.table()
+        r.collect()
+    spans = [e for e in tracer.events
+             if e["kind"] == "result_span" and e.get("query") == "q2"]
+    assert [(e["exec_ms"] > 0, e["to_arrow_ms"] > 0) for e in spans] == [
+        (True, False), (False, True)]
+
+
+def test_catalog_load_says_what_it_spent():
+    tracer = Tracer()
+    s = _small_session(tracer)
+    _run(s, "select a, b from t", "q")
+    _run(s, "select a, b from t", "q_again")
+    loads = [e for e in tracer.events if e["kind"] == "catalog_load"]
+    miss, hit = loads[0], loads[-1]
+    assert miss["cache"] == "miss" and hit["cache"] == "hit"
+    for k in ("read_ms", "encode_ms", "h2d_ms"):
+        assert miss[k] >= 0 and hit[k] == 0
+    assert miss["encode_ms"] > 0 and miss["h2d_ms"] > 0
+    assert (miss["read_ms"] + miss["encode_ms"] + miss["h2d_ms"]
+            <= miss["dur_ms"] + 0.01)
+
+
+def test_untraced_load_does_not_wait_for_the_copy(monkeypatch):
+    monkeypatch.setenv("NDS_FLIGHT_RECORDER", "off")
+    s = _small_session()
+    assert s.tracer is None
+    assert s.sql("select a, b from t").collect().num_rows == 6
+
+
+def test_compile_listener_names_stage_program_and_cache():
+    tracer = Tracer()
+    spans = obs_trace._on_compile_span
+    with bind(tracer):
+        spans("/jax/core/compile/jaxpr_trace_duration", 100.0, 100.5,
+              fun_name="gather")
+        obs_trace._on_compile_event("/jax/compilation_cache/cache_hits")
+        spans("/jax/core/compile/backend_compile_duration", 101.0, 101.25,
+              fun_name="jit(gather)")
+        spans("/jax/core/compile/backend_compile_duration", 102.0, 103.0,
+              fun_name="jit(_pad)")
+        spans("/jax/some/other_duration", 1.0, 2.0, fun_name="x")
+        with T.bind(T.Tally(tracer, 5)) as tl:
+            token = tl.enter("sort_by_words")
+            spans("/jax/core/compile/jaxpr_to_mlir_module_duration", 104.0,
+                  104.1, fun_name="jit(_kv_sort_perm)")
+            tl.leave(token)
+    got = [(e["stage"], e["fun"], e["cached"], e["dur_ms"], e["t0_ns"])
+           for e in tracer.events]
+    assert got == [
+        ("trace", "gather", False, 500.0, 100_000_000_000),
+        ("compile", "gather", True, 250.0, 101_000_000_000),
+        ("compile", "_pad", False, 1000.0, 102_000_000_000),
+        ("lower", "_kv_sort_perm", False, 100.0, 104_000_000_000),
+    ]
+    last = tracer.events[-1]
+    assert (last["exec_id"], last["depth"], last["in_seam"]) == (5, -1, True)
+    assert "exec_id" not in tracer.events[0]
+    assert R.validate_events(tracer.events) == []
+
+
+def test_only_a_file_or_sink_tracer_watches_compiles(tmp_path, monkeypatch):
+    calls = []
+    monkeypatch.setattr(obs_trace, "watch_compiles", calls.append)
+    ring_only = Session()
+    assert ring_only.tracer is not None and ring_only.tracer.path is None
+    assert calls == []
+    filed = Session(conf={"engine.trace_dir": str(tmp_path)})
+    assert calls == [filed.tracer]
+    filed.tracer.close()
+
+
+# ---------------------------------------------------------------------------
+# the readers inside the program
+# ---------------------------------------------------------------------------
+
+MS = 1_000_000  # ns
+
+
+def _ev(kind, t0_ms, dur_ms, **fields):
+    return {"ts": t0_ms + dur_ms, "kind": kind, "app": "a", "query": "q",
+            "t0_ns": int(t0_ms * MS), "dur_ms": float(dur_ms), **fields}
+
+
+SPLIT_EVENTS = [
+    _ev("query_span", 0, 1000, status="Completed", retries=0),
+    _ev("result_span", 50, 900, exec_id=1, exec_ms=880.0, to_arrow_ms=20.0,
+        launches={"compact_indices": 1}, launch_ms=10.0, reads=1,
+        read_wait_ms=15.0),
+    _ev("op_span", 50, 880, exec_id=1, seq=2, depth=0, node="MultiJoin",
+        explain="", rows=1, est_bytes=0, launches={"take_rows": 12},
+        launch_ms=90.0, reads=2, read_wait_ms=300.0),
+    _ev("op_span", 60, 200, exec_id=1, seq=1, depth=1, node="Scan",
+        explain="", rows=1, est_bytes=0, launches={}, launch_ms=0.0, reads=0,
+        read_wait_ms=0.0),
+    _ev("catalog_load", 60, 200, table="t", columns=1, loaded=1, rows=1,
+        cache="miss", read_ms=100.0, encode_ms=60.0, h2d_ms=40.0),
+    # a first-touch compile inside a seamed call inside the load's span
+    _ev("xla_compile", 100, 20, stage="trace", fun="_pad", cached=False,
+        exec_id=1, depth=1),
+    _ev("exec_cache", 300, 100, pipeline="p", bucket=1024, hit=False),
+    _ev("xla_compile", 310, 30, stage="trace", fun="pipe", cached=False,
+        exec_id=1, depth=0),
+    _ev("xla_compile", 315, 10, stage="trace", fun="inner", cached=False,
+        exec_id=1, depth=0),
+    _ev("xla_compile", 340, 40, stage="compile", fun="pipe", cached=True,
+        exec_id=1, depth=0),
+    _ev("aot_cache", 380, 10, op="load", result="hit"),
+    _ev("xla_compile", 500, 50, stage="compile", fun="gather", cached=False,
+        exec_id=1, depth=0, in_seam=True),
+    _ev("host_read", 600, 250, why="nrows", bytes=4, exec_id=1, depth=0),
+    _ev("host_read", 860, 50, why="join_size", bytes=8, exec_id=1, depth=0),
+    _ev("host_read", 935, 15, why="collect", bytes=64, exec_id=1, depth=-1),
+]
+
+
+def test_critical_path_splits_execute_into_disjoint_causes():
+    q = CP.critical_path(SPLIT_EVENTS)["queries"]["q"]
+    c = q["causes"]
+    assert c["execute"] == 0.0
+    assert c["device-wait"] == 315.0
+    assert c["xla-compile"] == 50.0
+    assert c["cache-load"] == 50.0  # the cached compile + the AOT load
+    assert c["jit-trace"] == 50.0  # 20 in the load + 30 (the nested 10 once)
+    assert c["exec-lookup"] == 20.0  # 100 less trace 30, compile 40, AOT 10
+    # the load's 200 ms less the 20 ms compile inside it, split 100:60:40
+    assert (c["read"], c["encode"], c["h2d"]) == (90.0, 54.0, 36.0)
+    assert c["catalog-load"] == 0.0
+    # launch_ms 100 less the 50 ms compile that fell inside a seamed call
+    assert c["launch"] == 50.0
+    # the rest of the 900 ms result_span
+    assert c["host-python"] == 900 - (315 + 50 + 50 + 50 + 20 + 180 + 50)
+    assert c["plan-host"] == 100.0  # the query_span outside the result_span
+    assert sum(c.values()) == pytest.approx(q["wall_ms"])
+    assert q["attributed_frac"] == 1.0
+    assert q["launches"] == {"take_rows": 12, "compact_indices": 1}
+    assert q["reads"]["nrows"] == {"count": 1, "ms": 250.0}
+    assert q["compiles"]["gather"] == {"count": 1, "ms": 50.0, "fresh": 1}
+    assert q["compiles"]["pipe"] == {"count": 1, "ms": 70.0, "fresh": 0}
+
+
+def test_plan_budget_wall_is_carved_out_of_plan_host():
+    events = SPLIT_EVENTS + [
+        _ev("plan_budget", 5, 40, verdict="direct", peak_bytes=1,
+            budget_bytes=2)]
+    c = CP.critical_path(events)["queries"]["q"]["causes"]
+    assert c["plan-budget"] == 40.0 and c["plan-host"] == 60.0
+
+
+def test_critical_path_of_a_real_statement_stays_attributed():
+    tracer = Tracer()
+    obs_trace.watch_compiles(tracer)
+    s = _small_session(tracer)
+    from nds_tpu.report import BenchReport
+
+    def stmt():
+        with faults.scope("q"):
+            return s.sql(JOIN_SQL).collect()
+
+    with bind(tracer):
+        BenchReport(s).report_on(stmt, name="q")
+    cp = CP.critical_path(tracer.events)
+    q = cp["queries"]["q"]
+    assert q["wall_ms"] > 0 and CP.min_attributed_frac(cp) >= 0.9
+    assert sum(q["causes"].values()) <= q["wall_ms"] * 1.001
+    assert all(v >= 0 for v in q["causes"].values())
+    assert q["causes"]["execute"] == 0.0
+    assert q["causes"]["device-wait"] > 0 and q["causes"]["launch"] > 0
+    assert q["launches"]["take_rows"] >= 1 and q["reads"]["collect"]["count"] == 1
+
+
+def test_profile_cli_prints_the_split_table(tmp_path, capsys):
+    import json
+
+    from nds_tpu.cli import profile as profile_cli
+
+    log = tmp_path / "events-x.jsonl"
+    log.write_text("".join(json.dumps(e) + "\n" for e in SPLIT_EVENTS))
+    assert not profile_cli.main(
+        [str(log), "--critical-path", "--min_attributed", "0.9"])
+    out = capsys.readouterr().out
+    for needle in ("device-wait", "host-python", "exec-lookup", "h2d",
+                   "launches: take_rows 12", "reads: nrows 1 (250.0 ms)",
+                   "compiles: pipe 1 (0 fresh, 70.0 ms)"):
+        assert needle in out, needle
+    assert "\n   execute " not in out
+    assert not profile_cli.main([str(log)])
+    assert "kernels by launches" in capsys.readouterr().out
+
+
+# ---------------------------------------------------------------------------
+# the lint that keeps the counter whole
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("line", [
+    "n = int(jnp.sum(mask))",
+    "got = jax.device_get(x)",
+    "jax.block_until_ready(out)",
+    "x.block_until_ready()",
+    "ok = bool(jnp.any(m))",
+])
+def test_lint_flags_a_read_outside_the_seam(line):
+    src = f"import jax\nimport jax.numpy as jnp\n\ndef f(x, mask, m, out):\n    {line}\n"
+    for path in ("engine/exec.py", "ops/kernels.py"):
+        assert [f.rule for f in L.lint_source(src, path)] == ["host-read-seam"]
+    assert L.lint_source(src, "obs/tally.py") == []
+    pragma = src.replace(line, line + "  # nds-lint: disable=host-read-seam")
+    assert L.lint_source(pragma, "engine/exec.py") == []
+
+
+def test_lint_passes_the_seam_and_static_shapes():
+    src = ("from ..obs.tally import host_read\nimport jax.numpy as jnp\n\n"
+           "def f(x):\n"
+           "    n = int(host_read('nrows', jnp.sum(x)))\n"
+           "    return n + int(x.shape[0]) + int(len(x))\n")
+    assert L.lint_source(src, "engine/exec.py") == []
+
+
+def test_the_tree_has_no_read_outside_the_seam():
+    assert [f for f in L.run_lint() if f.rule == "host-read-seam"] == []
+
+
+# ---------------------------------------------------------------------------
+# what the ring-only path pays
+# ---------------------------------------------------------------------------
+
+
+def test_new_work_on_the_ring_only_path_fits_its_budget(raw):
+    """ISSUE 25's budget: what this PR adds to a statement on the ring-only
+    path (the driver's untraced runs) stays under 1 ms: events per
+    statement x cost per emit, plus the clock reads and the seam's adds.
+    A CPU microbench of host-only work; the chip's number is in PERF.md."""
+    tracer = Tracer()
+    s = _tpcds_session(raw, tracer)
+    sql = _statements()["query3"]
+    _run(s, sql, "warm")
+    s.register_arrow("tick", pa.table({"n": [0]}))
+    _run(s, sql, "q")
+    events = [e for e in tracer.events if e.get("query") == "q"]
+    launches, reads = _counts(events, "q")
+    n_reads = sum(reads.values())
+    n_launch = sum(launches.values())
+    n_spans = sum(1 for e in events if "t0_ns" in e)
+
+    ring = Session().tracer  # the shape an untraced run has
+    assert ring.path is None and ring.events is None
+    tl = T.Tally(ring, 1)
+    n = 2000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        ring.emit("host_read", why="nrows", bytes=4, dur_ms=0.123,
+                  t0_ns=1, exec_id=1, depth=0)
+    emit_us = (time.perf_counter() - t0) / n * 1e6
+    t0 = time.perf_counter()
+    for _ in range(n):
+        tl.leave(tl.enter("take_rows"))
+    seam_us = (time.perf_counter() - t0) / n * 1e6
+    t0 = time.perf_counter()
+    for _ in range(n):
+        time.time_ns()
+        time.perf_counter()
+        time.perf_counter()
+    clock_us = (time.perf_counter() - t0) / n * 1e6
+    added_ms = (n_reads * (emit_us + clock_us) + n_launch * seam_us
+                + n_spans * clock_us + 1 * emit_us) / 1e3
+    print(f"{n_reads} reads, {n_launch} launches, {n_spans} spans a "
+          f"statement; emit {emit_us:.2f} us, seam {seam_us:.2f} us, "
+          f"clocks {clock_us:.2f} us: {added_ms:.3f} ms added")
+    assert n_reads > 0 and n_launch > 0
+    assert added_ms < 1.0

@@ -14,8 +14,9 @@ whole point of shape-bucketed executable reuse); a refactor that silently
 changes pipeline fingerprints, input signatures, or the cache keying drops
 the rate to ~0 and fails this gate.
 
-Part 2 measures steady-state device-dispatch counts (kernel_span events +
-fused pipeline calls under NDS_TRACE_KERNELS-style tracing) for the plan
+Part 2 measures steady-state device-dispatch counts (the launch seam's
+tally: `op_span.launches`, kernel entry points, seamed gathers and fused
+pipeline calls) for the plan
 shapes of the bench's tail queries — the multi-key grouped sum/avg chain
 (q4/q14's year_total), the global filtered aggregate (q9's bucket
 probes), and the join-fed grouped sum (q78) — eager vs fused, and
@@ -115,8 +116,9 @@ def _table(n, seed):
 
 
 def _steady_dispatches(query, fuse_conf, trace_dir):
-    """Counted device dispatches of one steady-state execution: kernel
-    entry points (kernel_span, synchronized) + fused pipeline calls. An
+    """Counted device dispatches of one steady-state execution: the
+    statement's launch tally (`launches` of its op_spans and result_span:
+    kernel entry points, seamed gathers, one per fused pipeline call). An
     undercount of the eager path (per-stage elementwise ops are not kernel
     entry points) — which only makes the fused<eager assertion stricter."""
     from nds_tpu.engine.session import Session
@@ -126,7 +128,6 @@ def _steady_dispatches(query, fuse_conf, trace_dir):
     sess = Session(conf=dict(fuse_conf, **{
         "engine.plan_cache": "off",
         "engine.trace_dir": trace_dir,
-        "engine.trace_kernels": "on",
     }))
     sess.register_arrow("t", _table(3000, 1))
     sess.register_arrow("u", _table(3000, 2))
@@ -137,13 +138,10 @@ def _steady_dispatches(query, fuse_conf, trace_dir):
         sess.sql(query).collect()  # steady: every dispatch traced
     sess.tracer.close()
     events = R.read_events([trace_dir], strict=True)
-    n = 0
-    for ev in events:
-        if ev.get("kind") == "kernel_span":
-            n += 1
-        elif ev.get("kind") == "pipeline_span" and ev.get("fused"):
-            n += 1
-    return n
+    return sum(
+        sum((ev.get("launches") or {}).values())
+        for ev in events if ev.get("kind") in ("op_span", "result_span")
+    )
 
 
 def dispatch_ab():
