@@ -231,17 +231,29 @@ class Table:
         count = self.nrows
         cap = bucket_cap(max(count, 1))
         idx = K.compact_indices(self.live, cap)
-        cols = {}
-        for name, c in self.columns.items():
-            cols[name] = Column(
-                K.take_rows(c.data, idx),
-                c.dtype,
-                None if c.valid is None else K.take_rows(c.valid, idx),
-                c.dictionary,
-                c.subset_stats(),
-            )
-        self._packed = Table(cols, count, unique_key=self.unique_key)
+        self._packed = Table(
+            gather_columns(self.columns, idx), count,
+            unique_key=self.unique_key,
+        )
         return self._packed
+
+
+def gather_columns(
+    columns: dict, idx, keep=None, *, stats=Column.subset_stats, owned=False,
+) -> dict:
+    """The rows `idx` of every column of one table side, by one call of
+    `kernels.take_columns` (which says what `keep` does). Dtype and
+    dictionary carry over; `stats` maps a source column to the stats
+    that survive this gather, `owned` is the new columns' flag."""
+    from ..ops import kernels as K
+
+    taken = K.take_columns(
+        tuple((c.data, c.valid) for c in columns.values()), idx, keep
+    )
+    return {
+        name: Column(data, c.dtype, valid, c.dictionary, stats(c), owned=owned)
+        for (name, c), (data, valid) in zip(columns.items(), taken)
+    }
 
 
 def table_device_bytes(table: Table) -> int:

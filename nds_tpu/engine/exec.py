@@ -40,6 +40,7 @@ from .columnar import (
     Table,
     _dyn_slice,
     bucket_cap,
+    gather_columns,
     table_to_arrow,
     unify_dictionaries,
     sort_dictionary,
@@ -1266,11 +1267,10 @@ class Executor:
         count = K.mask_count(ok)
         out_cap = bucket_cap(max(count, 1))
         sel = K.compact_indices(ok, out_cap)
-        pli = li[sel]
-        pri = ri[sel]
+        pli, pri = K.take_arrays((li, ri), sel)
         if residual is not None:
             # build pair table first, filter, recompact
-            pair = self._pair_table(left, right, pli, pri, count, rnull=None)
+            pair = self._pair_table(left, right, pli, pri, count)
             pmask = self._predicate_mask(pair, residual)
             if kind == "inner":
                 return self._masked(pair, pmask)
@@ -1282,11 +1282,10 @@ class Executor:
             count = K.mask_count(ok)
             out_cap = bucket_cap(max(count, 1))
             sel = K.compact_indices(ok, out_cap)
-            pli = li[sel]
-            pri = ri[sel]
+            pli, pri = K.take_arrays((li, ri), sel)
 
         if kind == "inner":
-            return self._pair_table(left, right, pli, pri, count, rnull=None)
+            return self._pair_table(left, right, pli, pri, count)
 
         if kind == "left":
             present = K.matched_mask(li, ok, left.cap)
@@ -1301,8 +1300,8 @@ class Executor:
                 [pri[:count] if count else pri[:0], jnp.zeros(n_un, jnp.int32)]
             )
             all_ri = jnp.pad(all_ri, (0, cap2 - all_ri.shape[0]))
-            rnull = jnp.arange(cap2) >= count  # right side null for appended rows
-            return self._pair_table(left, right, all_li, all_ri, total_rows, rnull)
+            rkeep = jnp.arange(cap2) < count  # right side null for appended rows
+            return self._pair_table(left, right, all_li, all_ri, total_rows, rkeep)
 
         if kind == "full":
             lpresent = K.matched_mask(li, ok, left.cap)
@@ -1324,10 +1323,10 @@ class Executor:
             all_li = jnp.pad(all_li, (0, cap2 - all_li.shape[0]))
             all_ri = jnp.pad(all_ri, (0, cap2 - all_ri.shape[0]))
             pos = jnp.arange(cap2)
-            rnull = (pos >= count) & (pos < count + n_lu)
-            lnull = pos >= count + n_lu
+            rkeep = (pos < count) | (pos >= count + n_lu)
+            lkeep = pos < count + n_lu
             return self._pair_table(
-                left, right, all_li, all_ri, total_rows, rnull, lnull
+                left, right, all_li, all_ri, total_rows, rkeep, lkeep
             )
         raise ExecError(f"join kind {kind}")
 
@@ -1413,12 +1412,9 @@ class Executor:
             # are fresh buffers owned by this output alone.
             out_cols = {n: c.disowned() for n, c in left.columns.items()}
             ri_safe = jnp.where(matched, ri, 0)
-            for name, c in right.columns.items():
-                valid = None if c.valid is None else K.take_rows(c.valid, ri_safe)
-                out_cols[name] = Column(
-                    K.take_rows(c.data, ri_safe), c.dtype, valid, c.dictionary,
-                    c.gather_stats(), owned=True,
-                )
+            out_cols.update(gather_columns(
+                right.columns, ri_safe, stats=Column.gather_stats, owned=True,
+            ))
             pair = Table(
                 dict(out_cols), jnp.sum(matched, dtype=jnp.int32),
                 live=matched, unique_key=left.unique_key,
@@ -1434,12 +1430,9 @@ class Executor:
         # left join: left-aligned output, unmatched rows null on the right
         out_cols = {n: c.disowned() for n, c in left.columns.items()}
         ri_safe = jnp.where(matched, ri, 0)
-        for name, c in right.columns.items():
-            valid = K.take_rows(c.valid, ri_safe) if c.valid is not None else jnp.ones(left.cap, bool)
-            out_cols[name] = Column(
-                K.take_rows(c.data, ri_safe), c.dtype, valid & matched, c.dictionary,
-                c.gather_stats(),
-            )
+        out_cols.update(gather_columns(
+            right.columns, ri_safe, matched, stats=Column.gather_stats,
+        ))
         return Table(
             out_cols, left.nrows_lazy, live=left.live,
             unique_key=left.unique_key,
@@ -1767,7 +1760,9 @@ class Executor:
         count = K.mask_count(ok)
         cap = bucket_cap(max(count, 1))
         sel = K.compact_indices(ok, cap)
-        pair = self._pair_table(left, right, li[sel], ri[sel], count, None)
+        pair = self._pair_table(
+            left, right, *K.take_arrays((li, ri), sel), count
+        )
         pmask = self._predicate_mask(pair, residual)
         # max-scatter: sel's padding duplicates index 0 (see _join residual)
         return ok & jnp.zeros(ok.shape, bool).at[sel].max(pmask)
@@ -1832,28 +1827,18 @@ class Executor:
 
         return [as_i64(a)], [as_i64(b)]
 
-    def _pair_table(self, left, right, li, ri, nrows, rnull, lnull=None):
+    def _pair_table(self, left, right, li, ri, nrows, rkeep=None, lkeep=None):
         # join-output gather can repeat rows: bounds survive, uniqueness
         # dies. Every buffer below is a fresh gather output owned by this
         # table alone — marked owned so a downstream fused pipeline may
-        # donate it (engine/fuse.py:_donate_slots)
-        cols = {}
-        for name, c in left.columns.items():
-            data = K.take_rows(c.data, li)
-            valid = None if c.valid is None else K.take_rows(c.valid, li)
-            if lnull is not None:
-                v = valid if valid is not None else jnp.ones(li.shape[0], bool)
-                valid = v & ~lnull
-            cols[name] = Column(data, c.dtype, valid, c.dictionary,
-                                c.gather_stats(), owned=True)
-        for name, c in right.columns.items():
-            data = K.take_rows(c.data, ri)
-            valid = None if c.valid is None else K.take_rows(c.valid, ri)
-            if rnull is not None:
-                v = valid if valid is not None else jnp.ones(ri.shape[0], bool)
-                valid = v & ~rnull
-            cols[name] = Column(data, c.dtype, valid, c.dictionary,
-                                c.gather_stats(), owned=True)
+        # donate it (engine/fuse.py:_donate_slots). One gather a side;
+        # rkeep / lkeep are False on the rows an outer join null-extends
+        cols = gather_columns(
+            left.columns, li, lkeep, stats=Column.gather_stats, owned=True,
+        )
+        cols.update(gather_columns(
+            right.columns, ri, rkeep, stats=Column.gather_stats, owned=True,
+        ))
         return Table(cols, nrows)
 
     def _cross_join(self, left, right):
@@ -1867,7 +1852,7 @@ class Executor:
         li = (p // max(rn, 1)).astype(jnp.int32)
         ri = (p % max(rn, 1)).astype(jnp.int32)
         li = jnp.clip(li, 0, max(left.cap - 1, 0))
-        return self._pair_table(left, right, li, ri, total, None)
+        return self._pair_table(left, right, li, ri, total)
 
     # ------------------------------------------------------------------
     # ------------------------------------------------------------------
@@ -2493,11 +2478,17 @@ class Executor:
         if order is not None:
             first_idx = K.segment_starts(gid, gcap)
             first_rows = order[jnp.clip(first_idx, 0, child.cap - 1)]
+        n_active = sum(1 for kc in key_cols if kc is not None)
+        taken = gather_columns(
+            {name: c for (e, name), c in zip(key_items, key_cols)
+             if c is not None},
+            first_rows, stats=lambda c: _group_key_stats(c, n_active),
+        )
         cols = {}
-        for i, ((e, name), c) in enumerate(zip(key_items, key_cols)):
+        for (e, name), c in zip(key_items, key_cols):
             if c is None:
                 # rolled-up key: all null
-                base = ev.eval(key_items[i][0])
+                base = ev.eval(e)
                 cols[name] = Column(
                     jnp.zeros(gcap, base.dtype.device_np_dtype()),
                     base.dtype,
@@ -2505,14 +2496,7 @@ class Executor:
                     base.dictionary,
                 )
             else:
-                data = K.take_rows(c.data, first_rows)
-                valid = None if c.valid is None else K.take_rows(c.valid, first_rows)
-                cols[name] = Column(
-                    data, c.dtype, valid, c.dictionary,
-                    _group_key_stats(
-                        c, sum(1 for kc in key_cols if kc is not None)
-                    ),
-                )
+                cols[name] = taken[name]
         for agg, name in agg_items:
             cols[name] = self._eval_agg(
                 agg, ev, order, gid, gcap, live_sorted, ngroups, child, subset,
@@ -2548,14 +2532,14 @@ class Executor:
             )
             return Column(counts.astype(jnp.int64), INT64)
         c = ev.eval(agg.arg)
-        weight = live_sorted
-        # order=None: direct (unsorted) aggregation — gid/live are row-aligned
-        sdata = c.data if order is None else K.take_rows(c.data, order)
-        if c.valid is not None:
-            weight = weight & (c.valid if order is None else K.take_rows(c.valid, order))
+        sdata, svalid = c.data, c.valid
         if c.dtype.is_string:
-            rank, sorted_dict = sort_dictionary(c)
-            sdata = rank if order is None else rank[order]
+            sdata, sorted_dict = sort_dictionary(c)
+        # order=None: direct (unsorted) aggregation — gid/live are row-aligned
+        if order is not None:
+            ((sdata, svalid),) = K.take_columns(((sdata, svalid),), order)
+        weight = live_sorted if svalid is None else live_sorted & svalid
+        if c.dtype.is_string:
             if fn in ("min", "max"):
                 red, counts = K.segment_reduce_with_count(
                     sdata, gid, weight, gcap, fn
@@ -2872,14 +2856,13 @@ class Executor:
         first2 = K.segment_starts(gid2, g2cap)
         rows2 = order2[jnp.clip(first2, 0, child.cap - 1)]
         live2 = jnp.arange(g2cap) < ng2
-        cvalid2 = None if c.valid is None else K.take_rows(c.valid, rows2)
         # re-group the distinct rows by the outer keys only. A fresh live2
         # word leads: the gathered words' embedded live bit reflects the
         # ORIGINAL rows' liveness, not the distinct slots' (dead slots gather
         # an arbitrary live row when the table has no dead tail).
         if gwords:
             okeys = [jnp.where(live2, jnp.int64(0), jnp.int64(1))]
-            okeys += [w[rows2] for w in gwords]
+            okeys += K.take_arrays(gwords, rows2)
             order3, gid3, ng3 = K.group_by_words(okeys, live2)
         else:
             # global distinct: reductions are order-independent
@@ -2889,10 +2872,13 @@ class Executor:
         if ng3 == 0:
             ng3 = 1
         g3cap = bucket_cap(ng3)
-        w3 = live2[order3]
-        if cvalid2 is not None:
-            w3 = w3 & cvalid2[order3]
-        vals = K.take_rows(c.data, rows2)[order3]
+        # the distinct slots' value and validity, then in outer-group order
+        (slot,) = K.take_columns(((c.data, c.valid),), rows2)
+        (vals, cvalid3), (w3, _) = K.take_columns(
+            (slot, (live2, None)), order3
+        )
+        if cvalid3 is not None:
+            w3 = w3 & cvalid3
         if agg.fn == "count":
             out = K.segment_reduce(vals, gid3, w3, g3cap, "count")
             col = Column(out.astype(jnp.int64), INT64)
@@ -2946,10 +2932,11 @@ class Executor:
         pwords = self._sort_words(pkeys, pcols, live)
         owords = self._sort_words(okeys, ocols, live, include_live=False)
         order = K.sort_by_words(pwords + owords)
-        sorted_ow = [w[order] for w in owords]
+        sorted_words = K.take_arrays(pwords + owords, order)
+        sorted_ow = list(sorted_words[len(pwords):])
         # partition group ids over sorted rows
         if pkeys:
-            sorted_p = [w[order] for w in pwords]
+            sorted_p = list(sorted_words[:len(pwords)])
             flags = K._word_flags(sorted_p)
             gid = K.fast_cumsum(flags.astype(jnp.int32)) - 1
             nlive = child.nrows
@@ -3001,10 +2988,11 @@ class Executor:
                 # (raw dictionary codes are in encounter order)
                 ranks, sorted_dict = sort_dictionary(c)
                 c = Column(ranks, c.dtype, c.valid, sorted_dict)
-            sdata = K.take_rows(c.data, order)
-            w = live[order]
-            if c.valid is not None:
-                w = w & K.take_rows(c.valid, order)
+            (sdata, svalid), (w, _) = K.take_columns(
+                ((c.data, c.valid), (live, None)), order
+            )
+            if svalid is not None:
+                w = w & svalid
             dtype = c.dtype
 
         # Classify the frame. SQL default: whole partition without ORDER BY,
@@ -3251,17 +3239,7 @@ class Executor:
         # idx is a permutation or de-duplicated subset of live rows
         # (sort order / compact indices), so base-table stats stay valid;
         # gather outputs are fresh owned buffers
-        cols = {}
-        for name, c in table.columns.items():
-            cols[name] = Column(
-                K.take_rows(c.data, idx),
-                c.dtype,
-                None if c.valid is None else K.take_rows(c.valid, idx),
-                c.dictionary,
-                c.subset_stats(),
-                owned=True,
-            )
-        return Table(cols, nrows)
+        return Table(gather_columns(table.columns, idx, owned=True), nrows)
 
     def _distinct_table(self, t: Table, spill_parts=0) -> Table:
         t = self._pack_sparse(t)
@@ -3420,14 +3398,9 @@ class Executor:
                 if n_w <= 0 and segments:
                     break
                 idx = _dyn_slice(order, start, wcap)
-                cols = {
-                    name: Column(
-                        K.take_rows(c.data, idx), c.dtype,
-                        None if c.valid is None else K.take_rows(c.valid, idx),
-                        c.dictionary,
-                    )
-                    for name, c in child.columns.items()
-                }
+                cols = gather_columns(
+                    child.columns, idx, stats=lambda c: None
+                )
                 segments.append(pool.put(Table(cols, n_w)))
                 session.spill_progress()
             return self._spill_finish(op, parts, pool, before, segments,

@@ -993,10 +993,8 @@ class FusedAggPipeline(_FusedBase):
             # global aggregate: exactly one output row (cell 0), over empty
             # input included — domain_cap equals the eager path's
             # bucket_cap(1) group capacity, so arrays line up unchanged
-            cols = {}
-            for meta in self.agg_meta:
-                cols.update(self._agg_column(meta, out, None))
-            return Table(cols, 1, unique_key=frozenset())
+            return Table(self._agg_columns(out, None), 1,
+                         unique_key=frozenset())
         occ = out[0]
         # the ONE host sync of the fused path — the same occupied-group
         # count the eager direct aggregation fetches (K.mask_count)
@@ -1033,28 +1031,39 @@ class FusedAggPipeline(_FusedBase):
                 value.astype(km.dtype.device_np_dtype()), km.dtype,
                 valid, km.dictionary, stats, owned=True,
             )
-        for meta in self.agg_meta:
-            cols.update(self._agg_column(meta, out, occ_cells))
+        cols.update(self._agg_columns(out, occ_cells))
         return Table(
             cols, ngroups,
             unique_key=frozenset(n for _, n in self.agg.keys),
         )
 
-    def _agg_column(self, meta, out, cells):
+    def _agg_columns(self, out, cells):
+        """The aggregates' output columns: every slot they read is taken
+        at the occupied `cells` by one gather (a global aggregate, `cells`
+        None, reads the first bucket of each)."""
+        slots = sorted({
+            s for *_, s1, s2 in self.agg_meta for s in (s1, s2)
+            if s is not None
+        })
+        if cells is None:
+            taken = [out[s][: bucket_cap(1)] for s in slots]
+        else:
+            taken = K.take_arrays([out[s] for s in slots], cells)
+        at_cells = dict(zip(slots, taken))
+        cols = {}
+        for meta in self.agg_meta:
+            cols.update(self._agg_column(meta, at_cells))
+        return cols
+
+    @staticmethod
+    def _agg_column(meta, at_cells):
         # 4th slot: dictionary for valcnt kinds, decimal scale for avg
         kind, name, dtype, dictionary, s1, s2 = meta
-
-        def gather(slot):
-            arr = out[slot]
-            if cells is None:
-                return arr[: bucket_cap(1)]
-            return arr[cells]
-
         if kind == "count":
-            return {name: Column(gather(s1).astype(jnp.int64), INT64,
+            return {name: Column(at_cells[s1].astype(jnp.int64), INT64,
                                  owned=True)}
         if kind == "avg":
-            s, n = gather(s1), gather(s2)
+            s, n = at_cells[s1], at_cells[s2]
             nz = jnp.maximum(n, 1)
             # eager _eval_agg's exact division sequence (elementwise, so
             # running it post-gather is value-identical to pre-gather)
@@ -1063,10 +1072,9 @@ class FusedAggPipeline(_FusedBase):
             else:
                 val = s.astype(jnp.float64) / nz
             return {name: Column(val, FLOAT64, n > 0, owned=True)}
-        red = gather(s1)
-        cnt = gather(s2)
         return {
-            name: Column(red, dtype, cnt > 0, dictionary, owned=True)
+            name: Column(at_cells[s1], dtype, at_cells[s2] > 0, dictionary,
+                         owned=True)
         }
 
     def _empty_output(self) -> Table:

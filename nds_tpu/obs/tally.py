@@ -45,8 +45,8 @@ class Tally:
         self.tracer = tracer
         self.exec_id = exec_id
         self.depth = -1
-        # launches: seam name -> entries; launch_ms: host time inside
-        # outermost seamed calls; reads / read_wait_ms: the host_reads
+        # launches: seam name -> launches counted; launch_ms: host time
+        # inside outermost seamed calls; reads / read_wait_ms: the host_reads
         self._zero()
         self.in_seam = False
 
@@ -82,11 +82,12 @@ class Tally:
          self.read_wait_ms) = saved
         return own
 
-    def enter(self, name):
-        """Count one seamed call. The outermost call of a nest gets a token
-        to time its host side with; a nested one (group_by_words ->
+    def enter(self, name, n=1):
+        """Count one seamed call as `n` launches (the gathers launch one
+        program a buffer). The outermost call of a nest gets a token to
+        time its host side with; a nested one (group_by_words ->
         sort_by_words) is counted and returns None."""
-        self.launches[name] = self.launches.get(name, 0) + 1
+        self.launches[name] = self.launches.get(name, 0) + n
         if self.in_seam:
             return None
         self.in_seam = True

@@ -53,7 +53,8 @@ U64 = jnp.uint64
 # executor flushes the counts as `op_span.launches` / `launch_ms`. Nothing
 # here waits for the device: a seam that synchronizes changes what it
 # measures, and device time is the profiler's to report. An entry is at
-# least one program launch (sort_by_words runs one sort per word). Zero
+# least one program launch (sort_by_words runs one sort per word and
+# counts one); the gathers count their buffers, one program each. Zero
 # cost with no tally bound: one thread-local read + None check per call.
 # Calls made while jax is TRACING (a fused pipeline body re-entering
 # segment_reduce) are skipped — they launch nothing.
@@ -64,21 +65,23 @@ def _has_jax_tracer(args) -> bool:
     for a in args:
         if isinstance(a, jax.core.Tracer):
             return True
-        if isinstance(a, (list, tuple)) and any(
-            isinstance(x, jax.core.Tracer) for x in a
-        ):
+        if isinstance(a, (list, tuple)) and _has_jax_tracer(a):
             return True
     return False
 
 
-def _ktraced(name):
+def _ktraced(name, programs=None):
+    """Count the decorated entry point into the bound tally under `name`:
+    once, or `programs(*args)` times where one call launches a program per
+    buffer (a call of none is not counted)."""
     def deco(fn):
         @wraps(fn)
         def wrapped(*args, **kwargs):
             t = _tally.current()
             if t is None or _has_jax_tracer(args):
                 return fn(*args, **kwargs)
-            token = t.enter(name)
+            n = 1 if programs is None else programs(*args, **kwargs)
+            token = t.enter(name, n) if n else None
             if token is None:
                 return fn(*args, **kwargs)
             try:
@@ -89,11 +92,57 @@ def _ktraced(name):
     return deco
 
 
-@_ktraced("take_rows")
-def take_rows(data: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """`data[idx]`: the eager row gather of joins, compaction and sorts,
-    one program launch per column, behind the seam so it is counted."""
-    return data[idx]
+# The row gather. One jitted program a BUFFER, keyed by its dtype and the
+# two capacities alone, so every table shares them. One program for the
+# whole table side was built first and lost on the v5e: the same gathers
+# take 26% to 90% more device time inside one program than launched one by
+# one (PERF.md, Findings PR 26). What the eager `data[idx]` cost the host,
+# jnp's indexing path walked once a buffer, is gone either way.
+
+
+@jax.jit
+def _gather(a, idx):
+    return a[idx]
+
+
+@jax.jit
+def _gather_valid(valid, idx, keep):
+    # a column with no validity buffer gets a copy of `keep` of its own:
+    # every output is a buffer nothing else references
+    return jnp.copy(keep) if valid is None else valid[idx] & keep
+
+
+def _n_gathers(pairs, idx, keep=None):
+    return sum(
+        1 + (valid is not None or keep is not None) for _, valid in pairs
+    )
+
+
+@_ktraced("take_columns", _n_gathers)
+def take_columns(pairs, idx, keep=None):
+    """The row gather of a table side: `(data[idx], valid[idx])` for every
+    `(data, valid)` pair (`valid` None where the column has no validity
+    buffer), in one call behind the seam. `keep`, an optional bool mask
+    over the output rows, is and-ed into every validity inside the
+    validity's own gather (a None validity becomes `keep`): the null
+    extension of outer joins. Indexing semantics are `data[idx]`'s own;
+    outputs are fresh buffers."""
+    if keep is None:
+        return tuple(
+            (_gather(d, idx), None if v is None else _gather(v, idx))
+            for d, v in pairs
+        )
+    return tuple(
+        (_gather(d, idx), _gather_valid(v, idx, keep)) for d, v in pairs
+    )
+
+
+@_ktraced("take_columns", lambda arrays, idx: len(arrays))
+def take_arrays(arrays, idx):
+    """`a[idx]` for every buffer of `arrays` (sort words, index vectors, one
+    column's data: buffers that carry no validity), by the same programs
+    and under the same name behind the seam as `take_columns`."""
+    return tuple(_gather(a, idx) for a in arrays)
 
 
 # ---------------------------------------------------------------------------
@@ -324,7 +373,7 @@ def group_by_words(words, live_mask, nlive=None):
     <=> equal keys). The word list must place live rows first (callers fold
     ~live into the leading word via the packer)."""
     order = sort_by_words(words)
-    sorted_words = [w[order] for w in words]
+    sorted_words = list(take_arrays(words, order))
     flags = _word_flags(sorted_words)
     gid = fast_cumsum(flags.astype(jnp.int32)) - 1
     if nlive is None:

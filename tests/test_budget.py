@@ -7,8 +7,6 @@ rule family (seeded violations per rule), and the new lint rules
 """
 
 import os
-import subprocess
-import sys
 from decimal import Decimal
 
 import numpy as np
@@ -30,8 +28,8 @@ from nds_tpu.obs import memwatch
 from nds_tpu.obs.trace import EVENT_SCHEMA, Tracer
 from nds_tpu.report import BenchReport
 from nds_tpu.schema import get_schemas
+from shared_data import DATA, raw_data
 
-DATA = "/tmp/nds_test_sf001"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -800,13 +798,7 @@ def test_lint_unread_conf_knob(tmp_path):
 
 @pytest.fixture(scope="module")
 def sf001_session():
-    if not os.path.exists(os.path.join(DATA, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", DATA, "--overwrite_output"],
-            check=True, capture_output=True, cwd=REPO,
-        )
-        open(os.path.join(DATA, ".complete"), "w").close()
+    raw_data()
     schemas = get_schemas(True)
     sess = Session(conf={})
     for t in ("store_sales", "store_returns", "date_dim", "item", "store"):

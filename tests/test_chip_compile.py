@@ -200,3 +200,50 @@ def test_fused_pipeline_compiles_for_v5e(one_chip, name, tmp_path,
         assert any(FACT_CAP in shape for shape, _ in shapes)
         compiled = _compile(fn, one_chip, *shapes)
         assert compiled.memory_analysis() is not None
+
+
+# ---------------------------------------------------------------------------
+# the row gather under a mesh: `kernels._gather` is jitted, so on sharded
+# buffers GSPMD partitions it (the eager `data[idx]` it replaced was
+# dispatched op by op). Fact columns shard on rows over `data`, dimension
+# columns replicate (session.py's placement); the index is whatever the
+# operator before left it as.
+# ---------------------------------------------------------------------------
+
+_MESH_GATHERS = {
+    # compaction or sort of a fact table
+    "fact_by_sharded_index": ("data", "data"),
+    # a dimension's columns under a fact-aligned join index
+    "dimension_by_sharded_index": (None, "data"),
+    # a fact column under an index some operator replicated
+    "fact_by_replicated_index": ("data", None),
+}
+
+
+@pytest.mark.parametrize("dtype", ["int64", "bool"])
+@pytest.mark.parametrize("case", sorted(_MESH_GATHERS))
+def test_row_gather_partitions_for_a_v5e_mesh(topo, case, dtype):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+
+    from nds_tpu.ops import kernels as K
+
+    mesh = Mesh(np.array(topo.devices).reshape(-1), ("data",))
+    assert mesh.devices.size == 4
+    src, idx = (NamedSharding(mesh, PS(axis)) for axis in _MESH_GATHERS[case])
+    rows = DATE_ROWS if _MESH_GATHERS[case][0] is None else FACT_CAP
+    data = jax.ShapeDtypeStruct((bucket_cap(rows),), dtype, sharding=src)
+    index = jax.ShapeDtypeStruct((FACT_CAP,), jnp.int32, sharding=idx)
+    compiled = K._gather.lower(data, index).compile()
+    assert compiled.memory_analysis() is not None
+    (out,) = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert out.mesh.devices.size == 4  # laid out on the mesh, by GSPMD
+    if case == "dimension_by_sharded_index":
+        # the star-query layout's point: a dimension join's gather stays
+        # on its chip and its output stays sharded like the index
+        assert out.spec == PS("data")
+        assert not any(op in compiled.as_text()
+                       for op in ("all-gather", "all-reduce", "all-to-all"))
+    if dtype == "bool":
+        keep = jax.ShapeDtypeStruct((FACT_CAP,), jnp.bool_, sharding=idx)
+        masked = K._gather_valid.lower(data, index, keep).compile()
+        assert masked.memory_analysis() is not None

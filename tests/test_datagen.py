@@ -362,3 +362,60 @@ def test_fact_primary_keys_unique(datadir):
              for c in pk], 1,
         )
         assert len(np.unique(m, axis=0)) == tab.num_rows, t
+
+
+@pytest.mark.parametrize("leftover", [False, True], ids=["cold", "after-a-dead-run"])
+def test_shared_data_is_generated_once_under_racing_workers(
+    tmp_path, monkeypatch, leftover,
+):
+    """tests/shared_data.py: workers that meet a cold temporary directory at
+    once (threads here, each with its own lock descriptor as processes
+    have) generate once; the others wait and find the directory there.
+    Nothing is written at the target itself, so what a run that died left
+    beside it is never read."""
+    import threading
+    import time
+
+    import shared_data
+
+    target = tmp_path / "shared" / "sf001"
+    if leftover:
+        (tmp_path / "shared" / "sf001.dead" / "store_sales").mkdir(parents=True)
+    calls = []
+
+    def fake_gen(cmd, **kw):
+        out = cmd[cmd.index("--data_dir") + 1]
+        calls.append(out)
+        assert out != str(target) and "--overwrite_output" in cmd
+        assert not target.exists()
+        time.sleep(0.2)  # long enough for every thread to queue on the lock
+        os.makedirs(os.path.join(out, "item"))
+        with open(os.path.join(out, "item", "item.dat"), "w") as f:
+            f.write("1|AAAA|")
+
+    monkeypatch.setattr(shared_data.subprocess, "run", fake_gen)
+    got = []
+    threads = [
+        threading.Thread(
+            target=lambda: got.append(shared_data._generated(str(target))))
+        for _ in range(6)
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert got == [str(target)] * 6 and len(calls) == 1
+    assert os.listdir(target) == ["item"]
+    assert not os.path.exists(calls[0])  # renamed into place, nothing left
+
+
+def test_shared_data_lives_under_the_runs_own_temporary_directory():
+    """Two runs with a `TMPDIR` each never meet, and no path is one that a
+    tree from before the helper writes (`<tmp>/nds_test_sf001`)."""
+    import tempfile
+
+    import shared_data
+
+    root = os.path.join(tempfile.gettempdir(), "nds_tpu_tests")
+    assert shared_data.DATA == os.path.join(root, "sf001")
+    assert shared_data.REFRESH == os.path.join(root, "sf001_refresh")

@@ -9,8 +9,6 @@ chaos-harness practice the reference gets for free from Spark's scheduler
 
 import json
 import os
-import subprocess
-import sys
 import time
 
 import pytest
@@ -21,8 +19,8 @@ from nds_tpu.io.fs import fs_open, fs_open_atomic
 from nds_tpu.power import gen_sql_from_stream, run_query_stream
 from nds_tpu.report import BenchReport
 from nds_tpu.engine.session import Session
+from shared_data import DATA, raw_data
 
-DATA = "/tmp/nds_test_sf001"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -306,13 +304,7 @@ def test_watchdog_timeout_classification():
 
 @pytest.fixture(scope="module")
 def data_dir(tmp_path_factory):
-    if not os.path.exists(os.path.join(DATA, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", DATA, "--overwrite_output"],
-            check=True, capture_output=True, cwd=REPO,
-        )
-        open(os.path.join(DATA, ".complete"), "w").close()
+    raw_data()
     # mini warehouse with only the tables the smoke stream touches: the
     # power driver's table setup eagerly reads every .dat dir it finds, and
     # these tests care about failure plumbing, not 25-table ingestion time

@@ -9,8 +9,6 @@ time-log/report writes all target memory:// paths.
 """
 
 import os
-import subprocess
-import sys
 
 import pytest
 
@@ -18,22 +16,15 @@ from nds_tpu.engine.session import Session
 from nds_tpu.lakehouse.table import LakehouseTable
 from nds_tpu.schema import get_schemas
 from nds_tpu.transcode import transcode_table
+import shared_data
 
-DATA = "/tmp/nds_test_sf001"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TABLES = ("store_sales", "date_dim", "item")
 
 
 @pytest.fixture(scope="module")
 def raw_data():
-    if not os.path.exists(os.path.join(DATA, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", DATA, "--overwrite_output"],
-            check=True, capture_output=True, cwd=REPO,
-        )
-        open(os.path.join(DATA, ".complete"), "w").close()
-    return DATA
+    return shared_data.raw_data()
 
 
 @pytest.fixture(scope="module")

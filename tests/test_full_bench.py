@@ -9,8 +9,8 @@ import sys
 import pytest
 
 from nds_tpu import full_bench as FB
+from shared_data import raw_data
 
-DATA = "/tmp/nds_test_sf001"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # fast, engine-friendly queries for the smoke streams (real templates are
@@ -68,14 +68,7 @@ def test_num_streams_must_be_odd():
 
 @pytest.fixture(scope="module")
 def data_dir():
-    if not os.path.exists(os.path.join(DATA, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", DATA, "--overwrite_output"],
-            check=True, capture_output=True, cwd=REPO,
-        )
-        open(os.path.join(DATA, ".complete"), "w").close()
-    return DATA
+    return raw_data()
 
 
 def _write_stream(path, n_queries=2):

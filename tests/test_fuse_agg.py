@@ -320,7 +320,7 @@ def test_launch_seam_counts_without_synchronizing(tmp_path, monkeypatch):
             assert isinstance(kernel, str) and isinstance(n, int) and n >= 1
             launches[kernel] = launches.get(kernel, 0) + n
     assert "segment_reduce_with_count" in launches
-    assert launches["take_rows"] >= 1
+    assert launches["take_columns"] >= 1
     lt = R.profile_events(events)["launch_totals"]
     assert lt == launches
 
@@ -353,10 +353,10 @@ def test_launch_seam_skips_jax_tracing():
     idx = jnp.asarray([7, 0, 7, 2], dtype=jnp.int32)
     t = obs_tally.Tally(tracer, 99)
     with obs_tally.bind(t):
-        traced = jax.jit(lambda d: K.take_rows(d, idx))(data)
+        (traced,) = jax.jit(lambda d: K.take_arrays((d,), idx))(data)
         assert t.launches == {}
-        eager = K.take_rows(data, idx)
-        assert t.launches == {"take_rows": 1}
+        (eager,) = K.take_arrays((data,), idx)
+        assert t.launches == {"take_columns": 1}
     assert eager.tolist() == data[idx].tolist() == traced.tolist()
     assert obs_tally.current() is None
 

@@ -18,35 +18,19 @@ from nds_tpu.maintenance import (
     replace_date,
     run_maintenance,
 )
+from shared_data import raw_data, refresh_data
 
-DATA = "/tmp/nds_test_sf001"
-REFRESH = "/tmp/nds_test_sf001_refresh"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
 def data_dir():
-    if not os.path.exists(os.path.join(DATA, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", DATA, "--overwrite_output"],
-            check=True, capture_output=True, cwd=REPO,
-        )
-        open(os.path.join(DATA, ".complete"), "w").close()
-    return DATA
+    return raw_data()
 
 
 @pytest.fixture(scope="module")
 def refresh_dir():
-    if not os.path.exists(os.path.join(REFRESH, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", REFRESH, "--update", "1",
-             "--overwrite_output"],
-            check=True, capture_output=True, cwd=REPO,
-        )
-        open(os.path.join(REFRESH, ".complete"), "w").close()
-    return REFRESH
+    return refresh_data()
 
 
 @pytest.fixture(scope="module")
@@ -186,6 +170,13 @@ def test_maintenance_all_functions(warehouse, refresh_dir, tmp_path):
     from nds_tpu.maintenance import INSERT_FUNCS, DELETE_FUNCS
 
     before = _counts(warehouse, ALL_FACTS)
+    # the rollback target below is a whole second (strftime truncates): let
+    # one pass between the Load's last commit (a table is created empty and
+    # ingested chunk by chunk) and maintenance's first
+    loaded_ms = max(
+        LakehouseTable(str(warehouse / t)).versions()[-1][1] for t in ALL_FACTS
+    )
+    time.sleep(max(0.0, loaded_ms / 1000 + 1 - time.time()))
 
     # ---- all 7 LF_* (INSERT) functions ----------------------------------
     jdir = tmp_path / "json_lf"
@@ -252,12 +243,9 @@ def test_maintenance_all_functions(warehouse, refresh_dir, tmp_path):
 
     import datetime
 
-    ts = max(
-        LakehouseTable(str(warehouse / t)).versions()[0][1] for t in ALL_FACTS
-    )
     rollback(
         str(warehouse),
-        datetime.datetime.fromtimestamp(ts / 1000 + 1).strftime(
+        datetime.datetime.fromtimestamp(loaded_ms / 1000 + 1).strftime(
             "%Y-%m-%d %H:%M:%S"
         ),
         tables=ALL_FACTS,

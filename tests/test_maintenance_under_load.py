@@ -20,9 +20,8 @@ from nds_tpu import faults
 from nds_tpu.engine.session import Session
 from nds_tpu.lakehouse.table import LakehouseTable
 from nds_tpu.maintenance import _p99_ms, run_maintenance
+from shared_data import raw_data, refresh_data
 
-DATA = "/tmp/nds_test_sf001"
-REFRESH = "/tmp/nds_test_sf001_refresh"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -244,27 +243,12 @@ def test_interleaved_writer_thread_with_schedule_and_vacuum(tmp_path):
 
 @pytest.fixture(scope="module")
 def data_dir():
-    if not os.path.exists(os.path.join(DATA, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", DATA, "--overwrite_output"],
-            check=True, capture_output=True, cwd=REPO,
-        )
-        open(os.path.join(DATA, ".complete"), "w").close()
-    return DATA
+    return raw_data()
 
 
 @pytest.fixture(scope="module")
 def refresh_dir():
-    if not os.path.exists(os.path.join(REFRESH, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", REFRESH, "--update", "1",
-             "--overwrite_output"],
-            check=True, capture_output=True, cwd=REPO,
-        )
-        open(os.path.join(REFRESH, ".complete"), "w").close()
-    return REFRESH
+    return refresh_data()
 
 
 @pytest.fixture(scope="module")

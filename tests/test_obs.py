@@ -10,7 +10,6 @@ asserted here against events produced by the real emission sites."""
 import json
 import os
 import subprocess
-import sys
 import threading
 import time
 
@@ -28,8 +27,8 @@ from nds_tpu.obs import reader as R
 from nds_tpu.obs.memwatch import MemorySampler
 from nds_tpu.obs.trace import EVENT_SCHEMA, Tracer, bind, tracer_from_conf
 from nds_tpu.report import BenchReport
+from shared_data import DATA, raw_data
 
-DATA = "/tmp/nds_test_sf001"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -1189,13 +1188,7 @@ def test_profile_cli_compact_subcommand(tmp_path, capsys):
 
 @pytest.fixture(scope="module")
 def data_dir(tmp_path_factory):
-    if not os.path.exists(os.path.join(DATA, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", DATA, "--overwrite_output"],
-            check=True, capture_output=True, cwd=REPO,
-        )
-        open(os.path.join(DATA, ".complete"), "w").close()
+    raw_data()
     mini = tmp_path_factory.mktemp("mini_wh")
     for t in ("store_sales", "date_dim"):
         os.symlink(os.path.join(DATA, t), mini / t)

@@ -9,16 +9,14 @@ implementation on a representative query battery."""
 import math
 import os
 import sqlite3
-import subprocess
-import sys
 
 import pytest
 
 from nds_tpu.engine.session import Session
 from nds_tpu.io.csv import read_dat_dir
 from nds_tpu.schema import get_schemas
+from shared_data import raw_data
 
-DATA = "/tmp/nds_test_sf001"
 TABLES = ("store_sales", "store_returns", "item", "date_dim", "store", "customer")
 
 # Every dialect difference is lowered by _to_sqlite below (ROLLUP ->
@@ -179,15 +177,7 @@ class _StddevSamp:
 
 @pytest.fixture(scope="module")
 def data_dir():
-    if not os.path.exists(os.path.join(DATA, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", DATA, "--overwrite_output"],
-            check=True, capture_output=True,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        )
-        open(os.path.join(DATA, ".complete"), "w").close()
-    return DATA
+    return raw_data()
 
 
 def _load_engines(data_dir, tables):

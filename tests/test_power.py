@@ -4,8 +4,6 @@ nds/nds_power.py:50-77,184-299 and nds/PysparkBenchReport.py:58-119)."""
 import csv
 import json
 import os
-import subprocess
-import sys
 
 import pytest
 
@@ -17,21 +15,13 @@ from nds_tpu.power import (
 )
 from nds_tpu.report import BenchReport
 from nds_tpu.engine.session import Session
+from shared_data import raw_data
 
-DATA = "/tmp/nds_test_sf001"
 
 
 @pytest.fixture(scope="module")
 def data_dir():
-    if not os.path.exists(os.path.join(DATA, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", DATA, "--overwrite_output"],
-            check=True, capture_output=True,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        )
-        open(os.path.join(DATA, ".complete"), "w").close()
-    return DATA
+    return raw_data()
 
 
 STREAM = """-- start query 1 in stream 0 using template query96.tpl

@@ -3,8 +3,6 @@ executes against generated data (the engine's acceptance gate for new
 templates)."""
 
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -13,21 +11,13 @@ from nds_tpu.datagen import query_streams as QS
 from nds_tpu.engine.session import Session
 from nds_tpu.engine.sql.parser import parse_sql
 from nds_tpu.schema import get_schemas
+from shared_data import raw_data
 
-DATA = "/tmp/nds_test_sf001"
 
 
 @pytest.fixture(scope="module")
 def data_dir():
-    if not os.path.exists(os.path.join(DATA, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", DATA, "--overwrite_output"],
-            check=True, capture_output=True,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        )
-        open(os.path.join(DATA, ".complete"), "w").close()
-    return DATA
+    return raw_data()
 
 
 @pytest.fixture(scope="module")

@@ -253,6 +253,58 @@ def test_counts_repeat_and_every_event_attaches(raw, name):
             sum(e["dur_ms"] for e in reads), abs=0.01 * len(reads) + 0.01)
 
 
+# The gathers a statement needs at SF0.01: calls of the gather (one per table
+# side) and the buffers they take (one program and one counted launch each).
+#   query3   3 compactions of join sides that arrive masked, 1 dense-join
+#            output; the sort join's candidate pairs (li, ri) under the
+#            verified selection and the 2 sides of its pair table; the
+#            group-by's sort words under the sort order
+#   query96  5 compactions, 3 dense-join outputs, 1 take under the LIMIT
+GATHERS = {"query3": (8, 33), "query96": (9, 33)}
+
+
+@pytest.mark.parametrize("name", sorted(GATHERS))
+def test_a_statement_gathers_once_per_table_side(raw, name, monkeypatch):
+    """A per-column loop over the gather shows here as calls by the dozen,
+    a column gathered twice as more buffers. Counts repeat between
+    executions, and the answer is the one the eager per-column `data[idx]`
+    gives."""
+    from nds_tpu.ops import kernels as K
+
+    calls = []
+    enter = T.Tally.enter
+
+    def counting(self, kernel, n=1):
+        if kernel == "take_columns":
+            calls.append(n)
+        return enter(self, kernel, n)
+
+    monkeypatch.setattr(T.Tally, "enter", counting)
+    tracer = Tracer()
+    s = _tpcds_session(raw, tracer)
+    sql = _statements()[name]
+    _run(s, sql, "warm")
+    answers, per_run = [], []
+    for tag in ("first", "second"):
+        s.register_arrow("tick", pa.table({"n": [len(answers)]}))
+        del calls[:]
+        answers.append(_run(s, sql, tag))
+        per_run.append(list(calls))
+    counts = [_counts(tracer.events, tag)[0] for tag in ("first", "second")]
+    assert counts[0] == counts[1] and per_run[0] == per_run[1]
+    assert (len(per_run[0]), sum(per_run[0])) == GATHERS[name]
+    assert counts[0]["take_columns"] == sum(per_run[0])
+
+    # the parent's computation: jnp's eager indexing, buffer by buffer
+    monkeypatch.setattr(K, "_gather", lambda a, idx: a[idx])
+    monkeypatch.setattr(
+        K, "_gather_valid",
+        lambda v, idx, keep: keep if v is None else v[idx] & keep)
+    s.register_arrow("tick", pa.table({"n": [2]}))
+    reference = _run(s, sql, "reference")
+    assert answers[0].equals(reference) and answers[1].equals(reference)
+
+
 @pytest.mark.parametrize("dtype", ["int32", "int64", "float64", "bool"])
 def test_seamed_gather_gives_what_indexing_gives(dtype):
     import jax.numpy as jnp
@@ -261,9 +313,165 @@ def test_seamed_gather_gives_what_indexing_gives(dtype):
 
     data = jnp.asarray(np.arange(64) % 5, dtype=dtype)
     idx = jnp.asarray([63, 0, 0, 17, 5], dtype=jnp.int32)
-    out = K.take_rows(data, idx)
+    (out,) = K.take_arrays((data,), idx)
     assert out.dtype == data.dtype
     assert out.tolist() == data[idx].tolist()
+
+
+# -- take_columns: one call for every column that shares an index -----------
+
+_N = 64
+_IDX = {
+    "plain": [63, 0, 17, 5, 1, 2, 3, 4],
+    "repeats": [7, 7, 7, 0, 0, 63, 63, 7],
+    # what `data[idx]` accepts today: negatives count from the end, and
+    # whatever lies outside the buffer is clamped to it
+    "negative": [-1, -64, -2, 0, 5, -63, 1, -5],
+    "out_of_range": [64, 1000, -65, -1000, 0, 63, 2**31 - 1, -2**31],
+}
+
+
+def _buffers(kinds):
+    import jax.numpy as jnp
+
+    made = {
+        "int32": lambda k: jnp.asarray(np.arange(_N) * 3 - k, jnp.int32),
+        "int64": lambda k: jnp.asarray(np.arange(_N) * 2**33 + k, jnp.int64),
+        "float64": lambda k: jnp.asarray(np.arange(_N) / 7.0 + k, jnp.float64),
+        "bool": lambda k: jnp.asarray((np.arange(_N) + k) % 3 == 0),
+    }
+    return [made[kind](k) for k, kind in enumerate(kinds)]
+
+
+def _pairs(kinds, with_valid):
+    import jax.numpy as jnp
+
+    return tuple(
+        (d, jnp.asarray((np.arange(_N) + k) % 4 != 0)
+         if with_valid and k % 2 == 0 else None)
+        for k, d in enumerate(_buffers(kinds)))
+
+
+MIXED = ("float64", "int32", "bool", "int64", "int32")
+
+
+@pytest.mark.parametrize("with_valid", [False, True], ids=["bare", "valid"])
+@pytest.mark.parametrize("idx_kind", list(_IDX))
+@pytest.mark.parametrize("kinds", [("int32",), ("int64",), ("float64",),
+                                   ("bool",), MIXED], ids="-".join)
+def test_take_columns_gives_what_indexing_gives(kinds, idx_kind, with_valid):
+    import jax.numpy as jnp
+
+    from nds_tpu.ops import kernels as K
+
+    pairs = _pairs(kinds, with_valid)
+    idx = jnp.asarray(_IDX[idx_kind], dtype=jnp.int32)
+    out = K.take_columns(pairs, idx)
+    assert len(out) == len(pairs)
+    for (d, v), (od, ov) in zip(pairs, out):
+        assert od.dtype == d.dtype and od.tolist() == d[idx].tolist()
+        if v is None:
+            assert ov is None
+        else:
+            assert ov.dtype == jnp.bool_ and ov.tolist() == v[idx].tolist()
+
+
+@pytest.mark.parametrize("idx_kind", list(_IDX))
+def test_take_columns_ands_the_row_mask_into_every_validity(idx_kind):
+    """`keep` is the null extension of an outer join: `valid[idx] & keep`,
+    and `keep` itself for a column that had no validity buffer."""
+    import jax.numpy as jnp
+
+    from nds_tpu.ops import kernels as K
+
+    pairs = _pairs(MIXED, True)
+    idx = jnp.asarray(_IDX[idx_kind], dtype=jnp.int32)
+    keep = jnp.asarray([True, True, False, True, False, False, True, True])
+    out = K.take_columns(pairs, idx, keep)
+    for (d, v), (od, ov) in zip(pairs, out):
+        assert od.tolist() == d[idx].tolist()
+        want = keep if v is None else v[idx] & keep
+        assert ov.tolist() == want.tolist()
+
+
+def test_take_columns_of_nothing_is_nothing_and_launches_nothing():
+    import jax.numpy as jnp
+
+    from nds_tpu.ops import kernels as K
+
+    tl = T.Tally(Tracer(), 1)
+    with T.bind(tl):
+        assert K.take_columns((), jnp.arange(4)) == ()
+    assert tl.launches == {}
+
+
+def test_take_columns_outputs_alias_nothing():
+    """Every output is a buffer of its own: not an input, not `keep`, not
+    another output (a join output's columns are `owned` and may be donated
+    one by one)."""
+    import jax.numpy as jnp
+
+    from nds_tpu.ops import kernels as K
+
+    d = jnp.arange(_N, dtype=jnp.int64)
+    idx = jnp.arange(_N, dtype=jnp.int32)  # the identity gather
+    keep = jnp.ones(_N, bool)
+    out = K.take_columns(((d, None), (d, keep), (d, None)), idx, keep)
+    bufs = [b for pair in out for b in pair]
+    ptrs = [b.unsafe_buffer_pointer() for b in bufs + [d, idx, keep]]
+    assert len(set(ptrs)) == len(ptrs)
+
+
+def test_take_columns_counts_its_buffers_and_nothing_under_a_trace():
+    """One call, one timed seam entry; `launches` counts the programs it
+    launches, one a buffer (with `keep`, a validity for every column)."""
+    import jax
+    import jax.numpy as jnp
+
+    from nds_tpu.ops import kernels as K
+
+    pairs = _pairs(MIXED, True)  # 5 data buffers, 3 of them with validity
+    idx = jnp.asarray(_IDX["plain"], dtype=jnp.int32)
+    keep = jnp.ones(len(_IDX["plain"]), bool)
+    tl = T.Tally(Tracer(), 1)
+    with T.bind(tl):
+        traced = jax.jit(lambda p: K.take_columns(p, idx))(pairs)
+        assert tl.launches == {}
+        eager = K.take_columns(pairs, idx)
+        assert tl.launches == {"take_columns": 8}
+        K.take_columns(pairs, idx, keep)
+        assert tl.launches == {"take_columns": 18}
+        K.take_arrays([d for d, _ in pairs], idx)  # same programs, same name
+        assert tl.launches == {"take_columns": 23}
+        assert K.take_arrays((), idx) == ()
+        assert tl.launches == {"take_columns": 23}
+    for (td, tv), (ed, ev) in zip(traced, eager):
+        assert td.tolist() == ed.tolist()
+        assert (tv is None and ev is None) or tv.tolist() == ev.tolist()
+
+
+def test_tables_with_the_same_column_types_share_their_programs():
+    """The gather is one jitted program a buffer, keyed by the buffer's
+    dtype and the two capacities: a second table with the same column
+    types, in any order, with or without validity, compiles nothing."""
+    import jax.numpy as jnp
+
+    from nds_tpu.ops import kernels as K
+
+    tracer = Tracer()
+    obs_trace.watch_compiles(tracer)
+    idx = jnp.asarray(_IDX["repeats"] * 3, dtype=jnp.int32)  # a new shape
+    a, b, c, d = _pairs(("int32", "float64", "int64", "float64"), True)
+    with bind(tracer):
+        first = K.take_columns((a, b, c, d), idx)
+        size = K._gather._cache_size()
+        n_compiles = sum(e["kind"] == "xla_compile" for e in tracer.events)
+        assert n_compiles >= 1
+        second = K.take_columns((d, c, (a[0], None), b), idx)
+    assert K._gather._cache_size() == size
+    assert sum(e["kind"] == "xla_compile" for e in tracer.events) == n_compiles
+    for x, y in zip(first, (second[2], second[3], second[1], second[0])):
+        assert x[0].tolist() == y[0].tolist()
 
 
 def test_launch_ms_leaves_out_the_reads_inside_a_seamed_call():
@@ -397,7 +605,7 @@ SPLIT_EVENTS = [
         launches={"compact_indices": 1}, launch_ms=10.0, reads=1,
         read_wait_ms=15.0),
     _ev("op_span", 50, 880, exec_id=1, seq=2, depth=0, node="MultiJoin",
-        explain="", rows=1, est_bytes=0, launches={"take_rows": 12},
+        explain="", rows=1, est_bytes=0, launches={"take_columns": 12},
         launch_ms=90.0, reads=2, read_wait_ms=300.0),
     _ev("op_span", 60, 200, exec_id=1, seq=1, depth=1, node="Scan",
         explain="", rows=1, est_bytes=0, launches={}, launch_ms=0.0, reads=0,
@@ -442,7 +650,7 @@ def test_critical_path_splits_execute_into_disjoint_causes():
     assert c["plan-host"] == 100.0  # the query_span outside the result_span
     assert sum(c.values()) == pytest.approx(q["wall_ms"])
     assert q["attributed_frac"] == 1.0
-    assert q["launches"] == {"take_rows": 12, "compact_indices": 1}
+    assert q["launches"] == {"take_columns": 12, "compact_indices": 1}
     assert q["reads"]["nrows"] == {"count": 1, "ms": 250.0}
     assert q["compiles"]["gather"] == {"count": 1, "ms": 50.0, "fresh": 1}
     assert q["compiles"]["pipe"] == {"count": 1, "ms": 70.0, "fresh": 0}
@@ -475,7 +683,7 @@ def test_critical_path_of_a_real_statement_stays_attributed():
     assert all(v >= 0 for v in q["causes"].values())
     assert q["causes"]["execute"] == 0.0
     assert q["causes"]["device-wait"] > 0 and q["causes"]["launch"] > 0
-    assert q["launches"]["take_rows"] >= 1 and q["reads"]["collect"]["count"] == 1
+    assert q["launches"]["take_columns"] >= 1 and q["reads"]["collect"]["count"] == 1
 
 
 def test_profile_cli_prints_the_split_table(tmp_path, capsys):
@@ -489,7 +697,7 @@ def test_profile_cli_prints_the_split_table(tmp_path, capsys):
         [str(log), "--critical-path", "--min_attributed", "0.9"])
     out = capsys.readouterr().out
     for needle in ("device-wait", "host-python", "exec-lookup", "h2d",
-                   "launches: take_rows 12", "reads: nrows 1 (250.0 ms)",
+                   "launches: take_columns 12", "reads: nrows 1 (250.0 ms)",
                    "compiles: pipe 1 (0 fresh, 70.0 ms)"):
         assert needle in out, needle
     assert "\n   execute " not in out
@@ -563,7 +771,7 @@ def test_new_work_on_the_ring_only_path_fits_its_budget(raw):
     emit_us = (time.perf_counter() - t0) / n * 1e6
     t0 = time.perf_counter()
     for _ in range(n):
-        tl.leave(tl.enter("take_rows"))
+        tl.leave(tl.enter("take_columns"))
     seam_us = (time.perf_counter() - t0) / n * 1e6
     t0 = time.perf_counter()
     for _ in range(n):

@@ -9,15 +9,13 @@ fork-per-process mode end-to-end as well.
 
 import csv
 import os
-import subprocess
-import sys
 
 import pytest
 
 from nds_tpu.schema import get_schemas
 from nds_tpu.throughput import run_throughput
+from shared_data import DATA, raw_data
 
-DATA = "/tmp/nds_test_sf001"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SMOKE_QUERY = """
@@ -30,13 +28,7 @@ order by d_year, d_moy
 
 @pytest.fixture(scope="module")
 def warehouse(tmp_path_factory):
-    if not os.path.exists(os.path.join(DATA, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", DATA, "--overwrite_output"],
-            check=True, capture_output=True, cwd=REPO,
-        )
-        open(os.path.join(DATA, ".complete"), "w").close()
+    raw_data()
     out = tmp_path_factory.mktemp("wh")
     from nds_tpu.transcode import transcode_table
 

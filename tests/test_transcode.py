@@ -12,21 +12,13 @@ import pytest
 from nds_tpu.io.csv import iter_dat_batches, read_dat_dir
 from nds_tpu.schema import get_schemas
 from nds_tpu.transcode import TABLE_PARTITIONING, transcode, transcode_table
+from shared_data import raw_data
 
-DATA = "/tmp/nds_test_sf001"
 
 
 @pytest.fixture(scope="module")
 def data_dir():
-    if not os.path.exists(os.path.join(DATA, ".complete")):
-        subprocess.run(
-            [sys.executable, "-m", "nds_tpu.cli.gen_data", "--scale", "0.01",
-             "--parallel", "2", "--data_dir", DATA, "--overwrite_output"],
-            check=True, capture_output=True,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        )
-        open(os.path.join(DATA, ".complete"), "w").close()
-    return DATA
+    return raw_data()
 
 
 def _args(data_dir, out, report, **kw):
