@@ -29,6 +29,9 @@ drives the program's own functions and sets no engine option:
    (compared beside the first pass's), counters and the device's
    `peak_bytes_in_use` are read and, traced, the profiler's slice reduced.
 
+With `--pass_only` the child ends after step 1: the same first pass in a
+fresh process of its own, the second reading `first_pass_s` is the lower of.
+
 Everything is handed to the parent in `<run_dir>/child.json`; the parent
 alone judges it and prints the result line.
 """
@@ -62,9 +65,8 @@ def parse_args(argv):
     ap.add_argument("--scale", type=float, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=[0, 1], required=True)
-    ap.add_argument("--gate", required=True,
-                    help="the window opens only once this file exists: the "
-                    "parent writes it when the reference child has exited")
+    ap.add_argument("--pass_only", action="store_true",
+                    help="the first pass alone: no rehearsal, no window")
     return ap.parse_args(argv)
 
 
@@ -191,6 +193,16 @@ def counters(session, watch):
     }
 
 
+def hand_over(session, rd, out):
+    """What the child found, whole or not at all, where the parent looks."""
+    if session.tracer is not None:
+        session.tracer.close()
+    with open(f"{rd}/child.json.tmp", "w") as f:
+        json.dump(out, f)
+    os.replace(f"{rd}/child.json.tmp", f"{rd}/child.json")
+    print("child: done", flush=True)
+
+
 def main(argv=None):
     args = parse_args(argv)
     t_start = time.time()
@@ -248,6 +260,8 @@ def main(argv=None):
     out["first_pass"] = first
     out["counters"] = {"first_pass_end": counters(session, watch)}
     print(f"child: first pass {first['power_test_ms']} ms", flush=True)
+    if args.pass_only:
+        return hand_over(session, rd, out)
 
     aot = getattr(session, "aot_cache", None)
     spans = Spans(False)
@@ -291,12 +305,6 @@ def main(argv=None):
     out["counters"]["rehearsal_end"] = counters(session, watch)
     print(f"child: rehearsal {time.perf_counter() - t_reh:.3f} s, "
           f"{len(rehearsal)} statements", flush=True)
-
-    # -- the reference must have left the host before the window opens ------
-    t0 = time.perf_counter()
-    while not os.path.exists(args.gate):
-        time.sleep(0.05)
-    out["gate_wait_s"] = time.perf_counter() - t0
 
     # -- window --------------------------------------------------------------
     import pyarrow as pa
@@ -371,12 +379,7 @@ def main(argv=None):
                 out["device_trace"] = reduce_trace(found[0])
             except lib.BenchmarkError as e:
                 out["trace_error"] = str(e)
-    if session.tracer is not None:
-        session.tracer.close()
-    with open(f"{rd}/child.json.tmp", "w") as f:
-        json.dump(out, f)
-    os.replace(f"{rd}/child.json.tmp", f"{rd}/child.json")
-    print("child: done", flush=True)
+    return hand_over(session, rd, out)
 
 
 if __name__ == "__main__":

@@ -1,8 +1,9 @@
 """Kernel launches per window statement as the program's launch seam counts
-them: the `launches` of every `op_span` and `result_span` (kernel entry
-points of `ops/kernels.py`, the per-column `take_rows` gathers, one per
-fused-pipeline call). An entry is at least one program launch. A count: it
-repeats exactly for one seed and one number of statements."""
+them: the `launches` of every `op_span` and `result_span` (one per kernel
+entry point of `ops/kernels.py` and per fused-pipeline call, each at least
+one program launch, and one per buffer for `take_columns`, the row gather,
+which runs one jitted program a buffer). A count: it repeats exactly for one
+seed and one number of statements."""
 
 from benchmarks.layer_metrics._spans import WINDOW, between
 

@@ -143,6 +143,16 @@ def events_between(run, kind, start_mark, end_mark):
             if e.get("kind") == kind and lo <= e.get("ts", 0) <= hi]
 
 
+def compiles_of(jax_counters):
+    """(programs through XLA's backend compile, those of them that jax's
+    persistent cache served) from a snapshot of the chip child's
+    `CompileWatch`: jax's own monitoring events, counted in every run."""
+    def count(key):
+        return jax_counters.get(key, [0])[0]
+    return (count("/jax/core/compile/backend_compile_duration"),
+            count("/jax/compilation_cache/cache_hits"))
+
+
 # -- traffic -----------------------------------------------------------------
 
 def make_streams(traffic, scale, first, count):
@@ -275,8 +285,11 @@ def tree_hash(paths, root=REPO):
     return h.hexdigest()[:16]
 
 
-def result_line(correct, attempted, failed, metrics, device, breakdown=None):
-    """The contract's last line. `metrics` maps name -> (value, unit)."""
+def result_line(correct, attempted, failed, metrics, device, compared,
+                breakdown=None, **more):
+    """The contract's last line. `metrics` maps name -> (value, unit);
+    `compared` is every number the comparison held to a limit, beside that
+    limit, and comes last; `more` is whatever else a run keeps on record."""
     out = {
         "correct": bool(correct),
         "attempted": int(attempted),
@@ -286,4 +299,6 @@ def result_line(correct, attempted, failed, metrics, device, breakdown=None):
     }
     if breakdown is not None:
         out["breakdown"] = breakdown
+    out.update(more)
+    out["compared"] = compared
     return json.dumps(out)

@@ -11,6 +11,12 @@ dialect to sqlite's, runs it, and writes the answer as
     python benchmarks/reference.py <raw_dir> <statements.json> <out_dir>
         [--control float32]
 
+sqlite answers one statement at a time on one core, and most of a child's
+seconds go into loading the tables: `run.py` starts one child a template,
+side by side into one `<out_dir>`, each over the few columns its own
+statements name. What a child loaded and the seconds it took are the last
+line of its output (its log).
+
 `--control float32` is the comparison's control: the same reference with
 every SUM and AVG accumulated in float32, the precision a later PR would be
 tempted to aggregate in on a chip whose 64-bit arithmetic is emulated. Its
@@ -269,8 +275,6 @@ def run(raw_dir, statements, out_dir, control=None):
                 names, zip(*rows) if rows else [[] for _ in names])}),
             os.path.join(out_dir, key, "part-0.parquet"))
     conn.close()
-    with open(os.path.join(out_dir, "reference.json"), "w") as f:
-        json.dump(info, f)
     return info
 
 

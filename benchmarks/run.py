@@ -15,10 +15,14 @@ traffic mix by name, and runs every phase as a child:
     Load        the warehouse, in the          data/<scale>-<seed>-<hash of
                 configuration's format       } what makes them>/, so a seed's
     reference   sqlite's answers (beside       second run finds them; written
-                Load and the first pass)     } to a temporary name, renamed
-    chip child  first pass + window (child.py), through ./nds-tpu-submit
+                Load, never beside a pass)   } to a temporary name, renamed
+    pass child  the first pass alone, in a fresh process (child.py --pass_only)
+    chip child  first pass + rehearsal + window (child.py)
 
-and then judges the run: fail, never fall back.
+and then judges the run: fail, never fall back. Both chip children go
+through ./nds-tpu-submit, one after the other, and nothing else of the
+benchmark's runs while either does: `first_pass_s` is the lower of their two
+first passes.
 
     --scale 0.01     a CPU rehearsal: runs to the end, then fails on the
                      platform check and prints no result line
@@ -194,32 +198,40 @@ class Run:
                 for si in (0, compared) for name, sql in streams[si]}
 
     def start_reference(self, raw, statements, control=None):
-        """sqlite's answers to `statements`: found, or a child started
-        beside Load and the first pass. Returns (dir, child or None)."""
+        """sqlite's answers to `statements`: found, or children started
+        beside Load, one a template. sqlite runs on one core and loads only
+        the tables and columns its statements name, so the children side by
+        side take the seconds of the slowest, about Load's own, where one
+        child took twice that. Returns (dir, children)."""
         with open(os.path.join(HERE, "reference.py"), "rb") as f:
             h = hashlib.sha256(f.read())
         h.update(json.dumps(statements, sort_keys=True).encode())
         ref = os.path.join(
             self.data, f"ref-{control or 'sound'}-{h.hexdigest()[:16]}")
         if os.path.isdir(ref):
-            return ref, None
+            return ref, []
         tmp = ref + ".tmp"
         shutil.rmtree(tmp, ignore_errors=True)
         os.makedirs(tmp)
-        with open(os.path.join(tmp, "statements.json"), "w") as f:
-            json.dump(statements, f)
-        child = self.spawn(f"reference_{control or 'sound'}", [
-            sys.executable, os.path.join(HERE, "reference.py"), raw,
-            os.path.join(tmp, "statements.json"), tmp,
-            *(["--control", control] if control else []),
-        ], env={"JAX_PLATFORMS": "cpu"})
-        child.final = ref
-        return ref, child
+        children = []
+        for template in sorted({key.split("/")[1] for key in statements}):
+            asked = os.path.join(tmp, f"statements-{template}.json")
+            with open(asked, "w") as f:
+                json.dump({k: v for k, v in statements.items()
+                           if k.split("/")[1] == template}, f)
+            children.append(self.spawn(
+                f"reference_{control or 'sound'}_{template}", [
+                    sys.executable, os.path.join(HERE, "reference.py"), raw,
+                    asked, tmp,
+                    *(["--control", control] if control else []),
+                ], env={"JAX_PLATFORMS": "cpu"}))
+        return ref, children
 
-    def finish_reference(self, child):
-        if child is not None:
+    def finish_reference(self, ref, children):
+        for child in children:
             self.wait(child)
-            os.rename(child.final + ".tmp", child.final)
+        if children:
+            os.rename(ref + ".tmp", ref)
 
     def ensure_warehouse(self, raw):
         load = self.config["load"]
@@ -242,38 +254,43 @@ class Run:
         return wh
 
     # -- the run -------------------------------------------------------------
-    def chip_child(self, wh, warm=False):
-        """The measured child or, `warm`, the same child with a window of
-        no seconds and no reference to wait for."""
-        a = self.args
-        name = "warm" if warm else "chip"
-        run_dir = os.path.join(self.run_dir, name) if warm else self.run_dir
-        os.makedirs(run_dir, exist_ok=True)
-        env = {}
-        if a.trace and not warm:
-            env["NDS_TRACE_DIR"] = os.path.join(run_dir, "trace")
-        self.gate = os.path.join(self.run_dir, "reference.done")
-        gate = self.run_dir if warm else self.gate
+    def stay_off_jax(self):
         if "jax" in sys.modules:
             raise BenchmarkError("the parent imported jax: it would hold "
                                  "the chip its child needs")
-        return self.spawn(name, [
+
+    def chip_child(self, wh, name="chip"):
+        """A child that owns the chip: `chip`, the measured one; `pass`, the
+        first pass alone; `warm`, the measured child's phases with a window
+        of no seconds. Each in a run directory of its own."""
+        a = self.args
+        measured = name == "chip"
+        run_dir = self.run_dir if measured else os.path.join(self.run_dir, name)
+        os.makedirs(run_dir, exist_ok=True)
+        env = {}
+        if a.trace and measured:
+            env["NDS_TRACE_DIR"] = os.path.join(run_dir, "trace")
+        self.stay_off_jax()
+        child = self.spawn(name, [
             os.path.join(REPO, "nds-tpu-submit"),
             self.config["power"]["template"], "benchmarks.child",
             "--warehouse", wh, "--run_dir", run_dir,
             "--traffic", self.spec.traffic_path(self.cell),
             "--seed", a.seed, "--scale", self.scale,
-            "--seconds", 0 if warm else a.seconds,
-            "--trace", 0 if warm else a.trace, "--gate", gate,
+            "--seconds", a.seconds if measured else 0,
+            "--trace", a.trace if measured else 0,
+            *(["--pass_only"] if name == "pass" else []),
         ], env=env)
+        child.run_dir = run_dir
+        return child
 
     def warm_checkout(self, wh):
         """`first_pass_s` is a first execution with warm disk caches. The
         first run of a cell in a checkout finds them empty, so it runs the
         first pass and the rehearsal once in a child that is thrown away
         (set-up: the compile of some minutes) and leaves a marker;
-        the child that is measured then finds every program on disk, as in
-        every later run."""
+        the children that are measured then find every program on disk, as
+        in every later run."""
         with open(self.spec.traffic_path(self.cell), "rb") as f:
             h = hashlib.sha256(f.read())
         h.update(json.dumps(self.config, sort_keys=True).encode())
@@ -281,7 +298,7 @@ class Run:
         marker = os.path.join(os.path.abspath(self.args.cache_dir),
                               f"warmed-{h.hexdigest()[:16]}")
         if not os.path.exists(marker):
-            self.wait(self.chip_child(wh, warm=True))
+            self.wait(self.chip_child(wh, "warm"))
             with open(marker, "w"):
                 pass
 
@@ -290,27 +307,34 @@ class Run:
         self.prepare()
         raw = self.ensure_raw()
         statements = self.statements()
-        ref, ref_child = self.start_reference(raw, statements)
+        ref, ref_children = self.start_reference(raw, statements)
         if a.control:
-            return self.control(raw, ref, ref_child)
+            return self.control(raw, ref, ref_children)
+        # Load is host-only and no metric times it: the reference may run
+        # beside it. Beside a child that owns the chip nothing of the
+        # benchmark's own runs: sqlite has exited before the first starts
         wh = self.ensure_warehouse(raw)
+        self.finish_reference(ref, ref_children)
         self.warm_checkout(wh)
-        chip = self.chip_child(wh)
-        self.finish_reference(ref_child)
-        with open(self.gate, "w"):
-            pass
-        self.wait(chip)
-        with open(chip.log.name, errors="replace") as f:
-            sys.stdout.write("".join(
-                line for line in f if line.startswith("child:")))
-        return self.judge(load_child(self.run_dir), ref, list(statements))
+        children = {}
+        for name in ("pass", "chip"):
+            child = self.chip_child(wh, name)
+            self.wait(child)
+            with open(child.log.name, errors="replace") as f:
+                sys.stdout.write("".join(
+                    f"{name} {line}" for line in f
+                    if line.startswith("child:")))
+            children[name] = load_child(child.run_dir)
+        return self.judge(children["chip"], children["pass"], ref,
+                          list(statements))
 
-    def control(self, raw, ref, ref_child):
+    def control(self, raw, ref, ref_children):
         """The control's answers in the program's place: must not pass."""
         asked = self.statements(self.args.control)
-        bad, bad_child = self.start_reference(raw, asked, self.args.control)
-        self.finish_reference(ref_child)
-        self.finish_reference(bad_child)
+        bad, bad_children = self.start_reference(raw, asked,
+                                                 self.args.control)
+        self.finish_reference(ref, ref_children)
+        self.finish_reference(bad, bad_children)
         per = compare.compare_answers(ref, bad, list(asked))
         ok, numbers = compare.verdict(per, self.config["correct_limits"])
         for key, p in per.items():
@@ -320,24 +344,26 @@ class Run:
         return 0 if not ok else 4
 
     # -- judgement -----------------------------------------------------------
-    def faults_of(self, child):
+    def faults_of(self, child, pass_only):
         """Fail, never fall back: everything but the answers that makes a
         run that reached its end not `correct`."""
         faults = []
-        for name, s in child["first_pass"]["statements"].items():
-            print(f"first pass {name}: {s.get('ms')} ms status={s['status']} "
-                  f"backend={s.get('backend')} mem={s.get('mem_bytes')} "
-                  f"({s.get('mem_source')})")
-            if s["status"] != ["Completed"]:
-                faults.append(f"first pass {name}: {s['status']} "
-                              f"{s.get('exceptions')}")
-            if s.get("backend") != "tpu":
-                faults.append(f"first pass {name}: ran on {s.get('backend')}")
-            if s.get("mem_source") != "device":
-                faults.append(f"first pass {name}: memory read from "
-                              f"{s.get('mem_source')}, not the device")
-            if s.get("ladder"):
-                faults.append(f"first pass {name}: ladder {s['ladder']}")
+        for where, first in (("pass-only first pass", pass_only["first_pass"]),
+                             ("first pass", child["first_pass"])):
+            for name, s in first["statements"].items():
+                print(f"{where} {name}: {s.get('ms')} ms "
+                      f"status={s['status']} backend={s.get('backend')} "
+                      f"mem={s.get('mem_bytes')} ({s.get('mem_source')})")
+                if s["status"] != ["Completed"]:
+                    faults.append(f"{where} {name}: {s['status']} "
+                                  f"{s.get('exceptions')}")
+                if s.get("backend") != "tpu":
+                    faults.append(f"{where} {name}: ran on {s.get('backend')}")
+                if s.get("mem_source") != "device":
+                    faults.append(f"{where} {name}: memory read from "
+                                  f"{s.get('mem_source')}, not the device")
+                if s.get("ladder"):
+                    faults.append(f"{where} {name}: ladder {s['ladder']}")
         for phase in ("rehearsal", "statements"):
             for s in child[phase]:
                 where = f"{phase} {s['name']} (stream {s['stream']})"
@@ -346,29 +372,56 @@ class Run:
                 if phase == "rehearsal" and s["status"] != "Completed":
                     faults.append(f"{where}: {s['status']} "
                                   f"{s.get('exceptions')}")
-        aot = child["counters"]["window_close"]["aot"] or {}
-        if aot.get("quarantined") or aot.get("call_failures"):
-            faults.append(f"AOT executables: {aot['quarantined']} quarantined,"
-                          f" {aot['call_failures']} failed at call")
+        for where, counters in (
+                ("pass-only child", pass_only["counters"]["first_pass_end"]),
+                ("measured child", child["counters"]["window_close"])):
+            aot = counters["aot"] or {}
+            if aot.get("quarantined") or aot.get("call_failures"):
+                faults.append(f"AOT executables of the {where}: "
+                              f"{aot['quarantined']} quarantined, "
+                              f"{aot['call_failures']} failed at call")
         return faults
 
-    def judge(self, child, ref, keys):
+    def judge(self, child, pass_only, ref, keys):
         device = child["device"]
         peaks = check_device(device, self.cell)
-        faults = self.faults_of(child)
-        first = child["first_pass"]["statements"]
+        if pass_only["device"] != device:
+            raise BenchmarkError(f"the pass-only child ran on "
+                                 f"{pass_only['device']}, not on {device}")
+        faults = self.faults_of(child, pass_only)
+        firsts = [pass_only["first_pass"]["statements"],
+                  child["first_pass"]["statements"]]
         stmts = child["statements"]
         done = [s for s in stmts if s["status"] == "Completed"]
         if not done:
             raise BenchmarkError("no statement completed in the window")
         failed = (len(stmts) - len(done)) + sum(
-            1 for s in first.values() if s["status"] != ["Completed"])
-        attempted = len(stmts) + len(first)
+            1 for first in firsts for s in first.values()
+            if s["status"] != ["Completed"])
+        attempted = len(stmts) + sum(len(first) for first in firsts)
 
-        # the answers the timed path produced, the first pass's and those of
-        # the window's first pass, each cell against sqlite's
+        # the two readings `first_pass_s` is the lower of, and whether each
+        # pass found its programs on disk (jax's own events, counted in
+        # every run, traced or not)
+        for key, tag, c in (("first_pass_a_s", "a (pass-only child)", pass_only),
+                            ("first_pass_b_s", "b (measured child)", child)):
+            child[key] = c["first_pass"]["power_test_ms"] / 1e3
+            at_end = c["counters"]["first_pass_end"]
+            compiles, hits = lib.compiles_of(at_end["jax"])
+            aot = at_end["aot"] or {}
+            print(f"first pass {tag}: {child[key]} s; {compiles} programs "
+                  f"through XLA, {compiles - hits} compiled anew, {hits} "
+                  f"from jax's disk cache; AOT executables "
+                  f"{aot.get('disk_hits')} loaded, {aot.get('misses')} "
+                  f"compiled")
+
+        # the answers the timed path produced: both first passes' and those
+        # of the window's first pass, each cell against sqlite's
         per = compare.compare_answers(
             ref, os.path.join(self.run_dir, "answers"), keys)
+        per.update({f"pass/{key}": p for key, p in compare.compare_answers(
+            ref, os.path.join(self.run_dir, "pass", "answers"),
+            [k for k in keys if k.startswith("s0/")]).items()})
         for key, p in per.items():
             print(f"answer {key}: rows {p['rows']} cells_differ "
                   f"{p['cells_differ']} rel_gap_max {p['rel_gap_max']:.3e}"
@@ -392,8 +445,7 @@ class Run:
               f"{sum(s.get('aot_loaded', 0) for s in reh)} loaded from disk")
         print(f"window: {child['window_s']:.3f} s, {len(done)} completed of "
               f"{len(stmts)}, {stmts[-1]['cycle']} whole cycles + part; "
-              f"new shapes met: {sum(s['new_shapes'] for s in stmts)}; "
-              f"gate wait {child['gate_wait_s']:.3f} s")
+              f"new shapes met: {sum(s['new_shapes'] for s in stmts)}")
         print("window per class, samples and median ms: " + json.dumps({
             k: [len(v), round(lib.percentile(v, 50), 1)]
             for k, v in by_class.items()}))
@@ -407,25 +459,35 @@ class Run:
             raise BenchmarkError("the device reports no peak_bytes_in_use")
         print(f"device: {json.dumps(device_out)} of "
               f"{child['memory']['bytes_limit']} B; peaks {json.dumps(peaks)}")
+        breakdown = None
         if self.args.trace:
-            return self.traced_line(child, attempted, failed, device_out,
-                                    not faults)
-        child["marks"]["parent_start"] = self.t0 * 1e3
-        metrics = self.spec.read_metrics(self.cell, "end_to_end", child)
-        wanted = self.spec.metrics_of(self.cell, "end_to_end")
-        if len(metrics) != len(wanted):
-            raise BenchmarkError("no value for " + ", ".join(
-                m["name"] for m in wanted if m["name"] not in metrics))
-        print(lib.result_line(not faults, attempted, failed, metrics,
-                              device_out))
+            metrics, breakdown = self.traced(child, device_out)
+        else:
+            child["marks"]["parent_start"] = self.t0 * 1e3
+            metrics = self.spec.read_metrics(self.cell, "end_to_end", child)
+            wanted = self.spec.metrics_of(self.cell, "end_to_end")
+            if len(metrics) != len(wanted):
+                raise BenchmarkError("no value for " + ", ".join(
+                    m["name"] for m in wanted if m["name"] not in metrics))
+        # the contract: each number compared beside its limit, as the last
+        # lines of standard error and last in the result's line
+        sys.stdout.flush()
+        for name, n in numbers.items():
+            print(f"compared {name}: {n['value']!r} limit {n['limit']!r}",
+                  file=sys.stderr)
+        sys.stderr.flush()
+        print(lib.result_line(
+            not faults, attempted, failed, metrics, device_out, numbers,
+            breakdown, first_passes_s=[child["first_pass_a_s"],
+                                       child["first_pass_b_s"]]))
         return 0
 
-    def traced_line(self, child, attempted, failed, device_out, correct):
+    def traced(self, child, device_out):
+        """The per-layer metrics and the breakdown of a traced run."""
         if "device_trace" not in child:
             raise BenchmarkError(f"no device trace: {child.get('trace_error')}")
         trace = child["device_trace"]
         child["events"] = lib.read_events(os.path.join(self.run_dir, "trace"))
-        metrics = self.spec.read_metrics(self.cell, "per_layer", child)
         device_out["busy_s"] = trace["busy_s"]
         device_out["window_s"] = trace["window_s"]
         print(f"traced slice: {trace['window_s']:.3f} s, busy "
@@ -433,11 +495,9 @@ class Run:
               f"{1 - trace['busy_s'] / trace['window_s']:.4f}, "
               f"{trace['statements']} statements, unannotated "
               f"{trace['unannotated_s']:.3f} s")
-        print(lib.result_line(
-            correct, attempted, failed, metrics, device_out,
-            {"device_ops": trace["device_ops"],
-             "idle_gaps": trace["idle_gaps"]}))
-        return 0
+        return (self.spec.read_metrics(self.cell, "per_layer", child),
+                {"device_ops": trace["device_ops"],
+                 "idle_gaps": trace["idle_gaps"]})
 
 
 def main(argv=None):

@@ -121,7 +121,8 @@ def test_per_layer_reader_agrees_with_its_entry(metric):
 #: what a chip child hands over, cut to what the end-to-end readers read
 RUN = {
     "marks": {"parent_start": 1000.0, "window_open": 91000.0},
-    "first_pass": {"power_test_ms": 52122},
+    "first_pass": {"power_test_ms": 55004},
+    "first_pass_a_s": 52.122, "first_pass_b_s": 55.004,
     "rehearsal": [{"ms": 500.0}, {"ms": 700.0}],
     "window_s": 4.0,
     "statements": [
@@ -146,6 +147,18 @@ def test_end_to_end_reader_reads_what_it_says(metric, want):
     reader = lib.Spec(REPO).reader("end_to_end", metric)
     assert (reader.UNIT, reader.SOURCE) == (entry["unit"], entry["source"])
     assert reader.read(RUN) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("a,b,want", [
+    (52.122, 55.004, 52.122), (61.5, 57.25, 57.25), (40.0, 40.0, 40.0),
+    (None, 55.004, None), (52.122, None, None), (None, None, None)])
+def test_first_pass_is_the_lower_of_two_and_nothing_of_one(a, b, want):
+    """Two fresh processes, the lower reading; a run in which either pass
+    left no reading reports no `first_pass_s` (and so no result line)."""
+    run = {k: v for k, v in (("first_pass_a_s", a), ("first_pass_b_s", b),
+                             ("first_pass", {"power_test_ms": 1}))
+           if v is not None}
+    assert lib.Spec(REPO).reader("end_to_end", "first_pass_s").read(run) == want
 
 
 def test_every_end_to_end_metric_has_a_reader():
@@ -211,13 +224,18 @@ def test_union_of_intervals():
 def test_result_line_has_exactly_the_contracts_keys():
     device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
               "memory_peak_bytes": 5}
+    compared = {"cells_differ": {"value": 0, "limit": 0}}
     line = json.loads(lib.result_line(
-        True, 10, 0, {"setup_s": (95.3127, "s")}, device))
-    assert tuple(line) == lib.RESULT_KEYS
+        True, 10, 0, {"setup_s": (95.3127, "s")}, device, compared))
+    assert tuple(line) == lib.RESULT_KEYS + ("compared",)
     assert line["metrics"] == {"setup_s": {"value": 95.3127, "unit": "s"}}
+    assert line["compared"] == compared
     traced = json.loads(lib.result_line(
-        False, 10, 1, {}, device, {"device_ops": [], "idle_gaps": []}))
-    assert tuple(traced) == lib.RESULT_KEYS + ("breakdown",)
+        False, 10, 1, {}, device, compared,
+        {"device_ops": [], "idle_gaps": []}, first_passes_s=[52.1, 55.0]))
+    # the numbers compared come last, whatever else the line carries
+    assert tuple(traced) == lib.RESULT_KEYS + (
+        "breakdown", "first_passes_s", "compared")
     assert traced["correct"] is False and traced["failed"] == 1
 
 

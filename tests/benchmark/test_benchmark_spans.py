@@ -106,6 +106,9 @@ WANT = {
     # 1031..1033, AOT load 0.25, read 1.5
     "unspanned_s.first": 50 - (5 + 11.5 + 0.25 + 1.5),
     "fresh_compiles.rehearsal": 2 / 4,
+    # of the first pass's two compile stages one was served from disk
+    "fresh_compiles.first": 1,
+    "fresh_compile_s.first": 2.0,
 }
 
 
@@ -132,10 +135,50 @@ def test_slice_readers_need_a_traced_slice(name):
     assert reader.read(run) is None
 
 
-def test_the_new_metrics_are_the_ten_appended_entries():
+def test_fresh_compiles_count_overlapping_compiles_each_and_their_seconds_once():
+    """Two threads compiling at once: two programs, the seconds of the
+    union; a pass whose every program came from disk reads 0, not nothing."""
+    events = [
+        span("xla_compile", 1010, 2000, stage="compile", fun="a", cached=False),
+        span("xla_compile", 1011, 3000, stage="compile", fun="b", cached=False),
+        span("xla_compile", 1020, 500, stage="compile", fun="c", cached=True),
+        # the rehearsal's are not the first pass's
+        span("xla_compile", 1061, 1500, stage="compile", fun="d", cached=False),
+    ]
+    spec = lib.Spec(lib.REPO)
+    count = spec.reader("per_layer", "fresh_compiles.first")
+    seconds = spec.reader("per_layer", "fresh_compile_s.first")
+    assert count.read(run_with(events)) == 2
+    assert seconds.read(run_with(events)) == pytest.approx(4.0)
+    warm = run_with(events[2:])
+    assert count.read(warm) == 0 and seconds.read(warm) == 0.0
+
+
+def test_fresh_compile_readers_over_a_first_pass_recorded_on_the_chip():
+    """`benchmarks/testdata/first_pass_compiles.v5e.json`: the compile
+    stages one first pass wrote on a v5e. 372 programs went through XLA and
+    231 of them compiled anew, in 13.89 s of the 15.51 s all of them took."""
+    import os
+
+    run = lib.load_json(os.path.join(
+        lib.REPO, "benchmarks", "testdata", "first_pass_compiles.v5e.json"))
+    assert len(run["events"]) == 372
+    spec = lib.Spec(lib.REPO)
+    read = {name: spec.reader("per_layer", name).read(run) for name in (
+        "fresh_compiles.first", "fresh_compile_s.first", "xla_load_s.first")}
+    assert read["fresh_compiles.first"] == 231
+    assert read["fresh_compile_s.first"] == pytest.approx(13.8903, abs=1e-3)
+    assert read["xla_load_s.first"] == pytest.approx(15.5129, abs=1e-3)
+    # the rehearsal's reader finds none of them: they all ended before it
+    assert spec.reader("per_layer", "fresh_compiles.rehearsal").read(
+        {**run, "rehearsal": [{"ms": 1.0}]}) is None
+
+
+def test_the_new_metrics_are_appended_entries():
     doc = lib.Spec(lib.REPO).doc
-    assert [m["name"] for m in doc["per_layer"]][-10:] == [
+    assert [m["name"] for m in doc["per_layer"]][-12:] == [
         "launches.stmt", "host_reads.stmt", "read_wait_ms.stmt",
         "exec_host_ms.stmt", "table_read_s.first", "h2d_s.first",
         "jit_trace_s.first", "xla_load_s.first", "unspanned_s.first",
-        "fresh_compiles.rehearsal"]
+        "fresh_compiles.rehearsal", "fresh_compiles.first",
+        "fresh_compile_s.first"]
