@@ -38,13 +38,27 @@ The store directory rides the AOT cache dir by default
 wiring shares learned cardinalities across processes and serve replicas
 exactly like compiled executables; `engine.feedback_dir` /
 NDS_FEEDBACK_DIR override, ""/"0" disables.
+
+When the store is written. A statement writes nothing: its executed
+nodes `record()` into a buffer in memory, and the buffer reaches the
+directory where a session's work ends (`Session.close()`: the end of a
+Power stream, of a Throughput stream, the serve path's shutdown) or, for
+a process that never calls it, at interpreter exit. The buffer is one per
+directory and PROCESS, not per store object, and `lookup` folds it over
+what the directory holds, so every session of a process plans from what
+any of them has measured as soon as it is measured, as it did when each
+statement flushed. What changed is what ANOTHER process sees: this
+one's records after its session ends, not after each statement. The store
+is an advisory cache of estimates: mode `record` reads nothing, a record
+lost with a killed process costs a later plan one estimate, and no answer
+and none of a deployment's guarantees depends on it.
 """
 
+import copy
 import hashlib
 import json
 import math
 import os
-import threading
 import time
 
 from ..engine import plan as P
@@ -233,6 +247,20 @@ def _entry_name(fp: str) -> str:
     return f"{_ENTRY_PREFIX}{fp}{_ENTRY_SUFFIX}"
 
 
+#: what this process has recorded and not yet written: store directory
+#: (absolute) -> {fp: record delta}. One buffer a directory, shared by
+#: every FeedbackStore the process opens on it; a flush takes the
+#: directory's whole buffer. Keyed by node fingerprint and merged in
+#: place, so it grows with the distinct plan nodes executed since the last
+#: flush, not with executions: the six statements of the benchmark's
+#: `replay6` touch 55 keys (18.4 KB as JSON), so the largest stream (103
+#: entries) holds about a thousand deltas, a third of a megabyte as JSON
+#: and a few times that as dicts, however often it is replayed.
+_PENDING = {}  # nds-guarded-by: _PENDING_LOCK
+#: held for dict work only, never across a file operation
+_PENDING_LOCK = make_lock("analysis/feedback.py:_PENDING_LOCK")
+
+
 def _merge_component(dst: dict, rows) -> dict:
     """Fold one observation into a {n,last,min,max,hist} component."""
     rows = int(rows)
@@ -255,43 +283,61 @@ class FeedbackStore:
     re-verify the FULL embedded key and a payload checksum on load — a
     filename-hash collision is a clean miss, a corrupt document is
     quarantined (renamed aside, once) and treated as a miss. Mutations
-    buffer in `_pending` and land on `flush()` (one merge+write per
-    touched key per statement, not per recorded node), after which the
-    LRU byte budget is re-enforced by mtime — lookups refresh an entry's
-    mtime so hot plan nodes survive eviction.
+    buffer in the process's `_PENDING` for this directory and land on
+    `flush()` (one merge+write per touched key, however often its node
+    ran), which no statement calls: `Session.close()` does, and the
+    session's exit hook. A flush costs what it writes, not what the
+    directory holds: the LRU byte budget is held against a running total
+    (`_total`: one listing at this store's first flush, then every write
+    and eviction counted), and the directory is listed again, and entries
+    evicted by mtime, only when that total passes the budget — lookups
+    refresh an entry's mtime so hot plan nodes survive eviction. Every
+    flush that had something to write emits one `feedback_flush` span.
 
     In-process state is guarded by an internal lock; session-level call
     sites additionally hold `Session.cache_lock` (the cache-lock-
     discipline lint enforces it for `feedback_store`, as for every other
     session cache)."""
 
-    def __init__(self, dirpath: str, budget_bytes: int):
+    def __init__(self, dirpath: str, budget_bytes: int, tracer=None):
         self.dir = dirpath
         self.budget = int(budget_bytes)
+        # callable returning the live tracer, as AotCache takes it (a
+        # Session's tracer can be swapped after construction)
+        self._tracer = tracer if callable(tracer) else (lambda: tracer)
+        self._pending_key = os.path.abspath(dirpath)
         self._lock = make_lock("FeedbackStore._lock")
         self._mem = {}  # fp -> record dict (None = known miss)  # nds-guarded-by: _lock
-        self._pending = {}  # fp -> record delta awaiting flush  # nds-guarded-by: _lock
         self._disabled = False  # first write error disables stores  # nds-guarded-by: _lock
+        # bytes of entries under `dir` as this process believes them: None
+        # until the first flush lists the directory. Other processes write
+        # there too, so it is an estimate, and every listing corrects it
+        self._total = None  # nds-guarded-by: _lock
         self._err_samples = []  # |log(est/actual)| ring  # nds-guarded-by: _lock
         self.stats = {  # nds-guarded-by: _lock
             "lookups": 0, "hits": 0, "misses": 0, "records": 0,
             "skew_records": 0, "flushes": 0, "stores": 0, "evictions": 0,
-            "quarantined": 0, "overrides": 0,
+            "quarantined": 0, "overrides": 0, "listings": 0,
         }
 
     # -- reads ----------------------------------------------------------
     def lookup(self, fp: str):
-        """The record for one key, or None. First disk read per key is
-        cached (hits AND misses) for the life of the session; a hit
-        refreshes the entry's mtime (LRU recency)."""
+        """The record for one key, or None: what the directory holds with
+        what this process has recorded since folded over it, so a second
+        execution plans from the first one's actuals before anything is
+        written. First disk read per key is cached (hits AND misses) for
+        the life of the session; a hit refreshes the entry's mtime (LRU
+        recency)."""
+        with _PENDING_LOCK:
+            delta = _PENDING.get(self._pending_key, {}).get(fp)
+            delta = copy.deepcopy(delta) if delta else None
         with self._lock:
             self.stats["lookups"] += 1
-            if fp in self._mem:
-                rec = self._mem[fp]
-                self.stats["hits" if rec is not None else "misses"] += 1
-                return dict(rec) if rec is not None else None
-            rec = self._load_locked(fp)
-            self._mem[fp] = rec
+            if fp not in self._mem:
+                self._mem[fp] = self._load_locked(fp)
+            rec = self._mem[fp]
+            if delta is not None:
+                rec = self._merge(copy.deepcopy(rec or {}), delta)
             self.stats["hits" if rec is not None else "misses"] += 1
             return dict(rec) if rec is not None else None
 
@@ -330,6 +376,11 @@ class FeedbackStore:
         return {"node_fp": fp, "v": FORMAT_VERSION}
 
     # -- buffered writes ------------------------------------------------
+    def _delta_locked(self, fp: str) -> dict:
+        """The pending delta of one key in this directory's buffer, made
+        on first use (caller holds `_PENDING_LOCK`)."""
+        return _PENDING.setdefault(self._pending_key, {}).setdefault(fp, {})
+
     def record(self, fp: str, rows=None, nbytes=None, est_rows=None):
         """Fold one executed node's actuals into the pending delta for
         `fp`. Returns the |log(est/actual)| error sample when the static
@@ -339,13 +390,14 @@ class FeedbackStore:
         if est_rows is not None and rows is not None:
             err = abs(math.log(max(int(est_rows), 1))
                       - math.log(max(int(rows), 1)))
-        with self._lock:
-            self.stats["records"] += 1
-            rec = self._pending.setdefault(fp, {})
+        with _PENDING_LOCK:
+            rec = self._delta_locked(fp)
             if rows is not None:
                 _merge_component(rec.setdefault("rows", {}), rows)
             if nbytes is not None:
                 _merge_component(rec.setdefault("bytes", {}), nbytes)
+        with self._lock:
+            self.stats["records"] += 1
             if err is not None:
                 self._err_samples.append(err)
                 if len(self._err_samples) > _ERR_SAMPLES_CAP:
@@ -356,42 +408,59 @@ class FeedbackStore:
         """Fold one exchange's measured received-row skew (max/mean) and
         its overflow-retry count into the pending delta for `fp` — the
         seed the next execution's capacity guess consumes."""
-        with self._lock:
-            self.stats["skew_records"] += 1
-            rec = self._pending.setdefault(fp, {})
-            sk = rec.setdefault("skew", {})
+        with _PENDING_LOCK:
+            sk = self._delta_locked(fp).setdefault("skew", {})
             sk["n"] = int(sk.get("n", 0)) + 1
             sk["last"] = round(float(skew), 3)
             sk["max"] = round(max(float(sk.get("max", 0.0)), float(skew)), 3)
             sk["retries"] = max(int(sk.get("retries", 0)), int(retries))
-
-    def flush(self) -> int:
-        """Merge every pending delta with its on-disk record and commit
-        (tempfile + rename per key), then re-enforce the byte budget.
-        Returns the number of keys written; write errors disable further
-        stores for this process (the cache must never take down a
-        query)."""
         with self._lock:
-            pending, self._pending = self._pending, {}
-            if not pending or self._disabled:
+            self.stats["skew_records"] += 1
+
+    def flush(self, where: str = "flush") -> int:
+        """Merge every pending delta with its on-disk record and commit
+        (tempfile + rename per key), then hold the byte budget against
+        the running total. Returns the number of keys written; write
+        errors disable further stores for this process (the cache must
+        never take down a query). `where` names the caller in the
+        `feedback_flush` span (`close`, `atexit`): the span is how a trace
+        shows a write that found its way back onto a statement's path."""
+        with _PENDING_LOCK:
+            pending = _PENDING.pop(self._pending_key, None)
+        if not pending:
+            return 0
+        t0_ns = time.time_ns()
+        t0 = time.perf_counter()
+        with self._lock:
+            if self._disabled:
                 return 0
             self.stats["flushes"] += 1
-            written = []
+            written = set()
+            nbytes = 0
             for fp, delta in pending.items():
                 base = self._mem.get(fp)
                 if base is None:
                     base = self._load_locked(fp) or {}
                 merged = self._merge(dict(base), delta)
                 merged["updated"] = int(time.time())
-                if self._write_locked(fp, merged):
+                size = self._write_locked(fp, merged)
+                if size:
                     self._mem[fp] = merged
-                    written.append(_entry_name(fp))
+                    written.add(_entry_name(fp))
+                    nbytes += size
                     self.stats["stores"] += 1
                 if self._disabled:
                     break
             if written:
-                self._enforce_budget_locked(keep=set(written))
-            return len(written)
+                self._enforce_budget_locked(keep=written, wrote=nbytes)
+        tracer = self._tracer()
+        if tracer is not None and not tracer.closed:
+            tracer.emit(
+                "feedback_flush", t0_ns=t0_ns,
+                dur_ms=round((time.perf_counter() - t0) * 1000.0, 3),
+                keys=len(written), bytes=nbytes, where=where,
+            )
+        return len(written)
 
     @staticmethod
     def _merge(base: dict, delta: dict) -> dict:
@@ -419,7 +488,9 @@ class FeedbackStore:
                                int(d.get("retries", 0)))
         return base
 
-    def _write_locked(self, fp: str, body: dict) -> bool:
+    def _write_locked(self, fp: str, body: dict) -> int:
+        """Bytes committed for one entry; 0 where the write failed (and
+        disabled the store)."""
         doc = {
             "key": self._key(fp),
             "body": body,
@@ -432,12 +503,13 @@ class FeedbackStore:
                f"{hashlib.sha256(os.urandom(8)).hexdigest()[:6]}")
         try:
             os.makedirs(self.dir, exist_ok=True)
+            data = json.dumps(doc, sort_keys=True).encode("utf-8")
             with open(tmp, "wb") as f:
-                f.write(json.dumps(doc, sort_keys=True).encode("utf-8"))
+                f.write(data)
                 f.flush()
                 os.fsync(f.fileno())
             os.replace(tmp, dest)
-            return True
+            return len(data)
         except OSError as exc:
             self._disabled = True
             import warnings
@@ -449,7 +521,7 @@ class FeedbackStore:
                 os.unlink(tmp)
             except OSError:
                 pass
-            return False
+            return 0
 
     def _quarantine_locked(self, path: str):
         self.stats["quarantined"] += 1
@@ -481,24 +553,39 @@ class FeedbackStore:
             out.append((st.st_mtime, st.st_size, n, path))
         return out
 
-    def _enforce_budget_locked(self, keep=frozenset()):
+    def _enforce_budget_locked(self, keep=frozenset(), wrote=0):
+        """Hold the directory to the byte budget, oldest mtime out first,
+        never an entry of `keep`. `wrote` is what the caller has just
+        committed: it goes onto the running total whole (a replaced
+        entry's old bytes are not known without a stat, so the total errs
+        high and a listing comes sooner), and the directory is listed
+        only when that total passes the budget, or was never taken."""
+        if self._total is not None:
+            self._total += wrote
+            if self._total <= self.budget:
+                return
         entries = self._entries()
+        self.stats["listings"] += 1
         total = sum(e[1] for e in entries)
-        if total <= self.budget:
-            return
-        for mtime, size, name, path in sorted(entries):
-            if total <= self.budget:
-                break
-            if name in keep:
-                continue
-            try:
-                os.unlink(path)
-            except OSError:
-                continue
-            total -= size
-            self.stats["evictions"] += 1
+        if total > self.budget:
+            for mtime, size, name, path in sorted(entries):
+                if total <= self.budget:
+                    break
+                if name in keep:
+                    continue
+                try:
+                    os.unlink(path)
+                except OSError:
+                    continue
+                total -= size
+                self.stats["evictions"] += 1
+        self._total = total
 
     def usage(self):
+        """(entries, bytes) the directory holds once what this process
+        has recorded is in it: a report of the store counts what its own
+        caller has seen."""
+        self.flush(where="usage")
         entries = self._entries()
         return len(entries), sum(e[1] for e in entries)
 
@@ -546,11 +633,14 @@ class FeedbackStore:
                 removed += 1
             except OSError:
                 continue
+        if drop_all:
+            with _PENDING_LOCK:
+                _PENDING.pop(self._pending_key, None)
         with self._lock:
             if drop_all:
                 self._mem.clear()
-                self._pending.clear()
             before = self.stats["evictions"]
+            self._total = None  # files went above: list, do not estimate
             self._enforce_budget_locked()
             removed += self.stats["evictions"] - before
         return removed

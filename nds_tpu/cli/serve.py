@@ -179,6 +179,10 @@ def main(argv=None):
     )
     stop.wait()
     service.handle_drain()
+    # nothing is in flight any more: write what the service measured
+    for session in (service.session, service.writer_session):
+        if session is not None:
+            session.close()
     print("serve: drained; bye", flush=True)
 
 

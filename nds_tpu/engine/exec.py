@@ -268,7 +268,7 @@ class Executor:
 
     def _record_feedback(self, node, out):
         """Record this node's measured cardinality into the session
-        FeedbackStore (buffered; Result.table flushes per statement).
+        FeedbackStore (buffered in memory; Session.close() writes it).
         Only called for nodes budget_plan annotated with `node_fp` —
         i.e. engine.plan_feedback is record/on and a store exists."""
         session = getattr(self.catalog, "session", None)

@@ -9,8 +9,10 @@ SQL, and returns Arrow tables.
 
 from __future__ import annotations
 
+import atexit
 import os
 import threading
+import weakref
 from time import monotonic as _monotonic, perf_counter as _perf
 from time import time_ns as _time_ns
 from typing import Optional
@@ -715,14 +717,6 @@ class Result:
         with _tally.bind(tally):
             if executed:
                 self._table = self.executor.execute(self.plan)
-                # commit this statement's buffered cardinality records (one
-                # merge+write per touched key, not per executed node) so a
-                # second run — or another process sharing the store dir —
-                # plans from what this one measured
-                store = getattr(self.session, "feedback_store", None)
-                if store is not None:
-                    with self.session.cache_lock:
-                        store.flush()
             t1 = _perf()
             if to_arrow:
                 arrow = table_to_arrow(self._table)
@@ -771,6 +765,14 @@ class Result:
             pacsv.write_csv(arrow, os.path.join(path, "part-0.csv"))
         else:
             raise ValueError(f"unsupported output format {fmt}")
+
+
+def _close_at_exit(session_ref):
+    """The exit hook of one Session (`atexit`, through a weak reference):
+    a session still alive when the interpreter ends is closed."""
+    session = session_ref()
+    if session is not None:
+        session.close(where="atexit")
 
 
 class Session:
@@ -874,6 +876,9 @@ class Session:
         # default (<dir>/feedback) so the --aot_cache_dir fleet wiring
         # shares learned cardinalities exactly like compiled
         # executables; works under a mesh (JSON stats, no executables).
+        # Executed nodes record into the store's buffer and no statement
+        # writes a file: the directory is written by close(), where this
+        # session's work ends, or by the exit hook registered below.
         # Disable with NDS_FEEDBACK_DIR=0 / engine.plan_feedback=off.
         from ..analysis.feedback import (
             FeedbackStore,
@@ -886,7 +891,8 @@ class Session:
         if _fb_dir:
             _aot_sweep(_fb_dir)  # same .tmp-<pid> naming scheme
             self.feedback_store = FeedbackStore(
-                _fb_dir, resolve_feedback_bytes(self.conf, _fb_dir)
+                _fb_dir, resolve_feedback_bytes(self.conf, _fb_dir),
+                tracer=lambda: self.tracer,
             )
         # stats of the most recent blocked union-aggregation any executor
         # of this session ran (bench.py's OOM-bail heuristic reads it)
@@ -939,6 +945,25 @@ class Session:
         # single atomic tuple store, read by the report watchdog from
         # another thread; an object-reference store cannot tear
         self._progress_ts = None  # nds-guarded-by: none
+        if self.feedback_store is not None:
+            # a process that never calls close() (a CLI that returns from
+            # main) still leaves what it measured on disk; through a weak
+            # reference, so the hook keeps no session and no table alive
+            atexit.register(_close_at_exit, weakref.ref(self))
+
+    def close(self, where: str = "close"):
+        """Where this session's work ends: what its statements recorded
+        into the cardinality-feedback store is written to the store's
+        directory (a `feedback_flush` span). The loops that own a session
+        call it once their clocks have stopped (`power.run_query_stream`,
+        so every Throughput stream too; `cli/serve` after the drain).
+        Idempotent, and not terminal: a second call writes nothing, a
+        statement run afterwards records again and the next call, or the
+        exit hook, writes it. Returns the number of keys written."""
+        if self.feedback_store is None:
+            return 0
+        with self.cache_lock:
+            return self.feedback_store.flush(where=where)
 
     @property
     def spill_pool(self):

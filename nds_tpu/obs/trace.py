@@ -241,6 +241,13 @@ EVENT_SCHEMA = {
     # est_rows / est_live_bytes / actual_rows / actual_bytes as optional
     # fields (est_bytes keeps its historical realized-bytes meaning)
     "plan_feedback": ("op", "result"),
+    # one FeedbackStore.flush that had something to write: `keys` entries
+    # and `bytes` committed, `where` the caller (`close`: Session.close,
+    # the end of a stream or of the service; `atexit`: the session's exit
+    # hook; `usage` / `flush`: a report or a direct call). No statement
+    # flushes: a span that ends inside a `result_span` is a write that
+    # found its way back onto the statement's path
+    "feedback_flush": ("keys", "bytes", "where", "dur_ms", "t0_ns"),
     # liveness beacon from the per-query memory-sampler thread
     # (obs/memwatch.py, armed by report.py while a traced query runs):
     # a hung query keeps heartbeating, so the hang is visible live on
@@ -559,6 +566,12 @@ class Tracer:
         self._fh.write(meta + "\n")
         self._fh.flush()
         self._seg_bytes = len(meta.encode("utf-8")) + 1
+
+    @property
+    def closed(self) -> bool:
+        """True once `close()` ran: an emitter that may outlive the
+        tracer's owner (the feedback store's exit hook) asks first."""
+        return self._closed
 
     def close(self):
         """Terminal: flush + release the file handle and refuse later

@@ -198,6 +198,10 @@ def run_query_stream(
             execution_time_list,
         )
     finally:
+        # the stream's clocks have stopped (`Power Test Time` times
+        # statements, not the store): write what it measured, whoever
+        # keeps the session afterwards
+        session.close()
         # the stream is this tracer's ONLY emitter: closing here (success
         # or crash) releases the handle and flushes the final line; a late
         # emit after this point is a harness bug the tracer now drops

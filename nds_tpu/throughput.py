@@ -125,7 +125,9 @@ def run_throughput(
     on device/IO work while sharing one warmed in-process compile cache.
     mode="process": forks one Power Run process per stream (the reference's
     `xargs -P` shape, nds/nds-throughput:18-23); processes share compiled
-    kernels through the persistent XLA cache instead."""
+    kernels through the persistent XLA cache instead. Either way a stream
+    is one `run_query_stream`, which closes its session (and so writes its
+    cardinality feedback) once the stream's clocks have stopped."""
     if mode == "process":
         return _run_throughput_processes(
             input_prefix, stream_paths, time_log_base, input_format,
