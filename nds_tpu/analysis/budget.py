@@ -648,7 +648,7 @@ class PlanBudgeter:
     def __init__(self, catalog=None, stats: Optional[CatalogStats] = None,
                  budget_bytes: Optional[int] = None, windowed: bool = False,
                  mesh_devices: Optional[int] = None, feedback=None):
-        from .verifier import PlanVerifier, _count_plan_refs
+        from .verifier import InputWidthSchemas, _count_plan_refs
 
         self.stats = stats or CatalogStats(catalog)
         self.budget_bytes = (
@@ -662,7 +662,7 @@ class PlanBudgeter:
         #: executor path (branches materialized, concat/join/aggregate per
         #: bounded window) instead of the direct full-concat path
         self.windowed = windowed
-        self._ver = PlanVerifier(catalog)
+        self._ver = InputWidthSchemas(catalog)
         self._count_refs = _count_plan_refs
         self._memo: dict = {}
         self._post: list = []

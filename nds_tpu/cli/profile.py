@@ -104,24 +104,15 @@ def _render_profile(prof, top: int, per_query: bool):
         print(f"\n-- {q}{runs}: wall {_fmt_ms(rec.get('wall_ms'))} ms  "
               f"plan {_fmt_ms(rec.get('root_incl_ms'))} ms  {status}{mem}")
         if per_query and rec["ops"]:
-            print(f"   {'operator':<18}{'count':>6}{'incl_ms':>12}"
-                  f"{'excl_ms':>12}{'rows':>12}")
-            for node, op in sorted(
+            _print_ops(sorted(
                 rec["ops"].items(), key=lambda kv: -kv[1]["excl_ms"]
-            ):
-                print(f"   {node:<18}{op['count']:>6}"
-                      f"{op['incl_ms']:>12,.1f}{op['excl_ms']:>12,.1f}"
-                      f"{op['rows']:>12,}")
+            ))
     hot = sorted(
         prof["op_totals"].items(), key=lambda kv: -kv[1]["excl_ms"]
     )[:top]
     if hot:
         print(f"\n== top {len(hot)} operators by exclusive time (run-wide)")
-        print(f"   {'operator':<18}{'count':>6}{'incl_ms':>12}"
-              f"{'excl_ms':>12}{'rows':>12}")
-        for node, op in hot:
-            print(f"   {node:<18}{op['count']:>6}{op['incl_ms']:>12,.1f}"
-                  f"{op['excl_ms']:>12,.1f}{op['rows']:>12,}")
+        _print_ops(hot)
     t = prof["tallies"]
     print(f"\n== tallies: plan-cache {t['plan_cache_hits']} hit / "
           f"{t['plan_cache_misses']} miss; catalog {t['catalog_loads']} "
@@ -219,6 +210,19 @@ def _render_profile(prof, top: int, per_query: bool):
             avg = k["dur_ms"] / k["count"] if k["count"] else 0.0
             print(f"   {name:<28}{k['count']:>6}{k['dur_ms']:>12,.1f}"
                   f"{avg:>10,.3f}{k['n_rows']:>14,}")
+
+
+def _print_ops(ops):
+    """The per-operator table. `cols in>out`: the columns of a Filter's,
+    Join's or MultiJoin's inputs and the columns it handed on (the plan's
+    `required`), summed over its executions; `-` for the other nodes."""
+    print(f"   {'operator':<18}{'count':>6}{'incl_ms':>12}"
+          f"{'excl_ms':>12}{'rows':>12}{'cols in>out':>14}")
+    for node, op in ops:
+        cols = (f"{op['cols_in']}>{op.get('cols_out', 0)}"
+                if "cols_in" in op else "-")
+        print(f"   {node:<18}{op['count']:>6}{op['incl_ms']:>12,.1f}"
+              f"{op['excl_ms']:>12,.1f}{op['rows']:>12,}{cols:>14}")
 
 
 def _accuracy_report(events, top: int) -> dict:

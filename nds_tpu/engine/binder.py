@@ -1154,8 +1154,7 @@ def _conjoin_ast(preds):
     return _conjoin(preds)
 
 
-def _refs(e):
-    return {x.name for x in E.walk(e) if isinstance(x, E.Col)}
+_refs = E.col_refs
 
 
 def _null_rejecting_shape(conj):

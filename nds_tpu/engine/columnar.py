@@ -201,6 +201,14 @@ class Table:
             unique_key=uk,
         )
 
+    def narrowed(self, required) -> "Table":
+        """This table with the columns `required` names and no others
+        (`narrow_columns`); itself where nothing falls away."""
+        cols = narrow_columns(self.columns, required)
+        if cols is self.columns:
+            return self
+        return self.select(cols)
+
     def rename(self, mapping: dict) -> "Table":
         uk = self.unique_key
         if uk is not None:
@@ -238,6 +246,27 @@ class Table:
         return self._packed
 
 
+def narrow_columns(columns: dict, required) -> dict:
+    """The columns that `required` names (any container of names; None ==
+    all). A table carries its capacity and its row mask's length in its
+    buffers, so where none is named one column stays to carry the rows:
+    the narrowest, one without a validity buffer first (the rule
+    `prune_columns` has for a bare `count(*)` scan)."""
+    if required is None:
+        return columns
+    kept = {n: c for n, c in columns.items() if n in required}
+    if len(kept) == len(columns):
+        return columns
+    if kept or not columns:
+        return kept
+    name = min(
+        columns,
+        key=lambda n: (columns[n].valid is not None,
+                       columns[n].data.dtype.itemsize),
+    )
+    return {name: columns[name]}
+
+
 def gather_columns(
     columns: dict, idx, keep=None, *, stats=Column.subset_stats, owned=False,
 ) -> dict:
@@ -246,6 +275,9 @@ def gather_columns(
     dictionary carry over; `stats` maps a source column to the stats
     that survive this gather, `owned` is the new columns' flag."""
     from ..ops import kernels as K
+
+    if not columns:
+        return {}
 
     taken = K.take_columns(
         tuple((c.data, c.valid) for c in columns.values()), idx, keep

@@ -41,6 +41,7 @@ from .columnar import (
     _dyn_slice,
     bucket_cap,
     gather_columns,
+    narrow_columns,
     table_to_arrow,
     unify_dictionaries,
     sort_dictionary,
@@ -381,6 +382,17 @@ class Executor:
                 est_bytes=table_device_bytes(out),
                 **own,
             )
+            if isinstance(node, (P.Filter, P.Join, P.MultiJoin)) or (
+                isinstance(node, P.Pipeline) and node.agg is None
+                and all(isinstance(st, P.Filter) for st in node.stages)
+            ):
+                # how far `required` narrowed what this node hands on:
+                # the columns of its inputs, the columns of its output
+                span["cols_in"] = sum(
+                    len(self._cte_cache[id(c)].columns)
+                    for c in node.children() if id(c) in self._cte_cache
+                )
+                span["cols_out"] = len(out.columns)
             if fp is not None:
                 # budgeter accounting (analysis/feedback.py annotations):
                 # est_rows/est_live_bytes are the STATIC model's numbers,
@@ -467,7 +479,10 @@ class Executor:
 
     def _exec_filter(self, node: P.Filter) -> Table:
         child = self.execute(node.child)
-        return self._masked(child, self._predicate_mask(child, node.predicate))
+        return self._masked(
+            child.narrowed(node.required),
+            self._predicate_mask(child, node.predicate),
+        )
 
     # -- fused Filter/Project pipelines -----------------------------------
     # A Pipeline node (fuse.mark_pipelines) executes its whole chain as ONE
@@ -1041,6 +1056,7 @@ class Executor:
             node.residual, node.mark_name,
             spill_parts=self._spill_parts_for(node),
             node_fp=getattr(node, "node_fp", None),
+            out=node.required,
         )
 
     def _exec_multijoin(self, node: P.MultiJoin) -> Table:
@@ -1068,20 +1084,23 @@ class Executor:
             tables, node.edges, trace=trace,
             spill_parts=self._spill_parts_for(node),
             node_fp=getattr(node, "node_fp", None),
+            required=node.required,
         )
 
     def _multijoin_over_tables(self, tables, edges, trace=None,
-                               spill_parts=0, node_fp=None) -> Table:
+                               spill_parts=0, node_fp=None,
+                               required=None) -> Table:
         """Greedy N-way inner join over already-executed relation tables
         (shared by _exec_multijoin and the blocked union-aggregation path,
         which re-joins each union window against the other relations).
         `trace`: optional dict; the first call records its join-order
         decisions into it and later calls replay them, skipping the greedy
         cost scan — whose current[g].nrows reads are blocking device->host
-        syncs that would otherwise run once per window per join step."""
+        syncs that would otherwise run once per window per join step.
+        `required`: the MultiJoin's (the names read above it; None: all)."""
         n = len(tables)
         if n == 1:
-            return tables[0]
+            return tables[0].narrowed(required)
         # adjacency: edge list by relation index
         edges = list(edges)
         merged = list(range(n))  # union-find-ish: relation -> group id
@@ -1094,7 +1113,8 @@ class Executor:
         current = {i: tables[i] for i in range(n)}
 
         return self._multijoin_greedy(current, edges, merged, group, n, trace,
-                                      spill_parts, node_fp=node_fp)
+                                      spill_parts, node_fp=node_fp,
+                                      required=required)
 
     def _execute_relations_batched(self, relations):
         """Execute a MultiJoin's relations and materialize their live
@@ -1113,12 +1133,21 @@ class Executor:
         return tables
 
     def _multijoin_greedy(self, current, edges, merged, group, n, trace=None,
-                          spill_parts=0, node_fp=None):
+                          spill_parts=0, node_fp=None, required=None):
         # greedy: repeatedly take the connecting edge whose joined inputs are
         # smallest (sum of live rows), execute that join. When `trace`
         # carries recorded steps, replay them instead (identical relation
         # sets join in the same order, and replay never reads .nrows — the
         # blocked union path joins every window with zero count syncs).
+        def read_after(rest):
+            # what is read of a step's output: what is read above the
+            # node, and the keys of the edges not yet consumed
+            if required is None:
+                return None
+            return frozenset(required).union(
+                *(E.col_refs(e) for _, _, le, re_ in rest for e in (le, re_))
+            )
+
         replay = trace is not None and "steps" in trace
         steps = trace["steps"] if replay else []
         step_i = 0
@@ -1148,7 +1177,8 @@ class Executor:
             if kind == "cross":
                 # disconnected components: cross join smallest two groups
                 joined = self._join(
-                    current[gi], current[gj], "cross", [], [], None
+                    current[gi], current[gj], "cross", [], [], None,
+                    out=read_after(edges),
                 )
                 merged[gj] = gi
                 current[gi] = joined
@@ -1170,6 +1200,7 @@ class Executor:
             joined = self._join(
                 current[gi], current[gj], "inner", lkeys, rkeys, None,
                 spill_parts=spill_parts, node_fp=node_fp,
+                out=read_after(edges),
             )
             merged[gj] = gi
             current[gi] = joined
@@ -1193,17 +1224,55 @@ class Executor:
             return t.compacted()
         return t
 
+    @staticmethod
+    def _read_with(out, residual):
+        """`out` (the names read of a join's result; None: all) and what
+        the join's own `residual` reads of the same table."""
+        if out is None or residual is None:
+            return out
+        return out | E.col_refs(residual)
+
     def _join(self, left, right, kind, left_keys, right_keys, residual,
-              mark_name=None, spill_parts=0, node_fp=None):
+              mark_name=None, spill_parts=0, node_fp=None, out=None):
+        """`out`: the names read of the result (None: all). The join hands
+        on those columns and no others: a key whose edge is consumed or a
+        filter column whose filter is applied is not gathered (one column
+        stays to carry the rows where none is named, `narrow_columns`).
+        Keys and the residual are evaluated on what they name.
+
+        The narrowing is decided here alone, on the way in and on the way
+        out; inside `_join_body` a site that gathers passes `out` to
+        `_sides` so as not to fetch what this drops."""
+        if out is None:
+            return self._join_body(
+                left, right, kind, left_keys, right_keys, residual,
+                mark_name, spill_parts, node_fp, None,
+            )
+        out = frozenset(out)
+        # what neither the reader nor this join's own expressions name
+        # falls away before anything packs or gathers it
+        need = self._read_with(out, residual).union(
+            *(E.col_refs(e) for e in (*left_keys, *right_keys))
+        )
+        joined = self._join_body(
+            left.narrowed(need), right.narrowed(need), kind,
+            left_keys, right_keys, residual, mark_name, spill_parts,
+            node_fp, out,
+        )
+        return joined.narrowed(out if mark_name is None else out | {mark_name})
+
+    def _join_body(self, left, right, kind, left_keys, right_keys, residual,
+                   mark_name, spill_parts, node_fp, out):
         if kind == "cross":
             return self._cross_join(left, right)
         left = self._pack_sparse(left)
         right = self._pack_sparse(right)
         if kind == "right":
             # swap before any matching so the residual is preserved
-            return self._join(right, left, "left", right_keys, left_keys,
-                              residual, spill_parts=spill_parts,
-                              node_fp=node_fp)
+            return self._join_body(
+                right, left, "left", right_keys, left_keys, residual,
+                mark_name, spill_parts, node_fp, out,
+            )
         lev = self._evaluator(left)
         rev = self._evaluator(right)
         lcols = [lev.eval(e) for e in left_keys]
@@ -1221,19 +1290,20 @@ class Executor:
         rlive = right.row_mask()
         fast = self._try_dense_join(
             left, right, kind, lcols, rcols, lk, lv, rk, rv, llive, rlive,
-            residual, mark_name,
+            residual, mark_name, out,
         )
         if fast is not None:
             return fast
         fast = self._try_exchange_join(
             left, right, kind, left_keys, right_keys,
             lk, lv, rk, rv, llive, rlive, residual, node_fp=node_fp,
+            out=out,
         )
         if fast is not None:
             return fast
         fast = self._try_packed_join(
             left, right, kind, aligned, right_keys, llive, rlive, residual,
-            mark_name,
+            mark_name, out,
         )
         if fast is not None:
             return fast
@@ -1246,7 +1316,7 @@ class Executor:
             # spill pool instead of accumulating it on device
             return self._spilled_join(
                 left, right, kind, left_keys, right_keys, residual,
-                lk, lv, llive, rk, rv, rlive, spill_parts,
+                lk, lv, llive, rk, rv, rlive, spill_parts, out=out,
             )
         li, ri, pl, total = K.join_candidates(lk, lv, llive, rk, rv, rlive)
         ok = K.verify_pairs(li, ri, pl, lk, lv, llive, rk, rv, rlive)
@@ -1256,11 +1326,7 @@ class Executor:
                 ok = self._apply_residual(ok, li, ri, left, right, residual)
             present = K.matched_mask(li, ok, left.cap)
             if kind == "mark":
-                out_cols = {
-                    n: c.disowned() for n, c in left.columns.items()
-                }
-                out_cols[mark_name] = Column(present, BOOL)
-                return Table(out_cols, left.nrows_lazy, live=left.live)
+                return self._mark_output(left, mark_name, present)
             mask = (present if kind == "semi" else ~present) & llive
             return self._masked(left, mask)
 
@@ -1269,8 +1335,13 @@ class Executor:
         sel = K.compact_indices(ok, out_cap)
         pli, pri = K.take_arrays((li, ri), sel)
         if residual is not None:
-            # build pair table first, filter, recompact
-            pair = self._pair_table(left, right, pli, pri, count)
+            # build pair table first, filter, recompact. An outer join's
+            # pair table is read by the residual alone
+            pair = self._pair_table(
+                left, right, pli, pri, count,
+                out=self._read_with(out, residual) if kind == "inner"
+                else E.col_refs(residual),
+            )
             pmask = self._predicate_mask(pair, residual)
             if kind == "inner":
                 return self._masked(pair, pmask)
@@ -1285,7 +1356,7 @@ class Executor:
             pli, pri = K.take_arrays((li, ri), sel)
 
         if kind == "inner":
-            return self._pair_table(left, right, pli, pri, count)
+            return self._pair_table(left, right, pli, pri, count, out=out)
 
         if kind == "left":
             present = K.matched_mask(li, ok, left.cap)
@@ -1301,7 +1372,9 @@ class Executor:
             )
             all_ri = jnp.pad(all_ri, (0, cap2 - all_ri.shape[0]))
             rkeep = jnp.arange(cap2) < count  # right side null for appended rows
-            return self._pair_table(left, right, all_li, all_ri, total_rows, rkeep)
+            return self._pair_table(
+                left, right, all_li, all_ri, total_rows, rkeep, out=out
+            )
 
         if kind == "full":
             lpresent = K.matched_mask(li, ok, left.cap)
@@ -1326,7 +1399,7 @@ class Executor:
             rkeep = (pos < count) | (pos >= count + n_lu)
             lkeep = pos < count + n_lu
             return self._pair_table(
-                left, right, all_li, all_ri, total_rows, rkeep, lkeep
+                left, right, all_li, all_ri, total_rows, rkeep, lkeep, out
             )
         raise ExecError(f"join kind {kind}")
 
@@ -1342,7 +1415,7 @@ class Executor:
 
     def _try_dense_join(
         self, left, right, kind, lcols, rcols, lk, lv, rk, rv, llive, rlive,
-        residual, mark_name,
+        residual, mark_name, out=None,
     ):
         if len(lk) != 1:
             return None
@@ -1382,27 +1455,49 @@ class Executor:
             lk[0].astype(jnp.int64), lnn, rmin, presence, rows, table_cap
         )
         return self._augment_join_output(
-            left, right, kind, matched, ri, llive, residual, mark_name
+            left, right, kind, matched, ri, llive, residual, mark_name, out
+        )
+
+    @staticmethod
+    def _sides(left, right, out):
+        """The two sides as a join's output takes them when its reader
+        names `out` (None: all): views of the named columns; where it
+        names none, one left column to carry the rows."""
+        if out is None:
+            return left, right
+        lnames = [n for n in left.columns if n in out]
+        rnames = [n for n in right.columns if n in out]
+        if not lnames and not rnames:
+            lnames = narrow_columns(left.columns, out)
+        return left.select(lnames), right.select(rnames)
+
+    @staticmethod
+    def _mark_output(left, mark_name, present):
+        """A mark join's output: the left columns, by reference, and the
+        "has a match" column."""
+        out_cols = {n: c.disowned() for n, c in left.columns.items()}
+        out_cols[mark_name] = Column(present, BOOL)
+        return Table(
+            out_cols, left.nrows_lazy, live=left.live,
+            unique_key=left.unique_key,
         )
 
     def _augment_join_output(
         self, left, right, kind, matched, ri, llive, residual, mark_name,
+        out=None,
     ):
         """Left-aligned join output for probe-style paths (dense, packed):
-        matched rows live in place, right columns gathered alongside — no
-        count sync, no compaction gathers."""
+        matched rows live in place, the right columns that `out` names
+        gathered alongside — no count sync, no compaction gathers."""
         if kind in ("semi", "anti", "mark"):
             if kind == "mark":
-                out_cols = {
-                    n: c.disowned() for n, c in left.columns.items()
-                }
-                out_cols[mark_name] = Column(matched, BOOL)
-                return Table(
-                    out_cols, left.nrows_lazy, live=left.live,
-                    unique_key=left.unique_key,
-                )
+                return self._mark_output(left, mark_name, matched)
             mask = (matched if kind == "semi" else ~matched) & llive
             return self._masked(left, mask)
+        left, right = self._sides(
+            left, right, self._read_with(out, residual)
+        )
+        ri_safe = jnp.where(matched, ri, 0) if right.columns else None
         if kind == "inner":
             # LEFT columns pass through by reference and are DISOWNED: the
             # left table may be a CTE/plan-cache-retained result (e.g. the
@@ -1411,12 +1506,11 @@ class Executor:
             # buffers that cached table still reads. Right-side gathers
             # are fresh buffers owned by this output alone.
             out_cols = {n: c.disowned() for n, c in left.columns.items()}
-            ri_safe = jnp.where(matched, ri, 0)
             out_cols.update(gather_columns(
                 right.columns, ri_safe, stats=Column.gather_stats, owned=True,
             ))
             pair = Table(
-                dict(out_cols), jnp.sum(matched, dtype=jnp.int32),
+                out_cols, jnp.sum(matched, dtype=jnp.int32),
                 live=matched, unique_key=left.unique_key,
             )
             if residual is not None:
@@ -1429,7 +1523,6 @@ class Executor:
             return pair
         # left join: left-aligned output, unmatched rows null on the right
         out_cols = {n: c.disowned() for n, c in left.columns.items()}
-        ri_safe = jnp.where(matched, ri, 0)
         out_cols.update(gather_columns(
             right.columns, ri_safe, matched, stats=Column.gather_stats,
         ))
@@ -1485,7 +1578,7 @@ class Executor:
 
     def _try_packed_join(
         self, left, right, kind, aligned, right_keys, llive, rlive,
-        residual, mark_name,
+        residual, mark_name, out=None,
     ):
         if not aligned:
             return None
@@ -1511,7 +1604,7 @@ class Executor:
         rnn = K._all_valid([c.valid for _, c in aligned], rlive)
         found, ri = K.member_lookup(lwords, lnn, rwords, rnn)
         return self._augment_join_output(
-            left, right, kind, found, ri, llive, residual, mark_name
+            left, right, kind, found, ri, llive, residual, mark_name, out
         )
 
     # -- distributed fact-fact hash join ---------------------------------
@@ -1530,7 +1623,7 @@ class Executor:
 
     def _try_exchange_join(
         self, left, right, kind, left_keys, right_keys,
-        lk, lv, rk, rv, llive, rlive, residual, node_fp=None,
+        lk, lv, rk, rv, llive, rlive, residual, node_fp=None, out=None,
     ):
         mesh = getattr(self.catalog, "session", None)
         mesh = getattr(mesh, "mesh", None)
@@ -1563,6 +1656,11 @@ class Executor:
         rnn = K._all_valid(rv, rlive)
         lh = K.hash_columns(lk, lv)
         rh = K.hash_columns(rk, rv)
+        whole = (left, right)  # the spill tier evaluates the keys again
+        # the keys ship as lk / rk: of the columns, only what is read of
+        # the result (or by the residual) crosses the interconnect
+        shipped = self._read_with(out, residual)
+        left, right = left.narrowed(shipped), right.narrowed(shipped)
 
         def ship(table):
             # data buffers for every column, then ONLY the real validity
@@ -1608,11 +1706,10 @@ class Executor:
             fn = get_exchange_hash_join(
                 mesh, len(lk), n_lc, n_rc, cap_l, cap_r, pair_cap, kind
             )
-            out = fn(
+            ok, *rest = fn(
                 (lh, lnn, *lk, *l_ship),
                 (rh, rnn, *rk, *r_ship),
             )
-            ok, rest = out[0], out[1:]
             used_l, used_r = cap_l, cap_r
             overflow = int(host_read("exchange", rest[-1]))
             if overflow == 0:
@@ -1651,8 +1748,8 @@ class Executor:
             self._exchange_disabled = True
             try:
                 return self._spilled_join(
-                    left, right, kind, left_keys, right_keys, residual,
-                    lk, lv, llive, rk, rv, rlive, parts,
+                    *whole, kind, left_keys, right_keys, residual,
+                    lk, lv, llive, rk, rv, rlive, parts, out=out,
                 )
             finally:
                 self._exchange_disabled = False
@@ -1761,7 +1858,8 @@ class Executor:
         cap = bucket_cap(max(count, 1))
         sel = K.compact_indices(ok, cap)
         pair = self._pair_table(
-            left, right, *K.take_arrays((li, ri), sel), count
+            left, right, *K.take_arrays((li, ri), sel), count,
+            out=E.col_refs(residual),
         )
         pmask = self._predicate_mask(pair, residual)
         # max-scatter: sel's padding duplicates index 0 (see _join residual)
@@ -1827,12 +1925,15 @@ class Executor:
 
         return [as_i64(a)], [as_i64(b)]
 
-    def _pair_table(self, left, right, li, ri, nrows, rkeep=None, lkeep=None):
+    def _pair_table(self, left, right, li, ri, nrows, rkeep=None, lkeep=None,
+                    out=None):
         # join-output gather can repeat rows: bounds survive, uniqueness
         # dies. Every buffer below is a fresh gather output owned by this
         # table alone — marked owned so a downstream fused pipeline may
-        # donate it (engine/fuse.py:_donate_slots). One gather a side;
+        # donate it (engine/fuse.py:_donate_slots). One gather a side, of
+        # the columns `out` names (_sides);
         # rkeep / lkeep are False on the rows an outer join null-extends
+        left, right = self._sides(left, right, out)
         cols = gather_columns(
             left.columns, li, lkeep, stats=Column.gather_stats, owned=True,
         )
@@ -1993,7 +2094,7 @@ class Executor:
                 None if i == uidx else next(it)
                 for i in range(len(mj.relations))
             ]
-            join_ctx = (mj.edges, uidx, tables)
+            join_ctx = (mj.edges, uidx, tables, mj.required)
         branches = [t.compacted() for t in branches]
         aligners = self._union_branch_aligners(branches)
         # mark the blocked path as ENTERED before any window executes: an
@@ -2028,7 +2129,10 @@ class Executor:
     def _apply_wrappers(self, t: Table, wrappers) -> Table:
         for w in reversed(wrappers):  # innermost wrapper first
             if isinstance(w, P.Filter):
-                t = self._masked(t, self._predicate_mask(t, w.predicate))
+                t = self._masked(
+                    t.narrowed(w.required),
+                    self._predicate_mask(t, w.predicate),
+                )
             else:
                 t = self._project_table(t, w.items)
         return t
@@ -2062,7 +2166,7 @@ class Executor:
                     stages = None
                     break
                 if isinstance(w, P.Filter):
-                    stages.append(P.Filter(predicate=w.predicate, child=None))
+                    stages.append(_dc_replace(w, child=None))
                 else:
                     stages.append(P.Project(items=list(w.items), child=None))
             if stages and not fuse._chain_worth_fusing(stages):
@@ -2155,11 +2259,12 @@ class Executor:
                     ctx.setdefault("wrapper_memo", {}),
                 )
                 if ctx["join"] is not None:
-                    edges, uidx, others = ctx["join"]
+                    edges, uidx, others, required = ctx["join"]
                     t = self._multijoin_over_tables(
                         [t if i == uidx else o for i, o in enumerate(others)],
                         edges,
                         trace=ctx["join_trace"],
+                        required=required,
                     )
                     ctx["max_table_cap"] = max(ctx["max_table_cap"], t.cap)
                 t = self._apply_wrappers_fused(
@@ -3335,7 +3440,8 @@ class Executor:
         return out
 
     def _spilled_join(self, left, right, kind, left_keys, right_keys,
-                      residual, lk, lv, llive, rk, rv, rlive, parts) -> Table:
+                      residual, lk, lv, llive, rk, rv, rlive, parts,
+                      out=None) -> Table:
         """Partitioned (Grace-style) hash join through the spill pool: both
         sides hash-partition on the join key, each partition pair joins
         with the regular engine paths (keys/residual re-evaluated over the
@@ -3362,10 +3468,10 @@ class Executor:
                 rpart = self._compact(right, (rp == p) & rlive)
                 if kind == "inner" and rpart.nrows == 0 and segments:
                     continue  # (LEFT must still null-extend its rows)
-                out = self._join(
-                    lpart, rpart, kind, left_keys, right_keys, residual
-                )
-                segments.append(pool.put(out))
+                segments.append(pool.put(self._join(
+                    lpart, rpart, kind, left_keys, right_keys, residual,
+                    out=out,
+                )))
                 session.spill_progress()
             return self._spill_finish("join", parts, pool, before, segments,
                                       t0=sp_t0)

@@ -233,6 +233,12 @@ def walk(e: Expr):
         yield from walk(c)
 
 
+def col_refs(e: Expr) -> set:
+    """Names of the columns `e` reads (bound `Col`s carry the full
+    "alias.col" or output name)."""
+    return {x.name for x in walk(e) if isinstance(x, Col)}
+
+
 def contains_agg(e: Expr) -> bool:
     return any(isinstance(x, Agg) for x in walk(e))
 

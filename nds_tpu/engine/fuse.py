@@ -66,7 +66,9 @@ from ..ops import kernels as K
 from . import expr as E
 from . import plan as P
 from .aotcache import xla_cache_hits
-from .columnar import Column, Table, bucket_cap, sort_dictionary
+from .columnar import (
+    Column, Table, bucket_cap, narrow_columns, sort_dictionary,
+)
 from .expr import Evaluator
 
 
@@ -217,7 +219,7 @@ def mark_pipelines(node: P.PlanNode, fuse_aggs: bool = True):
         stages = []
         for s in reversed(topdown):  # execution (innermost-first) order
             if isinstance(s, P.Filter):
-                stages.append(P.Filter(predicate=s.predicate, child=None))
+                stages.append(dataclasses.replace(s, child=None))
             else:
                 stages.append(P.Project(items=list(s.items), child=None))
         return stages, cur
@@ -414,9 +416,10 @@ class _FusedBase:
                 if pr.valid is not None:
                     mask = mask & pr.valid
                 mask = mask & t.row_mask()
+                # the filter hands on what is read above it (P.Filter)
                 t = Table(
-                    dict(t.columns), jnp.sum(mask, dtype=jnp.int32),
-                    live=mask,
+                    dict(narrow_columns(t.columns, s.required)),
+                    jnp.sum(mask, dtype=jnp.int32), live=mask,
                 )
             else:
                 cols = {name: ev.eval(e) for e, name in s.items}
@@ -759,6 +762,10 @@ class FusedPipeline(_FusedBase):
             if uk is None:
                 return None
             if isinstance(s, P.Filter):
+                if s.required is not None:
+                    names = names.intersection(s.required)
+                    if not uk <= names:
+                        uk = None
                 continue
             renames = {}
             for e, name in s.items:

@@ -55,6 +55,12 @@ class Project(PlanNode):
 class Filter(PlanNode):
     predicate: E.Expr
     child: PlanNode = None
+    # the columns something above this node reads (Session.prune_columns):
+    # a sorted tuple of names, None == all. The executor hands on these and
+    # no others. A dataclass field on purpose, as Scan.columns is: it
+    # participates in fingerprint, so a cached table narrowed for one
+    # consumer is never served to one that reads more.
+    required: tuple = None
 
     def children(self):
         return [self.child]
@@ -69,6 +75,7 @@ class Join(PlanNode):
     right_keys: list = field(default_factory=list)  # Exprs over right
     residual: Optional[E.Expr] = None  # non-equi condition applied post-match
     mark_name: Optional[str] = None  # kind == "mark": bool "has a match" column
+    required: tuple = None  # see Filter.required
 
     def children(self):
         return [self.left, self.right]
@@ -145,6 +152,7 @@ class MultiJoin(PlanNode):
     relations: list = field(default_factory=list)  # PlanNodes
     edges: list = field(default_factory=list)  # (i, j, left_expr, right_expr)
     residual: Optional[E.Expr] = None
+    required: tuple = None  # see Filter.required
 
     def children(self):
         return list(self.relations)

@@ -1483,39 +1483,71 @@ def _pk_holds(t, pk) -> bool:
 
 
 def prune_columns(node: P.PlanNode, catalog=None) -> P.PlanNode:
-    """Top-down required-column propagation; sets Scan.columns so the IO layer
-    only reads/transfers what the query touches (the columnar-format win the
-    reference gets from parquet + Spark column pruning)."""
+    """Top-down required-column propagation. Sets Scan.columns so the IO
+    layer only reads/transfers what the query touches (the columnar-format
+    win the reference gets from parquet + Spark column pruning), and leaves
+    on every node whose executor gathers rows (Filter, Join, MultiJoin) the
+    names something above it reads (`required`), so a join hands on no key
+    whose edge is consumed and no filter column whose filter is applied.
+    `None` means all; a node reached twice gets the union of its readers.
 
-    def expr_refs(e):
-        return {c.name for c in E.walk(e) if isinstance(c, E.Col)}
+    `required` holds only names the node's own subtree hands on: what is
+    read of the other relations of a join says nothing about this one, and
+    must not tell two otherwise equal subtrees apart (their fingerprints
+    key the fused pipelines' executables). `visit` returns those names on
+    its way up, from the same walk that sets Scan.columns, so a name kept
+    in `required` is a name some scan or projection below provides."""
+
+    expr_refs = E.col_refs
+    seen = {}  # id(node) -> (what its readers so far require, what it gave)
+
+    def of(req, *given):
+        # the names of `req` that come up from children which gave
+        # `given` (one of them None: not known, so all of `req`)
+        if req is None or None in given:
+            return req
+        return req.intersection(frozenset().union(*given))
 
     def visit(n, req):
+        """Push `req` (the names read above `n`; None: all) down; the
+        names of it that `n` hands on, or None where that is not known."""
+        if id(n) in seen:
+            old, gave = seen[id(n)]
+            req = None if old is None or req is None else old | req
+            if req == old:
+                return gave
+        req = None if req is None else frozenset(req)
+        gave = below(n, req)
+        seen[id(n)] = (req, gave)
+        if isinstance(n, (P.Filter, P.Join, P.MultiJoin)):
+            n.required = None if req is None else tuple(sorted(gave))
+        return gave
+
+    def below(n, req):
         if isinstance(n, P.Scan):
             if req is None:
                 n.columns = None
-            else:
-                bare = sorted({r.split(".", 1)[1] for r in req if r.startswith(n.alias + ".")})
-                if not bare and catalog is not None:
-                    # a pure row-count consumer (e.g. bare count(*)) still
-                    # needs one physical column to carry the row count
-                    sch = catalog.schema(n.table)
-                    if sch is not None:
-                        bare = [sch.names[0]]
-                n.columns = bare or None
-            return
+                return None
+            mine = frozenset(r for r in req if r.startswith(n.alias + "."))
+            bare = sorted(r.split(".", 1)[1] for r in mine)
+            if not bare and catalog is not None:
+                # a pure row-count consumer (e.g. bare count(*)) still
+                # needs one physical column to carry the row count
+                sch = catalog.schema(n.table)
+                if sch is not None:
+                    bare = [sch.names[0]]
+            n.columns = bare or None
+            return mine
         if isinstance(n, P.Project):
             child_req = set()
             for e, _ in n.items:
                 child_req |= expr_refs(e)
             visit(n.child, child_req)
-            return
+            return of(req, frozenset(name for _, name in n.items))
         if isinstance(n, P.Filter):
-            if req is None:
-                visit(n.child, None)
-            else:
-                visit(n.child, req | expr_refs(n.predicate))
-            return
+            return of(req, visit(
+                n.child, None if req is None else req | expr_refs(n.predicate)
+            ))
         if isinstance(n, P.Join):
             extra = set()
             for e in n.left_keys + n.right_keys:
@@ -1523,9 +1555,12 @@ def prune_columns(node: P.PlanNode, catalog=None) -> P.PlanNode:
             if n.residual is not None:
                 extra |= expr_refs(n.residual)
             sub = None if req is None else req | extra
-            visit(n.left, sub)
-            visit(n.right, sub)
-            return
+            left, right = visit(n.left, sub), visit(n.right, sub)
+            if n.kind in ("semi", "anti"):
+                return of(req, left)
+            if n.kind == "mark":
+                return of(req, left, frozenset([n.mark_name]))
+            return of(req, left, right)
         if isinstance(n, P.MultiJoin):
             extra = set()
             for _, _, le, re_ in n.edges:
@@ -1533,9 +1568,7 @@ def prune_columns(node: P.PlanNode, catalog=None) -> P.PlanNode:
             if n.residual is not None:
                 extra |= expr_refs(n.residual)
             sub = None if req is None else req | extra
-            for r in n.relations:
-                visit(r, sub)
-            return
+            return of(req, *[visit(r, sub) for r in n.relations])
         if isinstance(n, P.Aggregate):
             child_req = set()
             for e, _ in n.keys:
@@ -1544,34 +1577,32 @@ def prune_columns(node: P.PlanNode, catalog=None) -> P.PlanNode:
                 if a.arg is not None:
                     child_req |= expr_refs(a.arg)
             visit(n.child, child_req)
-            return
+            return of(req, frozenset(name for _, name in n.keys + n.aggs))
         if isinstance(n, P.Window):
             child_req = set() if req is None else set(req)
             for wf, _ in n.fns:
                 for c in wf.children():
                     child_req |= expr_refs(c)
-            visit(n.child, None if req is None else child_req)
-            return
+            return of(
+                req, visit(n.child, None if req is None else child_req),
+                frozenset(name for _, name in n.fns),
+            )
         if isinstance(n, P.Sort):
             child_req = None
             if req is not None:
                 child_req = set(req)
                 for e, _, _ in n.keys:
                     child_req |= expr_refs(e)
-            visit(n.child, child_req)
-            return
-        if isinstance(n, (P.Limit, P.Distinct)):
-            visit(n.child, req)
-            return
-        if isinstance(n, P.SetOp):
-            visit(n.left, None)
-            visit(n.right, None)
-            return
-        if isinstance(n, P.MaterializedScan):
-            return
+            return of(req, visit(n.child, child_req))
+        if isinstance(n, P.Limit):
+            return of(req, visit(n.child, req))
+        # DISTINCT compares whole rows and a set operation pairs columns
+        # by position: they read every column of their inputs, whatever
+        # is read above them; so does whatever this walk does not know
         for c in n.children():
             if c is not None:
                 visit(c, None)
+        return None
 
     visit(node, None)
     return node

@@ -297,24 +297,25 @@ def profile_events(events) -> dict:
         q = ev.get("query") or "<unscoped>"
         node = ev.get("node", "?")
         qrec = queries.setdefault(q, dict(_EMPTY_QUERY, ops={}))
-        op = qrec["ops"].setdefault(
-            node, {"count": 0, "incl_ms": 0.0, "excl_ms": 0.0, "rows": 0}
-        )
-        op["count"] += 1
-        op["incl_ms"] += float(ev.get("dur_ms") or 0.0)
-        op["excl_ms"] += ev["excl_ms"]
-        if ev.get("rows") is not None:
-            op["rows"] += int(ev["rows"])
+        for op in (
+            qrec["ops"].setdefault(
+                node, {"count": 0, "incl_ms": 0.0, "excl_ms": 0.0, "rows": 0}
+            ),
+            op_totals.setdefault(
+                node, {"count": 0, "incl_ms": 0.0, "excl_ms": 0.0, "rows": 0}
+            ),
+        ):
+            op["count"] += 1
+            op["incl_ms"] += float(ev.get("dur_ms") or 0.0)
+            op["excl_ms"] += ev["excl_ms"]
+            if ev.get("rows") is not None:
+                op["rows"] += int(ev["rows"])
+            # Filter / Join / MultiJoin: columns in, columns handed on
+            for k in ("cols_in", "cols_out"):
+                if ev.get(k) is not None:
+                    op[k] = op.get(k, 0) + int(ev[k])
         if ev.get("depth", 0) == 0:
             qrec["root_incl_ms"] += float(ev.get("dur_ms") or 0.0)
-        tot = op_totals.setdefault(
-            node, {"count": 0, "incl_ms": 0.0, "excl_ms": 0.0, "rows": 0}
-        )
-        tot["count"] += 1
-        tot["incl_ms"] += float(ev.get("dur_ms") or 0.0)
-        tot["excl_ms"] += ev["excl_ms"]
-        if ev.get("rows") is not None:
-            tot["rows"] += int(ev["rows"])
     tallies = {
         "plan_cache_hits": 0,
         "plan_cache_misses": 0,
@@ -610,6 +611,9 @@ def _merge_op(dst: dict, src: dict):
     dst["incl_ms"] = dst.get("incl_ms", 0.0) + float(src.get("incl_ms") or 0.0)
     dst["excl_ms"] = dst.get("excl_ms", 0.0) + float(src.get("excl_ms") or 0.0)
     dst["rows"] = dst.get("rows", 0) + int(src.get("rows") or 0)
+    for k in ("cols_in", "cols_out"):
+        if src.get(k) is not None:
+            dst[k] = dst.get(k, 0) + int(src[k])
 
 
 def merge_profiles(base: dict, extra: dict) -> dict:

@@ -74,7 +74,11 @@ from ..engine.lockdebug import make_lock
 EVENT_SCHEMA = {
     # first line of every file: identifies the producing process
     "trace_meta": ("pid", "version"),
-    # one per executed plan node (inclusive wall time; children nest inside)
+    # one per executed plan node (inclusive wall time; children nest inside).
+    # Filter / Join / MultiJoin spans (and a Pipeline whose stages are all
+    # Filters, no Project and no aggregate: its width is `required`'s doing
+    # alone) also carry the optional `cols_in` / `cols_out`: the columns of
+    # the node's inputs and the columns it handed on (plan `required`)
     "op_span": ("exec_id", "seq", "depth", "node", "explain", "dur_ms",
                 "rows", "est_bytes"),
     # one per benchmarked query/function (BenchReport.report_on)

@@ -696,3 +696,214 @@ def test_plan_verify_corpus_subset():
     spec.loader.exec_module(mod)
     # 14 is a two-statement template; 93 is the LEFT->INNER promotion shape
     assert mod.main(["--queries", "3,14,93"]) == 0
+
+
+# ---------------------------------------------------------------------------
+# required-column propagation: what each Filter / Join / MultiJoin hands on
+# ---------------------------------------------------------------------------
+
+
+def _star_session():
+    """A fact table and two dimensions, each with a key, a name that is
+    read above the join and a flag that only its filter reads."""
+    s = Session()
+    s.register_arrow("f", pa.table({
+        "a_sk": pa.array([1, 2, 2, 3], pa.int32()),
+        "b_sk": pa.array([1, 1, 2, 2], pa.int32()),
+        "q": pa.array([5, 6, 7, 8], pa.int32()),
+    }))
+    s.register_arrow("a", pa.table({
+        "a_sk": pa.array([1, 2, 3], pa.int32()),
+        "a_name": pa.array(["x", "y", "z"]),
+        "a_flag": pa.array([1, 1, 0], pa.int32()),
+    }))
+    s.register_arrow("b", pa.table({
+        "b_sk": pa.array([1, 2], pa.int32()),
+        "b_name": pa.array(["p", "q"]),
+        "b_flag": pa.array([2, 2], pa.int32()),
+    }))
+    return s
+
+
+def _pruned(s, sql):
+    """The bound plan after `prune_columns` alone: Filters still stand as
+    nodes of their own (mark_pipelines folds them into Pipelines later)."""
+    from nds_tpu.engine.session import prune_columns
+
+    return prune_columns(Binder(s.catalog).bind(parse_sql(sql)), s.catalog)
+
+
+def _nodes(plan, typ):
+    return [n for n in P.walk_plan(plan) if isinstance(n, typ)]
+
+
+def _filter_over(plan, table):
+    (f,) = [n for n in _nodes(plan, P.Filter)
+            if isinstance(n.child, P.Scan) and n.child.table == table]
+    return f
+
+
+# statement -> (type of the join node, the set it carries)
+_REQUIRED = {
+    "star_join_filter_only_dimensions": (
+        "select a_name, sum(q) sq from f, a, b where f.a_sk = a.a_sk and "
+        "f.b_sk = b.b_sk and a_flag = 1 and b_flag = 2 group by a_name",
+        P.MultiJoin, ("a.a_name", "f.q"),
+    ),
+    "count_star_reads_no_column": (
+        "select count(*) c from f, a where f.a_sk = a.a_sk and a_flag = 1",
+        P.MultiJoin, (),
+    ),
+    "residual_predicate": (
+        "select f.q from f left join a on f.a_sk = a.a_sk and a.a_flag > f.q",
+        P.Join, ("f.q",),
+    ),
+    "left_outer_join": (
+        "select f.q, a.a_name from f left join a on f.a_sk = a.a_sk",
+        P.Join, ("a.a_name", "f.q"),
+    ),
+    "full_outer_join": (
+        "select f.q, a.a_name from f full outer join a on f.a_sk = a.a_sk",
+        P.Join, ("a.a_name", "f.q"),
+    ),
+    "rollup_and_window": (
+        "select a_name, sum(q) sq, rank() over (partition by a_name "
+        "order by sum(q)) r from f, a where f.a_sk = a.a_sk and a_flag = 1 "
+        "group by rollup(a_name)",
+        P.MultiJoin, ("a.a_name", "f.q"),
+    ),
+    "filter_column_read_above_the_join": (
+        "select a_name, a_flag, q from f, a where f.a_sk = a.a_sk "
+        "and a_flag = 1",
+        P.MultiJoin, ("a.a_flag", "a.a_name", "f.q"),
+    ),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_REQUIRED))
+def test_join_carries_what_is_read_above_it(shape):
+    sql, typ, want = _REQUIRED[shape]
+    s = _star_session()
+    plan = _pruned(s, sql)
+    (join,) = _nodes(plan, typ)
+    assert join.required == want
+    if shape == "residual_predicate":
+        assert join.residual is not None
+        assert "a.a_flag" in E.col_refs(join.residual)
+    # the finished plan verifies (each parent reads inside what its child
+    # hands on) and answers the same with and without the verifier
+    checked = _star_session()
+    checked.conf["engine.verify_plans"] = "all"
+    assert checked.sql(sql).to_pylist() == s.sql(sql).to_pylist()
+
+
+def test_dimension_filter_hands_on_its_key_not_its_filter_column():
+    sql = _REQUIRED["star_join_filter_only_dimensions"][0]
+    plan = _pruned(_star_session(), sql)
+    fa, fb = _filter_over(plan, "a"), _filter_over(plan, "b")
+    # what the MultiJoin reads of a relation: what is read above it, and
+    # every edge's keys
+    assert fa.required == ("a.a_name", "a.a_sk")
+    assert fb.required == ("b.b_sk",)
+    # ... of this relation: what is read of the others is not in the set,
+    # so the same filter under another aggregate keeps its fingerprint
+    # (the fused pipelines' executables are keyed by it)
+    other = _pruned(_star_session(), sql.replace("sum(q)", "sum(f.b_sk)"))
+    assert P.fingerprint(_filter_over(other, "a")) == P.fingerprint(fa)
+    # a filter column that is also read above the join stays
+    plan = _pruned(
+        _star_session(), _REQUIRED["filter_column_read_above_the_join"][0]
+    )
+    assert "a.a_flag" in _filter_over(plan, "a").required
+
+
+def test_filter_stage_keeps_its_set_through_mark_pipelines():
+    s = _star_session()
+    plan = s.sql(_REQUIRED["star_join_filter_only_dimensions"][0]).plan
+    stages = [st for p in _nodes(plan, P.Pipeline) for st in p.stages
+              if isinstance(st, P.Filter)]
+    assert len(stages) == 2
+    assert all(st.required is not None for st in stages)
+    assert not any("a.a_flag" in st.required or "b.b_flag" in st.required
+                   for st in stages)
+
+
+def _shared_filter():
+    return P.Filter(
+        E.BinOp(">", E.Col("t1.v"), E.Lit(0)), P.Scan("t1", "t1")
+    )
+
+
+def test_subtree_reached_twice_gets_the_union_of_its_readers():
+    from nds_tpu.engine.session import prune_columns
+
+    s = _session()
+    shared = _shared_filter()
+    plan = P.Join(
+        "inner",
+        P.Project([(E.Col("t1.k"), "a")], shared),
+        P.Project([(E.Col("t1.s"), "b")], shared),
+        [E.Col("a")], [E.Col("b")],
+    )
+    prune_columns(plan, s.catalog)
+    assert shared.required == ("t1.k", "t1.s")
+    # the scan below reads both readers' columns and the predicate's
+    assert shared.child.columns == ["k", "s", "v"]
+    assert PlanVerifier(s.catalog).verify(plan) == []
+
+
+def test_no_reader_set_means_all():
+    from nds_tpu.engine.session import prune_columns
+
+    s = _session()
+    # SetOp children, and a root the walk reaches with no set
+    left, right = _shared_filter(), _shared_filter()
+    root = P.Filter(
+        E.BinOp(">", E.Col("t1.k"), E.Lit(0)),
+        P.SetOp("union_all", left, right),
+    )
+    prune_columns(root, s.catalog)
+    assert root.required is None
+    assert left.required is None and right.required is None
+    assert left.child.columns is None
+    # a MaterializedScan is whole whatever reads it; the Filter over it
+    # still carries its readers' names
+    f = P.Filter(E.BinOp(">", E.Col("v"), E.Lit(0)), P.MaterializedScan("m"))
+    prune_columns(P.Project([(E.Col("k"), "k")], f), s.catalog)
+    assert f.required == ("k",)
+
+
+def test_fingerprint_covers_what_reads_a_subtree():
+    from nds_tpu.engine.session import prune_columns
+
+    s = _session()
+
+    def reader(names):
+        # no Scan.columns below to tell the two apart: `required` alone
+        f = P.Filter(
+            E.BinOp(">", E.Col("v"), E.Lit(0)), P.MaterializedScan("m")
+        )
+        prune_columns(
+            P.Project([(E.Col(n), n) for n in names], f), s.catalog
+        )
+        return f
+
+    narrow, wide, again = reader(["k"]), reader(["k", "s"]), reader(["k"])
+    assert (narrow.required, wide.required) == (("k",), ("k", "s"))
+    assert P.fingerprint(narrow) != P.fingerprint(wide)
+    assert P.fingerprint(narrow) == P.fingerprint(again)
+
+
+def test_required_narrower_than_its_readers_flagged():
+    from nds_tpu.engine.session import prune_columns
+
+    s = _session()
+    f = _shared_filter()
+    plan = prune_columns(
+        P.Project([(E.Col("t1.k"), "k"), (E.Col("t1.s"), "s")], f),
+        s.catalog,
+    )
+    assert PlanVerifier(s.catalog).verify(plan) == []
+    f.required = ("t1.k",)  # a reader above still names t1.s
+    v = PlanVerifier(s.catalog).verify(plan)
+    assert v and "t1.s" in v[0]
