@@ -290,6 +290,46 @@ class Smoke:
             self.fail(f"mesh: {t['mesh_fallbacks']} mesh_fallback event(s)")
 
 
+def _load_sqlite(conn, data_dir, tables):
+    """Create, fill and index `tables` from the generator's .dat files."""
+    import datetime
+
+    import pyarrow as pa
+
+    from nds_tpu.io.csv import read_dat_dir
+    from nds_tpu.schema import get_schemas
+
+    for t, schema in get_schemas(use_decimal=False).items():
+        path = os.path.join(data_dir, t)
+        if t not in tables or not os.path.isdir(path):
+            continue
+        arrow = read_dat_dir(path, schema, use_decimal=False)
+        conn.execute(
+            f"create table {t} ({', '.join(f.name for f in schema)})"
+        )
+        ph = ",".join("?" * len(schema))
+        dates = [
+            i for i, f in enumerate(arrow.schema) if pa.types.is_date(f.type)
+        ]
+        # stream per record batch: to_pylist() of a whole SF1 fact table
+        # would box tens of millions of Python values at once
+        for batch in arrow.to_batches(max_chunksize=1 << 17):
+            cols = [c.to_pylist() for c in batch.columns]
+            for i in dates:
+                cols[i] = [
+                    v.isoformat() if isinstance(v, datetime.date) else v
+                    for v in cols[i]
+                ]
+            conn.executemany(f"insert into {t} values ({ph})", zip(*cols))
+        print(f"loaded {t}: {arrow.num_rows} rows", flush=True)
+        # sqlite's nested-loop joins need an index on every surrogate key
+        for f in schema:
+            if f.name.endswith("_sk") or f.name.endswith("_number"):
+                conn.execute(f"create index idx_{t}_{f.name} on {t}({f.name})")
+    conn.execute("analyze")
+    conn.commit()
+
+
 def reference_child(work):
     """sqlite over the tables the six statements read, then validate.py's
     comparison of the engine's written answers against sqlite's."""
@@ -299,12 +339,11 @@ def reference_child(work):
     import pyarrow as pa
     import pyarrow.parquet as pq
 
-    for d in ("tools", "tests", ""):
+    for d in ("tests", ""):
         sys.path.insert(0, os.path.join(REPO, d))
     from nds_tpu import validate
     from nds_tpu.power import gen_sql_from_stream
     from nds_tpu.schema import get_schemas
-    from sqlite_anchor import load
     from test_oracle import _StddevSamp, _to_sqlite
 
     stream = gen_sql_from_stream(f"{work}/streams/six.sql")
@@ -314,7 +353,7 @@ def reference_child(work):
     conn = sqlite3.connect(":memory:")
     conn.create_aggregate("stddev_samp", 1, _StddevSamp)
     t0 = time.perf_counter()
-    load(conn, f"{work}/raw", tables)
+    _load_sqlite(conn, f"{work}/raw", tables)
     out = {"tables": sorted(tables),
            "load_s": round(time.perf_counter() - t0, 3),
            "query_s": {}, "rows": {}}

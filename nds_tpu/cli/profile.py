@@ -9,7 +9,7 @@ event logs).
     python -m nds_tpu.cli.profile --check <failure-bundle-*.json>...
     python -m nds_tpu.cli.profile --compare OLD NEW
         [--ratio 1.25] [--min_ms 50] [--fail_on_regression]
-        [--bench OLD_BENCH NEW_BENCH]
+        [--bench OLD_MULTICHIP NEW_MULTICHIP]
     python -m nds_tpu.cli.profile compact <trace_dir> [--all] [--dry_run]
 
 Single-run mode aggregates one or more event logs (files or trace dirs —
@@ -32,7 +32,10 @@ recorder failure bundles (`failure-bundle-*.json`) are validated
 structurally (bundle keys + ring event schema) instead of being parsed
 as event logs — `profile --check <bundle>` is how CI asserts a crash
 left a USABLE black box. `--compare`
-diffs two runs and flags per-query and per-operator regressions.
+diffs two runs and flags per-query and per-operator regressions;
+`--bench` diffs two MULTICHIP round artifacts (a stored
+`MULTICHIP_r*.json`, or the block `tools/mesh_stream_check.py` writes)
+and exits 2 when neither file carries `n_devices`.
 `compact` folds closed rotation segments (engine.trace_rotate_bytes)
 into per-app summary artifacts and deletes the raw files, bounding a
 long-running fleet's trace-dir disk (--all folds the open tails too —
@@ -300,141 +303,6 @@ def _render_accuracy(acc):
                   f"(|log err| {s['abs_log_err']:.3f})")
 
 
-def _load_sqlite_shared(path):
-    """The `sqlite_shared` block out of a bench artifact: a saved compact
-    OUT line / bench JSON-lines output, or a driver capture whose `tail`
-    holds the last emitted line. Returns the dict or None."""
-    import re
-
-    with open(path) as fh:
-        text = fh.read()
-    best = None
-    for line in text.splitlines():
-        line = line.strip()
-        if not line.startswith("{"):
-            continue
-        try:
-            obj = json.loads(line)
-        except ValueError:
-            continue
-        if isinstance(obj.get("sqlite_shared"), dict):
-            best = obj["sqlite_shared"]
-        elif isinstance(obj.get("tail"), str):
-            # driver wrapper: scan the captured tail for the last block
-            m = None
-            for m in re.finditer(r'"sqlite_shared":\s*(\{[^{}]*\})',
-                                 obj["tail"]):
-                pass
-            if m is not None:
-                try:
-                    best = json.loads(m.group(1))
-                except ValueError:
-                    pass
-    return best
-
-
-def _load_bench_accuracy(path):
-    """The budgeter-accuracy fields (`budget_err_median`,
-    `feedback_hit_rate`) out of a bench artifact: the bench OUT line /
-    metrics report, or a driver capture whose `tail` holds it. Returns
-    the dict (values may be None) or None when the artifact carries
-    neither key — pre-feedback rounds compare as absent, not as zero."""
-    import re
-
-    try:
-        with open(path) as fh:
-            text = fh.read()
-    except OSError:
-        return None
-    best = None
-    for line in text.splitlines():
-        line = line.strip()
-        obj = None
-        if line.startswith("{"):
-            try:
-                obj = json.loads(line)
-            except ValueError:
-                obj = None
-        if isinstance(obj, dict) and isinstance(obj.get("tail"), str):
-            line, obj = obj["tail"], None  # scan the captured tail below
-        if isinstance(obj, dict):
-            if "budget_err_median" in obj or "feedback_hit_rate" in obj:
-                best = {
-                    "budget_err_median": obj.get("budget_err_median"),
-                    "feedback_hit_rate": obj.get("feedback_hit_rate"),
-                }
-            continue
-        # metrics.csv rows ("key,value"), printed dict reprs, captured
-        # tails — take the LAST occurrence, like the sqlite loader
-        for key in ("budget_err_median", "feedback_hit_rate"):
-            m = None
-            for m in re.finditer(
-                rf"['\"]?{key}['\"]?\s*[:,]\s*([0-9.]+|None|null)", line
-            ):
-                pass
-            if m is not None:
-                best = best if best is not None else {}
-                v = m.group(1)
-                best[key] = None if v in ("None", "null") else float(v)
-    return best
-
-
-def _compare_bench_accuracy(old_path, new_path):
-    """Budgeter-accuracy headline comparison record (ISSUE 18: budgeter
-    error is a published, shrinking number). Fail-soft like the other
-    bench headlines: artifacts without the fields yield no record at
-    all. Regression: the median |log(est/actual)| grew more than 25%
-    AND by at least 0.1 (below that is sampling noise)."""
-    old = _load_bench_accuracy(old_path) or {}
-    new = _load_bench_accuracy(new_path)
-    if new is None and not old:
-        return []
-    rec = {
-        "level": "bench", "query": "budget_accuracy",
-        "old_err": old.get("budget_err_median"),
-        "new_err": (new or {}).get("budget_err_median"),
-        "old_hit_rate": old.get("feedback_hit_rate"),
-        "new_hit_rate": (new or {}).get("feedback_hit_rate"),
-        "change": "headline",
-    }
-    e_old, e_new = rec["old_err"], rec["new_err"]
-    if (
-        e_old is not None and e_new is not None
-        and e_new > e_old * 1.25 and e_new - e_old >= 0.1
-    ):
-        rec["change"] = "regression"
-    return [rec]
-
-
-def _compare_sqlite_shared(old_path, new_path):
-    """sqlite_shared headline comparison records (ROADMAP item 3: publish
-    the engine-vs-sqlite shared-subset ratio until it crosses 1.0, flag
-    when it worsens). Regression: the ratio rose more than 2% — geomeans
-    over ~100 queries are stable, so drift beyond that is a real loss."""
-    old = _load_sqlite_shared(old_path)
-    new = _load_sqlite_shared(new_path)
-    out = []
-    if new is None:
-        out.append({
-            "level": "bench", "change": "status_change",
-            "query": "sqlite_shared",
-            "detail": f"no sqlite_shared block in {new_path}",
-        })
-        return out
-    r_new = new.get("ratio")
-    r_old = old.get("ratio") if old else None
-    rec = {
-        "level": "bench", "query": "sqlite_shared",
-        "old_ratio": r_old, "new_ratio": r_new,
-        "queries": new.get("queries"),
-        "change": "headline",
-    }
-    if r_old is not None and r_new is not None and r_new > r_old * 1.02:
-        rec["change"] = "regression"
-    out.append(rec)
-    return out
-
-
 def _load_multichip(path):
     """A MULTICHIP round artifact: the driver wrapper ({n_devices, rc, ok,
     tail}) or the mesh gate's metrics block (tools/mesh_stream_check.py).
@@ -449,11 +317,11 @@ def _load_multichip(path):
 
 def _compare_multichip(old_path, new_path):
     """MULTICHIP round comparison (ISSUE 13): the SF0.01 mesh-vs-oracle
-    gate's artifact against the newest stored MULTICHIP_r*.json — the
-    same fail-soft shape as the sqlite_shared headline. Old rounds
-    (r01–r05 are driver wrappers with only {ok, tail}) predate the
-    metrics block, so old_ratio starts null. Regression: the mesh run
-    stopped being ok, or the mesh-vs-oracle wall ratio worsened > 25%."""
+    gate's artifact against the newest stored MULTICHIP_r*.json,
+    fail-soft. Old rounds (r01–r05 are driver wrappers with only
+    {ok, tail}) predate the metrics block, so old_ratio starts null.
+    Regression: the mesh run stopped being ok, or the mesh-vs-oracle
+    wall ratio worsened > 25%."""
     old = _load_multichip(old_path) or {}
     new = _load_multichip(new_path)
     out = []
@@ -493,28 +361,17 @@ def _print_bench_rec(r):
               f"{fmt(r.get('old_err'))} -> {fmt(r.get('new_err'))} "
               f"(feedback hit rate {hr_s}){flag}")
         return
-    if r.get("query") == "multichip":
-        old_s = "-" if r.get("old_ratio") is None else f"{r['old_ratio']:.3f}"
-        new_s = "-" if r.get("new_ratio") is None else f"{r['new_ratio']:.3f}"
-        flag = "  ** REGRESSED" if r["change"] == "regression" else ""
-        ok = "ok" if r.get("new_ok") else "NOT OK"
-        print(f"== multichip mesh-vs-oracle wall ratio: {old_s} -> {new_s} "
-              f"over {r.get('queries')} matched queries ({ok}){flag}")
-        return
-    old_s = "-" if r["old_ratio"] is None else f"{r['old_ratio']:.3f}"
+    # the one other bench record: _compare_multichip's
+    old_s = "-" if r.get("old_ratio") is None else f"{r['old_ratio']:.3f}"
+    new_s = "-" if r.get("new_ratio") is None else f"{r['new_ratio']:.3f}"
     flag = "  ** REGRESSED" if r["change"] == "regression" else ""
-    above = (
-        "  (still above parity — target < 1.0)"
-        if (r["new_ratio"] or 0) > 1.0
-        else ""
-    )
-    print(f"== sqlite_shared ratio: {old_s} -> {r['new_ratio']:.3f} over "
-          f"{r['queries']} shared queries{flag}{above}")
+    ok = "ok" if r.get("new_ok") else "NOT OK"
+    print(f"== multichip mesh-vs-oracle wall ratio: {old_s} -> {new_s} "
+          f"over {r.get('queries')} matched queries ({ok}){flag}")
 
 
 def _render_compare(regs, ratio, min_ms):
-    # the sqlite_shared headline always prints, regressed or not (the
-    # ratio is published every round until it crosses 1.0)
+    # headlines always print, regressed or not
     headline = [r for r in regs if r["change"] == "headline"]
     regs = [r for r in regs if r["change"] != "headline"]
     for r in headline:
@@ -602,9 +459,9 @@ def main(argv=None):
     )
     parser.add_argument(
         "--bench", nargs=2, metavar=("OLD", "NEW"),
-        help="bench artifacts (saved compact OUT lines / driver captures) "
-        "to diff the sqlite_shared headline ratio, alongside or instead "
-        "of --compare",
+        help="two MULTICHIP round artifacts (a stored MULTICHIP_r*.json "
+        "or tools/mesh_stream_check.py's --out) to diff the mesh-vs-oracle "
+        "wall ratio, alongside or instead of --compare",
     )
     parser.add_argument("--top", type=int, default=10,
                         help="top-N hottest operators (10)")
@@ -678,23 +535,19 @@ def main(argv=None):
                     rec["change"] = "regression"
                 regs.append(rec)
         if args.bench:
-            # artifact-type detection: a MULTICHIP round carries n_devices
-            # (driver wrapper or mesh-gate metrics block); everything else
-            # is a bench OUT line with the sqlite_shared headline. EITHER
-            # side identifying as multichip routes here — an unreadable
-            # NEW artifact (gate died before writing) must land on
-            # _compare_multichip's fail-soft status_change record, not on
-            # the sqlite loader's bare open()
-            objs = [_load_multichip(p) for p in args.bench]
-            if any(
-                isinstance(o, dict) and "n_devices" in o for o in objs
+            # a MULTICHIP round carries n_devices (driver wrapper or
+            # mesh-gate metrics block). EITHER side identifying as one is
+            # enough: an unreadable NEW artifact (gate died before
+            # writing) lands on _compare_multichip's fail-soft
+            # status_change record
+            if not any(
+                "n_devices" in (_load_multichip(p) or {}) for p in args.bench
             ):
-                regs.extend(_compare_multichip(*args.bench))
-            else:
-                regs.extend(_compare_sqlite_shared(*args.bench))
-                # accuracy headline beside the sqlite_shared ratio (bench
-                # round arbitration: budgeter error must shrink)
-                regs.extend(_compare_bench_accuracy(*args.bench))
+                print("profile: --bench compares two MULTICHIP round "
+                      "artifacts; neither of these carries n_devices",
+                      file=sys.stderr)
+                sys.exit(2)
+            regs.extend(_compare_multichip(*args.bench))
         if args.as_json:
             print(json.dumps({"regressions": regs}, indent=2))
         else:

@@ -1068,8 +1068,8 @@ class Executor:
         # recorded order instead (same fingerprint => same query text and
         # literals, so the recorded order stays the right one; any order
         # is correct regardless). The round-5 join-graph optimizer cost
-        # q3 one such sync per steady run; `tools/q3_check.py` holds the
-        # replay in place. Unmeasured on an attached chip (ROADMAP A6).
+        # q3 one such sync per steady run; test_join_order_replay_memo holds
+        # the replay in place. Unmeasured on an attached chip (ROADMAP A6).
         trace = None
         session = getattr(self.catalog, "session", None)
         if (
@@ -2039,7 +2039,7 @@ class Executor:
     # ceiling: query5's per-channel sales+returns union is a fact-scale
     # concat (~32M rows x ~6 columns per channel at SF10) joined to
     # date_dim/store before aggregation — it hard-OOMs (and irrecoverably
-    # poisons) the device on the unblocked path (bench.py).
+    # poisons) the device on the unblocked path.
 
     def _blocked_union_ctx(self, node: P.Aggregate):
         """Prepare windowed execution of a blocked-union aggregate: execute
@@ -2099,8 +2099,8 @@ class Executor:
         aligners = self._union_branch_aligners(branches)
         # mark the blocked path as ENTERED before any window executes: an
         # OOM raised mid-window must still be attributable to a blocked
-        # plan (bench.py's poisoned-backend bail exempts those), so the
-        # marker cannot wait for successful completion in _annotate_blocked
+        # plan, so the marker cannot wait for successful completion in
+        # _annotate_blocked
         self.last_blocked_union = {
             "windows": 0,
             "window_rows": wrows,
@@ -2321,9 +2321,9 @@ class Executor:
             "total_rows": ctx["total_rows"],
             "max_table_cap": ctx["max_table_cap"],
         }
-        # session-level marker: harness loops (bench.py) read this to tell
-        # whether the statement they just ran routed through the blocked
-        # path (they reset it before each statement)
+        # session-level marker: tests/test_budget.py reads this to tell
+        # whether the statement it just ran routed through the blocked
+        # path, and at which window size
         session = getattr(self.catalog, "session", None)
         if session is not None:
             session.last_blocked_union = self.last_blocked_union

@@ -895,7 +895,7 @@ class Session:
                 tracer=lambda: self.tracer,
             )
         # stats of the most recent blocked union-aggregation any executor
-        # of this session ran (bench.py's OOM-bail heuristic reads it)
+        # of this session ran (tests/test_budget.py reads it)
         self.last_blocked_union = None
         # MultiJoin greedy-order memo: fingerprint -> recorded join steps
         # (exec._multijoin_greedy). Replaying skips the per-step blocking

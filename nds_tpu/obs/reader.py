@@ -525,8 +525,8 @@ def exec_cache_hit_rate(prof: dict):
 def feedback_hit_rate(prof: dict):
     """Feedback-store hit rate of a profiled run (budget-time lookups
     that found a recorded actual), or None when the run did no lookups
-    (plan_feedback off/record — record mode never probes). The bench OUT
-    line and `profile --bench` headline read this."""
+    (plan_feedback off/record — record mode never probes). full_bench's
+    metrics report and `profile --compare`'s headline read this."""
     fb = prof.get("feedback") or {}
     lookups = fb.get("lookups") or 0
     if not lookups:

@@ -393,9 +393,9 @@ def current_context() -> TraceContext | None:
 
 class Tracer:
     """Append-only JSON-lines event writer (or an in-memory collector when
-    `trace_dir` is None — the dev-tool mode tools/trace_query.py uses; or
-    a sink-only forwarder with `collect=False` — the live-telemetry-
-    without-a-trace-dir mode).
+    `trace_dir` is None — the mode the tests and tools/mesh_stream_check.py
+    read events back from in-process; or a sink-only forwarder with
+    `collect=False` — the live-telemetry-without-a-trace-dir mode).
 
     Thread-safe: a lock serializes writes, and each event line is emitted
     with a single write() + flush so concurrent streams/threads sharing a

@@ -1,13 +1,13 @@
 """Blocked (morsel-style) union-aggregation: the executor evaluates a
 union_all feeding an aggregate in bounded row windows with partial-aggregate
-merging instead of materializing the full concat (the SF10 HBM ceiling,
-bench.py). Blocked-path results must equal the unblocked path exactly;
+merging instead of materializing the full concat (the SF10 HBM ceiling).
+Blocked-path results must equal the unblocked path exactly;
 non-decomposable aggregates must stay on the unblocked path.
 
 Plus regression tests for the satellite fixes that rode along with the
-blocked path (ISSUE 1): SF10 bench data-dir derivation, the throughput
-start-gate timeout fallback, _to_ts_ms epoch windows, the join-expansion
-int32 guard, and _null_rejecting_shape vs nested boolean connectives.
+blocked path (ISSUE 1): the throughput start-gate timeout fallback,
+_to_ts_ms epoch windows, the join-expansion int32 guard, and
+_null_rejecting_shape vs nested boolean connectives.
 """
 
 import threading
@@ -281,18 +281,6 @@ def test_derived_window_rows_honors_conf_and_env(monkeypatch):
 # ---------------------------------------------------------------------------
 # satellite regressions
 # ---------------------------------------------------------------------------
-
-
-def test_sf10_data_dir_derivation(monkeypatch):
-    import bench
-
-    monkeypatch.delenv("NDS_BENCH_DATA", raising=False)
-    monkeypatch.delenv("NDS_BENCH_DATA_SF10", raising=False)
-    assert bench._sf10_data_dir() == "/tmp/nds_bench_sf10.0"
-    monkeypatch.setenv("NDS_BENCH_DATA", "/data/nds_sf1/")
-    assert bench._sf10_data_dir() == "/data/nds_sf1_sf10.0"
-    monkeypatch.setenv("NDS_BENCH_DATA_SF10", "/big/nds_sf10")
-    assert bench._sf10_data_dir() == "/big/nds_sf10"
 
 
 def test_start_gate_pure_timeout_falls_back_ungated():

@@ -584,6 +584,34 @@ def test_profile_compare_multichip_rounds(tmp_path):
     assert rec4["change"] == "status_change"
 
 
+def test_profile_bench_takes_multichip_artifacts_only(tmp_path, capsys):
+    """The `--bench` handler has one meaning: a pair of which either side
+    carries `n_devices` compares as MULTICHIP rounds; any other pair is
+    one line on stderr and exit 2."""
+    import json
+
+    from nds_tpu.cli import profile as profile_cli
+
+    old = tmp_path / "MULTICHIP_r05.json"
+    old.write_text(json.dumps({"n_devices": 8, "rc": 0, "ok": True}))
+    new = tmp_path / "gate.json"
+    new.write_text(json.dumps({
+        "n_devices": 8, "ok": True, "matched": 103,
+        "mesh_vs_oracle_wall_ratio": 2.5,
+    }))
+    profile_cli.main(["--bench", str(old), str(new)])
+    assert "multichip mesh-vs-oracle wall ratio: - -> 2.500" in (
+        capsys.readouterr().out
+    )
+    other = tmp_path / "out_line.json"
+    other.write_text(json.dumps({"sqlite_shared": {"ratio": 2.4}}))
+    with pytest.raises(SystemExit) as exc:
+        profile_cli.main(["--bench", str(other), str(tmp_path / "nope")])
+    assert exc.value.code == 2
+    err = capsys.readouterr().err
+    assert "n_devices" in err and len(err.strip().splitlines()) == 1
+
+
 def test_fact_columns_are_row_sharded(dist):
     t = dist.catalog.load("store_sales", ["ss_item_sk"])
     sharding = t.columns["ss_item_sk"].data.sharding

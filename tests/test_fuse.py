@@ -9,8 +9,8 @@ OOM-recovery wipes stay safe, and the chains the fuser must not touch
 (blocked union-aggregation wrappers, shared CTE subtrees, untraceable
 host-side casts) keep their exact prior semantics.
 
-Satellite regressions ride along: Limit-over-Sort top-k gather, the
-MultiJoin join-order replay memo, and the SF10 bench isolation helpers.
+Satellite regressions ride along: Limit-over-Sort top-k gather and the
+MultiJoin join-order replay memo.
 """
 
 import json
@@ -303,27 +303,70 @@ def test_limit_over_sort_topk():
         assert on.sql(q).collect().equals(off.sql(q).collect()), q
 
 
-def test_join_order_replay_memo():
+def _q3_star(n=2000, seed=11):
+    """Fact, date dimension and item of query3's shape, `_table`-sized."""
+    r = np.random.default_rng(seed)
+    n_dates, n_items = 400, 300
+    return {
+        "date_dim": pa.table({
+            "d_date_sk": pa.array(np.arange(n_dates), pa.int32()),
+            "d_year": pa.array(1998 + np.arange(n_dates) // 120, pa.int32()),
+            "d_moy": pa.array(1 + np.arange(n_dates) % 12, pa.int32()),
+        }),
+        "item": pa.table({
+            "i_item_sk": pa.array(np.arange(n_items), pa.int32()),
+            "i_brand_id": pa.array(r.integers(1, 40, n_items), pa.int32()),
+            "i_brand": pa.array([f"brand#{i % 40}" for i in range(n_items)]),
+            "i_manager_id": pa.array(r.integers(1, 20, n_items), pa.int32()),
+        }),
+        "store_sales": pa.table({
+            "ss_sold_date_sk": pa.array(r.integers(0, n_dates, n), pa.int32()),
+            "ss_item_sk": pa.array(r.integers(0, n_items, n), pa.int32()),
+            "ss_ext_sales_price": pa.array(
+                r.uniform(0, 500, n).round(2), pa.float64()
+            ),
+        }),
+    }
+
+
+JOIN_ORDER_QUERIES = {
+    "two_tables": (
+        "select t.k, sum(t.v) s from t, u where t.k = u.k and u.v > 0 "
+        "group by t.k order by t.k"
+    ),
+    "q3_star": (
+        "select d.d_year, i.i_brand_id brand_id, i.i_brand brand, "
+        "sum(ss_ext_sales_price) sum_agg "
+        "from date_dim d, store_sales, item i "
+        "where d.d_date_sk = ss_sold_date_sk and ss_item_sk = i.i_item_sk "
+        "and i.i_manager_id = 10 and d.d_moy = 11 "
+        "group by d.d_year, i.i_brand, i.i_brand_id "
+        "order by d.d_year, sum_agg desc, brand_id limit 100"
+    ),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(JOIN_ORDER_QUERIES))
+def test_join_order_replay_memo(shape):
     on, _ = _sessions()
-    q = ("select t.k, sum(t.v) s from t, u where t.k = u.k and u.v > 0 "
-         "group by t.k order by t.k")
+    for name, t in _q3_star().items():
+        on.register_arrow(name, t)
+    q = JOIN_ORDER_QUERIES[shape]
     a = on.sql(q).collect()
-    assert len(on.join_order_cache) >= 1
-    recorded = [v for v in on.join_order_cache.values() if "steps" in v]
+    assert a.num_rows > 0
+    recorded = {
+        fp: list(v["steps"])
+        for fp, v in on.join_order_cache.items() if "steps" in v
+    }
     assert recorded
     on.conf["engine.plan_cache"] = "off"
     assert on.sql(q).collect().equals(a)  # replayed order, same result
+    # the steady run replayed the memo, it did not record again
+    for fp, steps in recorded.items():
+        assert on.join_order_cache[fp]["steps"] == steps
     # catalog change invalidates the memo
     on.register_arrow("w", _table(100))
     assert on.join_order_cache == {}
-
-
-def test_sf10_isolation_helpers():
-    import bench
-
-    assert bench._last_json_line("junk\n{\"a\": 1}\nnot json") == {"a": 1}
-    assert bench._last_json_line("") is None
-    assert bench._OOM_EXIT_RC == 17
 
 
 def test_input_signature_dictionary_identity():

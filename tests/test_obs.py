@@ -124,7 +124,7 @@ def test_tracer_auto_scopes_query(tmp_path):
 
 
 def test_memory_tracer_collects_in_process():
-    tr = Tracer()  # no dir: in-memory (tools/trace_query.py mode)
+    tr = Tracer()  # no dir: in-memory
     tr.emit("plan_cache", node="Distinct", hit=False)
     assert tr.path is None
     assert [e["kind"] for e in tr.events] == ["plan_cache"]

@@ -17,8 +17,7 @@ paths the old dryrun row caps never exercised.
 
 Artifact: a compact JSON metrics block (the new MULTICHIP round shape) is
 written to --out and printed, with a fail-soft `baseline_compare` against
-the newest stored MULTICHIP_r*.json via the profiler's --bench comparison
-(the same pattern bench.py applies to BENCH_r*.json).
+the newest stored MULTICHIP_r*.json via the profiler's --bench comparison.
 
 Env knobs: NDS_MESH_GATE_DATA (data dir, default /tmp/nds_mesh_gate_sf0.01),
 NDS_MESH_GATE_QUERIES (comma-separated subset, debug aid).
@@ -359,7 +358,6 @@ def main(argv=None) -> int:
     }
 
     # fail-soft round comparison against the newest stored MULTICHIP round
-    # (same contract as bench.py's BENCH_r* baseline_compare)
     try:
         import glob
 

@@ -19,10 +19,10 @@ every template is estimated schema-only against the SF1 AND SF10 TPC-DS
 catalogs, and the two load-bearing calibration points are gated — at SF1
 every statement must be admitted `direct` (SF1 is known to fit 103/103:
 zero false positives), and at SF10 the round-5 per-query map's device-OOM
-set (query5/6/7, BENCH_r05.json) must be flagged over-budget (>= 90%
-coverage). A model change that drifts either way fails CI here, not in a
-bench round. NDS_PLAN_BUDGET_STRICT is set for the whole run, so a
-budgeter crash on any template is a hard failure too.
+set (query5/6/7) must be flagged over-budget (>= 90% coverage). A model
+change that drifts either way fails CI here, not in a run on the chip.
+NDS_PLAN_BUDGET_STRICT is set for the whole run, so a budgeter crash on
+any template is a hard failure too.
 
 Usage:
     python tools/plan_verify_corpus.py [--queries 5,14,93] [--scale 1.0]
@@ -85,8 +85,8 @@ def check_template(sess: Session, qnum: int, scale: float, rngseed: int) -> int:
     return n
 
 
-#: the queries that device-OOM'd in the round-5 SF10 per-query map
-#: (BENCH_r05.json sf10.failed); the budgeter must flag >= 90% of them
+#: the queries that device-OOM'd in the round-5 SF10 per-query map;
+#: the budgeter must flag >= 90% of them
 ROUND5_SF10_OOM = (5, 6, 7)
 
 #: verdicts that carry a PLANNED degradation (statically sized windows /
