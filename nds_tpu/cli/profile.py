@@ -218,14 +218,29 @@ def _render_profile(prof, top: int, per_query: bool):
 def _print_ops(ops):
     """The per-operator table. `cols in>out`: the columns of a Filter's,
     Join's or MultiJoin's inputs and the columns it handed on (the plan's
-    `required`), summed over its executions; `-` for the other nodes."""
+    `required`), summed over its executions; `-` for the other nodes.
+    `left_caps`: the rows a MultiJoin's steps ran their left sides at,
+    summed likewise. Under a query's MultiJoin, one line an order it
+    joined in (relation indices): each step's estimate of the rows it
+    leaves (`-`: none, so the step ranked by its inputs), each step's
+    `left_caps`, and `reordered` where the estimates changed the order."""
     print(f"   {'operator':<18}{'count':>6}{'incl_ms':>12}"
-          f"{'excl_ms':>12}{'rows':>12}{'cols in>out':>14}")
+          f"{'excl_ms':>12}{'rows':>12}{'cols in>out':>14}{'left_caps':>14}")
     for node, op in ops:
         cols = (f"{op['cols_in']}>{op.get('cols_out', 0)}"
                 if "cols_in" in op else "-")
+        caps = (f"{op['left_cap_rows']:,}" if "left_cap_rows" in op else "-")
         print(f"   {node:<18}{op['count']:>6}{op['incl_ms']:>12,.1f}"
-              f"{op['excl_ms']:>12,.1f}{op['rows']:>12,}{cols:>14}")
+              f"{op['excl_ms']:>12,.1f}{op['rows']:>12,}{cols:>14}{caps:>14}")
+        for order, join in sorted((op.get("joins") or {}).items()):
+            est, step_caps = (
+                "/".join("-" if v is None else f"{v:,}"
+                         for v in join.get(k) or ())
+                for k in ("step_est_rows", "left_caps")
+            )
+            print(f"      join_order {order} x{join['count']}  "
+                  f"step_est_rows {est}  left_caps {step_caps}"
+                  + ("  reordered" if join.get("reordered") else ""))
 
 
 def _accuracy_report(events, top: int) -> dict:

@@ -158,6 +158,39 @@ def _plain_col_names(exprs, table):
     return out
 
 
+def _key_stats(expr, table):
+    """The `ColStats` of a join key that is a plain column of `table`
+    (resolved as the evaluator does); None for any other key."""
+    if not isinstance(expr, E.Col):
+        return None
+    (key,) = _plain_col_names([expr], table)
+    c = table.columns.get(key)
+    return None if c is None else c.stats
+
+
+def _est_join_rows(lt, rt, le, re_):
+    """Rows the inner join `lt.le = rt.re_` is estimated to leave, from
+    host integers alone (the sides' live counts, the keys' `ColStats`), or
+    None where they say nothing: a key that is no plain column or has no
+    stats, or neither side `unique`.
+
+    The many side keeps the share of its rows whose key is still live on
+    the unique side. That share is taken over the keys the many side can
+    hold, not over the unique side's base: 366 of date_dim's 73,049 days
+    are a fifth of the 1,823 days store_sales references."""
+    ls, rs = _key_stats(le, lt), _key_stats(re_, rt)
+    if ls is None or rs is None:
+        return None
+    ests = [
+        many_t.nrows * min(1.0, one_t.nrows / max(
+            1, min(one.base_rows, many.vmax - many.vmin + 1)
+        ))
+        for one, one_t, many, many_t in ((ls, lt, rs, rt), (rs, rt, ls, lt))
+        if one.unique
+    ]
+    return min(ests) if ests else None
+
+
 def _active_key_names(key_items, key_cols):
     """Group-by output rows are pairwise distinct over the active (non-
     rolled-up) key columns; probe-style joins read this to skip runtime
@@ -224,6 +257,10 @@ class Executor:
         # budgeter) already decided the whole join can't fit — re-entering
         # the exchange per partition pair could recurse under skew
         self._exchange_disabled = False
+        # what the last `_join_body` ran at and the last `_multijoin_greedy`
+        # did, kept for the MultiJoin's op_span
+        self._left_cap = None
+        self._join_steps = None
         if tracer is None:
             tracer = getattr(
                 getattr(catalog, "session", None), "tracer", None
@@ -393,6 +430,11 @@ class Executor:
                     for c in node.children() if id(c) in self._cte_cache
                 )
                 span["cols_out"] = len(out.columns)
+            if isinstance(node, P.MultiJoin) and self._join_steps:
+                # the order joined (relation indices), each step's estimate
+                # of the rows it leaves, the capacity its left side ran at,
+                # and whether the estimates changed the order
+                span.update(self._join_steps)
             if fp is not None:
                 # budgeter accounting (analysis/feedback.py annotations):
                 # est_rows/est_live_bytes are the STATIC model's numbers,
@@ -1061,6 +1103,7 @@ class Executor:
 
     def _exec_multijoin(self, node: P.MultiJoin) -> Table:
         tables = self._execute_relations_batched(node.relations)
+        self._join_steps = None
         # join-order replay ACROSS statements: the greedy cost scan reads
         # joined-intermediate row counts, which is a blocking device->host
         # sync per join step after the first.
@@ -1069,7 +1112,9 @@ class Executor:
         # literals, so the recorded order stays the right one; any order
         # is correct regardless). The round-5 join-graph optimizer cost
         # q3 one such sync per steady run; test_join_order_replay_memo holds
-        # the replay in place. Unmeasured on an attached chip (ROADMAP A6).
+        # the replay in place. On the chip a replayed query7 still waits
+        # for five `nrows` reads an execution (PERF.md, section 5): the
+        # `_pack_sparse` at the head of each join, not the order's.
         trace = None
         session = getattr(self.catalog, "session", None)
         if (
@@ -1134,8 +1179,14 @@ class Executor:
 
     def _multijoin_greedy(self, current, edges, merged, group, n, trace=None,
                           spill_parts=0, node_fp=None, required=None):
-        # greedy: repeatedly take the connecting edge whose joined inputs are
-        # smallest (sum of live rows), execute that join. When `trace`
+        # greedy: repeatedly take the connecting edge whose join is
+        # estimated to leave the fewest rows (`_est_join_rows`: the dimension
+        # that keeps 1% of a fact table goes before the one that keeps all
+        # of it, however small that one is, and `_pack_sparse` then packs
+        # the fact side once for every later step), execute that join. An
+        # edge without an estimate ranks by the sum of its inputs' live
+        # rows, which also breaks ties, then the edge's index: without
+        # stats that is the whole rule. When `trace`
         # carries recorded steps, replay them instead (identical relation
         # sets join in the same order, and replay never reads .nrows — the
         # blocked union path joins every window with zero count syncs).
@@ -1150,6 +1201,11 @@ class Executor:
 
         replay = trace is not None and "steps" in trace
         steps = trace["steps"] if replay else []
+        # beside the steps, for the node's span: each step's estimate (None:
+        # it had none) and whether any step left the smallest-inputs order
+        ests = trace["step_est_rows"] if replay else []
+        reordered = trace["reordered"] if replay else 0
+        left_caps = []
         step_i = 0
         while True:
             groups = {group(i) for i in range(n)}
@@ -1159,23 +1215,32 @@ class Executor:
                 kind, gi, gj = steps[step_i]
                 step_i += 1
             else:
-                best = None
+                best = smallest = None
                 for k, (i, j, le, re_) in enumerate(edges):
                     gi, gj = group(i), group(j)
                     if gi == gj:
                         continue
                     cost = current[gi].nrows + current[gj].nrows
-                    if best is None or cost < best[0]:
-                        best = (cost, k, gi, gj)
+                    est = _est_join_rows(current[gi], current[gj], le, re_)
+                    rank = (cost if est is None else est, cost, k)
+                    if best is None or rank < best[0]:
+                        best = (rank, gi, gj, est)
+                    if smallest is None or (cost, k) < smallest:
+                        smallest = (cost, k)
                 if best is None:
                     kind, gi, gj = "cross", *sorted(
                         groups, key=lambda g: current[g].nrows
                     )[:2]
+                    ests.append(None)
                 else:
-                    kind, gi, gj = "edge", best[2], best[3]
+                    kind, gi, gj = "edge", best[1], best[2]
+                    ests.append(None if best[3] is None else int(best[3]))
+                    if best[0][1:] != smallest:
+                        reordered = 1
                 steps.append((kind, gi, gj))
             if kind == "cross":
                 # disconnected components: cross join smallest two groups
+                left_caps.append(current[gi].cap)
                 joined = self._join(
                     current[gi], current[gj], "cross", [], [], None,
                     out=read_after(edges),
@@ -1202,6 +1267,7 @@ class Executor:
                 spill_parts=spill_parts, node_fp=node_fp,
                 out=read_after(edges),
             )
+            left_caps.append(self._left_cap)
             merged[gj] = gi
             current[gi] = joined
         if trace is not None and not replay:
@@ -1209,7 +1275,17 @@ class Executor:
             # it from other statements' threads) or a blocked-union
             # context's private memo; both callers guarantee a session
             with self.catalog.session.cache_lock:
+                trace["step_est_rows"] = ests
+                trace["reordered"] = reordered
                 trace["steps"] = steps
+        if self.tracer is not None:
+            order = []
+            for _, gi, gj in steps:
+                order += [g for g in (gi, gj) if g not in order]
+            self._join_steps = dict(
+                join_order=order, step_est_rows=ests, left_caps=left_caps,
+                reordered=reordered,
+            )
         return current[group(0)]
 
     # ------------------------------------------------------------------
@@ -1267,6 +1343,9 @@ class Executor:
             return self._cross_join(left, right)
         left = self._pack_sparse(left)
         right = self._pack_sparse(right)
+        # the capacity this join's probe or sort runs at, for the span of
+        # the MultiJoin that asked for it (`left_caps`)
+        self._left_cap = left.cap
         if kind == "right":
             # swap before any matching so the residual is preserved
             return self._join_body(

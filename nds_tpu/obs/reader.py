@@ -314,6 +314,25 @@ def profile_events(events) -> dict:
             for k in ("cols_in", "cols_out"):
                 if ev.get(k) is not None:
                     op[k] = op.get(k, 0) + int(ev[k])
+            # MultiJoin: the rows its steps' left sides ran at (what a
+            # probe costs by), the executions the estimates reordered
+            if ev.get("join_order") is not None:
+                op["left_cap_rows"] = op.get("left_cap_rows", 0) + sum(
+                    ev.get("left_caps") or ()
+                )
+                op["reordered"] = op.get("reordered", 0) + int(
+                    ev.get("reordered") or 0
+                )
+        if ev.get("join_order") is not None:
+            # relation indices mean something inside one statement only:
+            # the orders themselves stay with the query's own record
+            join = qrec["ops"][node].setdefault("joins", {}).setdefault(
+                ">".join(map(str, ev["join_order"])), {"count": 0}
+            )
+            join["count"] += 1
+            join["step_est_rows"] = ev.get("step_est_rows")
+            join["left_caps"] = ev.get("left_caps")
+            join["reordered"] = int(ev.get("reordered") or 0)
         if ev.get("depth", 0) == 0:
             qrec["root_incl_ms"] += float(ev.get("dur_ms") or 0.0)
     tallies = {
@@ -611,9 +630,12 @@ def _merge_op(dst: dict, src: dict):
     dst["incl_ms"] = dst.get("incl_ms", 0.0) + float(src.get("incl_ms") or 0.0)
     dst["excl_ms"] = dst.get("excl_ms", 0.0) + float(src.get("excl_ms") or 0.0)
     dst["rows"] = dst.get("rows", 0) + int(src.get("rows") or 0)
-    for k in ("cols_in", "cols_out"):
+    for k in ("cols_in", "cols_out", "left_cap_rows", "reordered"):
         if src.get(k) is not None:
             dst[k] = dst.get(k, 0) + int(src[k])
+    for order, join in (src.get("joins") or {}).items():
+        mine = dst.setdefault("joins", {}).setdefault(order, {"count": 0})
+        mine.update(join, count=mine["count"] + int(join.get("count") or 0))
 
 
 def merge_profiles(base: dict, extra: dict) -> dict:

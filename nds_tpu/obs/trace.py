@@ -78,7 +78,11 @@ EVENT_SCHEMA = {
     # Filter / Join / MultiJoin spans (and a Pipeline whose stages are all
     # Filters, no Project and no aggregate: its width is `required`'s doing
     # alone) also carry the optional `cols_in` / `cols_out`: the columns of
-    # the node's inputs and the columns it handed on (plan `required`)
+    # the node's inputs and the columns it handed on (plan `required`).
+    # A MultiJoin's span also carries `join_order` (relation indices in the
+    # order joined), `step_est_rows` (each step's estimate of the rows it
+    # leaves; null: it had none), `left_caps` (the capacity each step's left
+    # side ran at) and `reordered` (1: the estimates changed the order)
     "op_span": ("exec_id", "seq", "depth", "node", "explain", "dur_ms",
                 "rows", "est_bytes"),
     # one per benchmarked query/function (BenchReport.report_on)
