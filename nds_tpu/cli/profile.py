@@ -23,7 +23,8 @@ summary artifacts merge with identical summary semantics.
 exec-lookup / host-python inside the statement's `result_span`, one
 `execute` lump for logs without it; exchange-wait / spill-io /
 catalog-load split into read / encode / h2d / ladder-retry /
-backoff-wait / hung-wait / plan-host — obs/critpath.py), lists per query
+backoff-wait / hung-wait / snapshot-pin / prune-planning / plan-budget /
+plan-host — obs/critpath.py), lists per query
 the launches by kernel, the blocking reads by `why` and the compiles by
 `fun`, and, on mesh traces, names the straggler device and the skew share
 of the exchange gap; `--min_attributed R` exits 1 when any query's attributed share

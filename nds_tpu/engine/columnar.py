@@ -375,6 +375,11 @@ def column_from_arrow(arr: pa.ChunkedArray | pa.Array, dtype: DType, cap: int,
             arr.indices.fill_null(0).to_numpy(zero_copy_only=False), dtype=np.int32
         )
         dictionary = arr.dictionary
+        if len(dictionary) == 0:
+            # no rows (a lakehouse scan whose every file the zone map
+            # pruned) or NULLs alone: one value no row refers to, so that
+            # every lookup by code has something to gather from
+            dictionary = pa.array([""], dictionary.type)
         data = _put(np.ascontiguousarray(codes), h2d)
     else:
         dictionary = None

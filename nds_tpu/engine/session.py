@@ -288,6 +288,13 @@ class Catalog:
         registered in the process-wide reader-lease table so a concurrent
         vacuum can never delete the pinned snapshot's files. Returns the
         pinned version, or None for non-lakehouse names."""
+        pin = self._pin(name, version)
+        return None if pin is None else pin[0]
+
+    def _pin(self, name, version=None):
+        """`pin_lakehouse`'s work, and what it did, for the `lake_pin`
+        span: (pinned version, `moved`: the pin moved and caches were
+        invalidated, `lease`: acquire | renew | held)."""
         e = self.entries.get(name)
         if e is None or e.fmt != "lakehouse":
             return None
@@ -296,7 +303,7 @@ class Catalog:
 
         held = getattr(self._pin_holds, "names", None)
         if version is None and held and name in held:
-            return e.pinned_version
+            return e.pinned_version, False, "held"
         lt = LakehouseTable(e.path, conf=self.session.conf)
         snap = lt.snapshot(version)
         ttl = resolve_lease_ttl(self.session.conf)
@@ -309,12 +316,13 @@ class Catalog:
             # registers locally AND (catalog mode) in the fleet catalog,
             # so a vacuum on another host respects this pin too
             e.lease_id = lt.acquire_reader_lease(snap, ttl)
-        else:
-            if e.pinned_snapshot is None:
-                e.pinned_snapshot = snap
-            if e.lease_id is None or not LEASES.renew(e.lease_id, ttl):
-                e.lease_id = lt.acquire_reader_lease(snap, ttl)
-        return e.pinned_version
+            return e.pinned_version, True, "acquire"
+        if e.pinned_snapshot is None:
+            e.pinned_snapshot = snap
+        if e.lease_id is None or not LEASES.renew(e.lease_id, ttl):
+            e.lease_id = lt.acquire_reader_lease(snap, ttl)
+            return e.pinned_version, False, "acquire"
+        return e.pinned_version, False, "renew"
 
     def hold_pins(self, names):
         """Context manager freezing the named tables' pins for this thread:
@@ -1286,10 +1294,27 @@ class Session:
         for n in P.walk_plan(plan):
             if isinstance(n, P.Scan):
                 if n.table not in pinned:
-                    pinned[n.table] = self.catalog.pin_lakehouse(n.table)
+                    pinned[n.table] = self._pin_scanned(n.table)
                 if pinned[n.table] is not None:
                     n.lake_version = pinned[n.table]
         return plan
+
+    def _pin_scanned(self, table):
+        """One scanned table's pin, as a `lake_pin` span where the table
+        is a lakehouse table: the manifest head resolved, the reader lease
+        acquired or renewed."""
+        t0, t0_ns = _perf(), _time_ns()
+        pin = self.catalog._pin(table)
+        if pin is None:
+            return None
+        version, moved, lease = pin
+        if self.tracer is not None:
+            self.tracer.emit(
+                "lake_pin", table=table, version=version, moved=moved,
+                lease=lease, t0_ns=t0_ns,
+                dur_ms=round((_perf() - t0) * 1000.0, 3),
+            )
+        return version
 
     def _prune_lake_scans(self, plan):
         """Zone-map file pruning: for each Filter directly over a pinned
@@ -1324,7 +1349,7 @@ class Session:
             preds = _zone_preds(n.predicate, scan.alias)
             if not preds:
                 continue
-            t0 = _perf()
+            t0, t0_ns = _perf(), _time_ns()
             keep, pruned_rows = prune_files(snap.rel_files, stats, preds)
             n_total = len(snap.rel_files)
             if len(keep) < n_total:
@@ -1336,7 +1361,7 @@ class Session:
                 self.tracer.emit(
                     "scan_prune", table=scan.table, files_total=n_total,
                     files_pruned=n_total - len(keep),
-                    rows_bound=scan.prune_rows,
+                    rows_bound=scan.prune_rows, t0_ns=t0_ns,
                     dur_ms=round((_perf() - t0) * 1000.0, 3),
                 )
         return plan

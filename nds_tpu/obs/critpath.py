@@ -59,6 +59,10 @@ construction):
     prune-planning  measured `scan_prune` dur_ms (zone-map evaluation
                     at plan time — carved out of what used to be the
                     plan-host residual)
+    snapshot-pin    measured `lake_pin` dur_ms (one a scanned lakehouse
+                    table a statement: the manifest head resolved, the
+                    reader lease acquired or renewed) — carved out of
+                    plan-host the same way
     plan-budget     measured `plan_budget` dur_ms (the static budgeter;
                     on a table's first use it reads the row count from
                     storage metadata, seconds for a partitioned fact
@@ -96,7 +100,8 @@ CAUSE_ORDER = (
     "cache-load", "exec-lookup", "host-python", "exchange-wait", "spill-io",
     "catalog-load", "read", "encode", "h2d", "ladder-retry",
     "backoff-wait", "hung-wait", "ingest-decode", "ingest-commit-wait",
-    "prune-planning", "plan-budget", "router-queue", "router-forward",
+    "snapshot-pin", "prune-planning", "plan-budget", "router-queue",
+    "router-forward",
     "plan-host",
 )
 
@@ -108,7 +113,7 @@ def _group_query_events(events) -> dict:
         kind = ev.get("kind")
         if kind in ("op_span", "query_span", "exchange", "spill",
                     "catalog_load", "ladder_rung", "watchdog_fire",
-                    "kernel_span", "ingest_chunk", "scan_prune",
+                    "kernel_span", "ingest_chunk", "scan_prune", "lake_pin",
                     "route_request", "host_read", "result_span",
                     "xla_compile", "aot_cache", "exec_cache",
                     "plan_budget"):
@@ -309,7 +314,7 @@ def critical_path(events) -> dict:
         cats, exchanges, spills = [], [], []
         exch_ms = skew_ms = spill_ms = cat_ms = 0.0
         ladder_ms = backoff_ms = hung_ms = kernel_ms = 0.0
-        decode_ms = commit_wait_ms = prune_ms = budget_ms = 0.0
+        decode_ms = commit_wait_ms = prune_ms = pin_ms = budget_ms = 0.0
         route_n = 0
         route_dur_ms = route_queue_ms = route_forward_ms = 0.0
         route_status = None
@@ -381,6 +386,8 @@ def critical_path(events) -> dict:
                 commit_wait_ms += float(ev.get("commit_ms") or 0.0)
             elif kind == "scan_prune":
                 prune_ms += float(ev.get("dur_ms") or 0.0)
+            elif kind == "lake_pin":
+                pin_ms += float(ev.get("dur_ms") or 0.0)
             elif kind == "plan_budget":
                 budget_ms += float(ev.get("dur_ms") or 0.0)
             elif kind == "route_request":
@@ -432,7 +439,7 @@ def critical_path(events) -> dict:
         others = (
             execute + sum((split or {}).values())
             + exch_ms + spill_ms + cat_ms + ladder_ms + backoff_ms
-            + decode_ms + commit_wait_ms + prune_ms + budget_ms
+            + decode_ms + commit_wait_ms + prune_ms + pin_ms + budget_ms
             + route_queue_ms + route_forward_ms
         )
         causes = {
@@ -447,6 +454,7 @@ def critical_path(events) -> dict:
             if hung_ms else 0.0,
             "ingest-decode": round(decode_ms, 3),
             "ingest-commit-wait": round(commit_wait_ms, 3),
+            "snapshot-pin": round(pin_ms, 3),
             "prune-planning": round(prune_ms, 3),
             "plan-budget": round(budget_ms, 3),
             "router-queue": round(route_queue_ms, 3),

@@ -205,7 +205,13 @@ EVENT_SCHEMA = {
     # surviving-row upper bound handed to the budgeter (None when
     # nothing pruned)
     "scan_prune": ("table", "files_total", "files_pruned", "rows_bound",
-                   "dur_ms"),
+                   "dur_ms", "t0_ns"),
+    # one scanned lakehouse table's snapshot pin at plan time
+    # (Session._pin_lake_scans, once a table a statement): the manifest
+    # head resolved to `version`; `moved` when the pin moved and the
+    # entry's caches were invalidated; `lease` acquire | renew | held
+    # (held: a DML transaction froze the pin, nothing was resolved)
+    "lake_pin": ("table", "version", "moved", "lease", "dur_ms", "t0_ns"),
     # one fleet-catalog commit arbitration (lakehouse/catalog.py): outcome
     # is ok | conflict | fenced | unreachable | expired (a slow
     # coordinator refusing a publish past the client's deadline) |
