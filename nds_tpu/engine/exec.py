@@ -1528,10 +1528,10 @@ class Executor:
         rnn = K._all_valid([rv[0]], rlive)
         rkey = rk[0].astype(jnp.int64)
         table_cap = bucket_cap(domain)
-        presence, rows = self._dense_build_route(rkey, rnn, rmin, table_cap)
+        rowid1 = self._dense_build_route(rkey, rnn, rmin, table_cap)
         lnn = K._all_valid([lv[0]], llive)
         matched, ri = K.dense_probe(
-            lk[0].astype(jnp.int64), lnn, rmin, presence, rows, table_cap
+            lk[0].astype(jnp.int64), lnn, rmin, rowid1, table_cap
         )
         return self._augment_join_output(
             left, right, kind, matched, ri, llive, residual, mark_name, out
@@ -1567,7 +1567,8 @@ class Executor:
     ):
         """Left-aligned join output for probe-style paths (dense, packed):
         matched rows live in place, the right columns that `out` names
-        gathered alongside — no count sync, no compaction gathers."""
+        gathered alongside by `ri`, which both probes hand over with row 0
+        where unmatched — no count sync, no compaction gathers."""
         if kind in ("semi", "anti", "mark"):
             if kind == "mark":
                 return self._mark_output(left, mark_name, matched)
@@ -1576,7 +1577,6 @@ class Executor:
         left, right = self._sides(
             left, right, self._read_with(out, residual)
         )
-        ri_safe = jnp.where(matched, ri, 0) if right.columns else None
         if kind == "inner":
             # LEFT columns pass through by reference and are DISOWNED: the
             # left table may be a CTE/plan-cache-retained result (e.g. the
@@ -1586,7 +1586,7 @@ class Executor:
             # are fresh buffers owned by this output alone.
             out_cols = {n: c.disowned() for n, c in left.columns.items()}
             out_cols.update(gather_columns(
-                right.columns, ri_safe, stats=Column.gather_stats, owned=True,
+                right.columns, ri, stats=Column.gather_stats, owned=True,
             ))
             pair = Table(
                 out_cols, jnp.sum(matched, dtype=jnp.int32),
@@ -1603,7 +1603,7 @@ class Executor:
         # left join: left-aligned output, unmatched rows null on the right
         out_cols = {n: c.disowned() for n, c in left.columns.items()}
         out_cols.update(gather_columns(
-            right.columns, ri_safe, matched, stats=Column.gather_stats,
+            right.columns, ri, matched, stats=Column.gather_stats,
         ))
         return Table(
             out_cols, left.nrows_lazy, live=left.live,
@@ -2870,8 +2870,9 @@ class Executor:
 
     def _dense_build_route(self, rkey, rnn, rmin, table_cap):
         """Join-candidate build-table promotion (`engine.pallas_join`):
-        `off` — the jnp scatter-max pair; `on` — the Pallas one-hot tile
-        kernel (exact integer maxima, no numeric caveat); `auto` — the
+        `off` — the jnp scatter-max; `on` — the Pallas one-hot tile
+        kernel (exact integer maxima, no numeric caveat; either way one
+        int32 table of row + 1, `kernels.dense_build`); `auto` — the
         same measured per-shape A/B as the aggregate route, recorded as
         `kernel_span` evidence and memoized on `Session.pallas_promotions`
         under key ("dense_build", rows, table_cap)."""

@@ -794,8 +794,8 @@ def _member_probe(rw_sorted, order, lwords, lnn):
     ).astype(jnp.int32)
     # packed words are non-negative, so the -1 dead-left probe never hits
     found = lnn & (rw_sorted[lo] == probe)
-    ri = order[lo]
-    return found, ri
+    # row 0 where unmatched, as `dense_probe` hands it on
+    return found, jnp.where(found, order[lo], 0)
 
 
 @_ktraced("verify_pairs")
@@ -835,30 +835,36 @@ def matched_mask(li, ok, cap):
 @_ktraced("dense_build")
 @partial(jax.jit, static_argnames=("table_cap",))
 def dense_build(rkey, rlive, rmin, table_cap):
-    """Build presence/row-index tables over the key domain
-    [rmin, rmin+table_cap). Out-of-range and dead rows scatter to drop.
-    Build-side uniqueness (needed by inner/left) is the caller's contract,
-    established from catalog ColStats — not re-checked on device."""
+    """Build the lookup table over the key domain [rmin, rmin+table_cap):
+    one int32 an entry, the build row + 1 of the key, 0 where no live row
+    has it, so that one value carries presence and row and a probe reads
+    the table once. Out-of-range and dead rows scatter to drop; of
+    duplicates the highest row stays. Build-side uniqueness (needed by
+    inner/left) is the caller's contract, established from catalog
+    ColStats — not re-checked on device."""
     slot = jnp.where(rlive, rkey.astype(I64) - rmin, jnp.int64(table_cap))
     slot = jnp.where((slot >= 0) & (slot <= table_cap), slot, table_cap)
-    presence = jnp.zeros(table_cap, bool).at[slot].max(rlive, mode="drop")
-    rows = (
+    return (
         jnp.zeros(table_cap, jnp.int32)
         .at[slot]
-        .max(jnp.arange(rkey.shape[0], dtype=jnp.int32), mode="drop")
+        .max(jnp.arange(1, rkey.shape[0] + 1, dtype=jnp.int32), mode="drop")
     )
-    return presence, rows
 
 
 @_ktraced("dense_probe")
 @partial(jax.jit, static_argnames=("table_cap",))
-def dense_probe(lkey, llive, rmin, presence, rows, table_cap):
-    """Per left row: matched flag + matching right row (valid iff matched)."""
+def dense_probe(lkey, llive, rmin, rowid1, table_cap):
+    """Per left row: matched flag + matching right row, row 0 where
+    unmatched, so every row handed back indexes the build side. One gather
+    from `dense_build`'s table: on the v5e a second table read by the same slot
+    cost more than the first (PERF.md, Findings PR 36), and
+    tests/test_kernels.py holds the program to one. The slot stays int64:
+    an int32 slot measured the same."""
     slot = lkey.astype(I64) - rmin
     inb = (slot >= 0) & (slot < table_cap) & llive
-    slot = jnp.clip(slot, 0, table_cap - 1)
-    matched = inb & presence[slot]
-    return matched, rows[slot]
+    r = rowid1[jnp.clip(slot, 0, table_cap - 1)]
+    matched = inb & (r > 0)
+    return matched, jnp.where(matched, r - 1, 0)
 
 
 # ---------------------------------------------------------------------------

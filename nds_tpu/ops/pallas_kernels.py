@@ -238,11 +238,11 @@ def _dense_build_kernel(domain_tile: int, slot_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("table_cap", "interpret"))
 def dense_build_pallas(rkey, rlive, rmin, table_cap: int,
                        interpret: bool = False):
-    """Dense-domain join build tables (presence, row index per key slot) —
-    the Pallas counterpart of `kernels.dense_build`, whose two scatter-max
-    dispatches serialize on TPU exactly like the groupby scatters. Each
-    row tile builds its one-hot slot mask in VMEM and folds presence/row
-    maxima per domain tile; integer maxima, so results are EXACT (same
+    """Dense-domain join build table (row index + 1 per key slot, 0: no
+    row) — the Pallas counterpart of `kernels.dense_build`, whose
+    scatter-max serializes on TPU exactly like the groupby scatters. Each
+    row tile builds its one-hot slot mask in VMEM and folds the row + 1
+    maximum per domain tile; integer maxima, so results are EXACT (same
     contract as dense_build: build-side uniqueness is the caller's — with
     duplicates both formulations keep the max row index). Dead and
     out-of-range rows take slot -1 and never match a domain column."""
@@ -252,10 +252,7 @@ def dense_build_pallas(rkey, rlive, rmin, table_cap: int,
         rlive & (slot >= 0) & (slot < table_cap), slot, jnp.int64(-1)
     ).astype(jnp.int32)
     if n == 0:
-        return (
-            jnp.zeros(table_cap, bool),
-            jnp.zeros(table_cap, jnp.int32),
-        )
+        return jnp.zeros(table_cap, jnp.int32)
     t, n_pad = _row_tiling(n, ROW_TILE)
     gt, g_pad = _group_tiling(table_cap)
     slot = jnp.pad(slot, (0, n_pad - n), constant_values=-1)
@@ -267,8 +264,7 @@ def dense_build_pallas(rkey, rlive, rmin, table_cap: int,
         out_shape=jax.ShapeDtypeStruct((g_pad, 1), jnp.int32),
         interpret=interpret,
     )(slot.reshape(-1, t))
-    rowid1 = out[:table_cap, 0]
-    return rowid1 > 0, jnp.maximum(rowid1 - 1, 0)
+    return out[:table_cap, 0]
 
 
 #: counting-sort routing caps (exec._sort_perm_route gates on them): the

@@ -247,3 +247,70 @@ def test_sort_key_words_preserve_order():
 
         expected = sorted(rows, key=cmp_to_key(cmp))
         assert got == expected, q
+
+
+# One statement a join kind over a dense key, held to a plain Python join of
+# the same rows. dim's row 0 (key 10) passes the filter and is probed: the
+# lookup table tells "row 0" from "no row". fact has null keys, keys below
+# dim's lowest and above its highest, and keys of filtered-out dim rows.
+_DIM_KEYS = list(range(10, 74))
+_DIM_VALS = [(7 * k) % 100 for k in _DIM_KEYS]  # key 10: 70, kept
+_FACT_KEYS = [10, None, 3, 9, 74, 500] + [
+    (11 * i) % 70 + 8 for i in range(250)
+]
+_LIVE = {k: v for k, v in zip(_DIM_KEYS, _DIM_VALS) if v >= 30}
+_FACT = list(enumerate(_FACT_KEYS))
+DENSE_KINDS = {
+    "inner": (
+        "select f_id, d_val from fact, (select * from dim where d_val >= 30) d"
+        " where f_sk = d_sk order by f_id",
+        [(i, _LIVE[k]) for i, k in _FACT if k in _LIVE],
+    ),
+    "left": (
+        "select f_id, d_val from fact left join"
+        " (select * from dim where d_val >= 30) d on f_sk = d_sk order by f_id",
+        [(i, _LIVE.get(k)) for i, k in _FACT],
+    ),
+    "semi": (
+        "select f_id from fact where f_sk in"
+        " (select d_sk from dim where d_val >= 30) order by f_id",
+        [(i,) for i, k in _FACT if k in _LIVE],
+    ),
+    "anti": (
+        "select f_id from fact where not exists"
+        " (select 1 from dim where d_sk = f_sk and d_val >= 30) order by f_id",
+        [(i,) for i, k in _FACT if k not in _LIVE],
+    ),
+    "mark": (
+        "select f_id from fact where f_id < 3 or exists"
+        " (select 1 from dim where d_sk = f_sk and d_val >= 30) order by f_id",
+        [(i,) for i, k in _FACT if i < 3 or k in _LIVE],
+    ),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(DENSE_KINDS))
+def test_dense_join_kinds_against_a_python_join(kind, monkeypatch):
+    s = Session()
+    s.register_arrow("dim", pa.table({
+        "d_sk": pa.array(_DIM_KEYS, pa.int32()),
+        "d_val": pa.array(_DIM_VALS, pa.int64()),
+    }))
+    s.register_arrow("fact", pa.table({
+        "f_id": pa.array(range(len(_FACT_KEYS)), pa.int64()),
+        "f_sk": pa.array(_FACT_KEYS, pa.int32()),
+    }))
+    took = []
+    dense = Executor._try_dense_join
+
+    def recording(self, left, right, join_kind, *a, **kw):
+        out = dense(self, left, right, join_kind, *a, **kw)
+        took.append((join_kind, out is not None))
+        return out
+
+    monkeypatch.setattr(Executor, "_try_dense_join", recording)
+    sql, want = DENSE_KINDS[kind]
+    assert 10 in _LIVE and _FACT_KEYS[0] == 10  # dim's row 0 is probed
+    got = s.sql(sql).collect()
+    assert took == [(kind, True)]
+    assert [tuple(r.values()) for r in got.to_pylist()] == want

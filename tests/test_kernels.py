@@ -3,6 +3,9 @@
 This exceeds the reference's test strategy on purpose (SURVEY.md §4: the
 reference has no unit tests; we unit-test every kernel)."""
 
+import re
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -206,6 +209,97 @@ class TestJoin:
         present = np.asarray(K.matched_mask(li, ok, lcap))
         expect = np.isin(lk, rk[:rn])
         np.testing.assert_array_equal(present[:ln], expect[:ln])
+
+
+class TestDenseJoin:
+    """`dense_build` + `dense_probe`: one int32 table of build row + 1 over
+    the key domain, read once a probe row, against a dictionary lookup."""
+
+    RMIN = 7
+
+    def _probe(self, rkey, rlive, lkey, llive, table_cap):
+        rowid1 = K.dense_build(
+            jnp.asarray(rkey, jnp.int64), jnp.asarray(rlive, bool),
+            self.RMIN, table_cap,
+        )
+        assert rowid1.shape == (table_cap,) and rowid1.dtype == jnp.int32
+        matched, ri = K.dense_probe(
+            jnp.asarray(lkey, jnp.int64), jnp.asarray(llive, bool),
+            self.RMIN, rowid1, table_cap,
+        )
+        return np.asarray(matched), np.asarray(ri)
+
+    def _held_to_a_dictionary(self, rkey, rlive, lkey, llive, table_cap):
+        row_of = {
+            int(k): i for i, (k, live) in enumerate(zip(rkey, rlive))
+            if live and self.RMIN <= k < self.RMIN + table_cap
+        }
+        matched, ri = self._probe(rkey, rlive, lkey, llive, table_cap)
+        want = [live and int(k) in row_of for k, live in zip(lkey, llive)]
+        np.testing.assert_array_equal(matched, want)
+        # the matching row where matched, row 0 where not
+        np.testing.assert_array_equal(
+            ri, [row_of[int(k)] if m else 0 for k, m in zip(lkey, want)]
+        )
+        return matched, ri
+
+    @pytest.mark.parametrize(
+        "n_build, table_cap, n_probe",
+        [(500, 1024, 4096), (64, 64, 512), (300, 128, 512), (3, 1, 64)],
+    )
+    def test_against_a_dictionary(self, n_build, table_cap, n_probe):
+        r = np.random.default_rng(n_build + table_cap)
+        # unique build keys, dead rows among them, and on both sides keys
+        # below rmin and at or above rmin + table_cap
+        pool = np.arange(self.RMIN - table_cap, self.RMIN + 2 * table_cap)
+        rkey = r.permutation(pool)[:n_build]
+        rlive = r.random(n_build) > 0.25
+        lkey = r.integers(self.RMIN - 8, self.RMIN + table_cap + 8, n_probe)
+        llive = r.random(n_probe) > 0.1  # nulls and dead rows alike
+        # build row 0 is live, in range and probed by a live row
+        rkey[rkey == self.RMIN] = rkey[0]
+        rkey[0], rlive[0] = self.RMIN, True
+        lkey[0], llive[0] = self.RMIN, True
+        matched, ri = self._held_to_a_dictionary(
+            rkey, rlive, lkey, llive, table_cap
+        )
+        assert matched[0] and ri[0] == 0
+        assert matched.any() and not matched.all()
+
+    def test_a_match_on_build_row_0_is_not_an_absent_key(self):
+        # both read row 0; only `matched` may tell them apart
+        matched, ri = self._probe([9, 8], [True, True], [9, 10, 8], [True] * 3, 4)
+        assert matched.tolist() == [True, False, True]
+        assert ri.tolist() == [0, 0, 1]
+
+    @pytest.mark.parametrize("n_build", [0, 16])
+    def test_a_build_side_with_no_live_row_matches_nothing(self, n_build):
+        rkey = np.arange(self.RMIN, self.RMIN + n_build)
+        lkey = np.arange(self.RMIN - 2, self.RMIN + 30)
+        matched, ri = self._held_to_a_dictionary(
+            rkey, np.zeros(n_build, bool), lkey, np.ones(len(lkey), bool), 32
+        )
+        assert not matched.any() and not ri.any()
+
+    def test_of_duplicate_build_keys_the_highest_row_stays(self):
+        matched, ri = self._probe(
+            [8, 9, 8, 8, 9], [True, True, True, False, True], [8, 9], [True] * 2, 8
+        )
+        assert matched.all() and ri.tolist() == [2, 4]
+
+    def test_a_probe_is_one_gather(self):
+        """On the v5e a second table gathered by the same slot cost more
+        than the first (PERF.md, Findings PR 36): a probe that reads two
+        tables again fails here, not on the chip."""
+        n, table_cap = 4096, 1024
+        hlo = K.dense_probe.__wrapped__.lower(
+            jax.ShapeDtypeStruct((n,), jnp.int64),
+            jax.ShapeDtypeStruct((n,), jnp.bool_),
+            self.RMIN,
+            jax.ShapeDtypeStruct((table_cap,), jnp.int32),
+            table_cap=table_cap,
+        ).compile().as_text()
+        assert len(re.findall(r"\bgather\(", hlo)) == 1, hlo
 
 
 class TestWindow:

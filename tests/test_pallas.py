@@ -130,20 +130,18 @@ def test_dense_build_pallas_matches_jnp(n, table_cap):
     # unique keys (the dense path's caller contract), some out of range
     keys = rng.permutation(6 * max(table_cap, 64))[:n].astype(np.int64) + rmin - 8
     live = rng.random(n) > 0.2
-    presence_j, rows_j = K.dense_build(
+    rowid1_j = K.dense_build(
         jnp.asarray(keys), jnp.asarray(live), rmin, table_cap
     )
-    presence_p, rows_p = dense_build_pallas(
+    rowid1_p = dense_build_pallas(
         jnp.asarray(keys), jnp.asarray(live), rmin, table_cap,
         interpret=True,
     )
-    np.testing.assert_array_equal(np.asarray(presence_j),
-                                  np.asarray(presence_p))
-    # row indices only meaningful where present
-    pj = np.asarray(presence_j)
-    np.testing.assert_array_equal(
-        np.asarray(rows_j)[pj], np.asarray(rows_p)[pj]
-    )
+    # one table of row + 1, 0 where the key has no live row
+    assert rowid1_p.dtype == rowid1_j.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(rowid1_j), np.asarray(rowid1_p))
+    in_range = live & (keys >= rmin) & (keys < rmin + table_cap)
+    assert int((np.asarray(rowid1_j) > 0).sum()) == int(in_range.sum())
 
 
 def test_pallas_join_wired_through_sql():
