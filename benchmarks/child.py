@@ -65,6 +65,8 @@ def parse_args(argv):
     ap.add_argument("--scale", type=float, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=[0, 1], required=True)
+    ap.add_argument("--trace_cycle", type=int,
+                    help="the cycle whose first passes a traced run records")
     ap.add_argument("--pass_only", action="store_true",
                     help="the first pass alone: no rehearsal, no window")
     return ap.parse_args(argv)
@@ -98,26 +100,26 @@ class Spans:
 
 
 class TraceSlice:
-    """The profiler, recording `passes` whole passes from the first pass
-    that starts after two fifths of the window. Tracing slows the host, so
-    it is a slice of a run of its own, never the run the end-to-end metrics
-    come from."""
+    """The profiler, recording the first `passes` whole passes of cycle
+    `cycle` of the window: a place in the traffic, not a time. The cycle's
+    order is `lib.window_order(traffic, seed, cycle)`, so two runs on one
+    seed trace the same statements in the same order however fast either
+    is, and the slice always holds the cycle's first pass, whose query93 no
+    cached answer serves. Tracing slows the host, so it is a slice of a run
+    of its own, never the run the end-to-end metrics come from."""
 
-    def __init__(self, wanted, directory, passes, spans, marks):
+    def __init__(self, wanted, directory, cycle, passes, spans, marks):
         self.state = "before" if wanted else "never"
-        self.directory, self.passes_left = directory, passes
+        self.directory, self.cycle, self.passes = directory, cycle, passes
+        self.passes_left = passes
         self.spans, self.marks = spans, marks
+        #: [stream, statement] of every statement run while it recorded
+        self.statements = []
 
-    def before_pass(self, share_of_window):
-        import jax
-
-        if self.state == "before" and share_of_window >= 0.4:
-            opts = jax.profiler.ProfileOptions()
-            # the engine's host code is Python: tracing every call would
-            # slow what is measured
-            opts.python_tracer_level = 0
-            opts.host_tracer_level = 2
-            jax.profiler.start_trace(self.directory, profiler_options=opts)
+    def before_pass(self, cycle, nth):
+        """Called as pass `nth` of cycle `cycle` is about to start."""
+        if self.state == "before" and (cycle, nth) == (self.cycle, 0):
+            self.start_profiler()
             self.marks["slice_start"] = time.time() * 1e3
             self.state = "on"
             self.spans.traced = True
@@ -126,15 +128,83 @@ class TraceSlice:
             if self.passes_left <= 0:
                 self.stop()
 
-    def stop(self):
-        import jax
+    def ran(self, stream, name):
+        if self.state == "on":
+            self.statements.append([stream, name])
 
+    def stop(self):
         if self.state == "on":
             self.spans.close_between()
             self.spans.traced = False
-            jax.profiler.stop_trace()
+            self.stop_profiler()
             self.marks["slice_end"] = time.time() * 1e3
             self.state = "done"
+
+    def start_profiler(self):
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        # the engine's host code is Python: tracing every call would slow
+        # what is measured
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(self.directory, profiler_options=opts)
+
+    def stop_profiler(self):
+        import jax
+
+        jax.profiler.stop_trace()
+
+    def record(self, wanted):
+        """What was traced, for the result line, and why it is no slice:
+        (`slice`, error). `wanted` is `lib.slice_statements(...)`: a window
+        that ends before the slice's cycle starts, or inside the slice, is
+        an error of the traced run, never a slice somewhere else."""
+        record = {"cycle": self.cycle, "passes": self.passes,
+                  "statements": self.statements}
+        if self.statements == wanted:
+            return record, None
+        if not self.statements:
+            return record, (f"the window ended before cycle {self.cycle} "
+                            f"started: no slice was traced")
+        return record, (
+            f"the window ended inside the traced slice: "
+            f"{len(self.statements)} of the {len(wanted)} statements of "
+            f"cycle {self.cycle}'s first {self.passes} passes ran")
+
+
+def run_window(traffic, streams, seed, seconds, tracing, one, new_cycle,
+               clock=time.perf_counter):
+    """The window: the mix's streams cycle after cycle, each cycle in the
+    order `lib.window_order` draws from the seed, until `seconds` have
+    passed on `clock`. No statement starts after the deadline; the one in
+    flight finishes. `new_cycle(cycle)` runs before each cycle,
+    `one(stream, name, sql, t_open, first)` runs one statement and returns
+    its record (`first`: it is of the window's first pass, whose answers
+    are compared). Returns (records, cycles begun, clock at the last
+    statement's end, clock at the opening)."""
+    statements = []
+    t_open = clock()
+    deadline = t_open + seconds
+    t_last = t_open
+    cycle = 0
+    while clock() < deadline:
+        new_cycle(cycle)
+        for nth, si in enumerate(lib.window_order(traffic, seed, cycle)):
+            if clock() >= deadline:
+                break
+            tracing.before_pass(cycle, nth)
+            for name, sql in streams[si]:
+                if clock() >= deadline:
+                    break
+                rec = one(si, name, sql, t_open, cycle == 0 and nth == 0)
+                rec["cycle"] = cycle
+                statements.append(rec)
+                tracing.ran(si, name)
+                t_last = clock()
+        cycle += 1
+    tracing.stop()
+    return statements, cycle, t_last, t_open
 
 
 def run_statement(session, sql, name, spans, rec, keep):
@@ -265,15 +335,18 @@ def main(argv=None):
 
     aot = getattr(session, "aot_cache", None)
     spans = Spans(False)
+    keep = {}
 
-    def one(si, name, sql, t_origin, keep=None):
-        """One statement as the Power loop runs one, timed on this clock."""
+    def one(si, name, sql, t_origin, first=False):
+        """One statement as the Power loop runs one, timed on this clock;
+        `first`: of the window's first pass, whose answers are kept."""
         t0 = time.perf_counter()
         rec = {"stream": si, "name": name, "start_s": t0 - t_origin}
         misses0 = session.exec_cache.misses
         aot0 = dict(aot.stats) if aot is not None else None
         summary = BenchReport(session).report_on(
-            run_statement, session, sql, name, spans, rec, keep,
+            run_statement, session, sql, name, spans, rec,
+            keep if first else None,
             retry_oom=True, name=name,
         )
         rec["ms"] = (time.perf_counter() - t0) * 1e3
@@ -310,42 +383,32 @@ def main(argv=None):
     import pyarrow as pa
 
     profile_dir = f"{rd}/profile"
-    tracing = TraceSlice(args.trace, profile_dir,
+    tracing = TraceSlice(args.trace, profile_dir, args.trace_cycle,
                          int(traffic.get("trace_passes", 2)), spans,
                          out["marks"])
-    statements = []
-    keep = {}
     compared_stream = lib.window_order(traffic, args.seed, 0)[0]
+
+    def new_cycle(cycle):
+        # a catalog registration drops the plan-result cache (and the
+        # join-order memo), by the engine's own rule: without it a cycle
+        # would be served from the answers of the one before
+        session.register_arrow(
+            "benchmark_cycle", pa.table({"cycle": [cycle]}))
+
     out["marks"]["window_open"] = time.time() * 1e3
-    t_open = time.perf_counter()
-    deadline = t_open + args.seconds
-    t_last = t_open
-    cycle = 0
     with obs_trace.bind(session.tracer):
-        while time.perf_counter() < deadline:
-            # a catalog registration drops the plan-result cache (and the
-            # join-order memo), by the engine's own rule: without it a
-            # cycle would be served from the answers of the one before
-            session.register_arrow(
-                "benchmark_cycle", pa.table({"cycle": [cycle]}))
-            for si in lib.window_order(traffic, args.seed, cycle):
-                tracing.before_pass(
-                    (time.perf_counter() - t_open) / args.seconds)
-                for name, sql in streams[si]:
-                    if time.perf_counter() >= deadline:
-                        break
-                    first_of = cycle == 0 and si == compared_stream
-                    statements.append(one(si, name, sql, t_open,
-                                          keep if first_of else None))
-                    statements[-1]["cycle"] = cycle
-                    t_last = time.perf_counter()
-            cycle += 1
-    tracing.stop()
+        statements, cycle, t_last, t_open = run_window(
+            traffic, streams, args.seed, args.seconds, tracing, one,
+            new_cycle)
     spans.close_between()
     out["marks"]["window_close"] = time.time() * 1e3
     out["window_s"] = t_last - t_open
     out["compared_stream"] = compared_stream
     out["statements"] = statements
+    if args.trace:
+        out["slice"], out["slice_error"] = tracing.record(
+            lib.slice_statements(traffic, streams, args.seed,
+                                 tracing.cycle, tracing.passes))
     out["counters"]["window_close"] = counters(session, watch)
     print(f"child: window {out['window_s']:.3f} s, "
           f"{len(statements)} statements, {cycle} cycles", flush=True)

@@ -167,12 +167,13 @@ def make_streams(traffic, scale, first, count):
     statements in a seeded permutation with other parameters, as the
     streams of a TPC-DS Throughput Run do.
 
-    The statements do not depend on `--seed`: that seeds the data and the
-    order of the window's passes (`window_order`). The engine compiles an
-    executable for every new literal, so statements drawn from the run's
-    seed would make every seed's first run compile all through its window
-    and its second run none of it; with one set of statements every seed
-    does the same work, on other data and in another order.
+    The statements do not depend on `--seed`: that draws the order of the
+    window's passes (`window_order`) and nothing else. The engine compiles
+    an executable for every new literal and every data-decided capacity, so
+    statements or data drawn from the run's seed would give every seed
+    other compiles and other work; with one set of statements over the
+    configuration's one database (`data_seed`) every seed does the same
+    work, in another order.
     """
     import numpy as np
 
@@ -214,6 +215,16 @@ def window_order(traffic, seed, cycle):
 
     rng = np.random.default_rng(np.random.SeedSequence([seed, cycle]))
     return [int(i) + 1 for i in rng.permutation(traffic["window_passes"])]
+
+
+def slice_statements(traffic, streams, seed, cycle, passes):
+    """`[stream, statement name]` of what a traced run records, in order:
+    the first `passes` passes of cycle `cycle` of the window. A function of
+    the mix and the seed alone: both sides of a pair trace the same
+    statements, with the same parameters."""
+    return [[si, name]
+            for si in window_order(traffic, seed, cycle)[:passes]
+            for name, _ in streams[si]]
 
 
 # -- arithmetic --------------------------------------------------------------

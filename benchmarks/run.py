@@ -11,13 +11,20 @@ This parent never imports jax (it checks): a parent that has touched jax
 holds the chip. It reads `BENCHMARK.json`, the cell's configuration and its
 traffic mix by name, and runs every phase as a child:
 
-    gen_data    the seeded raw data          } kept under benchmarks/.cache/
-    Load        the warehouse, in the          data/<scale>-<seed>-<hash of
-                configuration's format       } what makes them>/, so a seed's
-    reference   sqlite's answers (beside       second run finds them; written
-                Load, never beside a pass)   } to a temporary name, renamed
+    gen_data    the raw data, from the       } kept under benchmarks/.cache/
+                configuration's `data_seed`    data/<scale>-<data_seed>-<hash
+    Load        the warehouse, in the          of what makes them>/, so only a
+                configuration's format       } checkout's first run makes
+    reference   sqlite's answers (beside       them; written to a temporary
+                Load, never beside a pass)   } name, renamed
     pass child  the first pass alone, in a fresh process (child.py --pass_only)
     chip child  first pass + rehearsal + window (child.py)
+
+`--seed` draws the order in which the window replays its passes and
+nothing else: the database is the configuration's (`data_seed`, as the
+parameters are the mix's `param_seed`), because the engine's shapes, and so
+its compiles and its work, are decided by the data: every seed does the
+same work on the same database, in another order.
 
 and then judges the run: fail, never fall back. Both chip children go
 through ./nds-tpu-submit, one after the other, and nothing else of the
@@ -26,6 +33,10 @@ first passes.
 
     --scale 0.01     a CPU rehearsal: runs to the end, then fails on the
                      platform check and prints no result line
+    --trace_cycle 0  a traced rehearsal whose few seconds of window never
+                     reach the traffic mix's own `trace_cycle`
+    --data_seed N    another database than the configuration's: the control
+                     on three seeds, and whether the data changes the work
     --control float32   runs no chip child: the comparison's control, the
                      reference with float32 aggregation put in the
                      program's place, against the sound reference
@@ -50,8 +61,8 @@ sys.path.insert(0, REPO)
 from benchmarks import compare, lib  # noqa: E402
 from benchmarks.lib import BenchmarkError  # noqa: E402
 
-#: seeds whose data is kept; a full check's two sets of six share seeds
-KEEP_SEEDS = 8
+#: data directories kept (one a scale, data seed and generator)
+KEEP_DATA = 8
 #: a run must end inside the driver's 1200 s for a first run
 DEADLINE_S = 1150
 #: what makes the raw data and a warehouse: a change to any of it must not
@@ -134,6 +145,13 @@ class Run:
         self.traffic = spec.traffic(self.cell)
         self.spec = spec
         self.scale = a.scale if a.scale is not None else self.config["scale_factor"]
+        self.trace_cycle = (a.trace_cycle if a.trace_cycle is not None
+                            else self.traffic.get("trace_cycle"))
+        if a.trace and self.trace_cycle is None:
+            raise BenchmarkError(
+                f"the traffic mix {self.cell['traffic']!r} names no "
+                f"`trace_cycle`: a traced run opens its slice at that "
+                f"cycle's first pass")
         if not os.path.isfile(os.path.join(REPO, "nds-tpu-submit")):
             raise BenchmarkError("no nds-tpu-submit beside benchmarks/: the "
                                  "benchmark drives the repository it sits in")
@@ -143,9 +161,16 @@ class Run:
         shutil.rmtree(self.run_dir, ignore_errors=True)
         self.logs = os.path.join(self.run_dir, "logs")
         os.makedirs(self.logs)
+        if "data_seed" not in self.config:
+            raise BenchmarkError(
+                f"the configuration {self.cell['config']!r} names no "
+                f"`data_seed`: the database is the configuration's, not "
+                f"the run's")
+        self.data_seed = (a.data_seed if a.data_seed is not None
+                          else int(self.config["data_seed"]))
         key = lib.tree_hash(DATA_SOURCES)
-        self.data = os.path.join(cache, "data",
-                                 f"sf{self.scale}-{a.seed}-{key}")
+        self.data = os.path.join(
+            cache, "data", f"sf{self.scale}-{self.data_seed}-{key}")
         os.makedirs(self.data, exist_ok=True)
         os.utime(self.data)
         self.evict()
@@ -156,7 +181,8 @@ class Run:
         self.compiled = os.path.join(cache, "compiled")
         os.makedirs(self.compiled, exist_ok=True)
         n = sum(len(files) for _, _, files in os.walk(self.compiled))
-        print(f"cell {a.workload} seed {a.seed} scale {self.scale} "
+        print(f"cell {a.workload} seed {a.seed} (the window's order) data "
+              f"seed {self.data_seed} scale {self.scale} "
               f"trace {a.trace}; compile cache {self.compiled} ({n} files); "
               f"data {self.data}", flush=True)
 
@@ -164,7 +190,7 @@ class Run:
         root = os.path.dirname(self.data)
         dirs = sorted((os.path.join(root, d) for d in os.listdir(root)),
                       key=os.path.getmtime, reverse=True)
-        for d in dirs[KEEP_SEEDS:]:
+        for d in dirs[KEEP_DATA:]:
             shutil.rmtree(d, ignore_errors=True)
 
     def ensure_raw(self):
@@ -175,15 +201,16 @@ class Run:
             self.wait(self.spawn("gen_data", [
                 sys.executable, "-m", "nds_tpu.cli.gen_data", "local",
                 "--scale", self.scale, "--parallel", 4,
-                "--seed", self.args.seed, "--data_dir", tmp,
+                "--seed", self.data_seed, "--data_dir", tmp,
                 "--overwrite_output",
             ]))
             os.rename(tmp, raw)
         return raw
 
     def statements(self, control=None):
-        """What the reference answers, {key: stream entry}: stream 0 (the
-        first pass) and the stream the window replays first. The control
+        """What the reference answers, {key: stream entry}: every stream of
+        the mix, once a database, so that whichever stream a seed's window
+        replays first (`compared_keys`) its answers are found. The control
         answers stream 0's statements that the mix lists for it: its float32
         SUM and AVG are Python callbacks, which query1's correlated subquery
         calls for minutes."""
@@ -193,9 +220,15 @@ class Run:
             stream0 = dict(streams[0])
             return {f"s0/{t}": stream0[t]
                     for t in self.traffic["control_templates"]}
-        compared = lib.window_order(self.traffic, self.args.seed, 0)[0]
         return {f"s{si}/{name}": sql
-                for si in (0, compared) for name, sql in streams[si]}
+                for si, stream in enumerate(streams) for name, sql in stream}
+
+    def compared_keys(self, statements):
+        """The answers a run is held to: stream 0's (both first passes) and
+        those of the stream this seed's window replays first."""
+        compared = lib.window_order(self.traffic, self.args.seed, 0)[0]
+        return [k for k in statements
+                if k.split("/")[0] in ("s0", f"s{compared}")]
 
     def start_reference(self, raw, statements, control=None):
         """sqlite's answers to `statements`: found, or children started
@@ -279,6 +312,8 @@ class Run:
             "--seed", a.seed, "--scale", self.scale,
             "--seconds", a.seconds if measured else 0,
             "--trace", a.trace if measured else 0,
+            *(["--trace_cycle", self.trace_cycle]
+              if a.trace and measured else []),
             *(["--pass_only"] if name == "pass" else []),
         ], env=env)
         child.run_dir = run_dir
@@ -326,7 +361,7 @@ class Run:
                     if line.startswith("child:")))
             children[name] = load_child(child.run_dir)
         return self.judge(children["chip"], children["pass"], ref,
-                          list(statements))
+                          self.compared_keys(statements))
 
     def control(self, raw, ref, ref_children):
         """The control's answers in the program's place: must not pass."""
@@ -339,7 +374,7 @@ class Run:
         ok, numbers = compare.verdict(per, self.config["correct_limits"])
         for key, p in per.items():
             print(f"control {key}: {json.dumps(p)}")
-        print(f"control {self.args.control} seed {self.args.seed}: "
+        print(f"control {self.args.control} data seed {self.data_seed}: "
               f"correct={ok} {json.dumps(numbers)}")
         return 0 if not ok else 4
 
@@ -476,20 +511,33 @@ class Run:
             print(f"compared {name}: {n['value']!r} limit {n['limit']!r}",
                   file=sys.stderr)
         sys.stderr.flush()
+        more = {"first_passes_s": [child["first_pass_a_s"],
+                                   child["first_pass_b_s"]]}
+        if self.args.trace:
+            # what was traced: a pair is held to equal slices
+            more["slice"] = child["slice"]
         print(lib.result_line(
             not faults, attempted, failed, metrics, device_out, numbers,
-            breakdown, first_passes_s=[child["first_pass_a_s"],
-                                       child["first_pass_b_s"]]))
+            breakdown, **more))
         return 0
 
     def traced(self, child, device_out):
         """The per-layer metrics and the breakdown of a traced run."""
+        if child["slice_error"]:
+            raise BenchmarkError(child["slice_error"])
         if "device_trace" not in child:
             raise BenchmarkError(f"no device trace: {child.get('trace_error')}")
         trace = child["device_trace"]
         child["events"] = lib.read_events(os.path.join(self.run_dir, "trace"))
         device_out["busy_s"] = trace["busy_s"]
         device_out["window_s"] = trace["window_s"]
+        marks, sl = child["marks"], child["slice"]
+        print(f"traced slice: cycle {sl['cycle']}, {sl['passes']} passes, "
+              f"{len(sl['statements'])} statements, from "
+              f"{(marks['slice_start'] - marks['window_open']) / 1e3:.3f} s "
+              f"to {(marks['slice_end'] - marks['window_open']) / 1e3:.3f} s "
+              f"of a window of {child['window_s']:.3f} s: "
+              + " ".join(f"{si}/{name}" for si, name in sl["statements"]))
         print(f"traced slice: {trace['window_s']:.3f} s, busy "
               f"{trace['busy_s']:.3f} s, idle share "
               f"{1 - trace['busy_s'] / trace['window_s']:.4f}, "
@@ -508,6 +556,13 @@ def main(argv=None):
     ap.add_argument("--trace", type=int, choices=[0, 1], default=0)
     ap.add_argument("--scale", type=float,
                     help="override the configuration's scale: CPU rehearsal")
+    ap.add_argument("--trace_cycle", type=int,
+                    help="override the traffic mix's `trace_cycle`, the "
+                    "cycle whose first passes are traced: CPU rehearsal")
+    ap.add_argument("--data_seed", type=int,
+                    help="override the configuration's `data_seed`: the "
+                    "control on other databases, and the question whether "
+                    "the data changes the work")
     ap.add_argument("--control", choices=["float32"])
     ap.add_argument("--cache_dir", default=os.path.join(HERE, ".cache"),
                     help="where data, answers and run directories are kept "

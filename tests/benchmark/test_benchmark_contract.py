@@ -138,7 +138,8 @@ RUN = {
 
 @pytest.mark.parametrize("metric,want", [
     ("first_pass_s", 52.122), ("new_stmt_ms", 600.0), ("replay_qps", 1.25),
-    ("stmt_p50_ms", 870.0), ("query7_p50_ms", 880.0), ("setup_s", 90.0)])
+    ("stmt_p50_ms", 870.0), ("query7_p50_ms", 880.0), ("setup_s", 90.0),
+    ("query7_lake_p50_ms", 880.0)])
 def test_end_to_end_reader_reads_what_it_says(metric, want):
     """Every end-to-end metric has a reader of its own, found by its name:
     renaming or adding one edits no line of the harness. A statement that
@@ -165,8 +166,25 @@ def test_every_end_to_end_metric_has_a_reader():
     spec = lib.Spec(REPO)
     for m in DOC["end_to_end"]:
         assert callable(spec.reader("end_to_end", m["name"]).read)
-    got = spec.read_metrics(DOC["workloads"][0], "end_to_end", RUN)
-    assert list(got) == [m["name"] for m in DOC["end_to_end"]]
+    got = {cell["name"]: list(spec.read_metrics(cell, "end_to_end", RUN))
+           for cell in DOC["workloads"]}
+    shared = ["first_pass_s", "new_stmt_ms", "replay_qps", "stmt_p50_ms"]
+    # query7's median under a bound of each cell's own: device work alone
+    # over parquet, the pruned read beside it over the lakehouse
+    assert got == {
+        "sf1-parquet.replay6": [*shared, "query7_p50_ms", "setup_s"],
+        "sf1-lakehouse.replay6": [*shared, "setup_s", "query7_lake_p50_ms"]}
+
+
+@pytest.mark.parametrize("name", ["query7_p50_ms", "query7_lake_p50_ms"])
+def test_a_window_without_query7_has_no_median_of_it(name):
+    """Nothing to read, not 0: the run then reports no such metric and,
+    untraced, no result line."""
+    run = {"statements": [s for s in RUN["statements"]
+                          if s["name"] != "query7"]}
+    assert lib.Spec(REPO).reader("end_to_end", name).read(run) is None
+    assert lib.Spec(REPO).reader("end_to_end", name).read(
+        {"statements": []}) is None
 
 
 @pytest.mark.parametrize("cell", DOC["workloads"], ids=lambda w: w["name"])

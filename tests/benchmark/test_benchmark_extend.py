@@ -9,7 +9,10 @@ import shutil
 
 import pytest
 
+import doc_rules
 from benchmarks import lib
+
+NEW_CELL = "sf1-parquet-floats.light3"
 
 
 @pytest.fixture()
@@ -53,10 +56,16 @@ def grown(tmp_path):
     doc["workloads"].append({
         "name": "sf1-parquet-floats.light3", "chips": 1, "why": "x",
         "config": "sf1-parquet-floats-1chip", "traffic": "light3"})
+    # the new cell's name after the two that are there, on the list of
+    # every accepted per-layer metric it wants read in it, and a 26th
+    # entry of its own after the last
+    for m in doc["per_layer"][:20]:
+        m["workloads"].append(NEW_CELL)
+    assert len(doc["per_layer"]) == 25
     doc["per_layer"].append({
         "name": "execute_ms.stmt", "unit": "ms", "better": "lower",
         "source": "host_clock", "layer": "executor + fused pipelines",
-        "moves": "stmt_p50_ms", "workloads": ["sf1-parquet-floats.light3"]})
+        "moves": "stmt_p50_ms", "workloads": [NEW_CELL]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
     yield lib.Spec(str(tmp_path))
     for path, data in before.items():
@@ -69,7 +78,7 @@ def test_a_new_cell_is_found_by_name_alone(grown):
     assert grown.config(cell)["decimals"] is False
     assert grown.traffic(cell)["templates"] == ["query96", "query3", "query36"]
     names = [m["name"] for m in grown.metrics_of(cell, "per_layer")]
-    assert names == ["execute_ms.stmt"]
+    assert names == doc_rules.FIRST_TWENTY + ["execute_ms.stmt"]
     reader = grown.reader("per_layer", "execute_ms.stmt")
     assert reader.read({"statements": [{"execute_ms": 2.0},
                                        {"execute_ms": 4.0}]}) == 3.0
@@ -84,6 +93,60 @@ def test_a_new_cell_is_found_by_name_alone(grown):
     old = grown.cell("sf1-parquet.replay6")
     assert "execute_ms.stmt" not in [
         m["name"] for m in grown.metrics_of(old, "per_layer")]
+
+
+def test_a_document_that_only_grew_keeps_every_rule(grown):
+    """What a later PR does (a 26th per-layer entry appended, a third
+    cell's name appended to the lists) passes every rule the tests hold the
+    per-layer list to, entry by entry and list by list."""
+    assert len(grown.doc["per_layer"]) == 26
+    assert doc_rules.faults(grown) == []
+    for index in range(26):
+        assert doc_rules.entry_fault(grown, index) is None
+    for name in doc_rules.LISTED:
+        assert doc_rules.workloads_fault(grown, name) is None
+    assert doc_rules.faults(lib.Spec(lib.REPO)) == []
+
+
+def _insert_before_the_last_of_the_twenty(doc):
+    doc["per_layer"].insert(19, doc["per_layer"].pop())
+
+
+def _swap_two_of_the_twenty(doc):
+    per = doc["per_layer"]
+    per[3], per[8] = per[8], per[3]
+
+
+def _put_the_new_cell_first(doc):
+    doc["per_layer"][2]["workloads"].insert(0, NEW_CELL)
+
+
+def _list_a_parquet_cell_on_a_storage_metric(doc):
+    doc["per_layer"][20]["workloads"].append(NEW_CELL)
+
+
+def _list_the_lakehouse_cell_under_the_parquet_bound(doc):
+    e2e = {m["name"]: m for m in doc["end_to_end"]}
+    e2e["query7_p50_ms"]["workloads"].append(doc_rules.LAKE)
+
+
+def _give_an_entry_another_unit_than_its_reader(doc):
+    doc["per_layer"][-1]["unit"] = "s"
+
+
+def _name_an_entry_twice(doc):
+    doc["per_layer"].append(dict(doc["per_layer"][-1]))
+
+
+@pytest.mark.parametrize("edit", [
+    _insert_before_the_last_of_the_twenty, _swap_two_of_the_twenty,
+    _put_the_new_cell_first, _list_a_parquet_cell_on_a_storage_metric,
+    _list_the_lakehouse_cell_under_the_parquet_bound,
+    _give_an_entry_another_unit_than_its_reader, _name_an_entry_twice],
+    ids=lambda f: f.__name__.strip("_"))
+def test_an_insertion_a_move_or_a_wrong_list_breaks_a_rule(grown, edit):
+    edit(grown.doc)
+    assert doc_rules.faults(grown), edit.__name__
 
 
 def test_what_is_not_there_is_an_error(grown):

@@ -44,10 +44,15 @@ def test_feedback_io_reader_needs_a_window():
 
 
 def test_feedback_io_reader_declares_what_an_entry_will_carry():
-    """`BENCHMARK.json` has no entry for it yet (PERF.md, Open questions):
-    the four values a `per_layer` entry is held equal to."""
+    """The four values its `per_layer` entry (appended in PR 37, both
+    cells) is held equal to."""
     reader = lib.Spec(lib.REPO).reader("per_layer", "feedback_io_ms.stmt")
     assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == (
         "executor + fused pipelines", "ms", "stmt_p50_ms", "program_span")
-    layers = {m["layer"] for m in lib.Spec(lib.REPO).doc["per_layer"]}
-    assert reader.LAYER in layers
+    entry, = [m for m in lib.Spec(lib.REPO).doc["per_layer"]
+              if m["name"] == "feedback_io_ms.stmt"]
+    assert (entry["layer"], entry["unit"], entry["moves"], entry["source"],
+            entry["better"]) == (reader.LAYER, reader.UNIT, reader.MOVES,
+                                 reader.SOURCE, "lower")
+    assert entry["workloads"] == ["sf1-parquet.replay6",
+                                  "sf1-lakehouse.replay6"]

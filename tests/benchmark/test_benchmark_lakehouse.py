@@ -2,11 +2,12 @@
 `files_pruned_share.stmt`, `lake_pin_ms.stmt`) over hand-made runs: the
 value worked out by hand, and nothing where the program wrote no such event
 (the parent commit has no `lake_pin`; a parquet session prunes nothing).
-Their entries, and `feedback_io_ms.stmt`'s, wait for the `benchmark` PR that
-re-anchors `test_the_new_metrics_are_appended_entries` (an entry appended to
-`per_layer` fails it): each reader declares what its entry will say. And the
-cell itself, once, on the CPU at SF0.01: it ends `correct` against the
-reference, with pruning at work and every table read at one pinned version."""
+Their entries, and `feedback_io_ms.stmt`'s, were appended in PR 37 (the
+readers shipped ahead of them in PRs 30 and 35): each reader declares what
+its entry says, and the rules of `doc_rules.py` say which cells may list
+them. And the cell itself, once, on the CPU at SF0.01: it ends `correct`
+against the reference, with pruning at work, every table read at one pinned
+version and all five in its result line."""
 
 import json
 import os
@@ -14,13 +15,14 @@ import sys
 
 import pytest
 
+import doc_rules
 from benchmarks import lib, run as bench_run
 from test_benchmark_run import DRIVER, _run
 
 CELL = "sf1-lakehouse.replay6"
 NEW = ("scan_reads.stmt", "scan_ms.stmt", "files_pruned_share.stmt",
        "lake_pin_ms.stmt")
-#: readers that ship ahead of their `per_layer` entries
+#: the readers that shipped ahead of their `per_layer` entries
 WAITING = NEW + ("feedback_io_ms.stmt",)
 
 
@@ -121,13 +123,16 @@ def test_scan_ms_says_what_one_span_holds():
         assert part in doc, part
 
 
-def test_every_accepted_metric_is_read_in_the_new_cell_too():
-    doc = lib.Spec(lib.REPO).doc
-    for m in doc["per_layer"]:
-        assert m["workloads"] == ["sf1-parquet.replay6", CELL], m["name"]
-    # query7's median stays the parquet cell's alone
-    e2e = {m["name"]: m for m in doc["end_to_end"]}
-    assert e2e["query7_p50_ms"]["workloads"] == ["sf1-parquet.replay6"]
+@pytest.mark.parametrize("name", doc_rules.LISTED)
+def test_every_accepted_metric_is_read_in_the_new_cell_too(name):
+    """Each of the first twenty per-layer lists begins with the two replay6
+    cells (a later cell appends its name); the four storage metrics list
+    lakehouse cells and never the parquet cell; query7's median under the
+    parquet cell's bound stays the parquet cell's alone."""
+    assert doc_rules.workloads_fault(lib.Spec(lib.REPO), name) is None
+
+
+def test_the_cell_is_the_lakehouse_configuration_under_replay6():
     cell = lib.Spec(lib.REPO).cell(CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "sf1-lakehouse-1chip", "replay6", 1)
@@ -136,8 +141,7 @@ def test_every_accepted_metric_is_read_in_the_new_cell_too():
 @pytest.mark.parametrize("name", WAITING)
 def test_a_waiting_reader_declares_its_entry(name):
     """What the reader declares is a well-formed entry of a layer and an
-    end-to-end metric the benchmark names; once the entry is there, it says
-    the same."""
+    end-to-end metric the benchmark names, and its entry says the same."""
     spec = lib.Spec(lib.REPO)
     reader = spec.reader("per_layer", name)
     assert lib.UNIT_RE.match(reader.UNIT)
@@ -146,12 +150,12 @@ def test_a_waiting_reader_declares_its_entry(name):
     assert reader.LAYER in {m["layer"] for m in spec.doc["per_layer"]}
     if name in NEW:
         assert reader.LAYER == "session + catalog"
-    for entry in spec.doc["per_layer"]:
-        if entry["name"] == name:
-            assert (entry["layer"], entry["unit"], entry["moves"],
-                    entry["source"]) == (reader.LAYER, reader.UNIT,
-                                         reader.MOVES, reader.SOURCE)
-            assert CELL in entry["workloads"]
+    entry, = [m for m in spec.doc["per_layer"] if m["name"] == name]
+    assert (entry["layer"], entry["unit"], entry["moves"],
+            entry["source"]) == (reader.LAYER, reader.UNIT,
+                                 reader.MOVES, reader.SOURCE)
+    assert CELL in entry["workloads"]
+    assert ("sf1-parquet.replay6" in entry["workloads"]) == (name not in NEW)
 
 
 def test_the_configuration_states_the_deployment():
@@ -180,8 +184,9 @@ def test_the_cell_rehearsed_on_the_cpu_is_correct_and_prunes(tmp_path):
     driver = tmp_path / "driver.py"
     driver.write_text(DRIVER.format(repo=lib.REPO))
     p = _run([sys.executable, str(driver), "--workload", CELL,
-              "--seed", "2147483659", "--seconds", "2", "--trace", "1",
-              "--scale", "0.01"], str(tmp_path / "cache"))
+              "--seed", "2147483659", "--seconds", "4", "--trace", "1",
+              "--scale", "0.01", "--trace_cycle", "0"],
+             str(tmp_path / "cache"))
     out = p.stdout.strip().splitlines()
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-2000:]
     line = json.loads(out[-1])
@@ -192,16 +197,21 @@ def test_the_cell_rehearsed_on_the_cpu_is_correct_and_prunes(tmp_path):
     spec = lib.Spec(lib.REPO)
     assert sorted(metrics) == sorted(m["name"] for m in spec.doc["per_layer"])
     assert metrics["compiles.window"] == 0
-    # the readers that wait for their entries, over the program's own trace
+    # the five that waited for their entries are in the line now, read by
+    # the harness over the program's own trace
+    assert metrics["files_pruned_share.stmt"] > 0
+    assert metrics["scan_reads.stmt"] > 0 and metrics["scan_ms.stmt"] > 0
+    assert metrics["lake_pin_ms.stmt"] > 0
+    assert metrics["feedback_io_ms.stmt"] == 0.0
+    # the slice is the first two passes of the cycle the command named
+    traffic = spec.traffic(spec.cell(CELL))
+    assert line["slice"] == {
+        "cycle": 0, "passes": 2, "statements": lib.slice_statements(
+            traffic, lib.make_streams(traffic, 0.01, 0, 7), 2147483659, 0, 2)}
     run_dir = os.path.join(str(tmp_path / "cache"), "runs",
                            f"{CELL}-2147483659-t1")
     child = bench_run.load_child(run_dir)
     child["events"] = lib.read_events(os.path.join(run_dir, "trace"))
-    read = {n: spec.reader("per_layer", n).read(child) for n in WAITING}
-    assert read["files_pruned_share.stmt"] > 0
-    assert read["scan_reads.stmt"] > 0 and read["scan_ms.stmt"] > 0
-    assert read["lake_pin_ms.stmt"] > 0
-    assert read["feedback_io_ms.stmt"] == 0.0
     # guarantee (b) as far as a read-only run shows it: a process pins each
     # table at one version, and only its first pin of a table moves
     pins = {}

@@ -119,7 +119,7 @@ def test_the_rest_of_a_run_reports_the_contracts_line(cache, driver):
     p = _run([sys.executable, driver, *ARGS], cache)
     out = p.stdout.strip().splitlines()
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-2000:]
-    if cached:  # the test above ran in this process and left the seed's data
+    if cached:  # the test above ran in this process and left the database
         assert not any(line.startswith(("phase gen_data", "phase load",
                                         "phase reference")) for line in out)
     line = json.loads(out[-1])
@@ -162,15 +162,28 @@ def test_the_rest_of_a_traced_run_reports_the_per_layer_metrics(cache, driver):
     over them, and the line carries the device's busy seconds and the
     breakdown. The measured child's first pass is the one the `.first`
     metrics read; the pass-only child runs untraced."""
-    args = [a if a != "0" else "1" for a in ARGS]
+    # cycle 0: the first twelve statements of the window, which three
+    # seconds hold on a loaded host too (cycle 1 needs 48 of them)
+    args = [a if a != "0" else "1" for a in ARGS] + ["--trace_cycle", "0"]
     assert args[args.index("--trace") + 1] == "1"
     p = _run([sys.executable, driver, *args], cache)
     out = p.stdout.strip().splitlines()
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-2000:]
     line = json.loads(out[-1])
     assert tuple(line) == lib.RESULT_KEYS + (
-        "breakdown", "first_passes_s", "compared")
+        "breakdown", "first_passes_s", "slice", "compared")
     assert line["correct"] is True, p.stdout[-3000:]
+    # what was traced: the first two passes of the cycle the command named
+    # (the mix's own `trace_cycle` is out of reach of three seconds), in
+    # the order the seed gives that cycle
+    traffic = lib.Spec(lib.REPO).traffic(lib.Spec(lib.REPO).cell(CELL))
+    assert traffic["trace_cycle"] == 2 and traffic["trace_passes"] == 2
+    wanted = lib.slice_statements(
+        traffic, lib.make_streams(traffic, 0.01, 0, 7), 2147483659, 0, 2)
+    assert len(wanted) == 12
+    assert line["slice"] == {"cycle": 0, "passes": 2, "statements": wanted}
+    assert any(said.startswith(
+        "traced slice: cycle 0, 2 passes, 12 statements") for said in out)
     assert line["device"]["busy_s"] == 1.5 and line["device"]["window_s"] == 2.0
     spec = lib.Spec(lib.REPO)
     want = {m["name"] for m in spec.metrics_of(spec.cell(CELL), "per_layer")}
@@ -221,6 +234,7 @@ def test_without_the_program_there_is_no_result(tmp_path):
 def _args(cache):
     return argparse.Namespace(workload=CELL, seed=2147483659, seconds=3.0,
                               trace=0, scale=0.01, control=None,
+                              trace_cycle=None, data_seed=None,
                               cache_dir=str(cache))
 
 
@@ -280,12 +294,94 @@ def test_no_chip_child_is_spawned_while_a_reference_child_lives(tmp_path):
     assert run.beside_a_chip_child == []
     assert run.judged == ({"who": "chip", "pass_only": False},
                           {"who": "pass", "pass_only": True})
-    # a seed's second run finds data, warehouse, answers and warm caches:
+    # a checkout's second run finds data, warehouse, answers and warm caches:
     # the two timed children and nothing else
     again = Recorded(_args(tmp_path))
     assert again.run() == 0
     assert again.order == ["spawn pass", "wait pass", "spawn chip",
                            "wait chip"]
+
+
+@pytest.mark.parametrize("seed", [1, 12345, 2147483659, 2**31 + 12345])
+def test_every_seed_runs_over_the_configurations_database(tmp_path, seed):
+    """The database is the configuration's (`data_seed`), never the run's:
+    the generator is handed that seed whatever `--seed` says, and once one
+    seed's run has made data, warehouse and answers, a run on another seed
+    finds them all. `--seed` reaches the chip children alone, where it
+    draws the order of the window's passes."""
+    data_seed = lib.Spec(lib.REPO).config(
+        lib.Spec(lib.REPO).cell(CELL))["data_seed"]
+    spawned = {}
+
+    def keeping_commands(run):
+        real = run.spawn
+
+        def spawn(name, cmd, env=None):
+            child = real(name, cmd, env)
+            spawned[name] = child.cmd
+            return child
+
+        run.spawn = spawn
+        return run
+
+    first = keeping_commands(Recorded(_args(tmp_path)))
+    assert first.run() == 0
+    gen = spawned["gen_data"]
+    assert gen[gen.index("--seed") + 1] == str(data_seed) != "2147483659"
+    assert os.path.basename(first.data).startswith(f"sf0.01-{data_seed}-")
+    args = _args(tmp_path)
+    args.seed = seed
+    other = keeping_commands(Recorded(args))
+    assert other.run() == 0
+    assert other.data == first.data
+    assert other.order == ["spawn pass", "wait pass", "spawn chip",
+                           "wait chip"]
+    chip = spawned["chip"]
+    assert chip[chip.index("--seed") + 1] == str(seed)
+    # only the command line's own option names another database
+    args.data_seed = 77
+    elsewhere = keeping_commands(Recorded(args))
+    assert elsewhere.run() == 0
+    assert os.path.basename(elsewhere.data).startswith("sf0.01-77-")
+    assert spawned["gen_data"][spawned["gen_data"].index("--seed") + 1] == "77"
+
+
+@pytest.mark.parametrize("seed", [3, 2147483659, 2**31 + 12345])
+def test_the_reference_answers_every_stream_and_a_run_compares_two(
+        tmp_path, seed):
+    """sqlite answers every stream of the mix once a database, so whichever
+    stream a seed's window replays first is found; a run is held to stream
+    0's answers and that stream's."""
+    args = _args(tmp_path)
+    args.seed = seed
+    run = Recorded(args)
+    run.prepare()
+    asked = run.statements()
+    passes = run.traffic["window_passes"]
+    assert len(asked) == 6 * (1 + passes)
+    assert {k.split("/")[0] for k in asked} == {
+        f"s{i}" for i in range(1 + passes)}
+    first = lib.window_order(run.traffic, seed, 0)[0]
+    keys = run.compared_keys(asked)
+    assert len(keys) == 12
+    assert {k.split("/")[0] for k in keys} == {"s0", f"s{first}"}
+    # asked of the reference is the same whatever the seed: one directory
+    other = Recorded(_args(tmp_path))
+    other.prepare()
+    assert other.statements() == asked
+
+
+def test_a_configuration_without_a_data_seed_cannot_run(tmp_path, monkeypatch):
+    real = lib.Spec.config
+
+    def config(self, cell):
+        out = dict(real(self, cell))
+        del out["data_seed"]
+        return out
+
+    monkeypatch.setattr(lib.Spec, "config", config)
+    with pytest.raises(lib.BenchmarkError, match="names no `data_seed`"):
+        Recorded(_args(tmp_path)).prepare()
 
 
 def test_the_gate_is_gone_from_the_tree():

@@ -5,6 +5,7 @@ program from before these spans, as the parent commit is)."""
 
 import pytest
 
+import doc_rules
 from benchmarks import lib
 
 S = 1_000_000_000  # ns in a second; the run's clock starts at t = 1000 s
@@ -174,11 +175,16 @@ def test_fresh_compile_readers_over_a_first_pass_recorded_on_the_chip():
         {**run, "rehearsal": [{"ms": 1.0}]}) is None
 
 
-def test_the_new_metrics_are_appended_entries():
-    doc = lib.Spec(lib.REPO).doc
-    assert [m["name"] for m in doc["per_layer"]][-12:] == [
-        "launches.stmt", "host_reads.stmt", "read_wait_ms.stmt",
-        "exec_host_ms.stmt", "table_read_s.first", "h2d_s.first",
-        "jit_trace_s.first", "xla_load_s.first", "unspanned_s.first",
-        "fresh_compiles.rehearsal", "fresh_compiles.first",
-        "fresh_compile_s.first"]
+@pytest.mark.parametrize(
+    "index", range(len(lib.Spec(lib.REPO).doc["per_layer"])))
+def test_the_new_metrics_are_appended_entries(index):
+    """The twenty entries PR 28 left are the first twenty, each in its
+    place; every entry after them has a name of its own and a reader that
+    declares what it says. Appending is free, inserting and moving are not."""
+    assert doc_rules.entry_fault(lib.Spec(lib.REPO), index) is None
+
+
+def test_the_first_twenty_are_all_there():
+    names = [m["name"] for m in lib.Spec(lib.REPO).doc["per_layer"]]
+    assert names[:20] == doc_rules.FIRST_TWENTY
+    assert len(set(names)) == len(names)
