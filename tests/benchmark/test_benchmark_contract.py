@@ -162,18 +162,34 @@ def test_first_pass_is_the_lower_of_two_and_nothing_of_one(a, b, want):
     assert lib.Spec(REPO).reader("end_to_end", "first_pass_s").read(run) == want
 
 
+SHARED = ["first_pass_s", "new_stmt_ms", "replay_qps", "stmt_p50_ms"]
+
+
 def test_every_end_to_end_metric_has_a_reader():
     spec = lib.Spec(REPO)
     for m in DOC["end_to_end"]:
         assert callable(spec.reader("end_to_end", m["name"]).read)
-    got = {cell["name"]: list(spec.read_metrics(cell, "end_to_end", RUN))
-           for cell in DOC["workloads"]}
-    shared = ["first_pass_s", "new_stmt_ms", "replay_qps", "stmt_p50_ms"]
+    got = {name: list(spec.read_metrics(spec.cell(name), "end_to_end", RUN))
+           for name in ("sf1-parquet.replay6", "sf1-lakehouse.replay6")}
     # query7's median under a bound of each cell's own: device work alone
     # over parquet, the pruned read beside it over the lakehouse
-    assert got == {
-        "sf1-parquet.replay6": [*shared, "query7_p50_ms", "setup_s"],
-        "sf1-lakehouse.replay6": [*shared, "setup_s", "query7_lake_p50_ms"]}
+    assert got["sf1-parquet.replay6"] == [*SHARED, "query7_p50_ms", "setup_s"]
+    assert got["sf1-lakehouse.replay6"] == [
+        *SHARED, "setup_s", "query7_lake_p50_ms"]
+
+
+@pytest.mark.parametrize("cell", DOC["workloads"], ids=lambda w: w["name"])
+def test_every_cell_reports_the_shared_metrics_and_its_own(cell):
+    """Whatever cells a later PR adds: each reports the four shared metrics
+    and `setup_s`, and beyond them only metrics whose `workloads` list
+    names it."""
+    e2e = {m["name"]: m for m in DOC["end_to_end"]}
+    mine = [m["name"] for m in lib.Spec(REPO).metrics_of(cell, "end_to_end")]
+    assert [n for n in mine if n in SHARED] == SHARED and "setup_s" in mine
+    for name in set(mine) - {*SHARED, "setup_s"}:
+        assert cell["name"] in e2e[name]["workloads"], name
+    for name in [*SHARED, "setup_s"]:
+        assert "workloads" not in e2e[name], name
 
 
 @pytest.mark.parametrize("name", ["query7_p50_ms", "query7_lake_p50_ms"])

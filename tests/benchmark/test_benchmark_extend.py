@@ -1,18 +1,29 @@
 """The harness is driven by data: a later PR adds a cell, a configuration, a
 traffic mix and a per-layer metric as NEW files and entries, and edits no
-file that is there. Here a copy of the benchmark gets one of each, and the
-harness finds them by name."""
+file that is there. Here a copy of the benchmark is grown by the one recipe
+(`grow.py`: two cells, two configurations, two mixes, a metric of each
+group), and the harness finds them by name. Every count is taken from the
+real document: it grows too."""
 
-import json
 import os
 import shutil
 
 import pytest
 
 import doc_rules
+import grow
 from benchmarks import lib
 
-NEW_CELL = "sf1-parquet-floats.light3"
+LISTS = ("configs", "workloads", "end_to_end", "per_layer")
+
+
+def _files(top):
+    found = {}
+    for d, _, files in os.walk(top):
+        for f in files:
+            with open(os.path.join(d, f), "rb") as fh:
+                found[os.path.join(d, f)] = fh.read()
+    return found
 
 
 @pytest.fixture()
@@ -20,132 +31,125 @@ def grown(tmp_path):
     shutil.copytree(
         os.path.join(lib.REPO, "benchmarks"), tmp_path / "benchmarks",
         ignore=shutil.ignore_patterns(".cache", "__pycache__", "testdata"))
-    doc = lib.load_json(os.path.join(lib.REPO, "BENCHMARK.json"))
-    before = {}
-    for d, _, files in os.walk(tmp_path / "benchmarks"):
-        for f in files:
-            with open(os.path.join(d, f), "rb") as fh:
-                before[os.path.join(d, f)] = fh.read()
-    bench = tmp_path / "benchmarks"
-    config = lib.load_json(bench / "configs" / "sf1-parquet-1chip.json")
-    config.update(name="sf1-parquet-floats-1chip", decimals=False)
-    (bench / "configs" / "sf1-parquet-floats-1chip.json").write_text(
-        json.dumps(config))
-    (bench / "traffic" / "light3.json").write_text(json.dumps({
-        "templates": ["query96", "query3", "query36"],
-        "order": "tpcds_stream_permutation", "loop": "closed", "clients": 1,
-        "param_seed": 11, "window_passes": 3,
-        "control_templates": ["query3"]}))
-    (bench / "layer_metrics" / "execute_ms.stmt.py").write_text(
-        'LAYER = "executor + fused pipelines"\nUNIT = "ms"\n'
-        'MOVES = "stmt_p50_ms"\nSOURCE = "host_clock"\n\n\n'
-        "def read(run):\n"
-        "    ms = [s['execute_ms'] for s in run['statements']]\n"
-        "    return sum(ms) / len(ms) if ms else None\n")
-    (bench / "end_to_end" / "query36_p50_ms.py").write_text(
-        'from benchmarks import lib\n\nUNIT = "ms"\nSOURCE = "host_clock"\n\n\n'
-        "def read(run):\n"
-        "    return lib.window_percentile(run, 50, 'query36')\n")
-    doc["end_to_end"].append({
-        "name": "query36_p50_ms", "unit": "ms", "better": "lower",
-        "bound": 0.05, "source": "host_clock",
-        "workloads": ["sf1-parquet-floats.light3"]})
-    doc["configs"].append({
-        "name": "sf1-parquet-floats-1chip", "source": "x", "reduced": [],
-        "file": "benchmarks/configs/sf1-parquet-floats-1chip.json", "why": "x"})
-    doc["workloads"].append({
-        "name": "sf1-parquet-floats.light3", "chips": 1, "why": "x",
-        "config": "sf1-parquet-floats-1chip", "traffic": "light3"})
-    # the new cell's name after the two that are there, on the list of
-    # every accepted per-layer metric it wants read in it, and a 26th
-    # entry of its own after the last
-    for m in doc["per_layer"][:20]:
-        m["workloads"].append(NEW_CELL)
-    assert len(doc["per_layer"]) == 25
-    doc["per_layer"].append({
-        "name": "execute_ms.stmt", "unit": "ms", "better": "lower",
-        "source": "host_clock", "layer": "executor + fused pipelines",
-        "moves": "stmt_p50_ms", "workloads": [NEW_CELL]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
-    yield lib.Spec(str(tmp_path))
+    shutil.copy(os.path.join(lib.REPO, "BENCHMARK.json"), tmp_path)
+    before = _files(tmp_path / "benchmarks")
+    new = grow.grow(str(tmp_path))
+    spec = lib.Spec(str(tmp_path))
+    spec.new = new  # the names the recipe gave what it added
+    yield spec
+    after = _files(tmp_path / "benchmarks")
     for path, data in before.items():
-        with open(path, "rb") as fh:
-            assert fh.read() == data, f"{path} was edited"
+        assert after[path] == data, f"{path} was edited"
 
 
 def test_a_new_cell_is_found_by_name_alone(grown):
-    cell = grown.cell("sf1-parquet-floats.light3")
+    new = grown.new
+    cell = grown.cell(new.parquet_cell)
     assert grown.config(cell)["decimals"] is False
-    assert grown.traffic(cell)["templates"] == ["query96", "query3", "query36"]
+    assert grown.traffic(cell)["templates"] == grow.PARQUET_TEMPLATES
     names = [m["name"] for m in grown.metrics_of(cell, "per_layer")]
-    assert names == doc_rules.FIRST_TWENTY + ["execute_ms.stmt"]
-    reader = grown.reader("per_layer", "execute_ms.stmt")
+    assert names == doc_rules.BOTH_FIRST
+    # the lakehouse cell: other tables, another count of other templates,
+    # the storage metrics too, and a metric of each group that is its alone
+    lake = grown.cell(new.lake_cell)
+    accepted = grown.config(grown.cell(doc_rules.LAKE))
+    assert set(grown.config(lake)["tables"]) - set(accepted["tables"]) == {
+        "catalog_sales", "web_sales", "inventory"}
+    assert grown.traffic(lake)["templates"] == grow.LAKE_TEMPLATES
+    names = [m["name"] for m in grown.metrics_of(lake, "per_layer")]
+    assert set(names) == {*doc_rules.BOTH_FIRST, *doc_rules.STORAGE,
+                          new.per_layer}
+    assert names[-1] == new.per_layer
+    reader = grown.reader("per_layer", new.per_layer)
     assert reader.read({"statements": [{"execute_ms": 2.0},
                                        {"execute_ms": 4.0}]}) == 3.0
     assert reader.read({"statements": []}) is None
     # an end-to-end metric of its own, and none that names another cell
-    e2e = [m["name"] for m in grown.metrics_of(cell, "end_to_end")]
-    assert "query36_p50_ms" in e2e and "query7_p50_ms" not in e2e
-    run = {"statements": [{"name": "query36", "status": "Completed", "ms": m}
+    e2e = [m["name"] for m in grown.metrics_of(lake, "end_to_end")]
+    assert new.end_to_end in e2e and "query7_p50_ms" not in e2e \
+        and "query7_lake_p50_ms" not in e2e
+    run = {"statements": [{"name": "query22", "status": "Completed", "ms": m}
                           for m in (700.0, 1100.0, 900.0)]}
-    assert grown.reader("end_to_end", "query36_p50_ms").read(run) == 900.0
+    assert grown.reader("end_to_end", new.end_to_end).read(run) == 900.0
     # and the cells that were there are untouched by it
-    old = grown.cell("sf1-parquet.replay6")
-    assert "execute_ms.stmt" not in [
-        m["name"] for m in grown.metrics_of(old, "per_layer")]
+    for name in (doc_rules.PARQUET, doc_rules.LAKE, new.parquet_cell):
+        old = grown.cell(name)
+        assert new.per_layer not in [
+            m["name"] for m in grown.metrics_of(old, "per_layer")]
+        assert new.end_to_end not in [
+            m["name"] for m in grown.metrics_of(old, "end_to_end")]
 
 
 def test_a_document_that_only_grew_keeps_every_rule(grown):
-    """What a later PR does (a 26th per-layer entry appended, a third
-    cell's name appended to the lists) passes every rule the tests hold the
-    per-layer list to, entry by entry and list by list."""
-    assert len(grown.doc["per_layer"]) == 26
+    """What a later PR does (a per-layer entry appended after the last, new
+    cells' names appended to the lists) passes every rule the tests hold the
+    per-layer list to, entry by entry and list by list; and every entry the
+    real document has is where it was, its lists longer at the end alone."""
+    real = lib.Spec(lib.REPO)
+    n = len(real.doc["per_layer"])
+    assert len(grown.doc["per_layer"]) == n + 1
     assert doc_rules.faults(grown) == []
-    for index in range(26):
+    for index in range(n + 1):
         assert doc_rules.entry_fault(grown, index) is None
     for name in doc_rules.LISTED:
         assert doc_rules.workloads_fault(grown, name) is None
-    assert doc_rules.faults(lib.Spec(lib.REPO)) == []
+    assert doc_rules.faults(real) == []
+    for group in LISTS:
+        for was, now in zip(real.doc[group], grown.doc[group]):
+            cells = was.get("workloads", [])
+            assert now.get("workloads", [])[:len(cells)] == cells, was["name"]
+            assert {**now, "workloads": cells} == {**was, "workloads": cells}
+        assert len(grown.doc[group]) > len(real.doc[group]), group
 
 
-def _insert_before_the_last_of_the_twenty(doc):
+def _insert_before_the_last_of_the_twenty(doc, new):
     doc["per_layer"].insert(19, doc["per_layer"].pop())
 
 
-def _swap_two_of_the_twenty(doc):
+def _swap_two_of_the_twenty(doc, new):
     per = doc["per_layer"]
     per[3], per[8] = per[8], per[3]
 
 
-def _put_the_new_cell_first(doc):
-    doc["per_layer"][2]["workloads"].insert(0, NEW_CELL)
+def _put_the_new_cell_first(doc, new):
+    doc["per_layer"][2]["workloads"].insert(0, new.parquet_cell)
 
 
-def _list_a_parquet_cell_on_a_storage_metric(doc):
-    doc["per_layer"][20]["workloads"].append(NEW_CELL)
+def _cells_of(doc, name):
+    entry, = [m for m in doc["per_layer"] if m["name"] == name]
+    return entry["workloads"]
 
 
-def _list_the_lakehouse_cell_under_the_parquet_bound(doc):
+def _list_a_parquet_cell_on_a_storage_metric(doc, new):
+    _cells_of(doc, doc_rules.STORAGE[0]).append(new.parquet_cell)
+
+
+def _put_a_third_cell_before_the_second_on_feedback_io(doc, new):
+    _cells_of(doc, "feedback_io_ms.stmt").insert(1, new.parquet_cell)
+
+
+def _list_the_lakehouse_cell_under_the_parquet_bound(doc, new):
     e2e = {m["name"]: m for m in doc["end_to_end"]}
     e2e["query7_p50_ms"]["workloads"].append(doc_rules.LAKE)
 
 
-def _give_an_entry_another_unit_than_its_reader(doc):
+def _give_an_entry_another_unit_than_its_reader(doc, new):
     doc["per_layer"][-1]["unit"] = "s"
 
 
-def _name_an_entry_twice(doc):
+def _name_an_entry_twice(doc, new):
     doc["per_layer"].append(dict(doc["per_layer"][-1]))
 
 
 @pytest.mark.parametrize("edit", [
     _insert_before_the_last_of_the_twenty, _swap_two_of_the_twenty,
     _put_the_new_cell_first, _list_a_parquet_cell_on_a_storage_metric,
+    _put_a_third_cell_before_the_second_on_feedback_io,
     _list_the_lakehouse_cell_under_the_parquet_bound,
     _give_an_entry_another_unit_than_its_reader, _name_an_entry_twice],
     ids=lambda f: f.__name__.strip("_"))
 def test_an_insertion_a_move_or_a_wrong_list_breaks_a_rule(grown, edit):
-    edit(grown.doc)
+    edit(grown.doc, grown.new)
     assert doc_rules.faults(grown), edit.__name__
 
 
@@ -159,7 +163,7 @@ def test_what_is_not_there_is_an_error(grown):
 
 
 def test_the_new_mix_goes_through_the_one_generator(grown):
-    cell = grown.cell("sf1-parquet-floats.light3")
+    cell = grown.cell(grown.new.parquet_cell)
     traffic = grown.traffic(cell)
     a = lib.make_streams(traffic, 1, 0, 4)
     assert a == lib.make_streams(traffic, 1, 0, 4)
@@ -175,7 +179,7 @@ def test_the_new_mix_goes_through_the_one_generator(grown):
 
 
 def test_the_seed_orders_the_passes_and_changes_no_statement(grown):
-    traffic = grown.traffic(grown.cell("sf1-parquet-floats.light3"))
+    traffic = grown.traffic(grown.cell(grown.new.parquet_cell))
     orders = {tuple(lib.window_order(traffic, seed, cycle))
               for seed in (7, 2147483659, 2**31 + 12345) for cycle in range(4)}
     assert all(sorted(o) == [1, 2, 3] for o in orders)

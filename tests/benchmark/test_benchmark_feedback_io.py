@@ -4,6 +4,7 @@ it does and none ended inside the window, the quotient where one did."""
 
 import pytest
 
+import doc_rules
 from benchmarks import lib
 
 
@@ -44,8 +45,9 @@ def test_feedback_io_reader_needs_a_window():
 
 
 def test_feedback_io_reader_declares_what_an_entry_will_carry():
-    """The four values its `per_layer` entry (appended in PR 37, both
-    cells) is held equal to."""
+    """The four values its `per_layer` entry (appended in PR 37) is held
+    equal to; its list begins with the two `replay6` cells, and a later
+    cell that wants its feedback flushes read appends its name."""
     reader = lib.Spec(lib.REPO).reader("per_layer", "feedback_io_ms.stmt")
     assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == (
         "executor + fused pipelines", "ms", "stmt_p50_ms", "program_span")
@@ -54,5 +56,5 @@ def test_feedback_io_reader_declares_what_an_entry_will_carry():
     assert (entry["layer"], entry["unit"], entry["moves"], entry["source"],
             entry["better"]) == (reader.LAYER, reader.UNIT, reader.MOVES,
                                  reader.SOURCE, "lower")
-    assert entry["workloads"] == ["sf1-parquet.replay6",
-                                  "sf1-lakehouse.replay6"]
+    assert doc_rules.workloads_fault(
+        lib.Spec(lib.REPO), "feedback_io_ms.stmt") is None

@@ -94,6 +94,7 @@ def driver(tmp_path_factory):
     return str(path)
 
 
+@pytest.mark.rehearsal
 def test_a_cpu_run_ends_in_the_platform_failure_not_a_result(cache):
     p = _run([sys.executable, os.path.join(lib.REPO, "benchmarks", "run.py"),
               *ARGS], cache)
@@ -114,6 +115,7 @@ def test_a_cpu_run_ends_in_the_platform_failure_not_a_result(cache):
     assert any(line.startswith("chip child: window ") for line in out)
 
 
+@pytest.mark.rehearsal
 def test_the_rest_of_a_run_reports_the_contracts_line(cache, driver):
     cached = os.path.isdir(os.path.join(cache, "data"))
     p = _run([sys.executable, driver, *ARGS], cache)
@@ -157,15 +159,19 @@ def test_the_rest_of_a_run_reports_the_contracts_line(cache, driver):
     assert sum(line.startswith("answer pass/s0/") for line in out) == 6
 
 
+@pytest.mark.rehearsal
 def test_the_rest_of_a_traced_run_reports_the_per_layer_metrics(cache, driver):
     """`--trace 1`: the program's spans are kept, the per-layer readers run
     over them, and the line carries the device's busy seconds and the
     breakdown. The measured child's first pass is the one the `.first`
     metrics read; the pass-only child runs untraced."""
-    # cycle 0: the first twelve statements of the window, which three
-    # seconds hold on a loaded host too (cycle 1 needs 48 of them)
+    # cycle 0: the first twelve statements of the window (cycle 1 needs 48
+    # of them), in ten seconds: a window that ends inside the slice is an
+    # error, and on a loaded host one statement that loads a program from
+    # disk has taken three seconds
     args = [a if a != "0" else "1" for a in ARGS] + ["--trace_cycle", "0"]
     assert args[args.index("--trace") + 1] == "1"
+    args[args.index("--seconds") + 1] = "10"
     p = _run([sys.executable, driver, *args], cache)
     out = p.stdout.strip().splitlines()
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-2000:]
@@ -174,8 +180,8 @@ def test_the_rest_of_a_traced_run_reports_the_per_layer_metrics(cache, driver):
         "breakdown", "first_passes_s", "slice", "compared")
     assert line["correct"] is True, p.stdout[-3000:]
     # what was traced: the first two passes of the cycle the command named
-    # (the mix's own `trace_cycle` is out of reach of three seconds), in
-    # the order the seed gives that cycle
+    # (the mix's own `trace_cycle` leaves two whole cycles before it), in the
+    # order the seed gives that cycle
     traffic = lib.Spec(lib.REPO).traffic(lib.Spec(lib.REPO).cell(CELL))
     assert traffic["trace_cycle"] == 2 and traffic["trace_passes"] == 2
     wanted = lib.slice_statements(
@@ -201,6 +207,7 @@ def test_the_rest_of_a_traced_run_reports_the_per_layer_metrics(cache, driver):
     assert not os.path.exists(os.path.join(run_dir, "pass", "trace"))
 
 
+@pytest.mark.rehearsal
 def test_an_altered_answer_comes_out_as_not_correct(cache, driver):
     p = _run([sys.executable, driver, *ARGS], cache, {"BREAK": "1"})
     out = p.stdout.strip().splitlines()
@@ -211,6 +218,7 @@ def test_an_altered_answer_comes_out_as_not_correct(cache, driver):
     assert line["failed"] == 0, "the statements ran; only the answer is wrong"
 
 
+@pytest.mark.rehearsal
 def test_without_the_program_there_is_no_result(tmp_path):
     """In a directory that holds only BENCHMARK.json and the benchmark's own
     directories there is nothing to measure: exit code not 0, no line."""
