@@ -279,9 +279,100 @@ def _compact_full_sorted(mask: jnp.ndarray) -> jnp.ndarray:
     )
 
 
+# A sparse compaction by block select. `_compact_full` scatters n updates
+# whatever the mask holds, 5.8 ns each on the v5e: 24.5 ms at store_sales'
+# 4,194,304 rows to keep 65,536 (PERF.md, Findings PR 40, step 0). Here
+# everything of size n is a streaming pass (each block of `_SELECT_BLOCK`
+# rows packs its mask into 32-bit words and counts them) and what costs by
+# the row is of size `out_cap`: one max-scatter of the n / block block
+# starts, ONE gather of `out_cap` rows of a block's words, and the rank-th
+# set bit of each by population counts: 0.69 ms at that shape. Two
+# programs, so that the n-sized one is keyed by n alone as `_compact_full`
+# is, and the other by (n / block, out_cap).
+#
+# The rule is on the two shapes a call has. Block select won at every ratio
+# step 0 tried, down to n / out_cap = 2 (8.6 against 24.5 ms), but the
+# chip lays a slot's 16 words over 128 lanes, 512 B of temporaries a slot:
+# the rule stops at a ratio of 4 (a quarter of n, 0.5 GB at 4,194,304),
+# which covers every packing of a sparse table (`_pack_sparse`: under an
+# eighth live). Under `_SELECT_MIN_ROWS` a scatter costs what two
+# dispatches cost (0.6 ms at 65,536 either way; 2.5 against 0.7 ms at
+# 524,288).
+
+_SELECT_BLOCK = 512
+_SELECT_MIN_ROWS = 262_144
+_SELECT_CROSSOVER = 4
+_WORD = 32
+
+
+@jax.jit
+def _select_blocks(mask):
+    """(words, off, total): block b's mask as `_SELECT_BLOCK / 32` words,
+    lane l at bit l % 32 of word l // 32; off[b] the live rows before
+    block b; total all of them."""
+    nblocks = mask.shape[0] // _SELECT_BLOCK
+    bits = mask.reshape(nblocks, _SELECT_BLOCK // _WORD, _WORD)
+    words = jnp.sum(
+        bits.astype(jnp.uint32) << jnp.arange(_WORD, dtype=jnp.uint32),
+        axis=2, dtype=jnp.uint32,
+    )
+    counts = jnp.sum(
+        jax.lax.population_count(words), axis=1, dtype=jnp.int32
+    )
+    ends = fast_cumsum(counts)
+    return words, ends - counts, ends[-1]
+
+
+@partial(jax.jit, static_argnames=("out_cap",))
+def _select_rows(words, off, total, out_cap):
+    """Output slot j reads the block that starts last at or before j, at
+    rank j less that start (empty blocks share their start with the next
+    live one, which has the higher id and wins the max); the row is the
+    rank-th set bit of the block's words."""
+    nblocks, nwords = words.shape
+    j = jnp.arange(out_cap, dtype=jnp.int32)
+    first = jnp.zeros(out_cap, jnp.int32).at[off].max(
+        jnp.arange(1, nblocks + 1, dtype=jnp.int32), mode="drop"
+    )
+    live = j < total
+    block = jnp.where(live, fast_cummax(first) - 1, 0)
+    rank = j - fast_cummax(jnp.where(first > 0, j, 0))
+    row = words[block]
+    ones = jax.lax.population_count(row).astype(jnp.int32)
+    before = jnp.cumsum(ones, axis=1) - ones  # set bits before each word
+    # the word that holds the rank-th set bit starts last at or before it
+    widx = jnp.sum(before <= rank[:, None], axis=1, dtype=jnp.int32) - 1
+    here = jnp.arange(nwords, dtype=jnp.int32) == widx[:, None]
+    word = jnp.sum(jnp.where(here, row, 0), axis=1, dtype=jnp.uint32)
+    rank = rank - jnp.sum(jnp.where(here, before, 0), axis=1, dtype=jnp.int32)
+    bit = jnp.zeros(out_cap, jnp.int32)
+    for width in (16, 8, 4, 2, 1):
+        low = (word >> bit.astype(jnp.uint32)) & jnp.uint32((1 << width) - 1)
+        below = jax.lax.population_count(low).astype(jnp.int32)
+        up = rank >= below
+        rank = jnp.where(up, rank - below, rank)
+        bit = jnp.where(up, bit + width, bit)
+    idx = (block * nwords + widx) * _WORD + bit
+    return jnp.where(live, idx, 0)
+
+
+def _selects(n: int, out_cap: int) -> bool:
+    """Block select for a mask large enough to matter whose output is a
+    small enough share of it (the comment above has both numbers)."""
+    return (
+        n >= _SELECT_MIN_ROWS
+        and n % _SELECT_BLOCK == 0
+        and out_cap * _SELECT_CROSSOVER <= n
+    )
+
+
+@_ktraced("compact_select")
+def _compact_select(mask, out_cap):
+    return _select_rows(*_select_blocks(mask), out_cap)
+
+
 @_ktraced("compact_indices")
-def compact_indices(mask: jnp.ndarray, out_cap: int) -> jnp.ndarray:
-    """Indices of True entries, padded with 0 to out_cap."""
+def _compact_whole(mask, out_cap):
     if _multi_device(mask):
         full = _compact_full_sorted(mask)
     else:
@@ -290,6 +381,16 @@ def compact_indices(mask: jnp.ndarray, out_cap: int) -> jnp.ndarray:
     if out_cap <= n:
         return jax.lax.slice(full, (0,), (out_cap,))
     return jnp.pad(full, (0, out_cap - n))
+
+
+def compact_indices(mask: jnp.ndarray, out_cap: int) -> jnp.ndarray:
+    """Indices of True entries ascending, padded with 0 to out_cap. One
+    launch behind the seam, under `compact_select` where the two shapes
+    send the call to block select and under `compact_indices` elsewhere
+    (a dense mask, a small one, one sharded over a mesh)."""
+    if _selects(mask.shape[0], out_cap) and not _multi_device(mask):
+        return _compact_select(mask, out_cap)
+    return _compact_whole(mask, out_cap)
 
 
 def mask_count(mask: jnp.ndarray) -> int:

@@ -16,6 +16,7 @@ the same reason.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -118,6 +119,39 @@ def test_sort_perm_compiles_for_v5e(one_chip):
         PK.sort_perm_pallas, one_chip,
         ((FACT_ROWS,), jnp.int64), domain=PK.SORT_MAX_DOMAIN,
     ))
+
+
+# ---------------------------------------------------------------------------
+# the sparse compaction's two programs at store_sales' capacity (XLA, no
+# Pallas): what the v5e's compiler makes of them, without the chip
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("out_cap", [65_536, 524_288])
+def test_block_select_compiles_for_v5e_and_holds_nothing_of_n_rows(
+    one_chip, out_cap
+):
+    """The n-sized phase keeps no temporary at all (every pass streams) and
+    hands on n / 8 bytes of words; the `out_cap` phase's temporaries are of
+    `out_cap` rows, never of n."""
+    from nds_tpu.ops import kernels as K
+
+    nblocks = FACT_CAP // K._SELECT_BLOCK
+    nwords = K._SELECT_BLOCK // 32
+    blocks = _compile(K._select_blocks, one_chip, ((FACT_CAP,), jnp.bool_))
+    mem = blocks.memory_analysis()
+    assert mem.temp_size_in_bytes == 0
+    assert mem.output_size_in_bytes < FACT_CAP  # n / 8 of words + offsets
+    rows = _compile(
+        K._select_rows, one_chip,
+        ((nblocks, nwords), jnp.uint32), ((nblocks,), jnp.int32),
+        ((), jnp.int32), out_cap=out_cap,
+    )
+    hlo = rows.as_text()
+    assert len(re.findall(r" gather\(", hlo)) == 1, hlo
+    # a row of 16 words is laid out over 128 lanes: 512 B a slot, a few
+    # such arrays at most
+    assert rows.memory_analysis().temp_size_in_bytes <= 4 * 512 * out_cap
 
 
 # ---------------------------------------------------------------------------

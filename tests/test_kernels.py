@@ -37,6 +37,142 @@ class TestCompact:
         )
 
 
+def _mask_of(kind, n, out_cap, seed):
+    """A mask of n rows of one kind; of the kinds that name no share, never
+    more than out_cap rows live."""
+    mask = np.zeros(n, bool)
+    local = np.random.default_rng(seed)
+    if kind == "one row":
+        mask[n // 3] = True
+    elif kind == "last row alone":
+        mask[-1] = True
+    elif kind == "whole blocks full":
+        mask[K._SELECT_BLOCK:3 * K._SELECT_BLOCK] = True
+        mask[-K._SELECT_BLOCK:] = True
+    elif kind == "exactly out_cap":
+        mask[local.choice(n, out_cap, replace=False)] = True
+    elif kind == "a power of two and one":
+        mask[local.choice(n, out_cap // 2 + 1, replace=False)] = True
+    elif kind != "empty":
+        mask = local.random(n) < float(kind)
+    return mask
+
+
+class TestCompactSelect:
+    """The sparse route of `compact_indices` (block select, PERF.md Findings
+    PR 40) against the form it stands in for, entry for entry."""
+
+    KINDS = ("empty", "one row", "last row alone", "whole blocks full",
+             "exactly out_cap", "a power of two and one",
+             "0.014", "0.12", "0.5")
+
+    @pytest.mark.parametrize("kind", KINDS)
+    @pytest.mark.parametrize("n", [4_096, 65_536, 524_288, 4_194_304])
+    def test_it_answers_what_the_scatter_answers(self, n, kind):
+        out_cap = max(n // 64, 3 * K._SELECT_BLOCK)
+        mask_h = _mask_of(kind, n, out_cap, seed=n % 1_000 + len(kind))
+        if kind in ("0.014", "0.12", "0.5"):
+            out_cap = bucket_cap(int(mask_h.sum()))  # as the engine sizes it
+        assert mask_h.sum() <= out_cap <= n
+        mask = jnp.asarray(mask_h)
+        got = np.asarray(K._compact_select(mask, out_cap))
+        want = np.asarray(K._compact_whole(mask, out_cap))
+        live = np.flatnonzero(mask_h)
+        np.testing.assert_array_equal(want[: live.size], live)
+        assert not want[live.size:].any()
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(got, want)
+
+    def test_more_live_rows_than_out_cap_keeps_the_first(self):
+        mask = jnp.asarray(np.arange(8_192) % 3 == 0)
+        np.testing.assert_array_equal(
+            np.asarray(K._compact_select(mask, 1_024)),
+            np.asarray(K._compact_whole(mask, 1_024)),
+        )
+
+    @staticmethod
+    def _row_ops(jaxpr, found):
+        """(primitive, rows) of every gather and scatter under `jaxpr`:
+        a gather's output rows, a scatter's updates."""
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            if name == "gather":
+                found.append((name, eqn.outvars[0].aval.shape[0]))
+            elif name.startswith("scatter"):
+                found.append((name, eqn.invars[2].aval.shape[0]))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                TestCompactSelect._row_ops(sub, found)
+        return found
+
+    def test_nothing_of_n_rows_is_scattered_or_gathered(self):
+        """The cost model, held by the programs' jaxprs: the n-sized phase
+        streams; what costs by the row is one max-scatter of the block
+        starts and ONE gather of `out_cap` rows. A scatter or gather of n
+        rows again fails here, not on the chip."""
+        n, out_cap = 4_194_304, 65_536
+        mask = jax.ShapeDtypeStruct((n,), jnp.bool_)
+        blocks = jax.make_jaxpr(K._select_blocks)(mask)
+        assert self._row_ops(blocks.jaxpr, []) == []
+        rows = jax.make_jaxpr(
+            lambda r, o, t: K._select_rows(r, o, t, out_cap)
+        )(*blocks.out_avals)
+        assert sorted(self._row_ops(rows.jaxpr, [])) == [
+            ("gather", out_cap), ("scatter-max", n // K._SELECT_BLOCK)
+        ]
+        whole = jax.make_jaxpr(K._compact_full)(mask)
+        assert ("scatter", n) in self._row_ops(whole.jaxpr, [])
+
+    @staticmethod
+    def _launched(mask, out_cap):
+        from nds_tpu.obs import tally as T
+        from nds_tpu.obs.trace import Tracer
+
+        tl = T.Tally(Tracer(), 1)
+        with T.bind(tl):
+            idx = K.compact_indices(mask, out_cap)
+        return tl.launches, np.asarray(idx)
+
+    @pytest.mark.parametrize("n,out_cap,form", [
+        (K._SELECT_MIN_ROWS, K._SELECT_MIN_ROWS // K._SELECT_CROSSOVER,
+         "compact_select"),
+        (4 * K._SELECT_MIN_ROWS, 1_024, "compact_select"),
+        # a dense mask, a small one, out_cap >= n: the form they had
+        (K._SELECT_MIN_ROWS, 2 * K._SELECT_MIN_ROWS // K._SELECT_CROSSOVER,
+         "compact_indices"),
+        (K._SELECT_MIN_ROWS // 2, 1_024, "compact_indices"),
+        (K._SELECT_MIN_ROWS, K._SELECT_MIN_ROWS, "compact_indices"),
+        (K._SELECT_MIN_ROWS, 2 * K._SELECT_MIN_ROWS, "compact_indices"),
+        (K._SELECT_MIN_ROWS + 8, 1_024, "compact_indices"),
+    ])
+    def test_the_two_shapes_choose_the_form(self, n, out_cap, form):
+        mask_h = np.arange(n) % 1_031 == 5
+        before = (K._select_blocks._cache_size(), K._compact_full._cache_size())
+        launches, idx = self._launched(jnp.asarray(mask_h), out_cap)
+        assert launches == {form: 1}
+        grew = (K._select_blocks._cache_size() - before[0],
+                K._compact_full._cache_size() - before[1])
+        assert grew[form == "compact_select"] == 0
+        live = np.flatnonzero(mask_h)[:out_cap]
+        np.testing.assert_array_equal(idx[: live.size], live)
+        assert idx.shape == (out_cap,) and not idx[live.size:].any()
+
+    def test_a_sharded_mask_keeps_the_sorted_route(self):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+        n = K._SELECT_MIN_ROWS
+        mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+        mask_h = np.arange(n) % 257 == 0
+        mask = jax.device_put(
+            jnp.asarray(mask_h), NamedSharding(mesh, PartitionSpec("data"))
+        )
+        before = K._select_blocks._cache_size()
+        launches, idx = self._launched(mask, 2_048)
+        assert launches == {"compact_indices": 1}
+        assert K._select_blocks._cache_size() == before
+        live = np.flatnonzero(mask_h)
+        np.testing.assert_array_equal(idx[: live.size], live)
+
+
 class TestSort:
     def test_single_key_asc(self):
         n, cap = 900, 1024

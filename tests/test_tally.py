@@ -453,6 +453,64 @@ def test_take_columns_counts_its_buffers_and_nothing_under_a_trace():
         assert (tv is None and ev is None) or tv.tolist() == ev.tolist()
 
 
+def test_a_compaction_is_one_launch_under_the_name_of_its_form():
+    """`compact_select` where the two shapes send the call to block select,
+    `compact_indices` elsewhere: one launch a call either way, so a span's
+    `launches` say how often the mechanism engaged and how often it
+    declined, and their sum is what it was. Nothing under a trace."""
+    import jax
+    import jax.numpy as jnp
+
+    from nds_tpu.ops import kernels as K
+
+    n = K._SELECT_MIN_ROWS
+    sparse = jnp.arange(n) % 1_000 == 7
+    tl = T.Tally(Tracer(), 1)
+    with T.bind(tl):
+        jax.jit(lambda m: K.compact_indices(m, 1_024))(sparse)
+        assert tl.launches == {}
+        picked = K.compact_indices(sparse, 1_024)
+        assert tl.launches == {"compact_select": 1}
+        K.compact_indices(sparse, n)  # out_cap of a dense mask: declined
+        assert tl.launches == {"compact_select": 1, "compact_indices": 1}
+        K.compact_indices(sparse[: n // 2], 1_024)  # a small mask: declined
+        assert tl.launches == {"compact_select": 1, "compact_indices": 2}
+        K.compact_indices(sparse, 1_024)
+        assert tl.launches == {"compact_select": 2, "compact_indices": 2}
+    assert picked.tolist()[:3] == [7, 1_007, 2_007]
+
+
+@pytest.mark.parametrize("name", ["query3", "query96"])
+def test_a_statement_launches_as_much_with_block_select_engaged(
+    raw, name, monkeypatch
+):
+    """At SF0.01 no mask is large enough for the rule, so its floor is
+    lowered here: the statement's compactions move from `compact_indices`
+    to `compact_select`, one for one; every other count and the answer
+    stay."""
+    from nds_tpu.ops import kernels as K
+
+    tracer = Tracer()
+    s = _tpcds_session(raw, tracer)
+    sql = _statements()[name]
+    _run(s, sql, "warm")
+    s.register_arrow("tick", pa.table({"n": [0]}))
+    declined = _run(s, sql, "declined")
+    monkeypatch.setattr(K, "_SELECT_MIN_ROWS", 2 * K._SELECT_BLOCK)
+    s.register_arrow("tick", pa.table({"n": [1]}))
+    engaged = _run(s, sql, "engaged")
+    assert engaged.equals(declined)
+    before, reads_before = _counts(tracer.events, "declined")
+    after, reads_after = _counts(tracer.events, "engaged")
+    assert "compact_select" not in before and after["compact_select"] > 0
+    assert (after["compact_select"] + after.get("compact_indices", 0)
+            == before["compact_indices"])
+    assert sum(after.values()) == sum(before.values())
+    for k in set(before) - {"compact_indices"}:
+        assert after[k] == before[k]
+    assert reads_after == reads_before
+
+
 def test_tables_with_the_same_column_types_share_their_programs():
     """The gather is one jitted program a buffer, keyed by the buffer's
     dtype and the two capacities: a second table with the same column
