@@ -110,13 +110,13 @@ def _render_profile(prof, top: int, per_query: bool):
         if per_query and rec["ops"]:
             _print_ops(sorted(
                 rec["ops"].items(), key=lambda kv: -kv[1]["excl_ms"]
-            ))
+            ), rec.get("collect"))
     hot = sorted(
         prof["op_totals"].items(), key=lambda kv: -kv[1]["excl_ms"]
     )[:top]
     if hot:
         print(f"\n== top {len(hot)} operators by exclusive time (run-wide)")
-        _print_ops(hot)
+        _print_ops(hot, prof.get("collect_total"))
     t = prof["tallies"]
     print(f"\n== tallies: plan-cache {t['plan_cache_hits']} hit / "
           f"{t['plan_cache_misses']} miss; catalog {t['catalog_loads']} "
@@ -216,7 +216,7 @@ def _render_profile(prof, top: int, per_query: bool):
                   f"{avg:>10,.3f}{k['n_rows']:>14,}")
 
 
-def _print_ops(ops):
+def _print_ops(ops, collect=None):
     """The per-operator table. `cols in>out`: the columns of a Filter's,
     Join's or MultiJoin's inputs and the columns it handed on (the plan's
     `required`), summed over its executions; `-` for the other nodes.
@@ -224,7 +224,12 @@ def _print_ops(ops):
     summed likewise. Under a query's MultiJoin, one line an order it
     joined in (relation indices): each step's estimate of the rows it
     leaves (`-`: none, so the step ranked by its inputs), each step's
-    `left_caps`, and `reordered` where the estimates changed the order."""
+    `left_caps`, and `reordered` where the estimates changed the order.
+    Where the spans carry their own host time by name (`launch_ms_by`,
+    `compile_ms`, `host_ms`), a second table splits each operator's
+    exclusive time into reads, launches, compile stages, phases and
+    `other`, with the heaviest names under it; `collect`: the same for
+    what statements did outside every plan node (`(collect)`)."""
     print(f"   {'operator':<18}{'count':>6}{'incl_ms':>12}"
           f"{'excl_ms':>12}{'rows':>12}{'cols in>out':>14}{'left_caps':>14}")
     for node, op in ops:
@@ -242,6 +247,10 @@ def _print_ops(ops):
             print(f"      join_order {order} x{join['count']}  "
                   f"step_est_rows {est}  left_caps {step_caps}"
                   + ("  reordered" if join.get("reordered") else ""))
+    for line in R.format_host_table(
+        list(ops) + ([("(collect)", collect)] if collect else [])
+    ):
+        print(line)
 
 
 def _accuracy_report(events, top: int) -> dict:

@@ -400,6 +400,12 @@ class AotCache:
             "load", "hit", bytes=len(raw), dur_ms=dur, t0_ns=t0_ns,
             key=_entry_name(key),
         )
+        from ..obs import tally as obs_tally
+
+        tl = obs_tally.current()
+        if tl is not None:
+            # the statement's span says what it spent re-loading
+            tl.add_compile("load", t0_ns / 1e9, t0_ns / 1e9 + dur / 1e3)
         return compiled
 
     def _parse_entry(self, raw: bytes, key: dict, path: str):

@@ -36,6 +36,7 @@ import pyarrow as pa
 import pyarrow.compute as pc
 
 from ..dtypes import DType, parse_dtype, INT64, FLOAT64
+from ..obs import tally as _tally
 from ..obs.tally import host_read
 
 jax.config.update("jax_enable_x64", True)
@@ -224,7 +225,8 @@ class Table:
         """Bool mask of live rows."""
         if self.live is not None:
             return self.live
-        return jnp.arange(self.cap, dtype=jnp.int32) < self._nrows
+        with _tally.eager("row_mask"):
+            return jnp.arange(self.cap, dtype=jnp.int32) < self._nrows
 
     def compacted(self) -> "Table":
         """Pack live rows to the front (drops the mask). Reuses the count
@@ -595,13 +597,15 @@ def unify_dictionaries(a: Column, b: Column):
         return a.data, b.data, a.dictionary
     da = a.dictionary if a.dictionary is not None else pa.array([], type=pa.string())
     db = b.dictionary if b.dictionary is not None else pa.array([], type=pa.string())
-    unified = pc.unique(pa.concat_arrays([da.cast(pa.string()), db.cast(pa.string())]))
-    remap_a = pc.index_in(da.cast(pa.string()), unified).to_numpy(zero_copy_only=False)
-    remap_b = pc.index_in(db.cast(pa.string()), unified).to_numpy(zero_copy_only=False)
-    ra = jnp.asarray(remap_a.astype(np.int32))
-    rb = jnp.asarray(remap_b.astype(np.int32))
-    codes_a = ra[jnp.clip(a.data, 0, max(len(da) - 1, 0))] if len(da) else a.data
-    codes_b = rb[jnp.clip(b.data, 0, max(len(db) - 1, 0))] if len(db) else b.data
+    with _tally.phase("dict-merge"):
+        unified = pc.unique(pa.concat_arrays([da.cast(pa.string()), db.cast(pa.string())]))
+        remap_a = pc.index_in(da.cast(pa.string()), unified).to_numpy(zero_copy_only=False)
+        remap_b = pc.index_in(db.cast(pa.string()), unified).to_numpy(zero_copy_only=False)
+    with _tally.eager("dict_remap"):
+        ra = jnp.asarray(remap_a.astype(np.int32))
+        rb = jnp.asarray(remap_b.astype(np.int32))
+        codes_a = ra[jnp.clip(a.data, 0, max(len(da) - 1, 0))] if len(da) else a.data
+        codes_b = rb[jnp.clip(b.data, 0, max(len(db) - 1, 0))] if len(db) else b.data
     return codes_a, codes_b, unified
 
 
@@ -615,10 +619,12 @@ def sort_dictionary(col: Column):
     if d is None or len(d) == 0:
         # all-null string column (e.g. c_login): nothing to rank
         return col.data, d
-    d = d.cast(pa.string())
-    order = pc.array_sort_indices(d)  # indices of values in sorted order
-    rank = np.empty(len(d), dtype=np.int32)
-    rank[order.to_numpy(zero_copy_only=False)] = np.arange(len(d), dtype=np.int32)
-    sorted_dict = d.take(order)
-    ranks = jnp.asarray(rank)[jnp.clip(col.data, 0, len(d) - 1)]
+    with _tally.phase("dict-merge"):
+        d = d.cast(pa.string())
+        order = pc.array_sort_indices(d)  # indices of values in sorted order
+        rank = np.empty(len(d), dtype=np.int32)
+        rank[order.to_numpy(zero_copy_only=False)] = np.arange(len(d), dtype=np.int32)
+        sorted_dict = d.take(order)
+    with _tally.eager("dict_remap"):
+        ranks = jnp.asarray(rank)[jnp.clip(col.data, 0, len(d) - 1)]
     return ranks, sorted_dict

@@ -130,6 +130,7 @@ def _rollup_base_aggs(agg_items):
     return base, avg_items
 
 
+@_tally.seamed("agg")
 def _derive_rollup_avgs(part: "Table", avg_items):
     if not avg_items:
         return part
@@ -360,7 +361,7 @@ class Executor:
         )
         cache = self._session_cache() if cacheable else None
         if cache is not None:
-            with self.catalog.session.cache_lock:
+            with _tally.phase("plan-cache"), self.catalog.session.cache_lock:
                 hit = cache.get(self._fp(node))
             if tracer is not None:
                 tracer.emit(
@@ -397,67 +398,77 @@ class Executor:
                 # span's actual_rows should see it
                 fp = getattr(node, "node_fp", None)
                 if fp is not None:
-                    self._record_feedback(node, out)
+                    with _tally.phase("feedback"):
+                        self._record_feedback(node, out)
             finally:
                 self._span_depth = depth
-                own = tally.pop(saved)
+                own = tally.pop(saved, t0)
                 if bound is not None:
                     bound.__exit__(None, None, None)
             dur_ms = (_perf() - t0) * 1000.0
-            self._span_seq += 1
-            span = dict(
-                exec_id=self._exec_id,
-                seq=self._span_seq,
-                depth=depth,
-                node=type(node).__name__,
-                explain=P.node_desc(node)[:90],
-                t0_ns=t0_ns,
-                dur_ms=round(dur_ms, 3),
-                # nrows_known only: forcing a queued count would add a
-                # device sync to every traced node
-                rows=out.nrows_known,
-                est_bytes=table_device_bytes(out),
-                **own,
-            )
-            if isinstance(node, (P.Filter, P.Join, P.MultiJoin)) or (
-                isinstance(node, P.Pipeline) and node.agg is None
-                and all(isinstance(st, P.Filter) for st in node.stages)
-            ):
-                # how far `required` narrowed what this node hands on:
-                # the columns of its inputs, the columns of its output
-                span["cols_in"] = sum(
-                    len(self._cte_cache[id(c)].columns)
-                    for c in node.children() if id(c) in self._cte_cache
-                )
-                span["cols_out"] = len(out.columns)
-            if isinstance(node, P.MultiJoin) and self._join_steps:
-                # the order joined (relation indices), each step's estimate
-                # of the rows it leaves, the capacity its left side ran at,
-                # and whether the estimates changed the order
-                span.update(self._join_steps)
-            if fp is not None:
-                # budgeter accounting (analysis/feedback.py annotations):
-                # est_rows/est_live_bytes are the STATIC model's numbers,
-                # actual_* what this execution measured. `est_bytes`
-                # above keeps its historical meaning (realized device
-                # bytes — the calibration harness pins it)
-                span["node_fp"] = fp
-                span["est_rows"] = getattr(node, "est_rows", None)
-                span["est_live_bytes"] = getattr(
-                    node, "est_live_bytes", None
-                )
-                span["actual_rows"] = out.nrows_known
-                span["actual_bytes"] = table_device_bytes(out)
-            tracer.emit("op_span", **span)
+            # the span's own making (node_desc, byte counts, the emit) is
+            # its parent's host time
+            with _tally.phase("span-emit"):
+                self._emit_op_span(node, out, depth, t0_ns, dur_ms, own, fp)
         else:
             out = m(node)
             if getattr(node, "node_fp", None) is not None:
                 self._record_feedback(node, out)
         self._cte_cache[key] = out
         if cache is not None:
-            with self.catalog.session.cache_lock:
+            with _tally.phase("plan-cache"), self.catalog.session.cache_lock:
                 cache.put(self._fp(node), out)
         return out
+
+    def _emit_op_span(self, node, out, depth, t0_ns, dur_ms, own, fp):
+        """One executed plan node's `op_span`: `own` is the tally's frame
+        (the node's own launches, reads, compile stages and phases)."""
+        self._span_seq += 1
+        nbytes = table_device_bytes(out)
+        span = dict(
+            exec_id=self._exec_id,
+            seq=self._span_seq,
+            depth=depth,
+            node=type(node).__name__,
+            explain=P.node_desc(node)[:90],
+            t0_ns=t0_ns,
+            dur_ms=round(dur_ms, 3),
+            # nrows_known only: forcing a queued count would add a
+            # device sync to every traced node
+            rows=out.nrows_known,
+            est_bytes=nbytes,
+            **own,
+        )
+        if isinstance(node, (P.Filter, P.Join, P.MultiJoin)) or (
+            isinstance(node, P.Pipeline) and node.agg is None
+            and all(isinstance(st, P.Filter) for st in node.stages)
+        ):
+            # how far `required` narrowed what this node hands on:
+            # the columns of its inputs, the columns of its output
+            span["cols_in"] = sum(
+                len(self._cte_cache[id(c)].columns)
+                for c in node.children() if id(c) in self._cte_cache
+            )
+            span["cols_out"] = len(out.columns)
+        if isinstance(node, P.MultiJoin) and self._join_steps:
+            # the order joined (relation indices), each step's estimate
+            # of the rows it leaves, the capacity its left side ran at,
+            # and whether the estimates changed the order
+            span.update(self._join_steps)
+        if fp is not None:
+            # budgeter accounting (analysis/feedback.py annotations):
+            # est_rows/est_live_bytes are the STATIC model's numbers,
+            # actual_* what this execution measured. `est_bytes`
+            # above keeps its historical meaning (realized device
+            # bytes — the calibration harness pins it)
+            span["node_fp"] = fp
+            span["est_rows"] = getattr(node, "est_rows", None)
+            span["est_live_bytes"] = getattr(
+                node, "est_live_bytes", None
+            )
+            span["actual_rows"] = out.nrows_known
+            span["actual_bytes"] = nbytes
+        self.tracer.emit("op_span", **span)
 
     def to_arrow(self, node: P.PlanNode) -> pa.Table:
         return table_to_arrow(self.execute(node))
@@ -470,10 +481,11 @@ class Executor:
         # catalog entry, and after a device-OOM recovery wiped the cache
         # lake_files: the zone-map pruned file subset
         # (Session._prune_lake_scans) — the load opens only surviving files
-        t = self.catalog.load(
-            node.table, node.columns, lake_version=node.lake_version,
-            lake_files=node.lake_files,
-        )
+        with _tally.phase("scan"):
+            t = self.catalog.load(
+                node.table, node.columns, lake_version=node.lake_version,
+                lake_files=node.lake_files,
+            )
         uk = t.unique_key
         if uk is not None:
             uk = frozenset(f"{node.alias}.{n}" for n in uk)
@@ -573,31 +585,37 @@ class Executor:
                 and session.conf.get("engine.pallas_agg", "off") != "off"
             )
         ):
-            fp = getattr(node, "_stage_fp", None)
-            if fp is None:
-                fp = node._stage_fp = P.fingerprint(
-                    P.Pipeline(stages=node.stages, child=None, agg=node.agg)
-                )
-            sig = fuse.input_signature(child, with_stats=has_agg)
-            aot, conf_sig = self._aot_build_args(session)
-            if has_agg:
-                def build():
-                    return fuse.FusedAggPipeline(
-                        node.stages, node.agg, child,
-                        aot=aot, fp=fp, conf_sig=conf_sig,
+            with _tally.phase("exec-lookup"):
+                fp = getattr(node, "_stage_fp", None)
+                if fp is None:
+                    fp = node._stage_fp = P.fingerprint(
+                        P.Pipeline(
+                            stages=node.stages, child=None, agg=node.agg
+                        )
                     )
-            else:
+                sig = fuse.input_signature(child, with_stats=has_agg)
+                aot, conf_sig = self._aot_build_args(session)
+
                 def build():
-                    return fuse.FusedPipeline(
-                        node.stages, child, aot=aot, fp=fp,
-                        conf_sig=conf_sig,
+                    # traces the chain through the engine's own evaluator
+                    # (the seams are nothing under a jax trace); its jax
+                    # stages are `compile_ms`
+                    with _tally.phase("pipeline-build"):
+                        if has_agg:
+                            return fuse.FusedAggPipeline(
+                                node.stages, node.agg, child,
+                                aot=aot, fp=fp, conf_sig=conf_sig,
+                            )
+                        return fuse.FusedPipeline(
+                            node.stages, child, aot=aot, fp=fp,
+                            conf_sig=conf_sig,
+                        )
+                lk_ns = _time_ns() if tracer is not None else 0
+                lk0 = _perf()
+                with session.cache_lock:
+                    entry, hit = session.exec_cache.lookup(
+                        fp, sig, child.cap, build
                     )
-            lk_ns = _time_ns() if tracer is not None else 0
-            lk0 = _perf()
-            with session.cache_lock:
-                entry, hit = session.exec_cache.lookup(
-                    fp, sig, child.cap, build
-                )
             if tracer is not None:
                 # dur_ms: the lookup and, on a miss, the build (trace,
                 # lower, compile or AOT load: those have events of their own)
@@ -682,30 +700,26 @@ class Executor:
                     order = K.sort_by_words(words)
                     n = min(node.n, child.nrows)
                     cap = bucket_cap(max(n, 1))
-                    return self._take(child, order[:cap], n)
+                    with _tally.eager("limit"):
+                        head = order[:cap]
+                    return self._take(child, head, n)
                 child = dist
             n = min(node.n, child.nrows)
-            cap = bucket_cap(max(n, 1))
-            child = child.compacted()
-            cols = {
-                name: Column(
-                    c.data[:cap], c.dtype,
-                    None if c.valid is None else c.valid[:cap],
-                    c.dictionary, c.subset_stats(),
-                )
-                for name, c in child.columns.items()
-            }
-            return Table(cols, n)
+            return self._head(child.compacted(), n, bucket_cap(max(n, 1)))
         child = self.execute(node.child).compacted()
         n = min(node.n, child.nrows)
-        cap = bucket_cap(n)
+        return self._head(child, n, bucket_cap(n))
+
+    @staticmethod
+    @_tally.seamed("limit")
+    def _head(child: Table, n, cap) -> Table:
+        """The first `cap` slots of every buffer of a packed table: one
+        eager slice a buffer."""
         cols = {
             name: Column(
-                c.data[:cap],
-                c.dtype,
+                c.data[:cap], c.dtype,
                 None if c.valid is None else c.valid[:cap],
-                c.dictionary,
-                c.subset_stats(),
+                c.dictionary, c.subset_stats(),
             )
             for name, c in child.columns.items()
         }
@@ -731,6 +745,7 @@ class Executor:
                 return out
         return self._take(child, order, child.nrows_lazy)
 
+    @_tally.seamed("sort_words")
     def _sort_order_words(self, node: P.Sort, child: Table):
         """(sort words, distributed-sort result|None) for a Sort node over
         its already-executed input — shared by the full sort and the
@@ -773,6 +788,7 @@ class Executor:
     # leading 1-bit live field keeps dead rows last. Exact — codes are
     # monotone (and injective) per key.
 
+    @_tally.seamed("sort_words")
     def _sort_words(self, keys, cols, live, include_live=True):
         """keys: (data, valid, ascending, nulls_first) in major->minor
         order; cols: aligned Column|None for cached bounds (None or
@@ -835,6 +851,7 @@ class Executor:
             spec.append(("L",))
         return list(K.build_sort_words(tuple(spec), live, *arrays))
 
+    @_tally.seamed("sort_words")
     def _group_words(self, active_cols, live):
         """Word encoding for group-by adjacency (equality only): the sort
         encoding with asc/nulls-first defaults is injective, so equal words
@@ -1121,7 +1138,7 @@ class Executor:
             session is not None
             and session.conf.get("engine.join_order_cache", "on") != "off"
         ):
-            with session.cache_lock:
+            with _tally.phase("join-plan"), session.cache_lock:
                 trace = session.join_order_cache.setdefault(
                     self._fp(node), {}
                 )
@@ -1208,36 +1225,59 @@ class Executor:
         left_caps = []
         step_i = 0
         while True:
-            groups = {group(i) for i in range(n)}
-            if len(groups) == 1:
-                break
-            if replay:
-                kind, gi, gj = steps[step_i]
-                step_i += 1
-            else:
-                best = smallest = None
-                for k, (i, j, le, re_) in enumerate(edges):
-                    gi, gj = group(i), group(j)
-                    if gi == gj:
-                        continue
-                    cost = current[gi].nrows + current[gj].nrows
-                    est = _est_join_rows(current[gi], current[gj], le, re_)
-                    rank = (cost if est is None else est, cost, k)
-                    if best is None or rank < best[0]:
-                        best = (rank, gi, gj, est)
-                    if smallest is None or (cost, k) < smallest:
-                        smallest = (cost, k)
-                if best is None:
-                    kind, gi, gj = "cross", *sorted(
-                        groups, key=lambda g: current[g].nrows
-                    )[:2]
-                    ests.append(None)
+            # the phase `join-plan` is the order's choosing or replay and
+            # the step's keys; the step's join is not in it
+            with _tally.phase("join-plan"):
+                groups = {group(i) for i in range(n)}
+                if len(groups) == 1:
+                    break
+                if replay:
+                    kind, gi, gj = steps[step_i]
+                    step_i += 1
                 else:
-                    kind, gi, gj = "edge", best[1], best[2]
-                    ests.append(None if best[3] is None else int(best[3]))
-                    if best[0][1:] != smallest:
-                        reordered = 1
-                steps.append((kind, gi, gj))
+                    best = smallest = None
+                    for k, (i, j, le, re_) in enumerate(edges):
+                        gi, gj = group(i), group(j)
+                        if gi == gj:
+                            continue
+                        cost = current[gi].nrows + current[gj].nrows
+                        est = _est_join_rows(
+                            current[gi], current[gj], le, re_
+                        )
+                        rank = (cost if est is None else est, cost, k)
+                        if best is None or rank < best[0]:
+                            best = (rank, gi, gj, est)
+                        if smallest is None or (cost, k) < smallest:
+                            smallest = (cost, k)
+                    if best is None:
+                        kind, gi, gj = "cross", *sorted(
+                            groups, key=lambda g: current[g].nrows
+                        )[:2]
+                        ests.append(None)
+                    else:
+                        kind, gi, gj = "edge", best[1], best[2]
+                        ests.append(
+                            None if best[3] is None else int(best[3])
+                        )
+                        if best[0][1:] != smallest:
+                            reordered = 1
+                    steps.append((kind, gi, gj))
+                if kind != "cross":
+                    # gather ALL edges connecting these two groups as one
+                    # multi-key join
+                    lkeys, rkeys = [], []
+                    rest = []
+                    for (i, j, le, re_) in edges:
+                        if {group(i), group(j)} == {gi, gj}:
+                            if group(i) == gi:
+                                lkeys.append(le)
+                                rkeys.append(re_)
+                            else:
+                                lkeys.append(re_)
+                                rkeys.append(le)
+                        else:
+                            rest.append((i, j, le, re_))
+                    edges = rest
             if kind == "cross":
                 # disconnected components: cross join smallest two groups
                 left_caps.append(current[gi].cap)
@@ -1248,20 +1288,6 @@ class Executor:
                 merged[gj] = gi
                 current[gi] = joined
                 continue
-            # gather ALL edges connecting these two groups as one multi-key join
-            lkeys, rkeys = [], []
-            rest = []
-            for (i, j, le, re_) in edges:
-                if {group(i), group(j)} == {gi, gj}:
-                    if group(i) == gi:
-                        lkeys.append(le)
-                        rkeys.append(re_)
-                    else:
-                        lkeys.append(re_)
-                        rkeys.append(le)
-                else:
-                    rest.append((i, j, le, re_))
-            edges = rest
             joined = self._join(
                 current[gi], current[gj], "inner", lkeys, rkeys, None,
                 spill_parts=spill_parts, node_fp=node_fp,
@@ -1438,48 +1464,50 @@ class Executor:
             return self._pair_table(left, right, pli, pri, count, out=out)
 
         if kind == "left":
-            present = K.matched_mask(li, ok, left.cap)
-            unmatched = ~present & llive
-            n_un = K.mask_count(unmatched)
-            total_rows = count + n_un
-            cap2 = bucket_cap(max(total_rows, 1))
-            un_idx = K.compact_indices(unmatched, bucket_cap(max(n_un, 1)))
-            all_li = jnp.concatenate([pli[:count] if count else pli[:0], un_idx[:n_un]])
-            all_li = jnp.pad(all_li, (0, cap2 - all_li.shape[0]))
-            all_ri = jnp.concatenate(
-                [pri[:count] if count else pri[:0], jnp.zeros(n_un, jnp.int32)]
-            )
-            all_ri = jnp.pad(all_ri, (0, cap2 - all_ri.shape[0]))
-            rkeep = jnp.arange(cap2) < count  # right side null for appended rows
-            return self._pair_table(
-                left, right, all_li, all_ri, total_rows, rkeep, out=out
-            )
+            with _tally.eager("join"):
+                present = K.matched_mask(li, ok, left.cap)
+                unmatched = ~present & llive
+                n_un = K.mask_count(unmatched)
+                total_rows = count + n_un
+                cap2 = bucket_cap(max(total_rows, 1))
+                un_idx = K.compact_indices(unmatched, bucket_cap(max(n_un, 1)))
+                all_li = jnp.concatenate([pli[:count] if count else pli[:0], un_idx[:n_un]])
+                all_li = jnp.pad(all_li, (0, cap2 - all_li.shape[0]))
+                all_ri = jnp.concatenate(
+                    [pri[:count] if count else pri[:0], jnp.zeros(n_un, jnp.int32)]
+                )
+                all_ri = jnp.pad(all_ri, (0, cap2 - all_ri.shape[0]))
+                rkeep = jnp.arange(cap2) < count  # right side null for appended rows
+                return self._pair_table(
+                    left, right, all_li, all_ri, total_rows, rkeep, out=out
+                )
 
         if kind == "full":
-            lpresent = K.matched_mask(li, ok, left.cap)
-            rpresent = K.matched_mask(ri, ok, right.cap)
-            lun = ~lpresent & llive
-            run = ~rpresent & rlive
-            n_lu = K.mask_count(lun)
-            n_ru = K.mask_count(run)
-            total_rows = count + n_lu + n_ru
-            cap2 = bucket_cap(max(total_rows, 1))
-            lu_idx = K.compact_indices(lun, bucket_cap(max(n_lu, 1)))[:n_lu]
-            ru_idx = K.compact_indices(run, bucket_cap(max(n_ru, 1)))[:n_ru]
-            all_li = jnp.concatenate(
-                [pli[:count], lu_idx, jnp.zeros(n_ru, jnp.int32)]
-            )
-            all_ri = jnp.concatenate(
-                [pri[:count], jnp.zeros(n_lu, jnp.int32), ru_idx]
-            )
-            all_li = jnp.pad(all_li, (0, cap2 - all_li.shape[0]))
-            all_ri = jnp.pad(all_ri, (0, cap2 - all_ri.shape[0]))
-            pos = jnp.arange(cap2)
-            rkeep = (pos < count) | (pos >= count + n_lu)
-            lkeep = pos < count + n_lu
-            return self._pair_table(
-                left, right, all_li, all_ri, total_rows, rkeep, lkeep, out
-            )
+            with _tally.eager("join"):
+                lpresent = K.matched_mask(li, ok, left.cap)
+                rpresent = K.matched_mask(ri, ok, right.cap)
+                lun = ~lpresent & llive
+                run = ~rpresent & rlive
+                n_lu = K.mask_count(lun)
+                n_ru = K.mask_count(run)
+                total_rows = count + n_lu + n_ru
+                cap2 = bucket_cap(max(total_rows, 1))
+                lu_idx = K.compact_indices(lun, bucket_cap(max(n_lu, 1)))[:n_lu]
+                ru_idx = K.compact_indices(run, bucket_cap(max(n_ru, 1)))[:n_ru]
+                all_li = jnp.concatenate(
+                    [pli[:count], lu_idx, jnp.zeros(n_ru, jnp.int32)]
+                )
+                all_ri = jnp.concatenate(
+                    [pri[:count], jnp.zeros(n_lu, jnp.int32), ru_idx]
+                )
+                all_li = jnp.pad(all_li, (0, cap2 - all_li.shape[0]))
+                all_ri = jnp.pad(all_ri, (0, cap2 - all_ri.shape[0]))
+                pos = jnp.arange(cap2)
+                rkeep = (pos < count) | (pos >= count + n_lu)
+                lkeep = pos < count + n_lu
+                return self._pair_table(
+                    left, right, all_li, all_ri, total_rows, rkeep, lkeep, out
+                )
         raise ExecError(f"join kind {kind}")
 
     # -- dense-domain star-join fast path --------------------------------
@@ -1525,14 +1553,16 @@ class Executor:
             self._DENSE_MAX_DOMAIN, max(1 << 14, 8 * max(rst.base_rows, right.cap))
         ):
             return None
-        rnn = K._all_valid([rv[0]], rlive)
-        rkey = rk[0].astype(jnp.int64)
-        table_cap = bucket_cap(domain)
-        rowid1 = self._dense_build_route(rkey, rnn, rmin, table_cap)
-        lnn = K._all_valid([lv[0]], llive)
-        matched, ri = K.dense_probe(
-            lk[0].astype(jnp.int64), lnn, rmin, rowid1, table_cap
-        )
+        with _tally.eager("join"):
+            # the casts and validity masks between the build and the probe
+            rnn = K._all_valid([rv[0]], rlive)
+            rkey = rk[0].astype(jnp.int64)
+            table_cap = bucket_cap(domain)
+            rowid1 = self._dense_build_route(rkey, rnn, rmin, table_cap)
+            lnn = K._all_valid([lv[0]], llive)
+            matched, ri = K.dense_probe(
+                lk[0].astype(jnp.int64), lnn, rmin, rowid1, table_cap
+            )
         return self._augment_join_output(
             left, right, kind, matched, ri, llive, residual, mark_name, out
         )
@@ -1561,6 +1591,7 @@ class Executor:
             unique_key=left.unique_key,
         )
 
+    @_tally.seamed("join")
     def _augment_join_output(
         self, left, right, kind, matched, ri, llive, residual, mark_name,
         out=None,
@@ -1920,6 +1951,7 @@ class Executor:
         )
         return n_dev * n_dev * (per_l * cap_l + per_r * cap_r)
 
+    @_tally.seamed("join")
     def _null_extend_right(self, t: Table, right: Table) -> Table:
         """Append all-null right-side columns to a left-rows-only table
         (the LEFT-join null extension), dtype/dictionary-aligned with the
@@ -1932,6 +1964,7 @@ class Executor:
             )
         return Table(cols, t.nrows_lazy, live=t.live)
 
+    @_tally.seamed("join")
     def _apply_residual(self, ok, li, ri, left, right, residual):
         count = K.mask_count(ok)
         cap = bucket_cap(max(count, 1))
@@ -1944,6 +1977,7 @@ class Executor:
         # max-scatter: sel's padding duplicates index 0 (see _join residual)
         return ok & jnp.zeros(ok.shape, bool).at[sel].max(pmask)
 
+    @_tally.seamed("mask")
     def _predicate_mask(self, table: Table, predicate) -> jnp.ndarray:
         """SQL WHERE semantics: TRUE rows only (NULL/UNKNOWN filtered),
         restricted to live rows."""
@@ -1953,6 +1987,7 @@ class Executor:
             mask = mask & pr.valid
         return mask & table.row_mask()
 
+    @_tally.seamed("join")
     def _join_key_pair(self, a: Column, b: Column):
         """Align join key dtypes (incl. cross-dictionary string unification).
         Returns ([left_cols], [right_cols]) — one column pair for most
@@ -2021,6 +2056,7 @@ class Executor:
         ))
         return Table(cols, nrows)
 
+    @_tally.seamed("join")
     def _cross_join(self, left, right):
         # position arithmetic below assumes packed rows
         left = left.compacted()
@@ -2529,7 +2565,8 @@ class Executor:
             # yields exactly one row even over empty input (weights produce
             # the NULL/0 aggregate values).
             order = None
-            gid = jnp.zeros(child.cap, jnp.int32)
+            with _tally.eager("agg"):
+                gid = jnp.zeros(child.cap, jnp.int32)
             ngroups = 1
         if ngroups == 0:
             if active:
@@ -2540,7 +2577,8 @@ class Executor:
                 )
             ngroups = 1  # global agg over empty input yields one row
         gcap = bucket_cap(ngroups)
-        live_sorted = live if order is None else live[order]
+        with _tally.eager("agg"):
+            live_sorted = live if order is None else live[order]
         return self._agg_output(
             child, key_items, key_cols, agg_items, subset,
             order, gid, ngroups, ev, gcap, live_sorted, words,
@@ -2554,6 +2592,7 @@ class Executor:
     # aggregation + a cross-chip reduction of the small group table.
     _DIRECT_AGG_MAX_DOMAIN = 1 << 22
 
+    @_tally.seamed("agg")
     def _try_direct_agg(
         self, child, key_items, key_cols, agg_items, subset, ev, live
     ):
@@ -2641,6 +2680,7 @@ class Executor:
             )
         return Table(cols, ngroups, unique_key=_active_key_names(key_items, key_cols))
 
+    @_tally.seamed("agg")
     def _agg_output(
         self, child, key_items, key_cols, agg_items, subset,
         order, gid, ngroups, ev, gcap=None, live_sorted=None,
@@ -3011,6 +3051,7 @@ class Executor:
             )
         return rec["use"]
 
+    @_tally.seamed("agg")
     def _eval_distinct_agg(self, agg, ev, child, subset, key_cols, gcap,
                            ngroups, key_words=None):
         """count(distinct x) / sum(distinct x): two-level grouping.
@@ -3090,6 +3131,7 @@ class Executor:
             out_cols[name] = self._eval_window(child, wf)
         return Table(out_cols, child.nrows_lazy, live=child.live)
 
+    @_tally.seamed("window")
     def _eval_window(self, child: Table, wf: E.WindowFn) -> Column:
         ev = self._evaluator(child)
         live = child.row_mask()
@@ -3409,10 +3451,9 @@ class Executor:
             if transient
             else {n: c.disowned() for n, c in table.columns.items()}
         )
-        return Table(
-            cols, jnp.sum(mask, dtype=jnp.int32), live=mask,
-            unique_key=table.unique_key,
-        )
+        with _tally.eager("mask"):
+            count = jnp.sum(mask, dtype=jnp.int32)
+        return Table(cols, count, live=mask, unique_key=table.unique_key)
 
     def _compact(self, table: Table, mask) -> Table:
         count = K.mask_count(mask)
@@ -3437,7 +3478,8 @@ class Executor:
         order, gid, ng = K.group_by_words(words, live, t.nrows)
         gcap = bucket_cap(max(ng, 1))
         first = K.segment_starts(gid, gcap)
-        rows = order[jnp.clip(first, 0, t.cap - 1)]
+        with _tally.eager("distinct"):
+            rows = order[jnp.clip(first, 0, t.cap - 1)]
         out = self._take(t, rows, ng)
         out.unique_key = frozenset(out.columns)
         return out
@@ -3632,6 +3674,7 @@ class Executor:
         out.unique_key = frozenset(out.columns)
         return out
 
+    @_tally.seamed("concat")
     def _concat(self, a: Table, b: Table) -> Table:
         """Masked concatenation: columns append at full capacity (padded to
         a power-of-two bucket) under a combined live mask — no repacking

@@ -24,6 +24,7 @@ import pyarrow as pa
 import pyarrow.compute as pc
 
 from ..dtypes import BOOL, DATE, DType, FLOAT64, INT32, INT64, STRING
+from ..obs import tally as _tally
 from ..obs.tally import host_read
 from .columnar import Column, Table, sort_dictionary, unify_dictionaries
 
@@ -282,12 +283,22 @@ class Evaluator:
 
     def __init__(self, table: Table):
         self.table = table
+        self._in_eval = False
 
     def eval(self, e: Expr) -> Column:
         m = getattr(self, f"_eval_{type(e).__name__.lower()}", None)
         if m is None:
             raise NotImplementedError(f"eval of {type(e).__name__}")
-        return m(e)
+        if self._in_eval or type(e) is Col or _tally.current() is None:
+            return m(e)
+        # the outermost call of an expression evaluated op by op (a column
+        # reference launches nothing): the eager seam `eager:expr`
+        self._in_eval = True
+        try:
+            with _tally.eager("expr"):
+                return m(e)
+        finally:
+            self._in_eval = False
 
     # ---- leaves ---------------------------------------------------------
     def _eval_col(self, e: Col) -> Column:
@@ -836,22 +847,25 @@ def _cast_column(c: Column, target: DType, cap: int) -> Column:
 
 def _share_dictionary(cols):
     """Remap string columns onto one merged dictionary (CASE/COALESCE)."""
-    dicts = [
-        (c.dictionary if c.dictionary is not None else pa.array([], pa.string())).cast(
-            pa.string()
-        )
-        for c in cols
-    ]
-    unified = pc.unique(pa.concat_arrays(dicts))
+    with _tally.phase("dict-merge"):
+        dicts = [
+            (c.dictionary if c.dictionary is not None else pa.array([], pa.string())).cast(
+                pa.string()
+            )
+            for c in cols
+        ]
+        unified = pc.unique(pa.concat_arrays(dicts))
+        remaps = [
+            pc.index_in(d, unified).to_numpy(zero_copy_only=False).astype(np.int32)
+            if len(d) else None
+            for d in dicts
+        ]
     out = []
-    for c, d in zip(cols, dicts):
-        if len(d) == 0:
+    for c, d, remap in zip(cols, dicts, remaps):
+        if remap is None:
             out.append(Column(c.data, STRING, c.valid, unified))
             continue
-        remap = jnp.asarray(
-            pc.index_in(d, unified).to_numpy(zero_copy_only=False).astype(np.int32)
-        )
-        out.append(
-            Column(remap[jnp.clip(c.data, 0, len(d) - 1)], STRING, c.valid, unified)
-        )
+        with _tally.eager("dict_remap"):
+            codes = jnp.asarray(remap)[jnp.clip(c.data, 0, len(d) - 1)]
+        out.append(Column(codes, STRING, c.valid, unified))
     return out, unified

@@ -727,10 +727,13 @@ class Result:
                 self._table = self.executor.execute(self.plan)
             t1 = _perf()
             if to_arrow:
-                arrow = table_to_arrow(self._table)
+                # Arrow assembly; the collect's compaction and its one read
+                # are a seam and a host_read of their own inside it
+                with _tally.phase("to-arrow"):
+                    arrow = table_to_arrow(self._table)
         if tally is not None and (executed or to_arrow):
             t2 = _perf()
-            outside = tally.take()  # counted outside every op_span
+            outside = tally.take(t0)  # counted outside every op_span
             tally.tracer.emit(
                 "result_span", exec_id=tally.exec_id, t0_ns=t0_ns,
                 dur_ms=round((t2 - t0) * 1000.0, 3),

@@ -43,11 +43,24 @@ construction):
     jit-trace       `xla_compile` stages trace and lower
     exec-lookup     `exec_cache` dur_ms (the lookup and, on a miss, the
                     pipeline build) not already under a compile stage
-    launch          `launch_ms` of the op_spans and the result_span: host
-                    time inside seamed kernel calls and fused-pipeline
-                    calls, less the compile stages that fell inside them
+    launch          host time inside seamed kernel calls and fused-
+                    pipeline calls: the kernel seams of the spans' own
+                    `launch_ms_by` (the program has taken the reads and
+                    compile stages inside them out already); for a log from
+                    before that field `launch_ms` less the compile stages
+                    flagged `in_seam`
+    eager           host time inside the `eager:<site>` seams of
+                    `launch_ms_by`: eager `jnp` work that is no kernel
+                    entry (a log from before the field has none, and the
+                    time is `host-python`'s there, as it was)
     host-python     the rest of the `result_span`: Python between
-                    launches, dictionary work, Arrow assembly
+                    launches, dictionary work, Arrow assembly. Where the
+                    spans carry `host_ms` the query's `host_python` record
+                    splits it into the named phases (obs/trace.py
+                    HOST_PHASES; `exec-lookup` is then a phase here and no
+                    cause of its own, `scan` is the scan less its
+                    `catalog_load`) and `other`: what no seam, stage or
+                    phase covers, reported as one and never spread
     ladder-retry    failed attempts' wall (`ladder_rung.attempt_ms`)
     backoff-wait    deliberate sleeps between rungs (delay_s)
     hung-wait       a watchdog-abandoned attempt's budget
@@ -90,13 +103,15 @@ construction):
 
 from __future__ import annotations
 
+from .reader import format_host_table, host_by_operator
+
 #: residual share of wall beyond which plan-host stops counting as
 #: attributed (evidence-coverage collapse, not driver work)
 MAX_RESIDUAL_FRAC = 0.5
 
 #: cause names in render order
 CAUSE_ORDER = (
-    "execute", "device-wait", "launch", "jit-trace", "xla-compile",
+    "execute", "device-wait", "launch", "eager", "jit-trace", "xla-compile",
     "cache-load", "exec-lookup", "host-python", "exchange-wait", "spill-io",
     "catalog-load", "read", "encode", "h2d", "ladder-retry",
     "backoff-wait", "hung-wait", "ingest-decode", "ingest-commit-wait",
@@ -223,20 +238,49 @@ def _split_execution(results, spans, reads, compiles, aot_loads, lookups,
     iv += [(*_interval(e), "catalog-load") for e in cats]
     iv += [(*_interval(e), "exchange-wait") for e in exchanges]
     iv += [(*_interval(e), "spill-io") for e in spills]
+    own = list(spans) + list(results)
+    named = any("host_ms" in e for e in own)
+    if named:
+        # the lookup and the build are phases of the spans' own
+        iv = [x for x in iv if x[2] != "exec-lookup"]
     ms = _paint_ms(iv)
-    # launches have no interval of their own (no event per launch): their
-    # host time is a sum, and the compile stages that fell inside seamed
-    # calls are already counted above
-    in_seam = _paint_ms([
-        (*_interval(e), "jit-trace") for e in compiles if e.get("in_seam")
-    ])["jit-trace"]
-    launch = sum(
-        float(e.get("launch_ms") or 0.0) for e in list(spans) + list(results)
-    )
-    launch = min(max(launch - in_seam, 0.0), ms["result"])
     out = {c: ms[c] for c in _PAINT if c != "result"}
+    if named:
+        # the spans say what their own launches took, by seam name, with
+        # the reads and compile stages inside them taken out in the program
+        launch = eager = 0.0
+        for e in own:
+            for name, v in (e.get("launch_ms_by") or {}).items():
+                if name.startswith("eager:"):
+                    eager += float(v)
+                else:
+                    launch += float(v)
+        launch = min(launch, ms["result"])
+        out["eager"] = eager = min(eager, ms["result"] - launch)
+    else:
+        # launches have no interval of their own (no event per launch):
+        # their host time is a sum, and the compile stages that fell
+        # inside seamed calls are already counted above
+        in_seam = _paint_ms([
+            (*_interval(e), "jit-trace") for e in compiles
+            if e.get("in_seam")
+        ])["jit-trace"]
+        launch = sum(float(e.get("launch_ms") or 0.0) for e in own)
+        launch = min(max(launch - in_seam, 0.0), ms["result"])
     out["launch"] = launch
-    out["host-python"] = ms["result"] - launch
+    out["host-python"] = ms["result"] - launch - out.get("eager", 0.0)
+    if named:
+        phases = {}
+        for e in own:
+            for name, v in (e.get("host_ms") or {}).items():
+                phases[name] = phases.get(name, 0.0) + float(v)
+        # a scan's phase holds its catalog_load, which has causes of its own
+        if "scan" in phases:
+            phases["scan"] = max(phases["scan"] - ms["catalog-load"], 0.0)
+        out["_host_python"] = {
+            "phases": phases,
+            "other": out["host-python"] - sum(phases.values()),
+        }
     # a catalog load says what it spent: split its share accordingly
     parts = {
         k: sum(float(e.get(f"{k}_ms") or 0.0) for e in cats)
@@ -273,7 +317,8 @@ def _execution_detail(spans, results, reads, compiles) -> dict:
             rec["count"] += 1
             if not e.get("cached"):
                 rec["fresh"] += 1
-    return {"launches": launches, "reads": by_why, "compiles": by_fun}
+    return {"launches": launches, "reads": by_why, "compiles": by_fun,
+            "operators": host_by_operator(spans, results)}
 
 
 def _skew_ms(ev) -> float:
@@ -422,7 +467,7 @@ def critical_path(events) -> dict:
         # out (floored: an exchange that outlived its op span under
         # clock jitter must not go negative)
         execute = max(root_incl - exch_ms - spill_ms - cat_ms, 0.0)
-        split = None
+        split = host_python = None
         if results:
             # a log with the statement's own boundary: the lump opens
             split = _split_execution(
@@ -430,6 +475,7 @@ def critical_path(events) -> dict:
                 exchanges, spills,
             )
             execute = 0.0
+            host_python = split.pop("_host_python", None)
             exch_ms = split.pop("exchange-wait")
             spill_ms = split.pop("spill-io")
             cat_ms = split.pop("catalog-load")
@@ -484,6 +530,13 @@ def critical_path(events) -> dict:
             "chain": _op_tree_chain(spans),
             **_execution_detail(spans, results, reads, compiles),
         }
+        if host_python is not None:
+            # `host-python` by phase, and what is left of it without a name
+            qrec["host_python"] = {
+                "phases": {k: round(v, 3)
+                           for k, v in host_python["phases"].items()},
+                "other": round(host_python["other"], 3),
+            }
         if exch_worst is not None:
             sk, ev = exch_worst
             straggler = None
@@ -558,6 +611,13 @@ def render(cp: dict, out=None) -> None:
                 continue
             share = ms / rec["wall_ms"] if rec["wall_ms"] else 0.0
             p(f"   {cause:<14}{ms:>12,.1f} ms  {share:>6.1%}")
+            if cause == "host-python" and rec.get("host_python"):
+                hp = rec["host_python"]
+                for name, v in [*sorted(hp["phases"].items(),
+                                        key=lambda kv: -kv[1]),
+                                ("other", hp["other"])]:
+                    share = v / rec["wall_ms"] if rec["wall_ms"] else 0.0
+                    p(f"     {name:<16}{v:>8,.1f} ms  {share:>6.1%}")
         if rec.get("unattributed_ms"):
             p(f"   {'unattributed':<14}{rec['unattributed_ms']:>12,.1f} ms")
         if rec.get("launches"):
@@ -575,6 +635,10 @@ def render(cp: dict, out=None) -> None:
             p("   compiles: " + ", ".join(
                 f"{fun} {c['count']} ({c['fresh']} fresh, {c['ms']:,.1f} ms)"
                 for fun, c in top))
+        for line in format_host_table(sorted(
+                (rec.get("operators") or {}).items(),
+                key=lambda kv: -kv[1]["excl_ms"])):
+            p(line)
         if rec["chain"]:
             hops = " -> ".join(
                 f"{c['node']} {c['dur_ms']:,.0f}ms" for c in rec["chain"][:6]

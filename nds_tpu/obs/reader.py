@@ -263,6 +263,97 @@ def op_spans_with_exclusive(events) -> list:
     return out
 
 
+#: a span's own (exclusive) host time by name (obs/tally.py): seam name ->
+#: ms, compile stage -> ms, phase -> ms, eager site -> calls
+HOST_MS_FIELDS = ("launch_ms_by", "compile_ms", "host_ms")
+HOST_FIELDS = (*HOST_MS_FIELDS, "eager_calls")
+
+
+def add_host(dst: dict, ev: dict) -> None:
+    """Sum a span's own host time into `dst`, field by field and name by
+    name, as `launches` are summed; a span from before the fields adds
+    nothing and `dst` keeps no such key."""
+    if not any(f in ev for f in HOST_FIELDS):
+        return
+    dst["read_wait_ms"] = dst.get("read_wait_ms", 0.0) + float(
+        ev.get("read_wait_ms") or 0.0
+    )
+    for f in HOST_FIELDS:
+        by = dst.setdefault(f, {})
+        for name, v in (ev.get(f) or {}).items():
+            by[name] = by.get(name, 0) + v
+
+
+def host_parts(op: dict):
+    """(read, launch, compile, phases, other) milliseconds of an operator
+    record that `add_host` filled, or None for one from a log without the
+    fields. `other`: what is left of the operator's exclusive time, the
+    remainder no seam, stage or phase covers."""
+    if "launch_ms_by" not in op:
+        return None
+    read = op.get("read_wait_ms", 0.0)
+    parts = [sum(op[f].values()) for f in HOST_MS_FIELDS]
+    return (read, *parts, op["excl_ms"] - read - sum(parts))
+
+
+def host_by_operator(spans, results) -> dict:
+    """{plan-node type: its executions' own (exclusive) host time by name}
+    of some `op_span`s and the `result_span`s over them, `(collect)` for
+    what ran outside every plan node; empty for a log whose spans do not
+    carry the fields."""
+    ops = {}
+    roots = {}
+    for e in op_spans_with_exclusive(spans):
+        op = ops.setdefault(e.get("node", "?"), {"count": 0, "excl_ms": 0.0})
+        op["count"] += 1
+        op["excl_ms"] += e["excl_ms"]
+        add_host(op, e)
+        if e.get("depth", 0) == 0:
+            key = (e.get("app"), e.get("exec_id"))
+            roots[key] = roots.get(key, 0.0) + float(e.get("dur_ms") or 0.0)
+    for e in results:
+        add_collect(
+            ops.setdefault("(collect)", {"count": 0, "excl_ms": 0.0}),
+            e, roots,
+        )
+    return {n: op for n, op in ops.items() if "launch_ms_by" in op}
+
+
+def add_collect(op: dict, result: dict, roots: dict) -> None:
+    """A `result_span`'s own part, what the statement did outside every
+    plan node (the collect), into the operator record `op`; `roots`:
+    (app, exec_id) -> ms of the execution's root `op_span`."""
+    op["count"] += 1
+    op["excl_ms"] += float(result.get("dur_ms") or 0.0) - roots.get(
+        (result.get("app"), result.get("exec_id")), 0.0
+    )
+    add_host(op, result)
+
+
+def format_host_table(ops, top: int = 4) -> list:
+    """The per-operator table of the host's time, one line an operator and
+    under it its heaviest seam names, compile stages and phases."""
+    rows = [(node, op, host_parts(op)) for node, op in ops]
+    rows = [r for r in rows if r[2] is not None]
+    if not rows:
+        return []
+    lines = [f"   {'operator (own ms)':<18}{'excl_ms':>10}{'read_wait':>10}"
+             f"{'launch':>10}{'compile':>10}{'phases':>10}{'other':>10}"]
+    for node, op, (read, launch, comp, phases, other) in rows:
+        lines.append(
+            f"   {node:<18}{op['excl_ms']:>10,.1f}{read:>10,.1f}"
+            f"{launch:>10,.1f}{comp:>10,.1f}{phases:>10,.1f}{other:>10,.1f}"
+        )
+        for label, f, n in (("launch", "launch_ms_by", top),
+                            ("compile", "compile_ms", 4),
+                            ("phases", "host_ms", top)):
+            by = sorted(op[f].items(), key=lambda kv: -kv[1])[:n]
+            if by:
+                lines.append(f"      {label}: " + ", ".join(
+                    f"{name} {ms:,.1f}" for name, ms in by))
+    return lines
+
+
 _EMPTY_QUERY = {
     "wall_ms": None, "status": None, "runs": 0, "ops": {},
     "root_incl_ms": 0.0,
@@ -288,6 +379,10 @@ def profile_events(events) -> dict:
     # many programs the operators launched, which op times cannot say
     launch_totals = {}
 
+    # what statements did outside every plan node (result_span's own fields)
+    collect_total = {"count": 0, "excl_ms": 0.0}
+    root_ms = {}  # (app, exec_id) -> the execution's root op_span, ms
+
     def add_launches(ev):
         for kernel, n in (ev.get("launches") or {}).items():
             launch_totals[kernel] = launch_totals.get(kernel, 0) + int(n)
@@ -308,6 +403,7 @@ def profile_events(events) -> dict:
             op["count"] += 1
             op["incl_ms"] += float(ev.get("dur_ms") or 0.0)
             op["excl_ms"] += ev["excl_ms"]
+            add_host(op, ev)
             if ev.get("rows") is not None:
                 op["rows"] += int(ev["rows"])
             # Filter / Join / MultiJoin: columns in, columns handed on
@@ -335,6 +431,10 @@ def profile_events(events) -> dict:
             join["reordered"] = int(ev.get("reordered") or 0)
         if ev.get("depth", 0) == 0:
             qrec["root_incl_ms"] += float(ev.get("dur_ms") or 0.0)
+            key = (ev.get("app"), ev.get("exec_id"))
+            root_ms[key] = root_ms.get(key, 0.0) + float(
+                ev.get("dur_ms") or 0.0
+            )
     tallies = {
         "plan_cache_hits": 0,
         "plan_cache_misses": 0,
@@ -477,6 +577,18 @@ def profile_events(events) -> dict:
             ] += 1
         elif k == "result_span":
             add_launches(ev)
+            if any(f in ev for f in HOST_FIELDS):
+                # what the statement did outside every plan node (the
+                # collect), as an operator of its own in the host table
+                q = queries.setdefault(
+                    ev.get("query") or "<unscoped>",
+                    dict(_EMPTY_QUERY, ops={}),
+                )
+                for op in (
+                    q.setdefault("collect", {"count": 0, "excl_ms": 0.0}),
+                    collect_total,
+                ):
+                    add_collect(op, ev, root_ms)
         elif k == "kernel_span":
             kt = kernel_totals.setdefault(
                 ev.get("kernel") or "<unknown>",
@@ -524,6 +636,7 @@ def profile_events(events) -> dict:
         "op_totals": op_totals,
         "kernel_totals": kernel_totals,
         "launch_totals": launch_totals,
+        **({"collect_total": collect_total} if collect_total["count"] else {}),
         "tallies": tallies,
         "plan_budget": budget,
         "feedback": feedback,
@@ -633,6 +746,7 @@ def _merge_op(dst: dict, src: dict):
     for k in ("cols_in", "cols_out", "left_cap_rows", "reordered"):
         if src.get(k) is not None:
             dst[k] = dst.get(k, 0) + int(src[k])
+    add_host(dst, src)
     for order, join in (src.get("joins") or {}).items():
         mine = dst.setdefault("joins", {}).setdefault(order, {"count": 0})
         mine.update(join, count=mine["count"] + int(join.get("count") or 0))
@@ -673,8 +787,14 @@ def merge_profiles(base: dict, extra: dict) -> dict:
                 ),
                 op,
             )
+        if src.get("collect"):
+            _merge_op(dst.setdefault("collect", {}), src["collect"])
     for name, src in (extra.get("op_totals") or {}).items():
         _merge_op(base.setdefault("op_totals", {}).setdefault(name, {}), src)
+    if extra.get("collect_total"):
+        _merge_op(
+            base.setdefault("collect_total", {}), extra["collect_total"]
+        )
     for name, src in (extra.get("kernel_totals") or {}).items():
         dst = base.setdefault("kernel_totals", {}).setdefault(name, {})
         dst["count"] = dst.get("count", 0) + int(src.get("count") or 0)

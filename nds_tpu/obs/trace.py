@@ -67,6 +67,19 @@ from .. import faults
 from .. import __version__
 from ..engine.lockdebug import make_lock
 
+#: the vocabulary of a span's `host_ms`: the named phases of an operator's
+#: own host time (obs/tally.py `phase`; README "Observability" says what
+#: each covers). What no phase covers is the readers' `other`.
+HOST_PHASES = (
+    "plan-cache", "exec-lookup", "pipeline-build", "scan", "join-plan",
+    "dict-merge", "feedback", "to-arrow", "span-emit",
+)
+
+#: the stages of a span's `compile_ms`: jax's trace, lower and backend
+#: compile, and `load` for a compile stage jax's persistent cache served
+#: or an AOT executable load
+COMPILE_STAGES = ("trace", "lower", "load", "compile")
+
 #: kind -> tuple of required per-kind fields (beyond ts/kind/app).
 #: Optional fields events may also carry are documented in README
 #: "Observability". This mapping is the schema contract the golden test and
@@ -82,7 +95,14 @@ EVENT_SCHEMA = {
     # A MultiJoin's span also carries `join_order` (relation indices in the
     # order joined), `step_est_rows` (each step's estimate of the rows it
     # leaves; null: it had none), `left_caps` (the capacity each step's left
-    # side ran at) and `reordered` (1: the estimates changed the order)
+    # side ran at) and `reordered` (1: the estimates changed the order).
+    # Every span carries the node's OWN counters, exclusive of its children
+    # (obs/tally.py): `launches` {seam: n}, `launch_ms`, `reads`,
+    # `read_wait_ms`, and its own host time by name: `launch_ms_by`
+    # {seam name: ms, summing to launch_ms; eager:<site>: ms, beside it},
+    # `compile_ms` {trace | lower | load | compile: ms}, `host_ms` {phase
+    # of HOST_PHASES: ms}; optional `eager_calls` {site: calls} and, with a
+    # file sink only, `host_iv` [[name, start us from t0_ns, dur us], ...]
     "op_span": ("exec_id", "seq", "depth", "node", "explain", "dur_ms",
                 "rows", "est_bytes"),
     # one per benchmarked query/function (BenchReport.report_on)
@@ -112,14 +132,17 @@ EVENT_SCHEMA = {
     # one statement's execution as Result.collect / Result.table runs it
     # (run_script only plans): `exec_ms` the executor's root, `to_arrow_ms`
     # the collect. Optional: launches / launch_ms / reads / read_wait_ms
-    # counted outside every op_span (the collect's compaction and read)
+    # counted outside every op_span (the collect's compaction and read),
+    # and like an op_span launch_ms_by / compile_ms / host_ms / eager_calls
+    # / host_iv
     "result_span": ("exec_id", "t0_ns", "dur_ms", "exec_ms", "to_arrow_ms"),
     # one jax compile stage of one program (jax.monitoring time spans,
     # `watch_compiles`): stage trace | lower | compile, `fun` the jitted
     # function's name, `cached` True when jax's persistent compilation
     # cache served the compile stage. Optional: exec_id, depth (the
     # enclosing op_span, when an executor's tally is bound), in_seam (it
-    # fell inside a counted kernel call, so inside `launch_ms`)
+    # fell inside a counted kernel call; the span's `launch_ms_by` has it
+    # taken out already and its `compile_ms` holds it)
     "xla_compile": ("stage", "fun", "cached", "dur_ms", "t0_ns"),
     # executable-cache probe for a pipeline (hit=True: an executable for
     # this (structure, dtypes, bucket) already existed this session)
@@ -713,6 +736,9 @@ def _on_compile_span(event, start, end, fun_name="", **_):
         extra = {"exec_id": tl.exec_id, "depth": tl.depth}
         if tl.in_seam:
             extra["in_seam"] = True
+        # into the span's own `compile_ms`, and out of the seamed call or
+        # phase it fell inside
+        tl.add_compile("load" if cached else stage, start, end)
     fun = str(fun_name)
     if fun.startswith("jit(") and fun.endswith(")"):
         fun = fun[4:-1]  # lower and compile say `jit(f)`, trace says `f`

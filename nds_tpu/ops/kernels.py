@@ -49,8 +49,11 @@ U64 = jnp.uint64
 # Plan-node op_spans say WHICH operator is slow; they cannot say how many
 # programs it launched. Every decorated kernel entry point below counts
 # itself into the statement's tally (obs/tally.py: a dict add, and for the
-# outermost call of a nest one clock pair around its HOST side) and the
-# executor flushes the counts as `op_span.launches` / `launch_ms`. Nothing
+# outermost call of a nest one clock pair around its HOST side, kept under
+# the call's name) and the executor flushes the counts as
+# `op_span.launches` / `launch_ms` / `launch_ms_by`. Eager `jnp` work that
+# is no entry point of this file has seams of its own where it runs
+# (`obs/tally.py eager`, `eager:<site>`), never counted here. Nothing
 # here waits for the device: a seam that synchronizes changes what it
 # measures, and device time is the profiler's to report. An entry is at
 # least one program launch (sort_by_words runs one sort per word and

@@ -184,6 +184,93 @@ def test_engine_events_golden_schema(tmp_path):
     assert all(e["est_bytes"] >= 0 for e in ops)
 
 
+HOST_SQL = ("select a, sum(b) sb, count(*) n from t where b > 10 "
+            "group by a order by a limit 3")
+
+
+def test_spans_own_host_time_is_in_the_golden_schema(tmp_path):
+    """The new optional fields of `op_span` and `result_span`: there on
+    every span, their names from the fixed vocabularies, the schema
+    contract unbroken, and `host_iv` because this tracer writes a file."""
+    from nds_tpu.obs.trace import COMPILE_STAGES, HOST_PHASES
+
+    s = _traced_session(tmp_path)
+    with bind(s.tracer), faults.scope("q"):
+        s.sql(HOST_SQL).collect()
+    evs = _events(s.tracer.path)
+    assert R.validate_events(evs) == []
+    spans = [e for e in evs if e["kind"] in ("op_span", "result_span")]
+    assert {e["kind"] for e in spans} == {"op_span", "result_span"}
+    for e in spans:
+        for field in ("launches", "launch_ms_by", "compile_ms", "host_ms"):
+            assert isinstance(e[field], dict), (e["kind"], field)
+        assert set(e["host_ms"]) <= set(HOST_PHASES)
+        assert set(e["compile_ms"]) <= set(COMPILE_STAGES)
+        # `launch_ms` is the kernel seams' alone, as it always was
+        assert sum(v for k, v in e["launch_ms_by"].items()
+                   if not k.startswith("eager:")) == pytest.approx(
+            e["launch_ms"], abs=0.01)
+        assert set(e["launch_ms_by"]) <= set(e["launches"]) | {
+            f"eager:{site}" for site in e.get("eager_calls", ())}
+        for name, off_us, dur_us in e.get("host_iv", ()):
+            assert name in e["launch_ms_by"] or name in e["host_ms"]
+            assert off_us >= 0 and (off_us + dur_us) / 1e3 <= e["dur_ms"] + 0.01
+    assert any("host_iv" in e for e in spans)
+    phases = {p for e in spans for p in e["host_ms"]}
+    assert {"scan", "span-emit", "to-arrow"} <= phases
+    # the first execution compiled: the stages are the spans' own
+    assert any(e["compile_ms"] for e in spans)
+    result, = [e for e in spans if e["kind"] == "result_span"]
+    assert "to-arrow" in result["host_ms"]
+
+
+def test_a_spans_named_host_time_stays_inside_its_exclusive_time(tmp_path):
+    s = _traced_session(tmp_path)
+    with bind(s.tracer):
+        for name in ("cold", "warm"):
+            with faults.scope(name):
+                s.sql(HOST_SQL).collect()
+            s.register_arrow("tick", pa.table({"n": [1]}))
+    evs = _events(s.tracer.path)
+    for e in R.op_spans_with_exclusive(evs):
+        named = (e["read_wait_ms"] + sum(e["launch_ms_by"].values())
+                 + sum(e["compile_ms"].values()) + sum(e["host_ms"].values()))
+        assert named <= e["excl_ms"] + 0.02, (e["query"], e["node"])
+    prof = R.profile_events(evs)
+    for node, op in prof["queries"]["warm"]["ops"].items():
+        read, launch, comp, phases, other = R.host_parts(op)
+        assert other >= -0.05 and comp == 0.0, node
+    lines = R.format_host_table(prof["queries"]["warm"]["ops"].items())
+    assert lines[0].split()[:2] == ["operator", "(own"]
+    assert any("phases:" in ln for ln in lines)
+
+
+@pytest.mark.parametrize("shape", ["ring", "memory", "off"])
+def test_no_host_iv_where_no_file_keeps_it(shape, monkeypatch):
+    from nds_tpu.obs import flight as FL
+
+    FL.reset_shared()
+    if shape == "off":
+        monkeypatch.setenv("NDS_FLIGHT_RECORDER", "off")
+    s = Session()
+    if shape == "memory":
+        s.tracer = Tracer()
+    s.register_arrow(
+        "t", pa.table({"a": [1, 2, 3, 4, 2, 1], "b": [10, 20, 30, 40, 50, 60]}))
+    with bind(s.tracer), faults.scope("q"):
+        result = s.sql(HOST_SQL)
+        result.collect()
+    if shape == "off":
+        assert s.tracer is None and result.executor.tally is None
+        return
+    assert result.executor.tally.keep_iv is False
+    evs = s.tracer.events if shape == "memory" else FL.recorder().snapshot()
+    spans = [e for e in evs if e["kind"] in ("op_span", "result_span")]
+    assert spans and not any("host_iv" in e for e in spans)
+    assert all("launch_ms_by" in e and "host_ms" in e for e in spans)
+    FL.reset_shared()
+
+
 def test_op_span_nesting_invariants(tmp_path):
     s = _traced_session(tmp_path)
     with faults.scope("q"):

@@ -12,6 +12,7 @@ import subprocess
 import sys
 import time
 
+import jax
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -847,3 +848,493 @@ def test_new_work_on_the_ring_only_path_fits_its_budget(raw):
           f"clocks {clock_us:.2f} us: {added_ms:.3f} ms added")
     assert n_reads > 0 and n_launch > 0
     assert added_ms < 1.0
+
+
+# ---------------------------------------------------------------------------
+# the host's half by name: launch_ms_by, compile_ms, host_ms, host_iv
+# ---------------------------------------------------------------------------
+
+HOST_TABLES = ("store_sales", "date_dim", "item", "store",
+               "customer_demographics", "promotion")
+
+
+@pytest.fixture(scope="module")
+def host_run(raw, tmp_path_factory):
+    """query36, query3 and query7 over SF0.01, each run twice with a file
+    tracer (the second execution warm), and the events read back."""
+    from nds_tpu.datagen.query_streams import instantiate
+    from nds_tpu.schema import get_schemas
+
+    trace_dir = tmp_path_factory.mktemp("host_trace")
+    s = Session(conf={"engine.trace_dir": str(trace_dir)})
+    schemas = get_schemas(True)
+    for t in HOST_TABLES:
+        s.register_csv_dir(t, os.path.join(raw, t), schemas[t])
+    rng = np.random.default_rng(7)
+    for q in (36, 3, 7):
+        sql = instantiate(q, rng, 0.01)
+        _run(s, sql, f"query{q}.first")
+        s.register_arrow("tick", pa.table({"n": [q]}))
+        _run(s, sql, f"query{q}")
+    s.tracer.close()
+    return R.read_events(str(trace_dir))
+
+
+def _own_spans(events, query):
+    """(op_spans with `excl_ms`, result_spans) of one statement."""
+    ops = [e for e in R.op_spans_with_exclusive(events)
+           if e.get("query") == query]
+    results = [e for e in events
+               if e["kind"] == "result_span" and e.get("query") == query]
+    return ops, results
+
+
+def test_launch_ms_by_sums_to_launch_ms_and_a_nest_is_timed_once():
+    tl = T.Tally(Tracer(), 1)
+    outer = tl.enter("group_by_words")
+    time.sleep(0.002)
+    assert tl.enter("sort_by_words") is None  # counted, timed by the outer
+    time.sleep(0.002)
+    tl.leave(outer)
+    tl.leave(tl.enter("take_columns", 3))
+    own = tl.take()
+    assert own["launches"] == {"group_by_words": 1, "sort_by_words": 1,
+                               "take_columns": 3}
+    assert set(own["launch_ms_by"]) == {"group_by_words", "take_columns"}
+    assert own["launch_ms_by"]["group_by_words"] >= 4.0
+    assert sum(own["launch_ms_by"].values()) == pytest.approx(
+        own["launch_ms"], abs=2e-3)
+    assert own["compile_ms"] == {} and own["host_ms"] == {}
+    assert "eager_calls" not in own and "host_iv" not in own
+
+
+def test_a_compile_stage_inside_a_seamed_call_is_compile_ms_not_launch():
+    tracer = Tracer()
+    with bind(tracer), T.bind(T.Tally(tracer, 5)) as tl:
+        token = tl.enter("sort_by_words")
+        time.sleep(0.03)
+        now = time.time()
+        # a trace of 20 ms that held an inner trace of 5 ms, innermost first
+        obs_trace._on_compile_span(
+            "/jax/core/compile/jaxpr_trace_duration", now - 0.015,
+            now - 0.010, fun_name="inner")
+        obs_trace._on_compile_span(
+            "/jax/core/compile/jaxpr_trace_duration", now - 0.020, now,
+            fun_name="_kv_sort_perm")
+        obs_trace._on_compile_event("/jax/compilation_cache/cache_hits")
+        obs_trace._on_compile_span(
+            "/jax/core/compile/backend_compile_duration", now, now + 0.004,
+            fun_name="jit(_kv_sort_perm)")
+        tl.leave(token)
+        own = tl.take()
+    # each instant once: the inner trace's 5 ms are not counted twice
+    assert own["compile_ms"]["trace"] == pytest.approx(20.0, abs=0.01)
+    assert own["compile_ms"]["load"] == pytest.approx(4.0, abs=0.01)
+    assert set(own["compile_ms"]) == {"trace", "load"}
+    # the seamed call took 30 ms and more; the 24 ms of stages are not in it
+    assert 0 < own["launch_ms_by"]["sort_by_words"] < 30.0 + 10.0 - 24.0 + 6.0
+    assert own["launch_ms"] == pytest.approx(
+        own["launch_ms_by"]["sort_by_words"], abs=2e-3)
+    assert [e["in_seam"] for e in tracer.events] == [True] * 3
+
+
+def test_separate_stages_add_up_under_their_names():
+    tl = T.Tally(Tracer(), 1)
+    tl.add_compile("load", 10.0, 10.25)  # an AOT load
+    tl.add_compile("trace", 11.0, 11.1)
+    tl.add_compile("load", 12.0, 12.05)
+    assert tl.take()["compile_ms"] == {
+        "load": pytest.approx(300.0), "trace": pytest.approx(100.0)}
+
+
+def test_phases_nest_innermost_first_and_a_jax_trace_times_nothing_apart():
+    tl = T.Tally(Tracer(), 1)
+    seen = {}
+
+    def body(x):
+        # what a pipeline build does: the engine's own seamed code, traced
+        seen["eager"] = T.eager("expr")
+        seen["phase"] = T.phase("dict-merge")
+        seen["kernel"] = tl.enter("segment_reduce")
+        time.sleep(0.002)
+        return x + 1
+
+    with T.bind(tl):
+        with T.phase("exec-lookup"):
+            time.sleep(0.002)
+            with T.phase("pipeline-build"):
+                time.sleep(0.002)
+                jax.make_jaxpr(body)(1)
+            with T.eager("concat"):
+                time.sleep(0.002)
+        own = tl.take()
+    assert seen == {"eager": T._NOTHING, "phase": T._NOTHING, "kernel": None}
+    assert set(own["host_ms"]) == {"exec-lookup", "pipeline-build"}
+    assert set(own["host_ms"]) <= set(T.PHASES)
+    assert own["launch_ms_by"].keys() == {"eager:concat"}
+    assert own["eager_calls"] == {"concat": 1}  # counted where it is timed
+    assert own["launches"] == {"segment_reduce": 1}  # counted, as ever
+    # the build's 2 ms and the 2 ms under the trace: the phase's, or the
+    # trace stage's where a compile listener of this process reported it
+    assert set(own["compile_ms"]) <= {"trace"}
+    build = own["host_ms"]["pipeline-build"]
+    assert build + own["compile_ms"].get("trace", 0.0) >= 3.9 and build > 1.9
+    assert 1.9 < own["host_ms"]["exec-lookup"] < 4.0
+    assert tl.in_seam is False
+
+
+def test_an_eager_seam_is_timed_by_name_and_kept_out_of_the_launches():
+    tl = T.Tally(Tracer(), 1)
+    with T.bind(tl):
+        with T.eager("concat"):
+            time.sleep(0.002)
+            tl.leave(tl.enter("take_columns"))  # a kernel inside: its own
+        token = tl.enter("group_by_words")
+        with T.eager("cumsum"):  # inside a kernel seam: the kernel's time
+            pass
+        with T.phase("dict-merge"):
+            pass
+        tl.leave(token)
+        own = tl.take()
+    assert own["launches"] == {"take_columns": 1, "group_by_words": 1}
+    assert own["eager_calls"] == {"concat": 1}
+    assert set(own["launch_ms_by"]) == {
+        "eager:concat", "take_columns", "group_by_words"}
+    assert own["launch_ms_by"]["eager:concat"] >= 2.0
+    # `launch_ms` stays what it was: the kernel seams' alone
+    assert own["launch_ms"] == pytest.approx(
+        own["launch_ms_by"]["take_columns"]
+        + own["launch_ms_by"]["group_by_words"], abs=2e-3)
+    assert own["host_ms"] == {}
+
+
+def test_a_child_span_inside_a_kernel_seam_starts_outside_every_seam():
+    tl = T.Tally(Tracer(), 1)
+    saved = tl.push(0)
+    token = tl.enter("fused_pipeline")
+    inner = tl.push(1)
+    assert tl.in_seam is False
+    assert tl.enter("take_columns") is not None  # timed: its own frame
+    child = tl.pop(inner)
+    assert tl.in_seam is True  # the parent's seam is open again
+    tl.leave(token)
+    parent = tl.pop(saved)
+    assert child["launches"] == {"take_columns": 1}
+    assert set(parent["launch_ms_by"]) == {"fused_pipeline"}
+
+
+def test_a_child_span_is_not_its_parents_open_phase():
+    tl = T.Tally(Tracer(), 1)
+    with T.bind(tl):
+        saved = tl.push(0)
+        with T.phase("join-plan"):
+            inner = tl.push(1)  # a child plan node executes inside it
+            time.sleep(0.01)
+            child = tl.pop(inner)
+        parent = tl.pop(saved)
+    assert child["host_ms"] == {} and child["launch_ms_by"] == {}
+    assert parent["host_ms"]["join-plan"] < 5.0  # not the child's 10 ms
+
+
+@pytest.mark.parametrize("query", ["query36", "query3", "query7"])
+def test_a_spans_named_time_never_exceeds_its_exclusive_time(host_run, query):
+    ops, results = _own_spans(host_run, query)
+    assert len(results) == 1 and len(ops) >= 5
+    roots = sum(e["dur_ms"] for e in ops if e["depth"] == 0)
+    spans = ops + [dict(e, excl_ms=e["dur_ms"] - roots) for e in results]
+    named_total = 0.0
+    for e in spans:
+        assert sum(v for k, v in e["launch_ms_by"].items()
+                   if not k.startswith("eager:")) == pytest.approx(
+            e["launch_ms"], abs=0.01)
+        assert set(e["host_ms"]) <= set(T.PHASES)
+        assert set(e["compile_ms"]) <= set(T.COMPILE_STAGES)
+        assert all(v >= 0 for f in ("launch_ms_by", "compile_ms", "host_ms")
+                   for v in e[f].values())
+        named = (e["read_wait_ms"] + sum(e["launch_ms_by"].values())
+                 + sum(e["compile_ms"].values()) + sum(e["host_ms"].values()))
+        # rounding to the microsecond, field by field
+        assert named <= e["excl_ms"] + 0.02, (e["node"], named, e["excl_ms"])
+        named_total += named
+    # and the seams cover the statement: the remainder is the smaller part
+    assert named_total >= 0.75 * results[0]["dur_ms"]
+    eager = {k for e in spans for k in e.get("eager_calls") or ()}
+    assert eager and {f"eager:{k}" for k in eager} >= {
+        k for e in spans for k in e["launch_ms_by"] if k.startswith("eager:")}
+    assert not any(k.startswith("eager:")
+                   for e in spans for k in e["launches"])
+
+
+def test_query36_rebuilds_a_pipeline_at_every_execution(host_run):
+    """What `compiles.window` 0 cannot see and `compile_ms` says: the warm
+    execution traces again and builds a pipeline again (ROADMAP A3)."""
+    ops, _ = _own_spans(host_run, "query36")
+    stages = {}
+    for e in ops:
+        for k, v in e["compile_ms"].items():
+            stages[k] = stages.get(k, 0.0) + v
+    assert stages.get("trace", 0.0) > 0
+    assert sum(e["host_ms"].get("pipeline-build", 0.0) for e in ops) > 0
+    ops3, _ = _own_spans(host_run, "query3")
+    assert not any(e["compile_ms"] for e in ops3)
+
+
+@pytest.mark.parametrize("query", ["query36", "query3", "query7"])
+def test_host_iv_tiles_its_span_beside_the_reads(host_run, query):
+    ops, results = _own_spans(host_run, query)
+    reads = [e for e in host_run
+             if e["kind"] == "host_read" and e.get("query") == query]
+    placed = []
+    for e in ops + results:
+        lo, hi = e["t0_ns"], e["t0_ns"] + e["dur_ms"] * 1e6
+        names = set(e["launch_ms_by"]) | set(e["host_ms"])
+        for name, off_us, dur_us in e.get("host_iv", ()):
+            assert name in names, (name, e["node"] if "node" in e else "")
+            a = e["t0_ns"] + off_us * 1e3
+            b = a + dur_us * 1e3
+            assert dur_us >= 0 and off_us >= 0
+            assert lo - 2e3 <= a and b <= hi + 5e3  # inside its span
+            placed.append((a, b, name))
+        by_name = {}
+        for name, _, dur_us in e.get("host_iv", ()):
+            by_name[name] = by_name.get(name, 0.0) + dur_us / 1e3
+        for name, ms in {**e["launch_ms_by"], **e["host_ms"]}.items():
+            # the pieces of a name add up to its milliseconds
+            assert by_name.get(name, 0.0) == pytest.approx(ms, abs=0.25), name
+    assert len(placed) > 20
+    placed.sort()
+    for (a0, b0, n0), (a1, b1, n1) in zip(placed, placed[1:]):
+        assert a1 >= b0 - 2e3, (n0, n1)  # 1 us of rounding an end
+    for r in reads:
+        ra, rb = r["t0_ns"], r["t0_ns"] + r["dur_ms"] * 1e6
+        for a, b, name in placed:
+            # the two clocks are read one after the other at a span's
+            # start and at a read's: tens of microseconds under load
+            assert b <= ra + 5e4 or a >= rb - 5e4, (name, r["why"])
+
+
+def test_no_host_iv_without_a_file_and_no_event_per_launch_or_phase(raw):
+    ring = Session()  # the driver's untraced runs: ring-only
+    assert ring.tracer.path is None
+    collected = Tracer()  # in-memory, no file either
+    for tracer in (ring.tracer, collected):
+        assert T.Tally(tracer, 1).keep_iv is False
+    s = _tpcds_session(raw, collected)
+    _run(s, _statements()["query3"], "q")
+    spans = [e for e in collected.events
+             if e["kind"] in ("op_span", "result_span")]
+    assert spans and not any("host_iv" in e for e in spans)
+    assert all("launch_ms_by" in e and "host_ms" in e for e in spans)
+    # the seams emit nothing of their own: the kinds are the old ones
+    assert {e["kind"] for e in collected.events} <= set(EVENT_SCHEMA)
+    assert not any(k in ("launch", "phase", "eager")
+                   for k in {e["kind"] for e in collected.events})
+    launches, _ = _counts(collected.events, "q")
+    spans_q = [e for e in spans if e.get("query") == "q"]
+    assert sum(launches.values()) > len(spans_q) > 0
+    assert sum(len(e.get("eager_calls", ())) for e in spans_q) > 0
+
+
+def test_with_the_recorder_off_the_seams_read_no_clock(raw, monkeypatch):
+    monkeypatch.setenv("NDS_FLIGHT_RECORDER", "off")
+    s = Session()
+    assert s.tracer is None
+    from nds_tpu.schema import get_schemas
+
+    schemas = get_schemas(True)
+    for t in ("store_sales", "date_dim", "item"):
+        s.register_csv_dir(t, os.path.join(raw, t), schemas[t])
+    sql = _statements()["query3"]
+    s.sql(sql).collect()  # warm: nothing compiles below
+    reads = []
+
+    def counted():
+        reads.append(1)
+        return 0.0
+
+    from nds_tpu.ops import kernels as K
+
+    monkeypatch.setattr(T, "_perf", counted)
+    assert T.current() is None
+    with T.phase("dict-merge"), T.eager("concat"):
+        pass
+    K.mask_count(K.jnp.ones(8, bool))
+    assert T.phase("scan") is T.phase("to-arrow")  # one shared nothing
+    result = s.sql(sql)
+    assert result.executor is None or result.executor.tally is None
+    result.table()
+    assert result.executor.tally is None
+    assert reads == []
+
+
+def test_the_schema_names_the_new_optional_fields():
+    import inspect
+
+    src = inspect.getsource(obs_trace)
+    block = src[src.index("EVENT_SCHEMA = {"):src.index('"query_span"')]
+    for field in ("launch_ms_by", "compile_ms", "host_ms", "eager_calls",
+                  "host_iv"):
+        assert field in block, field
+    assert set(T.PHASES) == set(obs_trace.HOST_PHASES)
+    assert len(T.PHASES) <= 12
+    assert T.COMPILE_STAGES == ("trace", "lower", "load", "compile")
+    readme = open(os.path.join(REPO, "README.md")).read()
+    for name in (*T.PHASES, "launch_ms_by", "compile_ms", "host_ms",
+                 "host_iv", "eager_calls", "eager:<site>"):
+        assert name in readme, name
+
+
+def test_real_spans_with_the_new_fields_validate(host_run):
+    assert R.validate_events(host_run) == []
+    ops, results = _own_spans(host_run, "query36")
+    assert any("host_iv" in e for e in ops)
+    assert all(isinstance(iv, list) and len(iv) == 3
+               for e in ops + results for iv in e.get("host_iv", ()))
+
+
+NAMED_EVENTS = [
+    _ev("query_span", 0, 1000, status="Completed", retries=0),
+    _ev("result_span", 50, 900, exec_id=1, exec_ms=880.0, to_arrow_ms=20.0,
+        launches={"compact_indices": 1}, launch_ms=4.0, reads=1,
+        read_wait_ms=15.0, launch_ms_by={"compact_indices": 4.0},
+        compile_ms={}, host_ms={"to-arrow": 1.0}),
+    _ev("op_span", 50, 880, exec_id=1, seq=2, depth=0, node="Aggregate",
+        explain="", rows=1, est_bytes=0, launches={"take_columns": 12},
+        launch_ms=40.0, reads=2, read_wait_ms=300.0,
+        launch_ms_by={"take_columns": 40.0, "eager:concat": 100.0},
+        eager_calls={"concat": 2},
+        compile_ms={"trace": 60.0, "load": 40.0},
+        host_ms={"pipeline-build": 30.0, "exec-lookup": 5.0,
+                 "dict-merge": 20.0}),
+    _ev("op_span", 60, 200, exec_id=1, seq=1, depth=1, node="Scan",
+        explain="", rows=1, est_bytes=0, launches={}, launch_ms=0.0, reads=0,
+        read_wait_ms=0.0, launch_ms_by={}, compile_ms={},
+        host_ms={"scan": 198.0}),
+    _ev("catalog_load", 61, 190, table="t", columns=1, loaded=1, rows=1,
+        cache="miss", read_ms=100.0, encode_ms=50.0, h2d_ms=40.0),
+    _ev("exec_cache", 300, 135, pipeline="p", bucket=1024, hit=False),
+    _ev("xla_compile", 310, 60, stage="trace", fun="pipe", cached=False,
+        exec_id=1, depth=0),
+    _ev("aot_cache", 380, 40, op="load", result="hit"),
+    _ev("host_read", 600, 250, why="nrows", bytes=4, exec_id=1, depth=0),
+    _ev("host_read", 860, 50, why="join_size", bytes=8, exec_id=1, depth=0),
+    _ev("host_read", 935, 15, why="collect", bytes=64, exec_id=1, depth=-1),
+]
+
+
+def test_critical_path_opens_host_python_into_the_phases_and_other():
+    q = CP.critical_path(NAMED_EVENTS)["queries"]["q"]
+    c = q["causes"]
+    assert c["device-wait"] == 315.0
+    assert (c["jit-trace"], c["cache-load"]) == (60.0, 40.0)
+    # from the spans' own fields, nothing subtracted again; the eager
+    # seams are a cause of their own and `launch` stays the kernel seams'
+    assert (c["launch"], c["eager"]) == (44.0, 100.0)
+    # the lookup is a phase now, not the exec_cache event's 135 ms
+    assert c["exec-lookup"] == 0.0
+    assert (c["read"], c["encode"], c["h2d"]) == (100.0, 50.0, 40.0)
+    # the rest of the 900 ms result_span, one cause as ever
+    assert c["host-python"] == 900 - (315 + 100 + 190 + 144)
+    assert c["plan-host"] == 100.0
+    assert sum(c.values()) == pytest.approx(q["wall_ms"])
+    hp = q["host_python"]
+    assert hp["phases"] == {
+        "pipeline-build": 30.0, "exec-lookup": 5.0, "dict-merge": 20.0,
+        "to-arrow": 1.0, "scan": 8.0}  # the scan's 198 less its load's 190
+    assert hp["other"] == pytest.approx(c["host-python"] - 64.0)
+    ops = q["operators"]
+    assert set(ops) == {"Aggregate", "Scan", "(collect)"}
+    assert ops["Aggregate"]["launch_ms_by"] == {
+        "take_columns": 40.0, "eager:concat": 100.0}
+    assert ops["Aggregate"]["eager_calls"] == {"concat": 2}
+    assert ops["Aggregate"]["excl_ms"] == 680.0
+    assert R.host_parts(ops["Aggregate"]) == (300.0, 140.0, 100.0, 55.0, 85.0)
+    assert ops["(collect)"]["excl_ms"] == 20.0
+    # a log from before the fields has no such record
+    assert "host_python" not in CP.critical_path(SPLIT_EVENTS)["queries"]["q"]
+
+
+def test_profile_cli_prints_the_host_table_for_a_log_with_the_fields(
+        tmp_path, capsys):
+    import json
+
+    from nds_tpu.cli import profile as profile_cli
+
+    log = tmp_path / "events-x.jsonl"
+    log.write_text("".join(json.dumps(e) + "\n" for e in NAMED_EVENTS))
+    assert not profile_cli.main(
+        [str(log), "--critical-path", "--min_attributed", "0.9"])
+    out = capsys.readouterr().out
+    for needle in ("   host-python          151.0 ms   15.1%\n"
+                   "     pipeline-build      30.0 ms    3.0%\n"
+                   "     dict-merge          20.0 ms    2.0%\n",
+                   "     other               87.0 ms    8.7%\n",
+                   "operator (own ms)", "launch: eager:concat 100.0, "
+                   "take_columns 40.0", "compile: trace 60.0, load 40.0",
+                   "phases: pipeline-build 30.0, dict-merge 20.0, "
+                   "exec-lookup 5.0", "(collect)"):
+        assert needle in out, needle
+    assert "\n   exec-lookup " not in out
+    assert not profile_cli.main([str(log), "--per_query"])
+    out = capsys.readouterr().out
+    assert "operator (own ms)" in out and "eager:concat 100.0" in out
+
+
+GOLDEN_BEFORE_THE_FIELDS = """\
+== critical path: 1 queries
+
+-- q: wall 1,000.0 ms  Completed  (attributed 100%)
+   device-wait          315.0 ms   31.5%
+   launch                50.0 ms    5.0%
+   jit-trace             50.0 ms    5.0%
+   xla-compile           50.0 ms    5.0%
+   cache-load            50.0 ms    5.0%
+   exec-lookup           20.0 ms    2.0%
+   host-python          185.0 ms   18.5%
+   read                  90.0 ms    9.0%
+   encode                54.0 ms    5.4%
+   h2d                   36.0 ms    3.6%
+   plan-host            100.0 ms   10.0%
+   launches: take_columns 12, compact_indices 1
+   reads: nrows 1 (250.0 ms), join_size 1 (50.0 ms), collect 1 (15.0 ms)
+   compiles: pipe 1 (0 fresh, 70.0 ms), gather 1 (1 fresh, 50.0 ms), \
+_pad 0 (0 fresh, 20.0 ms), inner 0 (0 fresh, 10.0 ms)
+   chain: MultiJoin 880ms -> Scan 200ms
+"""
+
+
+def test_a_log_from_before_the_fields_prints_what_it_printed(tmp_path, capsys):
+    """The parent commit's output for SPLIT_EVENTS, to the character."""
+    import json
+
+    from nds_tpu.cli import profile as profile_cli
+
+    log = tmp_path / "events-x.jsonl"
+    log.write_text("".join(json.dumps(e) + "\n" for e in SPLIT_EVENTS))
+    assert not profile_cli.main(
+        [str(log), "--critical-path", "--min_attributed", "0.9"])
+    assert capsys.readouterr().out == GOLDEN_BEFORE_THE_FIELDS
+    assert not profile_cli.main([str(log), "--per_query"])
+    assert "operator (own ms)" not in capsys.readouterr().out
+
+
+def test_merged_profiles_sum_the_new_fields_as_they_sum_launches():
+    a = R.profile_events(NAMED_EVENTS)
+    b = R.profile_events(NAMED_EVENTS)
+    agg = a["queries"]["q"]["ops"]["Aggregate"]
+    assert agg["launch_ms_by"] == {"take_columns": 40.0, "eager:concat": 100.0}
+    assert agg["read_wait_ms"] == 300.0
+    assert a["queries"]["q"]["collect"]["host_ms"] == {"to-arrow": 1.0}
+    merged = R.merge_profiles(a, b)
+    agg = merged["queries"]["q"]["ops"]["Aggregate"]
+    assert agg["launch_ms_by"] == {"take_columns": 80.0, "eager:concat": 200.0}
+    assert agg["compile_ms"] == {"trace": 120.0, "load": 80.0}
+    assert agg["host_ms"]["pipeline-build"] == 60.0
+    assert agg["eager_calls"] == {"concat": 4}
+    assert merged["op_totals"]["Scan"]["host_ms"] == {"scan": 396.0}
+    assert merged["queries"]["q"]["collect"]["count"] == 2
+    assert merged["collect_total"]["launch_ms_by"] == {"compact_indices": 8.0}
+    old = R.merge_profiles(R.profile_events(SPLIT_EVENTS),
+                           R.profile_events(SPLIT_EVENTS))
+    assert "launch_ms_by" not in old["queries"]["q"]["ops"]["MultiJoin"]
+    assert "collect" not in old["queries"]["q"] and "collect_total" not in old
