@@ -24,10 +24,12 @@ configures it; the batches themselves live in the external engine).
 
 from __future__ import annotations
 
+import threading
+from collections import OrderedDict
 from dataclasses import dataclass, replace
-from functools import partial
+from functools import lru_cache, partial
 from time import perf_counter as _perf
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -581,50 +583,209 @@ def table_to_arrow(table: Table) -> pa.Table:
 # ---------------------------------------------------------------------------
 
 
+class _Derived(NamedTuple):
+    """One derivation of `_DictMemo`."""
+
+    inputs: tuple  # the dictionary objects it came from: held, so their
+    # `id`s stay theirs
+    dictionary: pa.Array  # the derived dictionary: this object, every time
+    remaps: tuple  # an input: a device vector from its codes to codes of
+    # `dictionary`, or None for the identity
+    nbytes: int  # what the entry keeps alive, host and device together
+
+
+class _DictMemo:
+    """Dictionary derivations by the identity of what they derive from.
+
+    A merged or a sorted dictionary is a function of the dictionary objects
+    it is derived from, and dictionaries are immutable Arrow arrays, so one
+    derivation serves every later call on the same objects: no Arrow work,
+    no host-to-device put, and above all the SAME derived object, which is
+    what `fuse.input_signature` keys an executable by. Keys are `id`s, so an
+    entry holds its inputs (a recycled address must not alias another
+    dictionary: the reasoning of `fuse._capture_inputs`). LRU by entry count
+    and by bytes: entries are dimension-sized host arrays and int32 device
+    vectors outside the memory budgeter, and a scan that encodes its
+    dictionaries anew at every execution (a lakehouse pruned read) leaves
+    an entry that can never hit at every execution; neither may grow it
+    without limit. `Session.recover_memory` and `Session.close` empty it."""
+
+    def __init__(self, max_entries: int = 128, max_bytes: int = 64 << 20):
+        self.max_entries = max_entries
+        self.max_bytes = max_bytes
+        self.nbytes = 0
+        self._entries = OrderedDict()  # (kind, id, ...) -> _Derived
+        self._lock = threading.Lock()
+
+    def derived(self, kind, inputs, derive) -> _Derived:
+        """The `kind` derivation of the dictionary objects `inputs`, from
+        `derive(inputs) -> (dictionary, remaps as numpy or None)` the first
+        time. Counts a `hit` or a `miss` into the bound tally."""
+        key = (kind, *map(id, inputs))
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is not None:
+                self._entries.move_to_end(key)
+        if entry is not None:
+            _tally.dict_memo("hit")
+            return entry
+        _tally.dict_memo("miss")
+        with _tally.phase("dict-merge"):
+            dictionary, remaps = derive(inputs)
+        if any(r is not None for r in remaps):
+            # under a jax trace (a CASE inside a fused pipeline) the vectors
+            # must still be concrete arrays: constants to that trace and
+            # every later one, never a tracer
+            with _tally.eager("dict_remap"), jax.ensure_compile_time_eval():
+                remaps = tuple(
+                    None if r is None else jnp.asarray(r, dtype=jnp.int32)
+                    for r in remaps
+                )
+        held = {id(d): d for d in (*inputs, dictionary)}
+        nbytes = sum(d.nbytes for d in held.values()) + sum(
+            r.nbytes for r in remaps if r is not None
+        )
+        entry = _Derived(tuple(inputs), dictionary, remaps, nbytes)
+        with self._lock:
+            # two threads may derive one key at once: the first to land
+            # stays, so a key never hands out two objects
+            kept = self._entries.setdefault(key, entry)
+            if kept is entry:
+                self.nbytes += nbytes
+            # oldest first; an entry larger than the whole bound goes too
+            # (its caller has it, the next call derives it again)
+            while self._entries and (
+                len(self._entries) > self.max_entries
+                or self.nbytes > self.max_bytes
+            ):
+                self.nbytes -= self._entries.popitem(last=False)[1].nbytes
+        return kept
+
+    def clear(self):
+        with self._lock:
+            self._entries.clear()
+            self.nbytes = 0
+
+    def __len__(self):
+        return len(self._entries)
+
+
+_DICT_MEMO = _DictMemo()
+_NO_STRINGS = pa.array([], type=pa.string())
+
+
+def clear_dictionary_memo():
+    """Let go of every kept derivation: its device vectors and the
+    dictionaries it holds (`Session.recover_memory`, `Session.close`). The
+    next call derives anew, into new objects."""
+    _DICT_MEMO.clear()
+
+
+@lru_cache(maxsize=1024)
+def literal_dictionary(value: str) -> pa.Array:
+    """A string literal's one-entry dictionary: one object a value, so a
+    derivation over (a column's dictionary, this) is found again the next
+    time the expression is evaluated or traced."""
+    return pa.array([value], type=pa.string())
+
+
+def _as_string(d: pa.Array) -> pa.Array:
+    return d if d.type == pa.string() else d.cast(pa.string())
+
+
+def _identity_or(remap: np.ndarray):
+    """None where `remap` maps every code to itself (no gather needed)."""
+    n = len(remap)
+    if np.array_equal(remap, np.arange(n, dtype=remap.dtype)):
+        return None
+    return remap.astype(np.int32)
+
+
+def _derive_merged(dicts):
+    casts = [_as_string(d) for d in dicts]
+    if len(casts) == 1:
+        # one object on every side: its own codes already mean its entries
+        return casts[0], (None,)
+    unified = pc.unique(pa.concat_arrays(casts))
+    remaps = tuple(
+        _identity_or(pc.index_in(c, unified).to_numpy(zero_copy_only=False))
+        for c in casts
+    )
+    if remaps[0] is None and len(unified) == len(casts[0]):
+        # `pc.unique` keeps first appearance: nothing new came after the
+        # first input, so the merged dictionary IS the first, entry for
+        # entry; hand that object back and chains converge on it
+        unified = casts[0]
+    return unified, remaps
+
+
+def _derive_sorted(dicts):
+    d = _as_string(dicts[0])
+    order = pc.array_sort_indices(d)  # indices of values in sorted order
+    rank = np.empty(len(d), dtype=np.int32)
+    rank[order.to_numpy(zero_copy_only=False)] = np.arange(len(d), dtype=np.int32)
+    if _identity_or(rank) is None:
+        return d, (None,)
+    return d.take(order), (rank,)
+
+
+def merge_dictionaries(dicts):
+    """One dictionary holding every entry of `dicts` (pyarrow arrays or
+    None, one a column), and a column's remap onto it: `(unified, remaps)`,
+    `remaps[i]` a device int32 vector indexed by column i's codes, or None
+    where those codes already are codes of `unified` (an empty or absent
+    dictionary; the identity). Derived once per tuple of distinct objects
+    (`_DictMemo`): the same inputs get the same `unified` object back.
+
+    Every non-empty input one object (a ROLLUP's levels over one base
+    column, a single-column CASE): that object is `unified`, no Arrow work
+    and no dispatch; its entries are not made distinct, which is what a
+    single column's own codes mean everywhere else."""
+    # the non-empty objects, each once, in order of first appearance
+    distinct = list(
+        {id(d): d for d in dicts if d is not None and len(d)}.values()
+    )
+    if not distinct or (
+        len(distinct) == 1 and distinct[0].type == pa.string()
+    ):
+        _tally.dict_memo("same")
+        return (distinct[0] if distinct else _NO_STRINGS), [None] * len(dicts)
+    entry = _DICT_MEMO.derived("merge", distinct, _derive_merged)
+    remap_of = dict(zip(map(id, distinct), entry.remaps))
+    return entry.dictionary, [remap_of.get(id(d)) for d in dicts]
+
+
+def remap_codes(codes, remap):
+    """`codes` through a remap of `merge_dictionaries` or a rank vector (an
+    entry a code of the column's own dictionary); None: as they are."""
+    if remap is None:
+        return codes
+    with _tally.eager("dict_remap"):
+        return remap[jnp.clip(codes, 0, remap.shape[0] - 1)]
+
+
 def unify_dictionaries(a: Column, b: Column):
     """Remap two string columns onto one shared dictionary.
 
     Needed before any cross-table comparison/join of string columns, because
     codes are only meaningful within their own dictionary. Returns
-    (codes_a, codes_b, unified_dictionary); the remap is O(|dict|) on host +
-    O(n) gathers on device.
-    """
-    if a.dictionary is not None and a.dictionary is b.dictionary:
-        # already share one dictionary (common after unions/CTE reuse over
-        # the same base column): codes are directly comparable — skip the
-        # host-side unique/index_in work, which costs real milliseconds
-        # per join on 100k-entry dictionaries
-        return a.data, b.data, a.dictionary
-    da = a.dictionary if a.dictionary is not None else pa.array([], type=pa.string())
-    db = b.dictionary if b.dictionary is not None else pa.array([], type=pa.string())
-    with _tally.phase("dict-merge"):
-        unified = pc.unique(pa.concat_arrays([da.cast(pa.string()), db.cast(pa.string())]))
-        remap_a = pc.index_in(da.cast(pa.string()), unified).to_numpy(zero_copy_only=False)
-        remap_b = pc.index_in(db.cast(pa.string()), unified).to_numpy(zero_copy_only=False)
-    with _tally.eager("dict_remap"):
-        ra = jnp.asarray(remap_a.astype(np.int32))
-        rb = jnp.asarray(remap_b.astype(np.int32))
-        codes_a = ra[jnp.clip(a.data, 0, max(len(da) - 1, 0))] if len(da) else a.data
-        codes_b = rb[jnp.clip(b.data, 0, max(len(db) - 1, 0))] if len(db) else b.data
-    return codes_a, codes_b, unified
+    (codes_a, codes_b, unified_dictionary). Columns that already share one
+    dictionary object (common after unions/CTE reuse over the same base
+    column) come back as they are; a pair of objects met before costs the
+    two gathers alone (`merge_dictionaries`)."""
+    unified, (ra, rb) = merge_dictionaries([a.dictionary, b.dictionary])
+    return remap_codes(a.data, ra), remap_codes(b.data, rb), unified
 
 
 def sort_dictionary(col: Column):
     """Return codes remapped so that code order == lexicographic value order.
 
     Lets ORDER BY / min / max on strings run entirely on device: comparing
-    rank codes is comparing strings.
-    """
+    rank codes is comparing strings. The rank vector and the sorted
+    dictionary are derived once a dictionary object (`_DictMemo`)."""
     d = col.dictionary
     if d is None or len(d) == 0:
         # all-null string column (e.g. c_login): nothing to rank
         return col.data, d
-    with _tally.phase("dict-merge"):
-        d = d.cast(pa.string())
-        order = pc.array_sort_indices(d)  # indices of values in sorted order
-        rank = np.empty(len(d), dtype=np.int32)
-        rank[order.to_numpy(zero_copy_only=False)] = np.arange(len(d), dtype=np.int32)
-        sorted_dict = d.take(order)
-    with _tally.eager("dict_remap"):
-        ranks = jnp.asarray(rank)[jnp.clip(col.data, 0, len(d) - 1)]
-    return ranks, sorted_dict
+    entry = _DICT_MEMO.derived("sort", (d,), _derive_sorted)
+    return remap_codes(col.data, entry.remaps[0]), entry.dictionary

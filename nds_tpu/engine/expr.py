@@ -26,7 +26,10 @@ import pyarrow.compute as pc
 from ..dtypes import BOOL, DATE, DType, FLOAT64, INT32, INT64, STRING
 from ..obs import tally as _tally
 from ..obs.tally import host_read
-from .columnar import Column, Table, sort_dictionary, unify_dictionaries
+from .columnar import (
+    Column, Table, literal_dictionary, merge_dictionaries, remap_codes,
+    sort_dictionary, unify_dictionaries,
+)
 
 _EPOCH = datetime.date(1970, 1, 1)
 
@@ -315,8 +318,10 @@ class Evaluator:
             data = jnp.zeros(cap, dtype=dtype.device_np_dtype())
             return Column(data, dtype, jnp.zeros(cap, dtype=bool))
         if dtype.is_string:
-            d = pa.array([value], type=pa.string())
-            return Column(jnp.zeros(cap, dtype=jnp.int32), STRING, None, d)
+            return Column(
+                jnp.zeros(cap, dtype=jnp.int32), STRING, None,
+                literal_dictionary(value),
+            )
         if dtype.kind == "date":
             v = date_to_days(value) if isinstance(value, str) else int(value)
             return Column(jnp.full(cap, v, dtype=jnp.int32), DATE)
@@ -846,26 +851,16 @@ def _cast_column(c: Column, target: DType, cap: int) -> Column:
 
 
 def _share_dictionary(cols):
-    """Remap string columns onto one merged dictionary (CASE/COALESCE)."""
-    with _tally.phase("dict-merge"):
-        dicts = [
-            (c.dictionary if c.dictionary is not None else pa.array([], pa.string())).cast(
-                pa.string()
-            )
-            for c in cols
-        ]
-        unified = pc.unique(pa.concat_arrays(dicts))
-        remaps = [
-            pc.index_in(d, unified).to_numpy(zero_copy_only=False).astype(np.int32)
-            if len(d) else None
-            for d in dicts
-        ]
+    """String columns over one merged dictionary (CASE/COALESCE, a
+    concatenation's two sides): `(columns, unified)`. A column whose codes
+    already are codes of `unified` comes back as it is."""
+    unified, remaps = merge_dictionaries([c.dictionary for c in cols])
     out = []
-    for c, d, remap in zip(cols, dicts, remaps):
-        if remap is None:
-            out.append(Column(c.data, STRING, c.valid, unified))
+    for c, remap in zip(cols, remaps):
+        if remap is None and c.dictionary is unified:
+            out.append(c)
             continue
-        with _tally.eager("dict_remap"):
-            codes = jnp.asarray(remap)[jnp.clip(c.data, 0, len(d) - 1)]
-        out.append(Column(codes, STRING, c.valid, unified))
+        out.append(
+            Column(remap_codes(c.data, remap), STRING, c.valid, unified)
+        )
     return out, unified

@@ -28,6 +28,7 @@ from . import plan as P
 from .binder import Binder
 from .columnar import (
     Table,
+    clear_dictionary_memo,
     table_device_bytes,
     table_from_arrow,
     table_to_arrow,
@@ -970,7 +971,11 @@ class Session:
         so every Throughput stream too; `cli/serve` after the drain).
         Idempotent, and not terminal: a second call writes nothing, a
         statement run afterwards records again and the next call, or the
-        exit hook, writes it. Returns the number of keys written."""
+        exit hook, writes it. Returns the number of keys written. The
+        dictionary derivations kept for its statements (`columnar._DictMemo`,
+        shared by the process's sessions) are let go as well: they hold
+        device vectors and this session's dictionaries."""
+        clear_dictionary_memo()
         if self.feedback_store is None:
             return 0
         with self.cache_lock:
@@ -1130,6 +1135,8 @@ class Session:
             # (rebuilds are cheap next to an OOM'd retry failing again)
             self.exec_cache.clear()
             self.join_order_cache.clear()
+        # so do the kept dictionary derivations (their remap vectors)
+        clear_dictionary_memo()
         for e in self.catalog.entries.values():
             e.device_cols = {}
         gc.collect()

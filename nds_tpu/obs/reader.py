@@ -264,9 +264,10 @@ def op_spans_with_exclusive(events) -> list:
 
 
 #: a span's own (exclusive) host time by name (obs/tally.py): seam name ->
-#: ms, compile stage -> ms, phase -> ms, eager site -> calls
+#: ms, compile stage -> ms, phase -> ms, eager site -> calls, dictionary
+#: derivations by how they were answered (same | hit | miss) -> calls
 HOST_MS_FIELDS = ("launch_ms_by", "compile_ms", "host_ms")
-HOST_FIELDS = (*HOST_MS_FIELDS, "eager_calls")
+HOST_FIELDS = (*HOST_MS_FIELDS, "eager_calls", "dict_memo")
 
 
 def add_host(dst: dict, ev: dict) -> None:
@@ -332,7 +333,8 @@ def add_collect(op: dict, result: dict, roots: dict) -> None:
 
 def format_host_table(ops, top: int = 4) -> list:
     """The per-operator table of the host's time, one line an operator and
-    under it its heaviest seam names, compile stages and phases."""
+    under it its heaviest seam names, compile stages and phases, and how
+    its dictionary derivations were answered (`dict_memo`)."""
     rows = [(node, op, host_parts(op)) for node, op in ops]
     rows = [r for r in rows if r[2] is not None]
     if not rows:
@@ -351,6 +353,10 @@ def format_host_table(ops, top: int = 4) -> list:
             if by:
                 lines.append(f"      {label}: " + ", ".join(
                     f"{name} {ms:,.1f}" for name, ms in by))
+        if op.get("dict_memo"):
+            lines.append("      dict_memo: " + ", ".join(
+                f"{k} {op['dict_memo'][k]}" for k in ("same", "hit", "miss")
+                if k in op["dict_memo"]))
     return lines
 
 

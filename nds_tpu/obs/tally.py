@@ -23,6 +23,10 @@ into the `Tally` the statement's `Executor` binds to its thread:
     load (`obs/trace._on_compile_span`, `engine/aotcache.py`), into
     `compile_ms` under `trace | lower | load | compile`.
 
+Beside them one counter with no clock: `dict_memo(outcome)`, how the
+dictionary derivations a span asked for were answered (`dict_memo` {same |
+hit | miss: calls}; `engine/columnar.py _DictMemo`).
+
 The four never overlap: at any instant the time belongs to the innermost
 open seam or phase, a read or a compile stage inside one is taken out of
 it, and a kernel seam is atomic (what opens inside it is counted under its
@@ -72,7 +76,8 @@ class Tally:
 
     __slots__ = ("tracer", "exec_id", "depth", "launches", "launch_ms",
                  "reads", "read_wait_ms", "in_seam", "launch_ms_by",
-                 "compile_ms", "host_ms", "eager_calls", "keep_iv", "iv",
+                 "compile_ms", "host_ms", "eager_calls", "dict_memo",
+                 "keep_iv", "iv",
                  "t0", "_acct_ms", "_open", "_seg_t0", "_compiled")
 
     def __init__(self, tracer, exec_id):
@@ -94,8 +99,9 @@ class Tally:
             self._piece(self.iv, self._open[-1], _perf())
         saved = (self.depth, self.launches, self.launch_ms, self.reads,
                  self.read_wait_ms, self.launch_ms_by, self.compile_ms,
-                 self.host_ms, self.eager_calls, self.iv, self.t0,
-                 self._acct_ms, self._open, self._compiled, self.in_seam)
+                 self.host_ms, self.eager_calls, self.dict_memo, self.iv,
+                 self.t0, self._acct_ms, self._open, self._compiled,
+                 self.in_seam)
         self.depth = depth
         self.in_seam = False
         self._zero()
@@ -110,6 +116,7 @@ class Tally:
         self.compile_ms = {}
         self.host_ms = {}
         self.eager_calls = {}
+        self.dict_memo = {}
         self.iv = []
         # milliseconds of this frame that already belong to something (a
         # closed seam or phase, a compile stage, a child span); with
@@ -134,6 +141,8 @@ class Tally:
         }
         if self.eager_calls:
             own["eager_calls"] = self.eager_calls
+        if self.dict_memo:
+            own["dict_memo"] = self.dict_memo
         if self.iv:
             if t0 is None:
                 t0 = self.t0
@@ -151,8 +160,9 @@ class Tally:
         now = self._seg_t0  # `take` has just read the clock
         (self.depth, self.launches, self.launch_ms, self.reads,
          self.read_wait_ms, self.launch_ms_by, self.compile_ms,
-         self.host_ms, self.eager_calls, self.iv, self.t0,
-         self._acct_ms, self._open, self._compiled, self.in_seam) = saved
+         self.host_ms, self.eager_calls, self.dict_memo, self.iv,
+         self.t0, self._acct_ms, self._open, self._compiled,
+         self.in_seam) = saved
         # the child's whole time is not its parent's open phase's, whose
         # next piece starts here
         self._acct_ms += (now - opened) * 1000.0
@@ -311,6 +321,17 @@ def eager(site):
     in `launches` or `launch_ms`."""
     t = _bound()
     return _NOTHING if t is None else _Span(t, "eager:" + site, _EAGER)
+
+
+def dict_memo(outcome):
+    """Count one dictionary derivation (`engine/columnar.py _DictMemo`)
+    into the span that asked: `same` (the inputs are one object: nothing to
+    derive), `hit` (derived before from these objects) or `miss` (derived
+    now). Counted under a jax trace too: a pipeline build's one call is the
+    only one its executable ever makes."""
+    t = getattr(_tls, "tally", None)
+    if t is not None:
+        t.dict_memo[outcome] = t.dict_memo.get(outcome, 0) + 1
 
 
 def seamed(site):

@@ -101,7 +101,8 @@ EVENT_SCHEMA = {
     # `read_wait_ms`, and its own host time by name: `launch_ms_by`
     # {seam name: ms, summing to launch_ms; eager:<site>: ms, beside it},
     # `compile_ms` {trace | lower | load | compile: ms}, `host_ms` {phase
-    # of HOST_PHASES: ms}; optional `eager_calls` {site: calls} and, with a
+    # of HOST_PHASES: ms}; optional `eager_calls` {site: calls}, `dict_memo`
+    # {same | hit | miss: dictionary derivations so answered} and, with a
     # file sink only, `host_iv` [[name, start us from t0_ns, dur us], ...]
     "op_span": ("exec_id", "seq", "depth", "node", "explain", "dur_ms",
                 "rows", "est_bytes"),
@@ -134,7 +135,7 @@ EVENT_SCHEMA = {
     # the collect. Optional: launches / launch_ms / reads / read_wait_ms
     # counted outside every op_span (the collect's compaction and read),
     # and like an op_span launch_ms_by / compile_ms / host_ms / eager_calls
-    # / host_iv
+    # / dict_memo / host_iv
     "result_span": ("exec_id", "t0_ns", "dur_ms", "exec_ms", "to_arrow_ms"),
     # one jax compile stage of one program (jax.monitoring time spans,
     # `watch_compiles`): stage trace | lower | compile, `fun` the jitted

@@ -20,9 +20,11 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+from nds_tpu import faults
 from nds_tpu.engine import fuse as F
 from nds_tpu.engine import plan as P
 from nds_tpu.engine.session import Session
+from nds_tpu.obs.trace import bind as obs_bind
 
 rng = np.random.default_rng(7)
 
@@ -375,3 +377,124 @@ def test_input_signature_dictionary_identity():
     sig1 = F.input_signature(t)
     sig2 = F.input_signature(s.catalog.load("t"))
     assert sig1 == sig2  # cached catalog columns: same dictionary objects
+
+
+# --- a ROLLUP over string keys keeps its pipelines (ISSUE 42) ---------------
+
+
+ROLLUP_36 = """
+select sum(ss_net_profit) / sum(ss_ext_sales_price) as gross_margin,
+       i_category, i_class,
+       grouping(i_category) + grouping(i_class) as lochierarchy,
+       rank() over (partition by grouping(i_category) + grouping(i_class),
+                    case when grouping(i_class) = 0 then i_category end
+                    order by sum(ss_net_profit) / sum(ss_ext_sales_price) asc)
+       as rank_within_parent
+from store_sales, item
+where i_item_sk = ss_item_sk
+group by rollup (i_category, i_class)
+order by lochierarchy desc,
+         case when lochierarchy = 0 then i_category end,
+         rank_within_parent
+limit 100
+"""
+
+
+def _rollup_tables():
+    r = np.random.default_rng(36)
+    n = 2000
+    item = pa.table({
+        "i_item_sk": pa.array(range(1, 51), pa.int32()),
+        "i_category": pa.array(
+            [["Books", "Music", "Shoes"][i % 3] for i in range(50)]),
+        "i_class": pa.array([f"class{i % 7}" for i in range(50)]),
+    })
+    store_sales = pa.table({
+        "ss_item_sk": pa.array(r.integers(1, 51, n), pa.int32()),
+        "ss_net_profit": pa.array(r.integers(-500, 900, n) / 4.0),
+        "ss_ext_sales_price": pa.array(r.integers(100, 5000, n) / 4.0),
+    })
+    return item, store_sales
+
+
+def _sqlite_rollup(item, store_sales):
+    """query36's shape as sqlite can say it: a UNION ALL of the ROLLUP's
+    three levels, the rank by level and parent."""
+    import sqlite3
+
+    conn = sqlite3.connect(":memory:")
+    for name, t in (("item", item), ("store_sales", store_sales)):
+        conn.execute(f"create table {name} ({', '.join(t.column_names)})")
+        conn.executemany(
+            f"insert into {name} values ({', '.join('?' * t.num_columns)})",
+            zip(*(c.to_pylist() for c in t.columns)))
+    levels = " union all ".join(
+        f"select sum(ss_net_profit) / sum(ss_ext_sales_price) gross_margin, "
+        f"{cat} i_category, {cls} i_class, {level} lochierarchy "
+        f"from store_sales, item where i_item_sk = ss_item_sk {group}"
+        for cat, cls, level, group in (
+            ("i_category", "i_class", 0, "group by i_category, i_class"),
+            ("i_category", "null", 1, "group by i_category"),
+            ("null", "null", 2, "")))
+    return conn.execute(
+        "select gross_margin, i_category, i_class, lochierarchy, "
+        "rank() over (partition by lochierarchy, "
+        "case when lochierarchy = 0 then i_category end "
+        f"order by gross_margin asc) from ({levels})").fetchall()
+
+
+def _rows(table):
+    key = lambda r: (-r[3], r[1] or "", r[2] or "", r[4])
+    return sorted(zip(*(c.to_pylist() for c in table.columns)), key=key)
+
+
+def test_a_rollup_over_string_keys_builds_its_pipelines_once(tmp_path):
+    """query36's ROLLUP with a CASE above it, three times warm: the levels
+    share their base columns' dictionary objects, so the dictionaries the
+    concatenation hands on are the same objects at every execution and the
+    Pipeline above the ROLLUP, keyed by their identity, is built once
+    (`exec_cache` hit false, true, true; PR 41 read false, false, false and
+    a trace + load in every warm execution)."""
+    item, store_sales = _rollup_tables()
+    s = Session(conf={"engine.trace_dir": str(tmp_path)})
+    s.register_arrow("item", item)
+    s.register_arrow("store_sales", store_sales)
+    answers = []
+    for i in range(3):
+        # a catalog change drops the plan-result cache, as before every
+        # cycle of the benchmark's window: the statement really executes
+        s.register_arrow("tick", pa.table({"n": [i]}))
+        with obs_bind(s.tracer), faults.scope(f"run{i}"):
+            answers.append(s.sql(ROLLUP_36).collect())
+    s.tracer.close()
+    evs = [json.loads(line)
+           for line in open(s.tracer.path, encoding="utf-8") if line.strip()]
+    by_pipeline = {}
+    for e in evs:
+        if e["kind"] == "exec_cache":
+            by_pipeline.setdefault(e["pipeline"], {}).setdefault(
+                e["query"], []).append(e["hit"])
+    assert by_pipeline
+    for fp, runs in by_pipeline.items():
+        assert set(runs) == {"run0", "run1", "run2"}, fp  # it executed
+        assert not any(runs["run0"]) and all(runs["run1"] + runs["run2"]), (
+            fp, runs)
+    for run in ("run1", "run2"):
+        spans = [e for e in evs if e.get("query") == run
+                 and e["kind"] in ("op_span", "result_span")]
+        assert len(spans) > 5
+        assert not any(e["compile_ms"] for e in spans)
+        memo = {}
+        for e in spans:
+            for k, n in (e.get("dict_memo") or {}).items():
+                memo[k] = memo.get(k, 0) + n
+        assert memo.get("same", 0) >= 4 and not memo.get("miss"), memo
+    assert len(answers[0]) == 3 * 7 + 3 + 1
+    assert answers[1].equals(answers[0]) and answers[2].equals(answers[0])
+    want = sorted(_sqlite_rollup(item, store_sales),
+                  key=lambda r: (-r[3], r[1] or "", r[2] or "", r[4]))
+    got = _rows(answers[0])
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g[1:] == w[1:]
+        assert g[0] == pytest.approx(w[0], rel=1e-9)

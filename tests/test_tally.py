@@ -1008,6 +1008,57 @@ def test_an_eager_seam_is_timed_by_name_and_kept_out_of_the_launches():
     assert own["host_ms"] == {}
 
 
+def test_dict_memo_counts_on_the_span_that_called():
+    tl = T.Tally(Tracer(), 1)
+    T.dict_memo("same")  # no tally bound: nothing, and no error
+    with T.bind(tl):
+        saved = tl.push(0)
+        T.dict_memo("same")
+        inner = tl.push(1)
+        T.dict_memo("hit")
+        T.dict_memo("miss")
+        T.dict_memo("hit")
+        child = tl.pop(inner)
+        T.dict_memo("same")
+        parent = tl.pop(saved)
+        T.dict_memo("miss")  # outside every plan node: the result_span's
+    assert child["dict_memo"] == {"hit": 2, "miss": 1}
+    assert parent["dict_memo"] == {"same": 2}
+    assert tl.take()["dict_memo"] == {"miss": 1}
+    assert "dict_memo" not in tl.take()  # none asked for: no field
+
+
+def test_dict_memo_under_a_jax_trace_counts_the_traces_one_call():
+    import jax.numpy as jnp
+
+    from nds_tpu.dtypes import STRING
+    from nds_tpu.engine import columnar as C
+
+    d = pa.array(["pear", "apple", "fig"])
+    C._DICT_MEMO.clear()
+
+    @jax.jit
+    def ranks(codes):
+        return C.sort_dictionary(C.Column(codes, STRING, None, d))[0]
+
+    codes = jnp.asarray([0, 1, 2, 1], dtype=jnp.int32)
+    tl = T.Tally(Tracer(), 1)
+    with T.bind(tl):
+        for _ in range(3):  # traced once, replayed twice: one call
+            assert np.asarray(ranks(codes)).tolist() == [2, 0, 1, 0]
+        traced = tl.take()
+        C.sort_dictionary(C.Column(codes, STRING, None, d))
+        eager = tl.take()
+    # the derivation happened under the trace: a miss, with no phase and
+    # no eager seam of its own (the time is the trace stage's)
+    assert traced["dict_memo"] == {"miss": 1}
+    assert "dict-merge" not in traced["host_ms"]
+    assert not any(k.startswith("eager:") for k in traced["launch_ms_by"])
+    assert eager["dict_memo"] == {"hit": 1}
+    assert eager["eager_calls"] == {"dict_remap": 1}
+    C._DICT_MEMO.clear()
+
+
 def test_a_child_span_inside_a_kernel_seam_starts_outside_every_seam():
     tl = T.Tally(Tracer(), 1)
     saved = tl.push(0)
@@ -1065,16 +1116,23 @@ def test_a_spans_named_time_never_exceeds_its_exclusive_time(host_run, query):
                    for e in spans for k in e["launches"])
 
 
-def test_query36_rebuilds_a_pipeline_at_every_execution(host_run):
-    """What `compiles.window` 0 cannot see and `compile_ms` says: the warm
-    execution traces again and builds a pipeline again (ROADMAP A3)."""
-    ops, _ = _own_spans(host_run, "query36")
-    stages = {}
-    for e in ops:
-        for k, v in e["compile_ms"].items():
-            stages[k] = stages.get(k, 0.0) + v
-    assert stages.get("trace", 0.0) > 0
-    assert sum(e["host_ms"].get("pipeline-build", 0.0) for e in ops) > 0
+def test_query36_builds_its_pipelines_once(host_run):
+    """What `compiles.window` 0 could not see and `compile_ms` said (PR 41:
+    the warm execution traced and built the Pipeline above the ROLLUP
+    again, ROADMAP A3): the ROLLUP's levels share their base columns'
+    dictionary objects, `_share_dictionary` hands those objects back, and
+    the executable keyed by their identity is found again (PR 42)."""
+    ops, results = _own_spans(host_run, "query36")
+    assert not any(e["compile_ms"] for e in ops + results)
+    assert not any(e["host_ms"].get("pipeline-build") for e in ops)
+    memo = {}
+    for e in ops + results:
+        for k, n in (e.get("dict_memo") or {}).items():
+            memo[k] = memo.get(k, 0) + n
+    assert memo.get("same", 0) >= 4 and not memo.get("miss")
+    hits = [e["hit"] for e in host_run
+            if e["kind"] == "exec_cache" and e.get("query") == "query36"]
+    assert hits and all(hits)
     ops3, _ = _own_spans(host_run, "query3")
     assert not any(e["compile_ms"] for e in ops3)
 
@@ -1202,7 +1260,7 @@ NAMED_EVENTS = [
         explain="", rows=1, est_bytes=0, launches={"take_columns": 12},
         launch_ms=40.0, reads=2, read_wait_ms=300.0,
         launch_ms_by={"take_columns": 40.0, "eager:concat": 100.0},
-        eager_calls={"concat": 2},
+        eager_calls={"concat": 2}, dict_memo={"same": 8, "miss": 1},
         compile_ms={"trace": 60.0, "load": 40.0},
         host_ms={"pipeline-build": 30.0, "exec-lookup": 5.0,
                  "dict-merge": 20.0}),
@@ -1272,12 +1330,14 @@ def test_profile_cli_prints_the_host_table_for_a_log_with_the_fields(
                    "operator (own ms)", "launch: eager:concat 100.0, "
                    "take_columns 40.0", "compile: trace 60.0, load 40.0",
                    "phases: pipeline-build 30.0, dict-merge 20.0, "
-                   "exec-lookup 5.0", "(collect)"):
+                   "exec-lookup 5.0", "      dict_memo: same 8, miss 1\n",
+                   "(collect)"):
         assert needle in out, needle
     assert "\n   exec-lookup " not in out
     assert not profile_cli.main([str(log), "--per_query"])
     out = capsys.readouterr().out
     assert "operator (own ms)" in out and "eager:concat 100.0" in out
+    assert out.count("dict_memo: same 8, miss 1") == 2  # the query, the run
 
 
 GOLDEN_BEFORE_THE_FIELDS = """\
@@ -1331,6 +1391,7 @@ def test_merged_profiles_sum_the_new_fields_as_they_sum_launches():
     assert agg["compile_ms"] == {"trace": 120.0, "load": 80.0}
     assert agg["host_ms"]["pipeline-build"] == 60.0
     assert agg["eager_calls"] == {"concat": 4}
+    assert agg["dict_memo"] == {"same": 16, "miss": 2}
     assert merged["op_totals"]["Scan"]["host_ms"] == {"scan": 396.0}
     assert merged["queries"]["q"]["collect"]["count"] == 2
     assert merged["collect_total"]["launch_ms_by"] == {"compact_indices": 8.0}

@@ -18,6 +18,10 @@ own time and by what the calls they make out of the engine take (a frame
 heavy in `jax` dispatches eager work), by cumulative time, and for the span
 fields the tracer wrote (`launch_ms_by`, `compile_ms`, `host_ms`) what share
 of `result_span - host_read` has a name.
+
+With `--out`, `summary.json` also holds each pipeline's `exec_cache` events
+of the warm executions in order (`hit` false at every execution: the
+pipeline is rebuilt and traced again) and the spans' `dict_memo` counts.
 """
 
 from __future__ import annotations
@@ -149,6 +153,16 @@ def named_share(events, name):
     return out, "\n".join(table)
 
 
+def exec_cache_hits(events, name):
+    """{pipeline: [hit, ...]} of the statement's `exec_cache` events, in
+    the order they were emitted."""
+    by = {}
+    for e in events:
+        if e["kind"] == "exec_cache" and e.get("query") == name:
+            by.setdefault(e["pipeline"], []).append(bool(e["hit"]))
+    return by
+
+
 def main(argv=None):
     args = parse_args(argv)
     out_dir = args.out and os.path.abspath(args.out)
@@ -209,6 +223,8 @@ def main(argv=None):
         for template in wanted:
             named, table = named_share(events, template + ".warm")
             summary[template].update(named)
+            summary[template]["exec_cache"] = exec_cache_hits(
+                events, template + ".warm")
             print(f"\n==== {template}: what the spans name")
             print(json.dumps(summary[template], indent=1))
             print(table)
