@@ -111,6 +111,9 @@ def _render_profile(prof, top: int, per_query: bool):
             _print_ops(sorted(
                 rec["ops"].items(), key=lambda kv: -kv[1]["excl_ms"]
             ), rec.get("collect"))
+        if per_query:
+            for line in R.format_within(rec.get("within_execute") or {}):
+                print(line)
     hot = sorted(
         prof["op_totals"].items(), key=lambda kv: -kv[1]["excl_ms"]
     )[:top]

@@ -262,6 +262,11 @@ class Executor:
         # did, kept for the MultiJoin's op_span
         self._left_cap = None
         self._join_steps = None
+        # what the last `_exec_setop` saw (traced executions only), kept for
+        # the SetOp's op_span, and the columns the scans of a traced
+        # execution asked of the catalog, read by `_scalar_value`
+        self._setop_info = None
+        self._scan_cols = 0
         if tracer is None:
             tracer = getattr(
                 getattr(catalog, "session", None), "tracer", None
@@ -455,6 +460,12 @@ class Executor:
             # of the rows it leaves, the capacity its left side ran at,
             # and whether the estimates changed the order
             span.update(self._join_steps)
+        if isinstance(node, P.SetOp) and self._setop_info:
+            # which set operation, over how many rows a side (counts the
+            # host already held: null where one is still queued), the left
+            # side's distinct rows and the candidate join's key columns
+            span.update(self._setop_info)
+            self._setop_info = None
         if fp is not None:
             # budgeter accounting (analysis/feedback.py annotations):
             # est_rows/est_live_bytes are the STATIC model's numbers,
@@ -486,6 +497,8 @@ class Executor:
                 node.table, node.columns, lake_version=node.lake_version,
                 lake_files=node.lake_files,
             )
+        if self.tracer is not None:
+            self._scan_cols += len(t.columns)
         uk = t.unique_key
         if uk is not None:
             uk = frozenset(f"{node.alias}.{n}" for n in uk)
@@ -1054,12 +1067,16 @@ class Executor:
         left = self.execute(node.left)
         right = self.execute(node.right)
         if node.op == "union_all":
-            return self._concat(left, right)
+            out = self._concat(left, right)
+            self._note_setop(node, left, right)
+            return out
         if node.op == "union":
-            return self._distinct_table(
+            out = self._distinct_table(
                 self._concat(left, right),
                 spill_parts=self._spill_parts_for(node),
             )
+            self._note_setop(node, left, right)
+            return out
         # intersect / except: set semantics over whole rows
         dl = self._distinct_table(left)
         names = list(dl.columns)
@@ -1104,7 +1121,23 @@ class Executor:
             mask = present & dl.row_mask()
         else:
             mask = ~present & dl.row_mask()
+        self._note_setop(node, left, right, dl, len(keys_l))
         return self._masked(dl, mask)
+
+    def _note_setop(self, node, left, right, distinct=None, key_words=None):
+        """The SetOp span's own fields, taken as the operation ends (its
+        inputs' spans have been emitted by then) and only where a tracer
+        will read them. `nrows_known`, as the span's `rows`: a count still
+        queued on the device stays null, never a sync."""
+        if self.tracer is None:
+            return
+        self._setop_info = dict(
+            op=node.op,
+            left_rows=left.nrows_known,
+            right_rows=right.nrows_known,
+            distinct_rows=None if distinct is None else distinct.nrows_known,
+            key_words=key_words,
+        )
 
     # ------------------------------------------------------------------
     def _exec_join(self, node: P.Join) -> Table:
@@ -2168,6 +2201,7 @@ class Executor:
         session = getattr(self.catalog, "session", None)
         if session is None:
             return None  # no budget tracking: stay on the unblocked path
+        started = (_time_ns(), _perf()) if self.tracer is not None else None
         base_aggs, avg_items = _rollup_base_aggs(node.aggs)
         if base_aggs is None:
             return None  # non-decomposable aggregate (distinct, stddev...)
@@ -2225,6 +2259,7 @@ class Executor:
         }
         session.last_blocked_union = self.last_blocked_union
         return {
+            "started": started,
             "outer_wrappers": outer,
             "join": join_ctx,
             "join_trace": {},  # first window records the order, rest replay
@@ -2443,7 +2478,12 @@ class Executor:
         if session is not None:
             session.last_blocked_union = self.last_blocked_union
         if self.tracer is not None:
-            self.tracer.emit("blocked_union", **self.last_blocked_union)
+            # from the branches' execution to the last window merged
+            t0_ns, t0 = ctx["started"]
+            self.tracer.emit(
+                "blocked_union", **self.last_blocked_union, t0_ns=t0_ns,
+                dur_ms=round((_perf() - t0) * 1000.0, 3),
+            )
 
     def _union_branch_aligners(self, tables):
         """Per-branch WINDOW aligners: unify column names (leftmost branch
@@ -3402,39 +3442,47 @@ class Executor:
 
     def _scalar_value(self, e: E.ScalarSubquery):
         key = id(e.plan)
-        if key not in self._scalar_cache:
-            cache = self._session_cache()
-            if cache is not None:
-                fp = self._fp(e.plan) + ":" + e.out_name
-                hit = cache.scalars.get(fp)
-                if hit is not None:
-                    self._scalar_cache[key] = hit
-                    return hit
+        if key in self._scalar_cache:
+            return self._scalar_cache[key]
+        tracer = self.tracer
+        if tracer is not None:
+            t0_ns, t0, cols0 = _time_ns(), _perf(), self._scan_cols
+        cache = self._session_cache()
+        fp = got = None
+        if cache is not None:
+            fp = self._fp(e.plan) + ":" + e.out_name
+            got = cache.scalars.get(fp)
+        source = "executed" if got is None else "session-cache"
+        if got is None:
             # the plan may yield a deferred-compaction table whose single
             # live row is NOT at index 0 — pack before slicing
             t = self.execute(e.plan).compacted()
             col = t.columns[e.out_name]
             if t.nrows == 0:
-                self._scalar_cache[key] = (None, col.dtype, col.dictionary)
+                got = (None, col.dtype, col.dictionary)
             else:
                 # one batched transfer for value + validity (vs two RTTs)
                 fetch = [col.data[:1]]
                 if col.valid is not None:
                     fetch.append(col.valid[:1])
-                got = host_read("scalar", fetch)
-                v = got[0][0]
-                valid = True if col.valid is None else bool(got[1][0])
-                self._scalar_cache[key] = (
-                    v if valid else None,
-                    col.dtype,
-                    col.dictionary,
-                )
-            cache = self._session_cache()
+                read = host_read("scalar", fetch)
+                valid = True if col.valid is None else bool(read[1][0])
+                got = (read[0][0] if valid else None, col.dtype,
+                       col.dictionary)
             if cache is not None:
-                cache.scalars[self._fp(e.plan) + ":" + e.out_name] = (
-                    self._scalar_cache[key]
-                )
-        return self._scalar_cache[key]
+                cache.scalars[fp] = got
+        self._scalar_cache[key] = got
+        if tracer is not None:
+            # one a distinct subquery plan a statement: the statement's own
+            # memo above answers a repeat without an event. `cols_read`:
+            # the columns this execution's scans asked of the catalog
+            tracer.emit(
+                "scalar_subquery", out_name=e.out_name, source=source,
+                dur_ms=round((_perf() - t0) * 1000.0, 3), t0_ns=t0_ns,
+                cols_read=self._scan_cols - cols0, null=got[0] is None,
+                exec_id=self._exec_id, depth=self._span_depth - 1,
+            )
+        return got
 
     def _masked(self, table: Table, mask, transient: bool = False) -> Table:
         """Deferred compaction: keep rows in place under a live mask, with

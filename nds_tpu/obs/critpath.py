@@ -99,11 +99,25 @@ construction):
                     means span evidence is missing, and the honest
                     answer is `unattributed` — the CI gate's >= 90%
                     assertion then fails instead of laundering the gap.
+
+Beside the causes, and never added to them, a query's `within_execute`
+names three operator classes whose time the causes above already hold
+(a scalar subquery's plan waits, launches and traces like any other):
+
+    setop            the SetOp spans' own (exclusive) time, with their
+                     count by `op` and the rows of their sides
+    scalar-subquery  the `scalar_subquery` spans, inclusive of the plan
+                     each ran: count and ms by `source` (executed |
+                     session-cache), the columns their scans read, how
+                     many yielded NULL
+    blocked-union    the `blocked_union` spans: count, windows, ms
 """
 
 from __future__ import annotations
 
-from .reader import format_host_table, host_by_operator
+from .reader import (
+    format_host_table, format_within, host_by_operator, within_execute,
+)
 
 #: residual share of wall beyond which plan-host stops counting as
 #: attributed (evidence-coverage collapse, not driver work)
@@ -131,7 +145,7 @@ def _group_query_events(events) -> dict:
                     "kernel_span", "ingest_chunk", "scan_prune", "lake_pin",
                     "route_request", "host_read", "result_span",
                     "xla_compile", "aot_cache", "exec_cache",
-                    "plan_budget"):
+                    "plan_budget", "scalar_subquery", "blocked_union"):
             q = ev.get("query") or "<unscoped>"
             out.setdefault(q, []).append(ev)
     return out
@@ -357,6 +371,7 @@ def critical_path(events) -> dict:
         spans = []
         results, reads, compiles, aot_loads, lookups = [], [], [], [], []
         cats, exchanges, spills = [], [], []
+        subqueries, blocked = [], []
         exch_ms = skew_ms = spill_ms = cat_ms = 0.0
         ladder_ms = backoff_ms = hung_ms = kernel_ms = 0.0
         decode_ms = commit_wait_ms = prune_ms = pin_ms = budget_ms = 0.0
@@ -435,6 +450,10 @@ def critical_path(events) -> dict:
                 pin_ms += float(ev.get("dur_ms") or 0.0)
             elif kind == "plan_budget":
                 budget_ms += float(ev.get("dur_ms") or 0.0)
+            elif kind == "scalar_subquery":
+                subqueries.append(ev)
+            elif kind == "blocked_union":
+                blocked.append(ev)
             elif kind == "route_request":
                 route_n += 1
                 route_dur_ms += float(ev.get("dur_ms") or 0.0)
@@ -530,6 +549,10 @@ def critical_path(events) -> dict:
             "chain": _op_tree_chain(spans),
             **_execution_detail(spans, results, reads, compiles),
         }
+        within = within_execute(spans, subqueries, blocked)
+        if within:
+            # views into the causes above, never added to them
+            qrec["within_execute"] = within
         if host_python is not None:
             # `host-python` by phase, and what is left of it without a name
             qrec["host_python"] = {
@@ -620,6 +643,8 @@ def render(cp: dict, out=None) -> None:
                     p(f"     {name:<16}{v:>8,.1f} ms  {share:>6.1%}")
         if rec.get("unattributed_ms"):
             p(f"   {'unattributed':<14}{rec['unattributed_ms']:>12,.1f} ms")
+        for line in format_within(rec.get("within_execute") or {}):
+            p(line)
         if rec.get("launches"):
             p("   launches: " + ", ".join(
                 f"{k} {n}" for k, n in sorted(

@@ -360,6 +360,96 @@ def format_host_table(ops, top: int = 4) -> list:
     return lines
 
 
+def within_execute(spans, subqueries, blocked) -> dict:
+    """The set operations, scalar subqueries and blocked unions of some
+    executions, as `profile --per_query` and `--critical-path` print them
+    under a query: the SetOp spans' own (exclusive) time by `op` with the
+    rows of their sides, the `scalar_subquery` spans by `source`
+    (inclusive of the plan each ran) and the `blocked_union` spans. Views
+    into time the operators and causes already hold, never added to
+    either; empty where the log has none of the three."""
+    out = {}
+    if any("excl_ms" not in e for e in spans):
+        spans = op_spans_with_exclusive(spans)
+    setops = [e for e in spans if e.get("node") == "SetOp"]
+    if setops:
+        by_op = {}
+        for e in setops:
+            rec = by_op.setdefault(e.get("op") or "?", {
+                "count": 0, "own_ms": 0.0, "left_rows": 0, "right_rows": 0,
+                "distinct_rows": 0})
+            rec["count"] += 1
+            rec["own_ms"] = round(rec["own_ms"] + e["excl_ms"], 3)
+            for k in ("left_rows", "right_rows", "distinct_rows"):
+                rec[k] += int(e.get(k) or 0)
+            if e.get("key_words") is not None:
+                rec["key_words"] = int(e["key_words"])
+        out["setop"] = {
+            "count": len(setops),
+            "own_ms": round(sum(e["excl_ms"] for e in setops), 3),
+            "by_op": by_op,
+        }
+    if subqueries:
+        by_source = {}
+        for e in subqueries:
+            rec = by_source.setdefault(e.get("source") or "?", {
+                "count": 0, "ms": 0.0, "cols_read": 0, "null": 0})
+            rec["count"] += 1
+            rec["ms"] = round(rec["ms"] + float(e.get("dur_ms") or 0.0), 3)
+            rec["cols_read"] += int(e.get("cols_read") or 0)
+            rec["null"] += int(bool(e.get("null")))
+        out["scalar-subquery"] = by_source
+    if blocked:
+        out["blocked-union"] = {
+            "count": len(blocked),
+            "windows": sum(int(e.get("windows") or 0) for e in blocked),
+            "ms": round(sum(float(e.get("dur_ms") or 0.0)
+                            for e in blocked), 3),
+        }
+    return out
+
+
+def format_within(within: dict) -> list:
+    """`within_execute` as the lines the profiler prints under a query."""
+    lines = []
+    setop = within.get("setop")
+    if setop:
+        lines.append(
+            f"   setop: {setop['count']} span(s), own "
+            f"{setop['own_ms']:,.1f} ms: " + ", ".join(
+                f"{op} x{r['count']} {r['left_rows']:,} | "
+                f"{r['right_rows']:,} rows"
+                + (f", {r['distinct_rows']:,} distinct, "
+                   f"{r.get('key_words', 0)} key words"
+                   if op in ("intersect", "except") else "")
+                + f" ({r['own_ms']:,.1f} ms)"
+                for op, r in sorted(setop["by_op"].items())))
+    for source, r in sorted((within.get("scalar-subquery") or {}).items()):
+        lines.append(
+            f"   scalar-subquery {source}: {r['count']} in "
+            f"{r['ms']:,.1f} ms, {r['cols_read']} columns read, "
+            f"{r['null']} NULL")
+    blocked = within.get("blocked-union")
+    if blocked:
+        lines.append(
+            f"   blocked-union: {blocked['count']} in "
+            f"{blocked['ms']:,.1f} ms, {blocked['windows']} windows")
+    return lines
+
+
+def merge_within(dst: dict, src: dict) -> dict:
+    """Add one `within_execute` into another: counts, rows and ms add;
+    `key_words`, a width, keeps the larger."""
+    for k, v in src.items():
+        if isinstance(v, dict):
+            merge_within(dst.setdefault(k, {}), v)
+        elif k == "key_words":
+            dst[k] = max(dst.get(k, 0), v)
+        else:
+            dst[k] = round(dst.get(k, 0) + v, 3)
+    return dst
+
+
 _EMPTY_QUERY = {
     "wall_ms": None, "status": None, "runs": 0, "ops": {},
     "root_incl_ms": 0.0,
@@ -389,6 +479,9 @@ def profile_events(events) -> dict:
     collect_total = {"count": 0, "excl_ms": 0.0}
     root_ms = {}  # (app, exec_id) -> the execution's root op_span, ms
 
+    # query -> (SetOp spans, scalar_subquery events, blocked_union events)
+    within = {}
+
     def add_launches(ev):
         for kernel, n in (ev.get("launches") or {}).items():
             launch_totals[kernel] = launch_totals.get(kernel, 0) + int(n)
@@ -398,6 +491,8 @@ def profile_events(events) -> dict:
         q = ev.get("query") or "<unscoped>"
         node = ev.get("node", "?")
         qrec = queries.setdefault(q, dict(_EMPTY_QUERY, ops={}))
+        if node == "SetOp":
+            within.setdefault(q, ([], [], []))[0].append(ev)
         for op in (
             qrec["ops"].setdefault(
                 node, {"count": 0, "incl_ms": 0.0, "excl_ms": 0.0, "rows": 0}
@@ -531,6 +626,11 @@ def profile_events(events) -> dict:
             tallies["faults_injected"] += 1
         elif k == "blocked_union":
             tallies["blocked_union_windows"] += int(ev.get("windows") or 0)
+            within.setdefault(
+                ev.get("query") or "<unscoped>", ([], [], []))[2].append(ev)
+        elif k == "scalar_subquery":
+            within.setdefault(
+                ev.get("query") or "<unscoped>", ([], [], []))[1].append(ev)
         elif k == "exchange":
             tallies["exchange_ops"] += 1
             tallies["exchange_bytes"] += int(ev.get("bytes_moved") or 0)
@@ -637,6 +737,9 @@ def profile_events(events) -> dict:
                         rec["err_max"] = e
         elif k == "mem_watermark":
             tallies["mem_watermarks"] += 1
+    for q, parts in within.items():
+        queries.setdefault(q, dict(_EMPTY_QUERY, ops={}))[
+            "within_execute"] = within_execute(*parts)
     return {
         "queries": queries,
         "op_totals": op_totals,
@@ -795,6 +898,10 @@ def merge_profiles(base: dict, extra: dict) -> dict:
             )
         if src.get("collect"):
             _merge_op(dst.setdefault("collect", {}), src["collect"])
+        if src.get("within_execute"):
+            merge_within(
+                dst.setdefault("within_execute", {}), src["within_execute"]
+            )
     for name, src in (extra.get("op_totals") or {}).items():
         _merge_op(base.setdefault("op_totals", {}).setdefault(name, {}), src)
     if extra.get("collect_total"):

@@ -96,6 +96,13 @@ EVENT_SCHEMA = {
     # order joined), `step_est_rows` (each step's estimate of the rows it
     # leaves; null: it had none), `left_caps` (the capacity each step's left
     # side ran at) and `reordered` (1: the estimates changed the order).
+    # A SetOp's span also carries `op` (union_all | union | intersect |
+    # except), `left_rows` / `right_rows` (its inputs' rows where the host
+    # held the count, else null: never a sync), `distinct_rows` (the left
+    # side after DISTINCT: intersect / except, else null) and `key_words`
+    # (key columns of the candidate join, null flags included; null for a
+    # union). A `union_all` that the budgeter blocked runs no SetOp at all:
+    # it is the `blocked_union` event below.
     # Every span carries the node's OWN counters, exclusive of its children
     # (obs/tally.py): `launches` {seam: n}, `launch_ms`, `reads`,
     # `read_wait_ms`, and its own host time by name: `launch_ms_by`
@@ -112,8 +119,20 @@ EVENT_SCHEMA = {
     "catalog_load": ("table", "columns", "loaded", "rows", "dur_ms", "cache"),
     # session plan-result cache probe on a cacheable plan node
     "plan_cache": ("node", "hit"),
-    # blocked union-aggregation completed (PR 1 window stats)
-    "blocked_union": ("windows", "window_rows", "total_rows"),
+    # blocked union-aggregation completed (PR 1 window stats); the span
+    # runs from the branches' execution to the last window merged
+    "blocked_union": ("windows", "window_rows", "total_rows", "dur_ms",
+                      "t0_ns"),
+    # one scalar subquery a statement evaluated and its own memo did not
+    # answer (Executor._scalar_value: one a distinct subquery plan a
+    # statement): `source` executed (its plan ran, inside this span, and one
+    # `host_read` why=scalar fetched the value) | session-cache (the
+    # plan-result cache's `scalars` answered); `cols_read` the columns the
+    # plan's scans asked of the catalog in this execution (0 from the
+    # cache); `null`: it yielded no row or a NULL. Optional: `exec_id` +
+    # `depth` of the enclosing op_span, as `host_read`
+    "scalar_subquery": ("out_name", "source", "cols_read", "null", "dur_ms",
+                        "t0_ns"),
     # one fused-pipeline execution (fused=False: eager per-stage fallback;
     # also carries `agg` when the pipeline has a fused aggregate tail)
     "pipeline_span": ("stages", "fused", "dur_ms"),
