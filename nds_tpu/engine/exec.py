@@ -584,6 +584,9 @@ class Executor:
         out = None
         fused = False
         has_agg = node.agg is not None
+        # how the aggregate tail reduced: the fused body's own route, or
+        # the eager aggregation, whose kernel seams say the rest
+        agg_route = "eager" if has_agg else None
         if (
             session is not None
             and session.conf.get("engine.fuse", "on") != "off"
@@ -653,6 +656,8 @@ class Executor:
                 try:
                     out = entry.call(child, donate)
                     fused = True
+                    if has_agg:
+                        agg_route = entry.agg_route
                 except Exception as exc:
                     if donate:
                         # the failed call may already have donated (and so
@@ -684,6 +689,7 @@ class Executor:
                 stages=len(node.stages),
                 fused=fused,
                 agg=has_agg,
+                agg_route=agg_route,
                 t0_ns=t0_ns,
                 dur_ms=round((_perf() - t0) * 1000.0, 3),
                 rows=out.nrows_known,
@@ -2600,13 +2606,13 @@ class Executor:
             # nlive None (fused filter mask): group_by_words syncs the count
             order, gid, ngroups = K.group_by_words(words, live, nlive)
         else:
-            # single global group: segment reductions are order-independent,
-            # so no sort at all — identity order, weight = live mask. SQL
+            # single global group: one run, so no sort and no ids at all —
+            # identity order, weight = live mask, and every reduction a
+            # masked reduce into cell 0 (K.segment_reduce with no gid). SQL
             # yields exactly one row even over empty input (weights produce
             # the NULL/0 aggregate values).
             order = None
-            with _tally.eager("agg"):
-                gid = jnp.zeros(child.cap, jnp.int32)
+            gid = None
             ngroups = 1
         if ngroups == 0:
             if active:
@@ -2739,8 +2745,15 @@ class Executor:
                 cols[name] = Column(jnp.zeros(1, jnp.int64), INT64, jnp.zeros(1, bool))
             return Table(cols, 0)
         first_rows = None
+        runs = None
         if order is not None:
-            first_idx = K.segment_starts(gid, gcap)
+            # the sort route's ids are sorted dense runs: their bounds are
+            # read once, and every count and integer sum below is a prefix
+            # difference at them instead of a scatter (None over a mesh)
+            runs = K.run_bounds(gid, live_sorted, gcap, ngroups)
+            first_idx = (
+                K.segment_starts(gid, gcap) if runs is None else runs[0]
+            )
             first_rows = order[jnp.clip(first_idx, 0, child.cap - 1)]
         n_active = sum(1 for kc in key_cols if kc is not None)
         taken = gather_columns(
@@ -2764,14 +2777,16 @@ class Executor:
         for agg, name in agg_items:
             cols[name] = self._eval_agg(
                 agg, ev, order, gid, gcap, live_sorted, ngroups, child, subset,
-                key_cols, key_words,
+                key_cols, key_words, runs,
             )
         return Table(cols, ngroups, unique_key=_active_key_names(key_items, key_cols))
 
     def _eval_agg(
         self, agg: E.Agg, ev, order, gid, gcap, live_sorted, ngroups, child,
-        subset, key_cols, key_words=None,
+        subset, key_cols, key_words=None, runs=None,
     ) -> Column:
+        # `gid` None: a global aggregate, one run; `runs`: the bounds of
+        # the sort route's sorted runs. K.segment_reduce routes by them.
         fn = agg.fn
         if fn == "grouping":
             # grouping(key) = 1 when the key is rolled away in this set.
@@ -2792,7 +2807,8 @@ class Executor:
             )
         if fn == "count" and agg.arg is None:
             counts = K.segment_reduce(
-                live_sorted.astype(jnp.int64), gid, live_sorted, gcap, "count"
+                live_sorted.astype(jnp.int64), gid, live_sorted, gcap, "count",
+                runs,
             )
             return Column(counts.astype(jnp.int64), INT64)
         c = ev.eval(agg.arg)
@@ -2806,21 +2822,21 @@ class Executor:
         if c.dtype.is_string:
             if fn in ("min", "max"):
                 red, counts = K.segment_reduce_with_count(
-                    sdata, gid, weight, gcap, fn
+                    sdata, gid, weight, gcap, fn, runs
                 )
                 return Column(
                     red.astype(jnp.int32), c.dtype, counts > 0, sorted_dict
                 )
             raise ExecError(f"agg {fn} on string column")
         if fn == "count":
-            counts = K.segment_reduce(sdata, gid, weight, gcap, "count")
+            counts = K.segment_reduce(sdata, gid, weight, gcap, "count", runs)
             return Column(counts.astype(jnp.int64), INT64)
         if fn in ("sum", "min", "max"):
             pall = self._pallas_segment_route(fn, c, sdata, gid, weight, gcap)
             if pall is not None:
                 return pall
             red, counts = K.segment_reduce_with_count(
-                sdata, gid, weight, gcap, fn
+                sdata, gid, weight, gcap, fn, runs
             )
             dtype = c.dtype
             if fn == "sum" and dtype.kind == "int32":
@@ -2829,7 +2845,7 @@ class Executor:
             return Column(red, dtype, counts > 0)
         if fn == "avg":
             s, n = K.segment_reduce_with_count(
-                sdata, gid, weight, gcap, "sum"
+                sdata, gid, weight, gcap, "sum", runs
             )
             nz = jnp.maximum(n, 1)
             if c.dtype.is_decimal:
@@ -2843,7 +2859,9 @@ class Executor:
                 x = x / 10**c.dtype.scale
             s = K.segment_reduce(x, gid, weight, gcap, "sum")
             sq = K.segment_reduce(x, gid, weight, gcap, "sumsq")
-            n = K.segment_reduce(x, gid, weight, gcap, "count").astype(jnp.float64)
+            n = K.segment_reduce(
+                x, gid, weight, gcap, "count", runs
+            ).astype(jnp.float64)
             nz = jnp.maximum(n, 2)
             var = (sq - s * s / jnp.maximum(n, 1)) / (nz - 1)
             var = jnp.maximum(var, 0.0)
@@ -2873,6 +2891,8 @@ class Executor:
         from ..ops import pallas_kernels as PK
 
         interpret = jax.devices()[0].platform != "tpu"
+        if gid is None:  # a global aggregate: the tile kernels want ids
+            gid = jnp.zeros(weight.shape[0], jnp.int32)
         pgid = jnp.where(weight, gid, -1).astype(jnp.int32)
         # mask dead/null lanes: a zero one-hot entry does not neutralize
         # NaN garbage (0*NaN=NaN would poison the whole group tile)

@@ -811,7 +811,9 @@ class FusedAggPipeline(_FusedBase):
     direct sort-free aggregation scheme, exec._try_direct_agg — bounds are
     baked as trace constants, so the input signature carries them), and
     scatters every aggregate into a domain-bucket cell array via the same
-    segment_reduce kernels the eager path dispatches one by one. The call
+    segment_reduce kernels the eager path dispatches one by one (a global
+    aggregate has no codes and scatters nothing: one masked reduce an
+    aggregate into cell 0, the call below reads it without a sync). The call
     then pays ONE host sync for the occupied-group count (exactly what the
     eager direct path pays), compacts the occupied cells, reconstructs the
     key columns from the cell codes, and gathers the aggregate values —
@@ -822,10 +824,20 @@ class FusedAggPipeline(_FusedBase):
     the direct-aggregation cap, or an argument cannot trace — the exact
     inputs the eager path would route to its sort-based aggregation."""
 
+    # The traced body's revision, in the `kind` half of the executable's
+    # key on disk (engine/aotcache.py): nothing else of that key moves
+    # when `_run_agg`'s body does, so without it an entry an older body
+    # wrote would be loaded and run for this one. Raise it with the body.
+    # 2 (PR 44): a keyless tail reduces whole and returns no occupancy.
+    BODY_REV = 2
+
     def __init__(self, stages, agg: P.Aggregate, sample: Table, aot=None,
                  fp=None, conf_sig=()):
         self.stages = stages
         self.agg = agg
+        # what `pipeline_span.agg_route` says: a keyless tail is one run
+        # and reduces whole, a keyed one scatters by its group codes
+        self.agg_route = "scatter" if agg.keys else "whole"
         self._capture_inputs(sample)
         # per-input-column host stats (vmin, vmax): the probe maps plain
         # key columns back to these; part of the cache signature, so a
@@ -862,8 +874,8 @@ class FusedAggPipeline(_FusedBase):
         # stats fold into the content signature: the mixed-radix bounds
         # bake into the trace, so a dataset with different bounds is a
         # different executable on disk too
-        self._init_aot(aot, fp, conf_sig, sample, "agg_pipeline",
-                       with_stats=True)
+        self._init_aot(aot, fp, conf_sig, sample,
+                       f"agg_pipeline.{self.BODY_REV}", with_stats=True)
 
     # -- build ------------------------------------------------------------
     def _probe_keys(self, *flat):
@@ -909,14 +921,10 @@ class FusedAggPipeline(_FusedBase):
         self.domain_cap = bucket_cap(domain)
 
     # -- traced body ------------------------------------------------------
-    def _run_agg(self, *flat):
-        t = self._apply_stages(self._flat_inputs(flat))
-        live = t.row_mask()
-        ev = Evaluator(t)
-        dc = self.domain_cap
-        # mixed-radix group code per row (mirrors K.direct_gid; NULL takes
-        # the reserved 0 code per nullable key, dead rows park at cell 0
-        # and are excluded by the live/weight masks)
+    def _group_codes(self, ev, live):
+        """Mixed-radix group code per row (mirrors K.direct_gid; NULL takes
+        the reserved 0 code per nullable key, dead rows park at cell 0 and
+        are excluded by the live/weight masks)."""
         gid = jnp.zeros(live.shape[0], jnp.int64)
         for (e, _), kmin, krange in zip(self.agg.keys, self.mins,
                                         self.ranges):
@@ -928,9 +936,22 @@ class FusedAggPipeline(_FusedBase):
             if c.valid is not None:
                 code = jnp.where(c.valid, code + 1, 0)
             gid = gid * krange + code
-        gid = jnp.where(live, gid, 0).astype(jnp.int32)
-        occ = jnp.zeros(dc, bool).at[gid].max(live, mode="drop")
-        flat_out = [occ]
+        return jnp.where(live, gid, 0).astype(jnp.int32)
+
+    def _run_agg(self, *flat):
+        t = self._apply_stages(self._flat_inputs(flat))
+        live = t.row_mask()
+        ev = Evaluator(t)
+        dc = self.domain_cap
+        if self.agg.keys:
+            gid = self._group_codes(ev, live)
+            flat_out = [jnp.zeros(dc, bool).at[gid].max(live, mode="drop")]
+        else:
+            # a global aggregate is one run: no ids, so every reduction
+            # below is a masked reduce into cell 0 (K.segment_reduce), and
+            # no occupancy, which only a keyed call() reads
+            gid = None
+            flat_out = []
         agg_meta = []
         for a, name in self.agg.aggs:
             fn = a.fn

@@ -101,7 +101,7 @@ construction):
                     assertion then fails instead of laundering the gap.
 
 Beside the causes, and never added to them, a query's `within_execute`
-names three operator classes whose time the causes above already hold
+names operator classes whose time the causes above already hold
 (a scalar subquery's plan waits, launches and traces like any other):
 
     setop            the SetOp spans' own (exclusive) time, with their
@@ -111,6 +111,8 @@ names three operator classes whose time the causes above already hold
                      session-cache), the columns their scans read, how
                      many yielded NULL
     blocked-union    the `blocked_union` spans: count, windows, ms
+    aggregate-tail   the `pipeline_span`s of aggregate tails by `agg_route`
+                     (whole | scatter | eager): a count, no time
 """
 
 from __future__ import annotations
@@ -145,7 +147,8 @@ def _group_query_events(events) -> dict:
                     "kernel_span", "ingest_chunk", "scan_prune", "lake_pin",
                     "route_request", "host_read", "result_span",
                     "xla_compile", "aot_cache", "exec_cache",
-                    "plan_budget", "scalar_subquery", "blocked_union"):
+                    "plan_budget", "scalar_subquery", "blocked_union",
+                    "pipeline_span"):
             q = ev.get("query") or "<unscoped>"
             out.setdefault(q, []).append(ev)
     return out
@@ -371,7 +374,7 @@ def critical_path(events) -> dict:
         spans = []
         results, reads, compiles, aot_loads, lookups = [], [], [], [], []
         cats, exchanges, spills = [], [], []
-        subqueries, blocked = [], []
+        subqueries, blocked, pipelines = [], [], []
         exch_ms = skew_ms = spill_ms = cat_ms = 0.0
         ladder_ms = backoff_ms = hung_ms = kernel_ms = 0.0
         decode_ms = commit_wait_ms = prune_ms = pin_ms = budget_ms = 0.0
@@ -454,6 +457,8 @@ def critical_path(events) -> dict:
                 subqueries.append(ev)
             elif kind == "blocked_union":
                 blocked.append(ev)
+            elif kind == "pipeline_span":
+                pipelines.append(ev)
             elif kind == "route_request":
                 route_n += 1
                 route_dur_ms += float(ev.get("dur_ms") or 0.0)
@@ -549,7 +554,7 @@ def critical_path(events) -> dict:
             "chain": _op_tree_chain(spans),
             **_execution_detail(spans, results, reads, compiles),
         }
-        within = within_execute(spans, subqueries, blocked)
+        within = within_execute(spans, subqueries, blocked, pipelines)
         if within:
             # views into the causes above, never added to them
             qrec["within_execute"] = within

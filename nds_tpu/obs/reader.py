@@ -360,14 +360,15 @@ def format_host_table(ops, top: int = 4) -> list:
     return lines
 
 
-def within_execute(spans, subqueries, blocked) -> dict:
+def within_execute(spans, subqueries, blocked, pipelines=()) -> dict:
     """The set operations, scalar subqueries and blocked unions of some
     executions, as `profile --per_query` and `--critical-path` print them
     under a query: the SetOp spans' own (exclusive) time by `op` with the
     rows of their sides, the `scalar_subquery` spans by `source`
-    (inclusive of the plan each ran) and the `blocked_union` spans. Views
-    into time the operators and causes already hold, never added to
-    either; empty where the log has none of the three."""
+    (inclusive of the plan each ran), the `blocked_union` spans and, a
+    count with no time of its own, the `pipeline_span`s of aggregate tails
+    by `agg_route`. Views into time the operators and causes already
+    hold, never added to either; empty where the log has none of them."""
     out = {}
     if any("excl_ms" not in e for e in spans):
         spans = op_spans_with_exclusive(spans)
@@ -406,6 +407,12 @@ def within_execute(spans, subqueries, blocked) -> dict:
             "ms": round(sum(float(e.get("dur_ms") or 0.0)
                             for e in blocked), 3),
         }
+    routes = {}
+    for e in pipelines:
+        if e.get("agg_route"):
+            routes[e["agg_route"]] = routes.get(e["agg_route"], 0) + 1
+    if routes:
+        out["aggregate-tail"] = routes
     return out
 
 
@@ -434,6 +441,10 @@ def format_within(within: dict) -> list:
         lines.append(
             f"   blocked-union: {blocked['count']} in "
             f"{blocked['ms']:,.1f} ms, {blocked['windows']} windows")
+    routes = within.get("aggregate-tail")
+    if routes:
+        lines.append("   aggregate-tail: " + ", ".join(
+            f"{route} x{int(n)}" for route, n in sorted(routes.items())))
     return lines
 
 
@@ -479,8 +490,13 @@ def profile_events(events) -> dict:
     collect_total = {"count": 0, "excl_ms": 0.0}
     root_ms = {}  # (app, exec_id) -> the execution's root op_span, ms
 
-    # query -> (SetOp spans, scalar_subquery events, blocked_union events)
+    # query -> (SetOp spans, scalar_subquery events, blocked_union events,
+    # pipeline_span events)
     within = {}
+
+    def within_parts(ev):
+        return within.setdefault(
+            ev.get("query") or "<unscoped>", ([], [], [], []))
 
     def add_launches(ev):
         for kernel, n in (ev.get("launches") or {}).items():
@@ -492,7 +508,7 @@ def profile_events(events) -> dict:
         node = ev.get("node", "?")
         qrec = queries.setdefault(q, dict(_EMPTY_QUERY, ops={}))
         if node == "SetOp":
-            within.setdefault(q, ([], [], []))[0].append(ev)
+            within_parts(ev)[0].append(ev)
         for op in (
             qrec["ops"].setdefault(
                 node, {"count": 0, "incl_ms": 0.0, "excl_ms": 0.0, "rows": 0}
@@ -626,11 +642,9 @@ def profile_events(events) -> dict:
             tallies["faults_injected"] += 1
         elif k == "blocked_union":
             tallies["blocked_union_windows"] += int(ev.get("windows") or 0)
-            within.setdefault(
-                ev.get("query") or "<unscoped>", ([], [], []))[2].append(ev)
+            within_parts(ev)[2].append(ev)
         elif k == "scalar_subquery":
-            within.setdefault(
-                ev.get("query") or "<unscoped>", ([], [], []))[1].append(ev)
+            within_parts(ev)[1].append(ev)
         elif k == "exchange":
             tallies["exchange_ops"] += 1
             tallies["exchange_bytes"] += int(ev.get("bytes_moved") or 0)
@@ -681,6 +695,8 @@ def profile_events(events) -> dict:
             tallies[
                 "pipelines_fused" if ev.get("fused") else "pipelines_eager"
             ] += 1
+            if ev.get("agg_route"):
+                within_parts(ev)[3].append(ev)
         elif k == "result_span":
             add_launches(ev)
             if any(f in ev for f in HOST_FIELDS):

@@ -676,30 +676,145 @@ def group_rows(keys, valids, live_mask, nlive=None):
     return group_by_words(key_words(tuples, live_mask), live_mask, nlive)
 
 
-@_ktraced("segment_reduce")
-@partial(jax.jit, static_argnames=("num_segments", "op"))
-def segment_reduce(vals, gid, weight, num_segments, op):
-    """Segment reduction with a live/validity weight mask.
+# A segment reduction routes by what its caller can state about the ids.
+# `jax.ops.segment_sum` / `segment_min` / `segment_max` are a serial scatter
+# on the v5e, and over int64 (emulated) into colliding cells it costs 62.6 ns
+# an update at 4,194,304 rows into one cell, 82-89 at 16,777,216 sorted rows
+# (PERF.md, Findings PR 44, step 0). Ids that are RUNS need none of it:
+#
+#   gid None          one run, a global aggregate: a masked `jnp.sum` /
+#                     `jnp.min` / `jnp.max` into cell 0, the other cells as
+#                     the scatter leaves them (0, or the extreme). Every
+#                     op and dtype; a row-sharded input lowers to partials
+#                     and one all-reduce. Seam name `reduce_whole`.
+#   runs=(starts, ends)  sorted dense runs (`run_bounds` over what
+#                     `group_by_words` made): `count`, and `sum` over
+#                     integers, are differences of a prefix sum gathered at
+#                     the run ends, in the dtype the scatter accumulates in
+#                     (two's-complement wraparound cancels, so bit for bit
+#                     the scatter's). Seam name `reduce_runs`. A float sum
+#                     (prefix differences cancel), min and max stay on the
+#                     scatter; beside one of those a count still reads the
+#                     prefix, as a launch of its own.
+#   neither           ids in any order: the scatter, as ever
+#                     (`segment_reduce`, `segment_reduce_with_count`).
+#
+# Nothing is detected on the device and nothing is a knob: the caller says
+# what it knows, and the op and the dtype are static.
 
-    op: sum | min | max | count | sumsq
-    """
+
+def _masked(vals, weight, op):
+    """(operand, identity) of `op` with dead / NULL rows neutralized."""
     if op == "count":
-        return jax.ops.segment_sum(weight.astype(I64), gid, num_segments)
+        return weight.astype(I64), jnp.zeros((), I64)
     if op == "sum":
-        v = jnp.where(weight, vals, jnp.zeros((), vals.dtype))
-        return jax.ops.segment_sum(v, gid, num_segments)
+        zero = jnp.zeros((), vals.dtype)
+        return jnp.where(weight, vals, zero), zero
     if op == "sumsq":
-        v = jnp.where(weight, vals.astype(jnp.float64) ** 2, 0.0)
-        return jax.ops.segment_sum(v, gid, num_segments)
+        zero = jnp.zeros((), jnp.float64)
+        return jnp.where(weight, vals.astype(jnp.float64) ** 2, zero), zero
+    if op in ("min", "max"):
+        ident = _extreme(vals.dtype, op == "min")
+        return jnp.where(weight, vals, ident), ident
+    raise ValueError(op)
+
+
+def _scatter_one(vals, gid, weight, num_segments, op):
+    v, _ = _masked(vals, weight, op)
     if op == "min":
-        big = _extreme(vals.dtype, True)
-        v = jnp.where(weight, vals, big)
         return jax.ops.segment_min(v, gid, num_segments)
     if op == "max":
-        small = _extreme(vals.dtype, False)
-        v = jnp.where(weight, vals, small)
         return jax.ops.segment_max(v, gid, num_segments)
-    raise ValueError(op)
+    return jax.ops.segment_sum(v, gid, num_segments)
+
+
+def _program(name):
+    """Name the function that follows `name` before it is jitted: a device
+    trace calls its programs `jit_<name>/...`, and the two scatters keep
+    the names every breakdown since PR 24 has read them under, whatever the
+    dispatcher in front of them is called."""
+    def deco(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+    return deco
+
+
+@_ktraced("segment_reduce")
+@partial(jax.jit, static_argnames=("num_segments", "op"))
+@_program("segment_reduce")
+def _reduce_scatter(vals, gid, weight, num_segments, op):
+    return _scatter_one(vals, gid, weight, num_segments, op)
+
+
+@_ktraced("segment_reduce_with_count")
+@partial(jax.jit, static_argnames=("num_segments", "op"))
+@_program("segment_reduce_with_count")
+def _reduce_scatter_with_count(vals, gid, weight, num_segments, op):
+    return (
+        _scatter_one(vals, gid, weight, num_segments, op),
+        _scatter_one(vals, gid, weight, num_segments, "count"),
+    )
+
+
+@_ktraced("reduce_whole")
+@partial(jax.jit, static_argnames=("num_segments", "ops"))
+def _reduce_whole(vals, weight, num_segments, ops):
+    first = jnp.arange(num_segments, dtype=jnp.int32) == 0
+    out = []
+    for op in ops:
+        v, ident = _masked(vals, weight, op)
+        if op == "min":
+            r = jnp.min(v)
+        elif op == "max":
+            r = jnp.max(v)
+        else:
+            r = jnp.sum(v, dtype=v.dtype)
+        out.append(jnp.where(first, r, ident))
+    return tuple(out)
+
+
+@_ktraced("reduce_runs")
+@partial(jax.jit, static_argnames=("ops",))
+def _reduce_runs(vals, weight, runs, ops):
+    starts, ends = runs
+    last = weight.shape[0] - 1
+    hi = jnp.clip(ends - 1, 0, last)
+    lo = jnp.clip(starts - 1, 0, last)
+    out = []
+    for op in ops:
+        if op == "count":  # n < 2**31 rows: an int32 prefix holds them
+            v = weight.astype(jnp.int32)
+        else:
+            v, _ = _masked(vals, weight, op)
+        c = fast_cumsum(v)
+        zero = jnp.zeros((), c.dtype)
+        r = jnp.where(
+            ends > starts, c[hi] - jnp.where(starts > 0, c[lo], zero), zero
+        )
+        out.append(r.astype(I64) if op == "count" else r)
+    return tuple(out)
+
+
+def _prefix_exact(vals, op) -> bool:
+    """Ops a difference of prefix sums answers bit for bit as the scatter
+    does: every count, and a sum over integers (decimals are)."""
+    return op == "count" or (
+        op == "sum" and jnp.issubdtype(vals.dtype, jnp.integer)
+    )
+
+
+def segment_reduce(vals, gid, weight, num_segments, op, runs=None):
+    """Segment reduction with a live/validity weight mask.
+
+    op: sum | min | max | count | sumsq. `gid` None: one segment, cell 0
+    of the answer. `runs`: the `(starts, ends)` of sorted dense runs
+    (`run_bounds`), beside the `gid` they were read from. The answer has
+    `num_segments` cells whichever route computed it (comment above)."""
+    if gid is None:
+        return _reduce_whole(vals, weight, num_segments, (op,))[0]
+    if runs is not None and _prefix_exact(vals, op):
+        return _reduce_runs(vals, weight, runs, (op,))[0]
+    return _reduce_scatter(vals, gid, weight, num_segments, op)
 
 
 def _extreme(dtype, is_max):
@@ -709,18 +824,24 @@ def _extreme(dtype, is_max):
     return jnp.asarray(jnp.inf if is_max else -jnp.inf, dtype)
 
 
-@_ktraced("segment_reduce_with_count")
-@partial(jax.jit, static_argnames=("num_segments", "op"))
-def segment_reduce_with_count(vals, gid, weight, num_segments, op):
-    """(reduction, live count) per segment in ONE dispatch.
+def segment_reduce_with_count(vals, gid, weight, num_segments, op,
+                              runs=None):
+    """(reduction, live count) per segment in ONE dispatch (two where the
+    reduction scatters beside a count that need not).
 
     Every non-count aggregate needs both — the count drives SQL
     NULL-on-empty output validity — and issuing them as two jitted calls
     paid a second dispatch and let XLA re-derive the masked operand
     instead of sharing it."""
+    if gid is None:
+        return _reduce_whole(vals, weight, num_segments, (op, "count"))
+    if runs is None:
+        return _reduce_scatter_with_count(vals, gid, weight, num_segments, op)
+    if _prefix_exact(vals, op):
+        return _reduce_runs(vals, weight, runs, (op, "count"))
     return (
-        segment_reduce(vals, gid, weight, num_segments, op),
-        segment_reduce(vals, gid, weight, num_segments, "count"),
+        _reduce_scatter(vals, gid, weight, num_segments, op),
+        _reduce_runs(vals, weight, runs, ("count",))[0],
     )
 
 
@@ -1018,6 +1139,40 @@ def segment_starts(gid, num_segments):
     n = gid.shape[0]
     idx = jnp.arange(n, dtype=jnp.int32)
     return jax.ops.segment_min(idx, gid, num_segments)
+
+
+@jax.jit
+def _run_flags(gid, live):
+    """True at the first row of every live run of a sorted `gid`."""
+    return jnp.concatenate([jnp.ones(1, bool), gid[1:] != gid[:-1]]) & live
+
+
+@jax.jit
+def _run_ends(starts, live, ngroups):
+    g = jnp.arange(starts.shape[0], dtype=jnp.int32)
+    nlive = jnp.sum(live, dtype=jnp.int32)
+    following = jnp.concatenate([starts[1:], nlive[None]])
+    # the last live run, and every cell past it, ends at the live count
+    return (
+        jnp.where(g < ngroups, starts, nlive),
+        jnp.where(g + 1 < ngroups, following, nlive),
+    )
+
+
+def run_bounds(gid, live, num_segments, ngroups):
+    """`(starts, ends)` of the runs of a sorted dense `gid` as
+    `group_by_words` makes it (non-decreasing, live rows first, the live
+    groups 0 .. ngroups - 1): run g is rows [starts[g], ends[g]); a cell
+    past `ngroups` reads start = end = the live count, an empty run. What
+    `segment_reduce(..., runs=)` takes; None for ids sharded over a mesh,
+    which stay on the scatter as a sharded compaction does. The starts are
+    the boundary flags compacted (`ngroups` <= `num_segments` of them, so
+    block select at a fact table's rows), not `segment_starts`' scatter-min
+    of every row's index."""
+    if _multi_device(gid):
+        return None
+    starts = compact_indices(_run_flags(gid, live), num_segments)
+    return _run_ends(starts, live, jnp.int32(ngroups))
 
 
 @partial(jax.jit, static_argnames=())

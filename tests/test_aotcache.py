@@ -153,6 +153,44 @@ def test_environment_key_mismatch_is_clean_miss(tmp_path):
     assert cache.stats["quarantined"] == 0
 
 
+@pytest.mark.parametrize("writer", ["parent", "revision_1"])
+def test_an_older_aggregate_body_s_entry_is_a_clean_miss(
+        tmp_path, monkeypatch, writer):
+    """`FusedAggPipeline._run_agg`'s body is in no other part of the key,
+    so its revision is (`BODY_REV`, in the kind): a directory warmed by a
+    tree whose keyless tail scattered (the parent wrote kind
+    `agg_pipeline`) must not answer for the tail that reduces whole: a
+    clean miss, the old entry left where it is."""
+    from nds_tpu.engine import fuse
+
+    q = "select count(*) c, sum(v) s, avg(v) a from t where v > -50"
+    init_aot = fuse._FusedBase._init_aot
+    with monkeypatch.context() as m:
+        if writer == "parent":
+            m.setattr(
+                fuse._FusedBase, "_init_aot",
+                lambda self, aot, fp, conf_sig, sample, kind, with_stats:
+                init_aot(self, aot, fp, conf_sig, sample,
+                         kind.split(".")[0], with_stats),
+            )
+        else:
+            m.setattr(fuse.FusedAggPipeline, "BODY_REV", 1)
+        old = _session(tmp_path)
+        ref = old.sql(q).collect().to_pylist()
+        assert old.aot_cache.stats["stores"] >= 1
+        # the same body finds its entry again: the directory is warm
+        again = _session(tmp_path)
+        assert again.sql(q).collect().to_pylist() == ref
+        assert again.aot_cache.stats["disk_hits"] >= 1
+        assert again.aot_cache.stats["misses"] == 0
+
+    new = _session(tmp_path)
+    assert new.sql(q).collect().to_pylist() == ref
+    assert new.aot_cache.stats["disk_hits"] == 0
+    assert new.aot_cache.stats["misses"] >= 1
+    assert new.aot_cache.stats["quarantined"] == 0
+
+
 def test_filename_collision_reads_as_miss_not_wrong_load(tmp_path):
     """A file whose NAME matches but whose recorded key differs (hash
     collision / foreign entry) must read as a miss: load verifies the

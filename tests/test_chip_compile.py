@@ -155,6 +155,45 @@ def test_block_select_compiles_for_v5e_and_holds_nothing_of_n_rows(
 
 
 # ---------------------------------------------------------------------------
+# a reduction over runs at inventory's capacity (query22's ROLLUP) and at
+# store_sales' (query9's global aggregates): no scatter in what the v5e's
+# compiler makes of them, and temporaries of a few columns at most
+# ---------------------------------------------------------------------------
+
+INVENTORY_CAP = 16_777_216
+
+
+@pytest.mark.parametrize("dtype", ["int32", "int64"])
+@pytest.mark.parametrize("route,n", [
+    ("whole", FACT_CAP), ("runs", INVENTORY_CAP),
+])
+def test_run_reductions_compile_for_v5e_without_a_scatter(
+    one_chip, route, n, dtype
+):
+    from nds_tpu.ops import kernels as K
+
+    gcap = 32_768
+    ops = ("sum", "count")
+    if route == "whole":
+        compiled = _compile(
+            lambda v, w: K._reduce_whole.__wrapped__(v, w, 1_024, ops),
+            one_chip, ((n,), jnp.dtype(dtype)), ((n,), jnp.bool_),
+        )
+    else:
+        compiled = _compile(
+            lambda v, w, s, e: K._reduce_runs.__wrapped__(v, w, (s, e), ops),
+            one_chip, ((n,), jnp.dtype(dtype)), ((n,), jnp.bool_),
+            ((gcap,), jnp.int32), ((gcap,), jnp.int32),
+        )
+    hlo = compiled.as_text()
+    assert " scatter(" not in hlo
+    if route == "whole":
+        assert " gather(" not in hlo
+    # the prefix sums of a value column and of a count, blocked twice
+    assert compiled.memory_analysis().temp_size_in_bytes <= 6 * 8 * n
+
+
+# ---------------------------------------------------------------------------
 # fused pipelines: built as the engine builds them, over a small sample of
 # store_sales' column types, then lowered at the SF1 capacity bucket
 # ---------------------------------------------------------------------------
@@ -175,9 +214,15 @@ _PIPELINES = {
         "where ss_quantity > 10 group by ss_store_sk",
         "FusedAggPipeline",
     ),
-    # the cheapest statement: one global count (q96)
+    # the cheapest statement: one global count (q96; q9's first shape)
     "global_count": (
         "select count(*) from store_sales where ss_quantity between 5 and 60",
+        "FusedAggPipeline",
+    ),
+    # q9's second shape: a global average over a range of the fact table
+    "global_avg": (
+        "select avg(ss_ext_sales_price) from store_sales "
+        "where ss_quantity between 21 and 40",
         "FusedAggPipeline",
     ),
 }
@@ -234,6 +279,9 @@ def test_fused_pipeline_compiles_for_v5e(one_chip, name, tmp_path,
         assert any(FACT_CAP in shape for shape, _ in shapes)
         compiled = _compile(fn, one_chip, *shapes)
         assert compiled.memory_analysis() is not None
+        if name.startswith("global_"):
+            # a keyless tail is one run: a masked reduce, no scatter
+            assert " scatter(" not in compiled.as_text()
 
 
 # ---------------------------------------------------------------------------

@@ -175,6 +175,59 @@ def test_compact_indices_sharded_matches_replicated(mesh):
                 np.testing.assert_array_equal(a, b, err_msg=str((n, frac, cap)))
 
 
+@pytest.mark.parametrize("op", ["sum", "count", "min", "max"])
+@pytest.mark.parametrize("dtype", ["int64", "int32", "float64"])
+def test_one_run_reduces_row_sharded_rows_as_replicated_ones(mesh, op, dtype):
+    """A global aggregate has no ids: `segment_reduce(vals, None, ...)` is
+    a masked reduce, which over a row-sharded column lowers to partials
+    and one all-reduce and answers what the replicated column answers
+    (and, cell for cell, what the scatter into cell 0 does)."""
+    from nds_tpu.ops import kernels as K
+
+    shard = NamedSharding(mesh, P("data"))
+    rng = np.random.default_rng(44)
+    n = 1024 * N_DEV
+    vals_np = (rng.normal(size=n) * 1e3).astype(dtype)
+    w_np = (np.arange(n) < n - 300) & (rng.random(n) < 0.8)
+    vals_s = jax.device_put(jnp.asarray(vals_np), shard)
+    w_s = jax.device_put(jnp.asarray(w_np), shard)
+    got = K.segment_reduce_with_count(vals_s, None, w_s, 1024, op)
+    rep = K.segment_reduce_with_count(
+        jnp.asarray(vals_np), None, jnp.asarray(w_np), 1024, op)
+    old = K.segment_reduce_with_count(
+        jnp.asarray(vals_np), jnp.zeros(n, jnp.int32), jnp.asarray(w_np),
+        1024, op)
+    for g, r, o in zip(got, rep, old):
+        assert g.dtype == r.dtype == o.dtype and g.shape == (1024,)
+        if dtype == "float64" and op == "sum":
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       rtol=1e-12)
+            np.testing.assert_allclose(np.asarray(g), np.asarray(o),
+                                       rtol=1e-12)
+        else:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(o))
+
+
+def test_sorted_runs_over_a_mesh_keep_the_scatter(mesh):
+    """Sharded ids get no run bounds (as a sharded mask gets no block
+    select), so the sort route's reductions stay on the scatter there."""
+    from nds_tpu.ops import kernels as K
+
+    shard = NamedSharding(mesh, P("data"))
+    n = 1024 * N_DEV
+    gid_np = (np.arange(n) // 700).astype(np.int32)
+    live = jnp.arange(n) < n
+    assert K.run_bounds(jax.device_put(jnp.asarray(gid_np), shard),
+                        jax.device_put(live, shard), 1024, 12) is None
+    starts, ends = K.run_bounds(jnp.asarray(gid_np), live, 1024, 12)
+    np.testing.assert_array_equal(np.asarray(starts)[:12],
+                                  np.arange(12) * 700)
+    np.testing.assert_array_equal(np.asarray(ends)[:11],
+                                  np.arange(1, 12) * 700)
+    assert int(ends[11]) == n and int(starts[12]) == int(ends[12]) == n
+
+
 def test_multihost_single_process_degenerates(mesh):
     """multihost utilities: in a 1-process world initialize() is a no-op,
     global_mesh covers the local devices, and shard_rows_across_hosts is a

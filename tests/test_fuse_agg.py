@@ -499,3 +499,134 @@ def test_pallas_mode_keeps_chain_fusion():
     for x, y in zip(a, b):
         assert x["k"] == y["k"]
         assert x["sf"] == pytest.approx(y["sf"], rel=1e-9)
+
+
+# ---------------------------------------------------------------------------
+# a keyless tail is one run: it reduces whole and scatters nothing (PR 44)
+# ---------------------------------------------------------------------------
+
+# query9's two subquery shapes (a count and an average over a range of a
+# fact column), a mixed tail, and a float64 measure
+KEYLESS_TAILS = {
+    "count": "select count(*) c from t where v between 1 and 20",
+    "avg": "select avg(amt) a from t where v between 1 and 20",
+    "mixed": "select count(*) c, count(v) cv, sum(v) sv, avg(v) av, "
+             "min(v) mn, max(amt) mx, min(cat) mc from t where v > -60",
+    "float64": "select sum(cast(v as double)) s, avg(cast(v as double)) a, "
+               "max(cast(v as double)) m from t where v > -60",
+    "empty": "select count(*) c, sum(v) sv, avg(amt) a from t where v > 1000",
+}
+
+
+def _scatters(jaxpr, found):
+    import jax
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("scatter"):
+            found.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _scatters(sub, found)
+    return found
+
+
+def _dispatched(monkeypatch, session, sql):
+    """[(FusedAggPipeline, its flat arguments)] of the aggregate tails a
+    statement dispatched, and its answer."""
+    from nds_tpu.engine import fuse
+
+    seen = []
+    dispatch = fuse.FusedAggPipeline._dispatch
+
+    def spy(self, flat, slots):
+        seen.append((self, flat))
+        return dispatch(self, flat, slots)
+
+    monkeypatch.setattr(fuse.FusedAggPipeline, "_dispatch", spy)
+    return seen, session.sql(sql).collect()
+
+
+@pytest.mark.parametrize("shape", sorted(KEYLESS_TAILS))
+def test_keyless_tail_scatters_nothing_and_equals_eager(shape, monkeypatch):
+    import jax
+
+    on, off = _sessions()
+    q = KEYLESS_TAILS[shape]
+    seen, got = _dispatched(monkeypatch, on, q)
+    assert got.equals(off.sql(q).collect()), q
+    assert len(seen) == 1
+    entry, flat = seen[0]
+    assert entry.agg_route == "whole"
+    jaxpr = jax.make_jaxpr(entry._run_agg)(*flat)
+    assert _scatters(jaxpr.jaxpr, []) == []
+    # the layout holds the aggregates' slots and no occupancy before them
+    slots = {s for *_, s1, s2 in entry.agg_meta for s in (s1, s2)
+             if s is not None}
+    assert slots == set(range(len(jaxpr.out_avals)))
+    assert all(a.shape == (1024,) for a in jaxpr.out_avals)
+
+
+def test_keyed_tail_still_scatters_by_its_codes(monkeypatch):
+    import jax
+
+    on, _ = _sessions()
+    seen, _ = _dispatched(
+        monkeypatch, on, "select k, sum(v) sv, count(*) c from t group by k")
+    entry, flat = seen[0]
+    assert entry.agg_route == "scatter"
+    found = _scatters(jax.make_jaxpr(entry._run_agg)(*flat).jaxpr, [])
+    assert "scatter-max" in found and "scatter-add" in found
+    assert entry.agg_meta[0][4] == 1  # slot 0 is the occupancy
+
+
+def test_query9_shape_fused_equals_eager():
+    """Scalar subqueries, each a keyless aggregate over a range of the
+    fact table, under a CASE: query9's shape."""
+    on, off = _sessions()
+    q = ("select case when (select count(*) from t where v between 1 and 20) "
+         "> 100 then (select avg(amt) from t where v between 1 and 20) "
+         "else (select avg(v) from t where v between 1 and 20) end b1, "
+         "case when (select count(*) from t where v between 21 and 40) "
+         "> 100000 then (select avg(amt) from t where v between 21 and 40) "
+         "else (select avg(v) from t where v between 21 and 40) end b2 "
+         "from u where k = 1 limit 1")
+    assert on.sql(q).collect().equals(off.sql(q).collect())
+
+
+@pytest.mark.parametrize("fuse_conf", ["on", "off"])
+def test_the_spans_name_the_route(fuse_conf):
+    """A global aggregate's reductions run under `reduce_whole` on the
+    eager path and inside one fused launch on the fused one, whose
+    `pipeline_span` says `agg_route` whole; a sorted aggregation's counts
+    and integer sums run under `reduce_runs`; `profile --per_query`
+    prints both."""
+    from nds_tpu.obs import reader as R
+    from nds_tpu.obs.trace import Tracer
+
+    s = Session(conf={"engine.fuse": fuse_conf})
+    s.tracer = tracer = Tracer()
+    s.register_arrow("t", _table(2000))
+    s.sql("select count(*) c, avg(amt) a from t where v > 0").collect()
+    # a float key has no static bounds: the sort route
+    s.sql("select cast(v as double) d, count(*) c, sum(v) sv, min(amt) mn "
+          "from t group by cast(v as double)").collect()
+    launches = {}
+    for ev in tracer.events:
+        if ev["kind"] in ("op_span", "result_span"):
+            for kernel, n in ev["launches"].items():
+                launches[kernel] = launches.get(kernel, 0) + n
+    routes = [e.get("agg_route") for e in tracer.events
+              if e["kind"] == "pipeline_span" and e.get("agg")]
+    if fuse_conf == "on":
+        assert routes[0] == "whole"
+        assert "reduce_whole" not in launches  # traced inside the pipeline
+    else:
+        assert launches["reduce_whole"] == 2 and not routes
+    # count(*), sum(v) + its count; min(amt) scatters beside its count
+    assert launches["reduce_runs"] == 3
+    assert launches["segment_reduce"] == 1
+    assert "segment_reduce_with_count" not in launches
+    prof = R.profile_events(tracer.events)
+    lines = [ln for q in prof["queries"].values()
+             for ln in R.format_within(q.get("within_execute") or {})]
+    assert any("aggregate-tail" in ln and "whole x1" in ln
+               for ln in lines) == (fuse_conf == "on")

@@ -267,6 +267,209 @@ class TestGroup:
             assert counts[g] == len(sel)
 
 
+class TestRunReduce:
+    """A segment reduction whose ids are runs does not scatter (PR 44):
+    `gid` None is one run, `runs=` the bounds of sorted dense runs. Every
+    answer is held to the scatter's, cell for cell and dtype for dtype."""
+
+    CAP = 4_096
+    DTYPES = ("int32", "int64", "decimal", "float64", "bool")
+    # (live rows, share of live rows whose weight is dead)
+    INPUTS = {"rows": (3_000, 0.2), "one_row": (1, 0.0), "one_null": (1, 1.0),
+              "empty": (0, 0.0), "all_dead": (3_000, 1.0), "full": (4_096, 0.1)}
+
+    @staticmethod
+    def _vals(dtype, cap, wrap=False):
+        r = np.random.default_rng(7)
+        if dtype == "float64":
+            return r.normal(size=cap) * 1e6
+        if dtype == "bool":
+            return r.random(cap) < 0.5
+        if dtype == "int32":
+            lo, hi = (2**31 - 1_000, 2**31 - 1) if wrap else (-2**31, 2**31 - 1)
+            return r.integers(lo, hi, cap).astype(np.int32)
+        if wrap:  # sums pass 2**63: two's complement wraps in both routes
+            return r.integers(2**62, 2**63 - 1, cap).astype(np.int64)
+        if dtype == "decimal":  # unscaled decimal(15,2) values
+            return r.integers(-10**14, 10**14, cap).astype(np.int64)
+        return r.integers(-2**40, 2**40, cap).astype(np.int64)
+
+    @staticmethod
+    def _ops(dtype):
+        return ("count",) if dtype == "bool" else (
+            "sum", "min", "max", "count", "sumsq")
+
+    @staticmethod
+    def _same(got, want, exact):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        if exact:
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        else:
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(want), rtol=1e-12)
+
+    def _weight(self, nlive, dead_share, nullable):
+        r = np.random.default_rng(11)
+        live = np.arange(self.CAP) < nlive
+        if not nullable and dead_share < 1.0:
+            return jnp.asarray(live)
+        return jnp.asarray(live & (r.random(self.CAP) >= dead_share))
+
+    @pytest.mark.parametrize("nullable", [False, True])
+    @pytest.mark.parametrize("shape", sorted(INPUTS))
+    @pytest.mark.parametrize("dtype", DTYPES)
+    def test_one_run_answers_what_the_scatter_answers(
+            self, dtype, shape, nullable):
+        vals = jnp.asarray(self._vals(dtype, self.CAP))
+        weight = self._weight(*self.INPUTS[shape], nullable)
+        zeros = jnp.zeros(self.CAP, jnp.int32)
+        for op in self._ops(dtype):
+            # a float sum reduces pairwise where the scatter adds in row
+            # order: as exact or better, not bit for bit
+            exact = not (op == "sumsq" or (dtype == "float64" and op == "sum"))
+            want = K.segment_reduce(vals, zeros, weight, 1_024, op)
+            self._same(K.segment_reduce(vals, None, weight, 1_024, op),
+                       want, exact)
+            if op in ("sum", "min", "max"):
+                got = K.segment_reduce_with_count(vals, None, weight, 1_024, op)
+                want = K.segment_reduce_with_count(vals, zeros, weight, 1_024,
+                                                   op)
+                self._same(got[0], want[0], exact)
+                self._same(got[1], want[1], True)
+
+    def _sorted_ids(self, nlive, ngroups):
+        """Ids as `group_by_words` leaves them: dense and non-decreasing
+        over the live rows, going on past `ngroups` over the dead tail."""
+        r = np.random.default_rng(13)
+        cuts = np.sort(r.choice(np.arange(1, max(nlive, 2)),
+                                max(min(ngroups, nlive) - 1, 0), replace=False))
+        flags = np.zeros(self.CAP, np.int32)
+        flags[cuts] = 1
+        flags[nlive:] = r.random(self.CAP - nlive) < 0.3
+        return jnp.asarray(np.cumsum(flags, dtype=np.int32))
+
+    @pytest.mark.parametrize("gcap", [64, 1_024])
+    @pytest.mark.parametrize("nullable", [False, True])
+    @pytest.mark.parametrize("shape", ["rows", "one_row", "all_dead", "full"])
+    @pytest.mark.parametrize("dtype", DTYPES)
+    def test_sorted_runs_answer_what_the_scatter_answers(
+            self, dtype, shape, nullable, gcap):
+        """Dead rows sorted last with ids of their own, `gcap` above
+        `ngroups`: the cells past the live groups read what the scatter
+        leaves there."""
+        nlive, dead_share = self.INPUTS[shape]
+        vals = jnp.asarray(self._vals(dtype, self.CAP))
+        weight = self._weight(nlive, dead_share, nullable)
+        live = jnp.arange(self.CAP) < nlive
+        gid = self._sorted_ids(nlive, 37)
+        ngroups = int(gid[nlive - 1]) + 1
+        runs = K.run_bounds(gid, live, gcap, ngroups)
+        np.testing.assert_array_equal(
+            np.asarray(runs[0])[:ngroups],
+            np.asarray(K.segment_starts(gid, gcap))[:ngroups])
+        for op in self._ops(dtype):
+            self._same(
+                K.segment_reduce(vals, gid, weight, gcap, op, runs),
+                K.segment_reduce(vals, gid, weight, gcap, op), True)
+            if op in ("sum", "min", "max"):
+                got = K.segment_reduce_with_count(vals, gid, weight, gcap, op,
+                                                  runs)
+                want = K.segment_reduce_with_count(vals, gid, weight, gcap, op)
+                self._same(got[0], want[0], True)
+                self._same(got[1], want[1], True)
+
+    @pytest.mark.parametrize("dtype", ["int32", "int64"])
+    @pytest.mark.parametrize("route", ["whole", "runs"])
+    def test_a_sum_that_wraps_wraps_as_the_scatter_does(self, route, dtype):
+        vals = jnp.asarray(self._vals(dtype, self.CAP, wrap=True))
+        live = jnp.arange(self.CAP) < 4_000
+        if route == "whole":
+            gid, runs, known = jnp.zeros(self.CAP, jnp.int32), None, None
+        else:
+            gid = known = self._sorted_ids(4_000, 5)
+            runs = K.run_bounds(gid, live, 64, 5)
+        want = K.segment_reduce(vals, gid, live, 64, "sum")
+        exact = int(np.asarray(vals)[:4_000].astype(object).sum())
+        assert exact > np.iinfo(np.asarray(vals).dtype).max  # it does wrap
+        self._same(K.segment_reduce(vals, known, live, 64, "sum", runs),
+                   want, True)
+
+    @staticmethod
+    def _launched(fn):
+        from nds_tpu.obs import tally as T
+        from nds_tpu.obs.trace import Tracer
+
+        tl = T.Tally(Tracer(), 1)
+        with T.bind(tl):
+            fn()
+        return tl.launches
+
+    @pytest.mark.parametrize("known,op,dtype,names", [
+        ("none", "sum", "int64", {"reduce_whole": 1}),
+        ("none", "min", "float64", {"reduce_whole": 1}),
+        ("runs", "sum", "int32", {"reduce_runs": 1}),
+        # a float sum and an extreme cancel / have no prefix: the scatter,
+        # and beside it the count by the prefix, a launch of its own
+        ("runs", "sum", "float64", {"segment_reduce": 1, "reduce_runs": 1}),
+        ("runs", "max", "int64", {"segment_reduce": 1, "reduce_runs": 1}),
+        ("unknown", "sum", "int64", {"segment_reduce_with_count": 1}),
+    ])
+    def test_the_seam_names_the_route(self, known, op, dtype, names):
+        vals = jnp.asarray(self._vals(dtype, self.CAP))
+        live = jnp.arange(self.CAP) < 3_000
+        gid = self._sorted_ids(3_000, 9)
+        runs = K.run_bounds(gid, live, 64, 9) if known == "runs" else None
+        assert self._launched(lambda: K.segment_reduce_with_count(
+            vals, None if known == "none" else gid, live, 64, op, runs
+        )) == names
+
+    @pytest.mark.parametrize("known,op,dtype", [
+        ("none", "sum", "int64"), ("none", "sum", "float64"),
+        ("none", "count", "int64"), ("none", "min", "int32"),
+        ("none", "max", "float64"), ("none", "sumsq", "float64"),
+        ("runs", "sum", "int64"), ("runs", "sum", "int32"),
+        ("runs", "count", "int64"),
+    ])
+    def test_a_reduction_over_runs_scatters_nothing(self, known, op, dtype):
+        """The cost model, held by the programs' jaxprs at query22's
+        shape: one run streams; sorted runs stream a prefix sum and gather
+        at the `gcap` run ends. A scatter of n rows again fails here, not
+        on the chip."""
+        n, gcap = 16_777_216, 32_768
+        vals = jax.ShapeDtypeStruct((n,), jnp.dtype(dtype))
+        weight = jax.ShapeDtypeStruct((n,), jnp.bool_)
+        bound = jax.ShapeDtypeStruct((gcap,), jnp.int32)
+        row_ops = TestCompactSelect._row_ops
+        if known == "none":
+            jaxpr = jax.make_jaxpr(lambda v, w: K.segment_reduce_with_count(
+                v, None, w, gcap, op) if op in ("sum", "min", "max")
+                else K.segment_reduce(v, None, w, gcap, op))(vals, weight)
+            assert row_ops(jaxpr.jaxpr, []) == []
+            return
+        jaxpr = jax.make_jaxpr(lambda v, w, s, e: K.segment_reduce(
+            v, jnp.zeros(n, jnp.int32), w, gcap, op, (s, e)
+        ))(vals, weight, bound, bound)
+        assert sorted(set(row_ops(jaxpr.jaxpr, []))) == [("gather", gcap)]
+        scattered = jax.make_jaxpr(lambda v, w: K.segment_reduce(
+            v, jnp.zeros(n, jnp.int32), w, gcap, op))(vals, weight)
+        assert ("scatter-add", n) in row_ops(scattered.jaxpr, [])
+
+    def test_sharded_ids_keep_the_scatter_and_one_run_reduces_sharded(self):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+        mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+        rows = NamedSharding(mesh, PartitionSpec("data"))
+        vals_h = self._vals("int64", self.CAP)
+        live_h = np.arange(self.CAP) < 3_000
+        gid = jax.device_put(self._sorted_ids(3_000, 9), rows)
+        vals = jax.device_put(jnp.asarray(vals_h), rows)
+        live = jax.device_put(jnp.asarray(live_h), rows)
+        assert K.run_bounds(gid, live, 64, 9) is None
+        got = K.segment_reduce_with_count(vals, None, live, 1_024, "sum")
+        assert int(got[0][0]) == int(vals_h[live_h].sum())
+        assert int(got[1][0]) == 3_000 and not np.asarray(got[0])[1:].any()
+
+
 class TestJoin:
     def _join_np(self, lk, rk):
         pairs = []
